@@ -2,8 +2,8 @@
 #
 # Test-suite wall time is CPU-bound (the XLA:CPU backend compiles and
 # runs every test's programs; user time ~= real time on 1 core). The
-# persistent compilation cache (.jax_cache, wired in tests/conftest.py
-# and inherited by subprocess worlds) cuts repeat-run compile cost; on
+# persistent compilation cache (.jax_cache, the package's own default —
+# paddle_tpu/runtime/compile_cache.py) cuts repeat-run compile cost; on
 # multi-core hosts `make test` shards test FILES across xdist workers
 # for near-linear speedup (file granularity is xdist-safe by
 # construction).
@@ -24,7 +24,7 @@ DIST_FLAGS := -n auto --dist loadfile
 endif
 endif
 
-.PHONY: test test-fast test-seq bench check lint trace-smoke debugz-smoke mfu-smoke serve-smoke gen-smoke router-smoke chaos-smoke tracez-smoke kernel-smoke quant-smoke spec-smoke memplan-smoke autotune-smoke ir-opt-smoke slo-smoke goodput-smoke opprof-smoke paged-smoke bench-trend
+.PHONY: test test-fast test-seq bench check lint trace-smoke debugz-smoke mfu-smoke serve-smoke gen-smoke router-smoke chaos-smoke tracez-smoke kernel-smoke quant-smoke spec-smoke memplan-smoke autotune-smoke ir-opt-smoke slo-smoke goodput-smoke opprof-smoke paged-smoke chip-smoke bench-trend
 
 lint:  # graphlint gate: pure-AST framework lint, waivers must justify every exception
 	python tools/graphlint.py --check
@@ -94,6 +94,9 @@ opprof-smoke:  # per-op attribution: >=0.9 coverage, time-accuracy envelope, mea
 
 paged-smoke:  # paged KV: ring parity at bounded compiles, shared-prefix FLOPs+TTFT win, >=1.3x slots at equal HBM, strict pool admission
 	JAX_PLATFORMS=cpu python tools/paged_smoke.py
+
+chip-smoke:  # the main path once on the TPU, full width, one process; exits 1 without a chip (no JAX_PLATFORMS here on purpose)
+	python chip_smoke.py
 
 bench-trend:  # compare the two newest BENCH_r*.json, warn on >20% headline regressions
 	python tools/bench_trend.py

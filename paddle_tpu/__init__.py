@@ -238,3 +238,9 @@ from . import reader  # noqa: E402  (reader decorator library, paddle.reader)
 from . import nets  # noqa: E402  (composite helpers, fluid/nets.py)
 from . import flags as _flags_mod  # noqa: E402
 from .flags import get_flags, set_flags  # noqa: E402  (core.globals() API)
+
+# one compile-cache location for every program that imports the package
+# (runtime/compile_cache.py); nothing has compiled yet at this point
+from .runtime import compile_cache as _compile_cache  # noqa: E402
+
+_compile_cache.apply()

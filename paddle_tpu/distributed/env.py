@@ -47,12 +47,7 @@ def _distributed_client_active() -> bool:
     """Whether jax.distributed.initialize already ran — checked WITHOUT
     touching the XLA backend (jax.process_count() would initialize it,
     which forbids a later jax.distributed.initialize)."""
-    try:
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-    except Exception:
-        return False
+    return jax.distributed.is_initialized()
 
 
 def get_rank() -> int:

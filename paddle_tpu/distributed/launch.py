@@ -9,6 +9,11 @@ processes == hosts, not devices. ``spawn`` exists for multi-host emulation
 and CPU-mesh testing (SURVEY.md §4: subprocess tests on localhost); on a
 real pod each host runs the same script and jax.distributed coordinates.
 
+One process for each chip: ``--nproc N`` starts N copies of ONE environment,
+and a TPU chip belongs to one process at a time, so ``--nproc`` above 1 on
+one TPU host is unsupported (the second rank fails or hangs waiting for the
+chip). Use it with ``JAX_PLATFORMS=cpu``, or with one process per host.
+
 Usage: python -m paddle_tpu.distributed.launch --nproc 2 train.py
 
 Fault diagnosis: ``--debug-port 8080`` hands every rank a live debug

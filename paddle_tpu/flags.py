@@ -17,8 +17,7 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass
 
-__all__ = ["define_flag", "get_flags", "set_flags", "flag", "globals_view",
-           "watch_flag"]
+__all__ = ["define_flag", "get_flags", "set_flags", "flag", "globals_view"]
 
 
 @dataclass
@@ -34,15 +33,6 @@ class _Flag:
 
 
 _REGISTRY: dict[str, _Flag] = {}
-_WATCHERS: dict[str, list] = {}
-
-
-def watch_flag(name: str, callback):
-    """Invoke ``callback(new_value)`` whenever ``set_flags`` changes the
-    flag — for flags whose consumers must react immediately (e.g. the
-    executor re-syncs jax's persistent compile cache on change) rather
-    than at their next natural read."""
-    _WATCHERS.setdefault(name, []).append(callback)
 
 
 def _coerce(value, typ):
@@ -101,8 +91,6 @@ def set_flags(flags_map: dict):
             raise InvalidArgumentError(
                 f"flag {name!r} expects {f.type.__name__}, got {value!r}"
             ) from e
-        for cb in _WATCHERS.get(name, ()):
-            cb(f.value)
         # flag flips are exactly the kind of breadcrumb a post-mortem
         # needs ("who turned donation off mid-run?") — record each one
         try:
@@ -589,14 +577,6 @@ define_flag("fault_injection", "",
             "chaos directives: 'action:k=v,...;...' with actions "
             "kill|exit|delay|raise at points step|mid_save (empty: off)")
 
-# static/executor.py — JAX persistent compilation cache directory: repeated
-# process starts skip XLA recompilation of unchanged programs (the role of
-# TVM's ahead-of-time compiled module artifact). Empty string disables.
-# Applied lazily at the first executor compile after the flag is set.
-define_flag("persistent_compile_cache_dir", "",
-            "directory for the XLA persistent compilation cache "
-            "(empty: disabled)")
-
 # runtime/compiled.py CompiledStore — ONE bound for every compiled-
 # executable LRU cache (executor jit entries, TrainStepFn per-batch-
 # signature executables, generation prefill/decode programs). Before the
@@ -672,9 +652,9 @@ define_flag("io_prefetch_overlap", True,
 #   search — like cached, plus misses enqueue a background per-
 #            device_kind search whose winner applies at the next
 #            CompiledStore compile of the signature (never inline)
-# Winners persist next to FLAGS_persistent_compile_cache_dir
-# (tuning/cache.py); runtime/compiled.py folds the schedule token into
-# every compile identity so a swap is a clean recompile.
+# Winners live in memory, and in a file only when a path is handed to
+# tuning.reset_tuning_cache(path); runtime/compiled.py folds the schedule
+# token into every compile identity so a swap is a clean recompile.
 define_flag("kernel_autotune", "cached",
             "pallas kernel schedule policy: off | cached | search "
             "(search tunes misses in the background, offline-style)")

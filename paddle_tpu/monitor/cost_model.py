@@ -50,8 +50,8 @@ def analyze_cost(stage) -> dict | None:
     ``stage`` is a jax ``Lowered`` or ``Compiled`` (both expose the
     client-side HLO cost analysis). Backends differ: some return a dict,
     some a one-element list of dicts (per-partition), some ``None`` or an
-    empty mapping, and proxy/tunneled backends may raise — every caller
-    used to hand-roll this guard; now there is exactly one.
+    empty mapping, and some raise — every caller used to hand-roll this
+    guard; now there is exactly one.
     """
     if stage is None:
         return None
@@ -281,10 +281,12 @@ _PEAKS_TABLE = (
             "hbm_bytes": 32e9}),
     ("v5p", {"flops": 459e12, "hbm_bw": 2765e9, "ici_bw": 600e9,
              "hbm_bytes": 95e9}),
+    # the v5e reports device_kind "TPU v5 lite" and
+    # memory_stats()["bytes_limit"] == 16,909,336,064 (chip run, PR 21)
     ("v5 lite", {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 200e9,
-                 "hbm_bytes": 16e9}),
+                 "hbm_bytes": 16.9e9}),
     ("v5e", {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 200e9,
-             "hbm_bytes": 16e9}),
+             "hbm_bytes": 16.9e9}),
     ("v5", {"flops": 459e12, "hbm_bw": 2765e9, "ici_bw": 600e9,
             "hbm_bytes": 95e9}),
     ("v4", {"flops": 275e12, "hbm_bw": 1228e9, "ici_bw": 300e9,
@@ -295,25 +297,25 @@ _PEAKS_TABLE = (
             "hbm_bytes": 16e9}),
 )
 
-# CPU / unknown backends get NOMINAL peaks (order-of-magnitude host
-# numbers) so the utilization plumbing works everywhere — the absolute
-# MFU is only meaningful on known silicon or with FLAGS_device_peaks set,
-# and the payload says so via "nominal": true.
+# The CPU gets NOMINAL peaks (order-of-magnitude host numbers) so the
+# utilization plumbing works in the test suite — the payload says so via
+# "nominal": true. An accelerator that is not in the table is an error,
+# not a default: MFU against made-up peaks is worse than none.
 _NOMINAL_PEAKS = {"flops": 1e11, "hbm_bw": 5e10, "ici_bw": 1e10,
                   "hbm_bytes": 8e9}
+_PEAK_KEYS = ("flops", "hbm_bw", "ici_bw", "hbm_bytes")
 
 _detected_kind = [None]  # cache: jax backend init is not free
 _parse_memo = [None, {}]  # [last raw flag string, its parsed overrides]
 
 
 def _device_kind() -> str:
+    """``device_kind`` of local device 0 (the CPU's reads "cpu"). A
+    backend that fails to initialise raises here."""
     if _detected_kind[0] is None:
-        try:
-            import jax
+        import jax
 
-            _detected_kind[0] = str(jax.local_devices()[0].device_kind)
-        except Exception:
-            _detected_kind[0] = "unknown"
+        _detected_kind[0] = str(jax.local_devices()[0].device_kind)
     return _detected_kind[0]
 
 
@@ -330,7 +332,7 @@ def _parse_peaks_flag(raw: str) -> dict:
             continue
         k, _, v = part.partition("=")
         k = k.strip().lower()
-        if k not in ("flops", "hbm_bw", "ici_bw", "hbm_bytes"):
+        if k not in _PEAK_KEYS:
             continue
         try:
             out[k] = float(v)
@@ -345,7 +347,10 @@ def device_peaks(kind=None) -> dict:
     "nominal"}`` — the MFU/bandwidth/roofline denominators plus the HBM
     capacity the static memory planner budgets against.
     ``FLAGS_device_peaks`` overrides any subset; an override clears the
-    nominal marker (the operator asserted real numbers)."""
+    nominal marker (the operator asserted real numbers). The CPU (kind
+    "cpu") gets the nominal sheet; any other kind the table does not
+    know raises ``ValueError`` naming it, unless the flag supplies all
+    four peaks (new silicon)."""
     kind = kind if kind is not None else _device_kind()
     lowered = kind.lower()
     peaks, nominal = None, True
@@ -353,15 +358,18 @@ def device_peaks(kind=None) -> dict:
         if sub in lowered:
             peaks, nominal = dict(vals), False
             break
+    raw = str(flag("device_peaks"))
+    if raw != _parse_memo[0]:  # memo: skip re-parsing per call
+        _parse_memo[0], _parse_memo[1] = raw, _parse_peaks_flag(raw)
+    override = _parse_memo[1]
     if peaks is None:
+        if lowered != "cpu" and not all(k in override for k in _PEAK_KEYS):
+            raise ValueError(
+                f"no peak numbers for device_kind {kind!r}: add it to "
+                "monitor/cost_model.py _PEAKS_TABLE with its source, or "
+                "set FLAGS_device_peaks=flops=..,hbm_bw=..,ici_bw=..,"
+                "hbm_bytes=..")
         peaks = dict(_NOMINAL_PEAKS)
-    try:
-        raw = str(flag("device_peaks"))
-        if raw != _parse_memo[0]:  # memo: skip re-parsing per call
-            _parse_memo[0], _parse_memo[1] = raw, _parse_peaks_flag(raw)
-        override = _parse_memo[1]
-    except Exception:
-        override = {}
     if override:
         peaks.update(override)
         nominal = False

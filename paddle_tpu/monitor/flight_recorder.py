@@ -569,13 +569,9 @@ class _JaxKVChannel:
         self._client = client
 
     def set(self, key, value):
-        # coordination-service keys are write-once on older jax — a
-        # retried exchange (same barrier token failing twice) must
+        # a retried exchange (same barrier token failing twice) must
         # overwrite rather than die before any tails are collected
-        try:
-            self._client.key_value_set(key, value, allow_overwrite=True)
-        except TypeError:  # jax without the allow_overwrite kwarg
-            self._client.key_value_set(key, value)
+        self._client.key_value_set(key, value, allow_overwrite=True)
 
     def get(self, key, timeout_s):
         return self._client.blocking_key_value_get(
@@ -583,13 +579,12 @@ class _JaxKVChannel:
 
 
 def _default_channel():
-    try:
-        from jax._src import distributed as _dist
+    # jax exposes is_initialized() but no public handle on the
+    # coordination-service client; this is the one private import
+    from jax._src import distributed as _dist
 
-        client = _dist.global_state.client
-        return _JaxKVChannel(client) if client is not None else None
-    except Exception:
-        return None
+    client = _dist.global_state.client
+    return _JaxKVChannel(client) if client is not None else None
 
 
 def exchange_and_diagnose(tag="trip", timeout_s=15.0, channel=None,

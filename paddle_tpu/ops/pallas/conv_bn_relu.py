@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..._internal_tuning import register_schedule, resolve_schedule
-from ._platform import on_tpu_platform
+from ._platform import can_emit_mosaic, on_tpu_platform
 
 __all__ = ["conv_bn_relu"]
 
@@ -246,7 +246,8 @@ def _mm_stats_kernel(x_ref, w_ref, co_ref, ps_ref, *, dt, nrows, tile_m):
     co_ref[:] = co
     cf = co.astype(jnp.float32)
     row = i * tile_m + lax.broadcasted_iota(jnp.int32, cf.shape, 0)
-    ps_ref[0] = jnp.sum(jnp.where(row < nrows, cf, 0.0), axis=0)
+    ps_ref[0] = jnp.sum(jnp.where(row < nrows, cf, 0.0), axis=0,
+                        keepdims=True)
 
 
 def _centered_sumsq_kernel(co_ref, mean_ref, pss_ref, *, nrows, tile_m):
@@ -264,7 +265,7 @@ def _centered_sumsq_kernel(co_ref, mean_ref, pss_ref, *, nrows, tile_m):
     d = cf - mean_ref[0]
     row = i * tile_m + lax.broadcasted_iota(jnp.int32, cf.shape, 0)
     d = jnp.where(row < nrows, d, 0.0)
-    pss_ref[0] = jnp.sum(d * d, axis=0)
+    pss_ref[0] = jnp.sum(d * d, axis=0, keepdims=True)
 
 
 def _bn_relu_kernel(co_ref, s_ref, b_ref, y_ref, *, dt):
@@ -273,18 +274,28 @@ def _bn_relu_kernel(co_ref, s_ref, b_ref, y_ref, *, dt):
     y_ref[:] = jnp.maximum(y, 0.0).astype(dt)
 
 
-def _specs(pl, pltpu, tile_m, tile_n, kp):
+def _tile_specs(pl, pltpu, tile_m, tile_n):
+    """Block specs over a (row-tile i, col-tile j) grid: an [M, C] tile,
+    a [1, C] per-channel vector, and a per-row-tile partial sum. The
+    partials are [gm, 1, C] with a (1, 1, tile_n) block: Mosaic wants a
+    block's second-to-last dim to be a multiple of 8 or the array's own,
+    which a (1, tile_n) block of a [gm, C] array is not."""
+    tile = pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j),
+                        memory_space=pltpu.VMEM)
+    vec = pl.BlockSpec((1, tile_n), lambda i, j: (0, j),
+                       memory_space=pltpu.VMEM)
+    part = pl.BlockSpec((1, 1, tile_n), lambda i, j: (i, 0, j),
+                        memory_space=pltpu.VMEM)
+    return tile, vec, part
+
+
+def _mm_specs(pl, pltpu, tile_m, tile_n, kp):
+    """Full-K operand stripes of the matmul passes."""
     row = pl.BlockSpec((tile_m, kp), lambda i, j: (i, 0),
                        memory_space=pltpu.VMEM)
     col = pl.BlockSpec((kp, tile_n), lambda i, j: (0, j),
                        memory_space=pltpu.VMEM)
-    out = pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j),
-                       memory_space=pltpu.VMEM)
-    vec = pl.BlockSpec((1, tile_n), lambda i, j: (0, j),
-                       memory_space=pltpu.VMEM)
-    part = pl.BlockSpec((1, tile_n), lambda i, j: (i, j),
-                        memory_space=pltpu.VMEM)
-    return row, col, out, vec, part
+    return row, col
 
 
 def _mm_affine_relu(p2, w2, scale, shift, interpret=False, tiles=None):
@@ -302,9 +313,11 @@ def _mm_affine_relu(p2, w2, scale, shift, interpret=False, tiles=None):
     wp = _pad_mat(w2, kp, cp)
     sp = _pad_vec(scale.astype(jnp.float32), cp).reshape(1, cp)
     bp = _pad_vec(shift.astype(jnp.float32), cp).reshape(1, cp)
-    row, col, out, vec, _ = _specs(pl, pltpu, tile_m, tile_n, kp)
+    row, col = _mm_specs(pl, pltpu, tile_m, tile_n, kp)
+    out, vec, _ = _tile_specs(pl, pltpu, tile_m, tile_n)
     y = pl.pallas_call(
         functools.partial(_mm_affine_relu_kernel, dt=dt),
+        name="conv_mm_affine_relu",
         grid=(pl.cdiv(mp, tile_m), pl.cdiv(cp, tile_n)),
         in_specs=[row, col, vec, vec],
         out_specs=out,
@@ -332,21 +345,23 @@ def _mm_stats(p2, w2, interpret=False, tiles=None):
         mp, kp, cp, dt)
     xp = _pad_mat(p2, mp, kp)
     wp = _pad_mat(w2, kp, cp)
-    row, col, out, _, part = _specs(pl, pltpu, tile_m, tile_n, kp)
+    row, col = _mm_specs(pl, pltpu, tile_m, tile_n, kp)
+    out, _, part = _tile_specs(pl, pltpu, tile_m, tile_n)
     gm = pl.cdiv(mp, tile_m)
     co, ps = pl.pallas_call(
         functools.partial(_mm_stats_kernel, dt=dt, nrows=m,
                           tile_m=tile_m),
+        name="conv_mm_stats",
         grid=(gm, pl.cdiv(cp, tile_n)),
         in_specs=[row, col],
         out_specs=[out, part],
         out_shape=[
             jax.ShapeDtypeStruct((mp, cp), dt),
-            jax.ShapeDtypeStruct((gm, cp), jnp.float32),
+            jax.ShapeDtypeStruct((gm, 1, cp), jnp.float32),
         ],
         interpret=interpret,
     )(xp, wp)
-    return co, ps.sum(axis=0)[:c]
+    return co, ps.sum(axis=(0, 1))[:c]
 
 
 def _centered_sumsq(co_p, mean, nrows, interpret=False, tiles=None):
@@ -360,23 +375,19 @@ def _centered_sumsq(co_p, mean, nrows, interpret=False, tiles=None):
     tile_m, tile_n = tiles if tiles is not None else _schedule_tiles(
         mp, _LANES, cp, co_p.dtype)
     meanp = _pad_vec(mean.astype(jnp.float32), cp).reshape(1, cp)
-    tile = pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j),
-                        memory_space=pltpu.VMEM)
-    vec = pl.BlockSpec((1, tile_n), lambda i, j: (0, j),
-                       memory_space=pltpu.VMEM)
-    part = pl.BlockSpec((1, tile_n), lambda i, j: (i, j),
-                        memory_space=pltpu.VMEM)
+    tile, vec, part = _tile_specs(pl, pltpu, tile_m, tile_n)
     gm = pl.cdiv(mp, tile_m)
     pss = pl.pallas_call(
         functools.partial(_centered_sumsq_kernel, nrows=nrows,
                           tile_m=tile_m),
+        name="conv_centered_sumsq",
         grid=(gm, pl.cdiv(cp, tile_n)),
         in_specs=[tile, vec],
         out_specs=part,
-        out_shape=jax.ShapeDtypeStruct((gm, cp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((gm, 1, cp), jnp.float32),
         interpret=interpret,
     )(co_p, meanp)
-    return pss.sum(axis=0)[:c]
+    return pss.sum(axis=(0, 1))[:c]
 
 
 def _bn_relu(co, scale, shift, interpret=False, tiles=None):
@@ -392,12 +403,10 @@ def _bn_relu(co, scale, shift, interpret=False, tiles=None):
     cop = _pad_mat(co, mp, cp)
     sp = _pad_vec(scale.astype(jnp.float32), cp).reshape(1, cp)
     bp = _pad_vec(shift.astype(jnp.float32), cp).reshape(1, cp)
-    tile = pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j),
-                        memory_space=pltpu.VMEM)
-    vec = pl.BlockSpec((1, tile_n), lambda i, j: (0, j),
-                       memory_space=pltpu.VMEM)
+    tile, vec, _ = _tile_specs(pl, pltpu, tile_m, tile_n)
     y = pl.pallas_call(
         functools.partial(_bn_relu_kernel, dt=dt),
+        name="conv_bn_relu",
         grid=(pl.cdiv(mp, tile_m), pl.cdiv(cp, tile_n)),
         in_specs=[tile, vec, vec],
         out_specs=tile,
@@ -425,9 +434,10 @@ def _bn_bwd_partials_kernel(co_ref, g_ref, s_ref, b_ref, pdy_ref,
     row = i * tile_m + lax.broadcasted_iota(jnp.int32, cf.shape, 0)
     valid = row < nrows
     dyr = jnp.where(valid, dyr, 0.0)
-    pdy_ref[0] = jnp.sum(dyr, axis=0)
+    pdy_ref[0] = jnp.sum(dyr, axis=0, keepdims=True)
     # cf must be masked too: 0 * (out-of-bounds NaN) is still NaN
-    pdyc_ref[0] = jnp.sum(dyr * jnp.where(valid, cf, 0.0), axis=0)
+    pdyc_ref[0] = jnp.sum(dyr * jnp.where(valid, cf, 0.0), axis=0,
+                          keepdims=True)
 
 
 def _bn_bwd_dco_kernel(co_ref, g_ref, s_ref, b_ref, k3_ref, b0_ref,
@@ -453,26 +463,22 @@ def _bn_bwd_partials(co, g2, scale, shift, interpret=False, tiles=None):
     gp = _pad_mat(g2, mp, cp)  # zero-padded rows/cols -> exact partials
     sp = _pad_vec(scale, cp).reshape(1, cp)
     bp = _pad_vec(shift, cp).reshape(1, cp)
-    tile = pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j),
-                        memory_space=pltpu.VMEM)
-    vec = pl.BlockSpec((1, tile_n), lambda i, j: (0, j),
-                       memory_space=pltpu.VMEM)
-    part = pl.BlockSpec((1, tile_n), lambda i, j: (i, j),
-                        memory_space=pltpu.VMEM)
+    tile, vec, part = _tile_specs(pl, pltpu, tile_m, tile_n)
     gm = pl.cdiv(mp, tile_m)
     pdy, pdyc = pl.pallas_call(
         functools.partial(_bn_bwd_partials_kernel, nrows=m,
                           tile_m=tile_m),
+        name="conv_bn_bwd_partials",
         grid=(gm, pl.cdiv(cp, tile_n)),
         in_specs=[tile, tile, vec, vec],
         out_specs=[part, part],
         out_shape=[
-            jax.ShapeDtypeStruct((gm, cp), jnp.float32),
-            jax.ShapeDtypeStruct((gm, cp), jnp.float32),
+            jax.ShapeDtypeStruct((gm, 1, cp), jnp.float32),
+            jax.ShapeDtypeStruct((gm, 1, cp), jnp.float32),
         ],
         interpret=interpret,
     )(cop, gp, sp, bp)
-    return pdy.sum(axis=0)[:c], pdyc.sum(axis=0)[:c]
+    return pdy.sum(axis=(0, 1))[:c], pdyc.sum(axis=(0, 1))[:c]
 
 
 def _bn_bwd_dco(co, g2, scale, shift, k3, b0, interpret=False, tiles=None):
@@ -489,12 +495,10 @@ def _bn_bwd_dco(co, g2, scale, shift, k3, b0, interpret=False, tiles=None):
     vecs = [
         _pad_vec(v, cp).reshape(1, cp) for v in (scale, shift, k3, b0)
     ]
-    tile = pl.BlockSpec((tile_m, tile_n), lambda i, j: (i, j),
-                        memory_space=pltpu.VMEM)
-    vec = pl.BlockSpec((1, tile_n), lambda i, j: (0, j),
-                       memory_space=pltpu.VMEM)
+    tile, vec, _ = _tile_specs(pl, pltpu, tile_m, tile_n)
     dco = pl.pallas_call(
         _bn_bwd_dco_kernel,
+        name="conv_bn_bwd_dco",
         grid=(pl.cdiv(mp, tile_m), pl.cdiv(cp, tile_n)),
         in_specs=[tile, tile, vec, vec, vec, vec],
         out_specs=tile,
@@ -604,7 +608,7 @@ _eval_core.defvjp(_eval_core_fwd, _eval_core_bwd)
 
 
 def _supported(x, w, stride, padding, data_format, dilation, groups):
-    if not on_tpu_platform():
+    if not can_emit_mosaic():
         return False
     if str(x.dtype) not in _SUBLANES or x.dtype != w.dtype:
         return False

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..._internal_tuning import register_schedule, resolve_schedule
-from ._platform import on_tpu_platform
+from ._platform import can_emit_mosaic, on_tpu_platform
 
 __all__ = ["flash_attention"]
 
@@ -369,6 +369,7 @@ def _pallas_fwd_small(q, k, v, bias, seed, causal, scale, rate):
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd_small",
         grid=(b,),
         in_specs=specs,
         out_specs=[
@@ -432,6 +433,7 @@ def _pallas_bwd_small(q, k, v, bias, seed, causal, scale, rate, lse, g,
 
     dq, dk, dv = pl.pallas_call(
         kernel,
+        name="flash_bwd_small",
         grid=(b,),
         in_specs=specs,
         out_specs=[tile(lq), tile(lk), tile(lk)],
@@ -547,6 +549,7 @@ def _pallas_fwd(q, k, v, bias, seed, causal, scale, rate,
                     num_q=lq // block_q, rate=rate, unroll=unroll)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -764,6 +767,7 @@ def _pallas_bwd(q, k, v, bias, seed, causal, scale, rate, out, lse, g,
 
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         grid=(b * h, lq // block_q),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0),
@@ -814,6 +818,7 @@ def _pallas_bwd(q, k, v, bias, seed, causal, scale, rate, out, lse, g,
 
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         grid=(b * h, lk // block_k),
         in_specs=dkv_specs,
         out_specs=[
@@ -835,7 +840,7 @@ def _pallas_bwd(q, k, v, bias, seed, causal, scale, rate, out, lse, g,
 
 
 def _supported(q, k, v, bias):
-    if not on_tpu_platform():
+    if not can_emit_mosaic():
         return False
     b, h, lq, d = q.shape
     lk = k.shape[2]

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from ..._internal_tuning import register_schedule, resolve_schedule
-from ._platform import on_tpu_platform
+from ._platform import can_emit_mosaic, on_tpu_platform
 
 __all__ = ["int8_matmul"]
 
@@ -153,6 +153,7 @@ def _pallas_matmul(x, w, interpret=False, tiles=None):
 
     out = pl.pallas_call(
         kernel,
+        name="int8_matmul",
         grid=(pl.cdiv(pm, tile_m), pl.cdiv(pn, tile_n)),
         in_specs=[
             pl.BlockSpec((tile_m, pk), lambda i, j: (i, 0),
@@ -180,6 +181,6 @@ def int8_matmul(x, w):
 
     x = jnp.asarray(x)
     w = jnp.asarray(w)
-    if flag("use_int8_matmul") and on_tpu_platform() and _supported(x, w):
+    if flag("use_int8_matmul") and can_emit_mosaic() and _supported(x, w):
         return _pallas_matmul(x, w)
     return _jnp_matmul(x, w)

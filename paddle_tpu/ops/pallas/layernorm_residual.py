@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..._internal_tuning import register_schedule, resolve_schedule
-from ._platform import on_tpu_platform
+from ._platform import can_emit_mosaic, on_tpu_platform
 
 __all__ = ["layernorm_residual"]
 
@@ -124,10 +124,11 @@ def _reference(x, res, w, b, eps):
 
 def _fwd_kernel(x_ref, r_ref, w_ref, b_ref, y_ref, mean_ref, rstd_ref, *,
                 eps, dt):
-    # the add happens in the INPUT dtype ``dt`` (bf16 rounds), exactly
-    # like the unfused norm(x + res) path — only the statistics are
-    # f32. ``dt`` is passed statically because interpret mode presents
-    # bf16 refs as f32 (losslessly, so the cast recovers input dtype)
+    # the add happens in ``dt``, the dtype ``x + res`` promotes to (bf16
+    # rounds when both are bf16), exactly like the unfused norm(x + res)
+    # path — only the statistics are f32. ``dt`` is passed statically
+    # because interpret mode presents bf16 refs as f32 (losslessly, so
+    # the cast recovers input dtype)
     a = (x_ref[:].astype(dt) + r_ref[:].astype(dt)).astype(jnp.float32)
     mean = jnp.mean(a, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(a - mean), axis=-1, keepdims=True)
@@ -167,8 +168,9 @@ def _bwd_kernel(x_ref, r_ref, w_ref, mean_ref, rstd_ref, dy_ref, da_ref,
     row = i * block_r + lax.broadcasted_iota(jnp.int32, dy.shape, 0)
     valid = row < nrows
     dy_m = jnp.where(valid, dy, 0.0)
-    dwp_ref[0] = jnp.sum(dy_m * jnp.where(valid, xhat, 0.0), axis=0)
-    dbp_ref[0] = jnp.sum(dy_m, axis=0)
+    dwp_ref[0] = jnp.sum(dy_m * jnp.where(valid, xhat, 0.0), axis=0,
+                         keepdims=True)
+    dbp_ref[0] = jnp.sum(dy_m, axis=0, keepdims=True)
 
 
 def _pallas_fwd(x2, r2, w, b, eps, interpret=False, block_r=None):
@@ -186,7 +188,9 @@ def _pallas_fwd(x2, r2, w, b, eps, interpret=False, block_r=None):
     col_spec = pl.BlockSpec((block_r, 1), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
     y, mean, rstd = pl.pallas_call(
-        functools.partial(_fwd_kernel, eps=eps, dt=x2.dtype),
+        functools.partial(_fwd_kernel, eps=eps,
+                          dt=jnp.promote_types(x2.dtype, r2.dtype)),
+        name="layernorm_residual_fwd",
         grid=grid,
         in_specs=[row_spec, row_spec, vec_spec, vec_spec],
         out_specs=[row_spec, col_spec, col_spec],
@@ -214,41 +218,47 @@ def _pallas_bwd(x2, r2, w, mean, rstd, dy2, interpret=False, block_r=None):
                             memory_space=pltpu.VMEM)
     col_spec = pl.BlockSpec((block_r, 1), lambda i: (i, 0),
                             memory_space=pltpu.VMEM)
-    part_spec = pl.BlockSpec((1, h), lambda i: (i, 0),
+    # per-tile partials are [ntiles, 1, h] with a (1, 1, h) block: Mosaic
+    # wants a block's second-to-last dim to be a multiple of 8 or the
+    # array's own, which a (1, h) block of an [ntiles, h] array is not
+    part_spec = pl.BlockSpec((1, 1, h), lambda i: (i, 0, 0),
                              memory_space=pltpu.VMEM)
+    # d_input comes out in the dtype ``x + res`` promotes to; the caller
+    # casts it to each operand's own dtype
+    dt = jnp.promote_types(x2.dtype, r2.dtype)
     da, dwp, dbp = pl.pallas_call(
-        functools.partial(_bwd_kernel, nrows=rows, block_r=block_r,
-                          dt=x2.dtype),
+        functools.partial(_bwd_kernel, nrows=rows, block_r=block_r, dt=dt),
+        name="layernorm_residual_bwd",
         grid=(ntiles,),
         in_specs=[row_spec, row_spec, vec_spec, col_spec, col_spec,
                   row_spec],
         out_specs=[row_spec, part_spec, part_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, h), x2.dtype),
-            jax.ShapeDtypeStruct((ntiles, h), jnp.float32),
-            jax.ShapeDtypeStruct((ntiles, h), jnp.float32),
+            jax.ShapeDtypeStruct((rows, h), dt),
+            jax.ShapeDtypeStruct((ntiles, 1, h), jnp.float32),
+            jax.ShapeDtypeStruct((ntiles, 1, h), jnp.float32),
         ],
         interpret=interpret,
     )(x2, r2, w.reshape(1, h), mean, rstd, dy2)
-    return da, dwp.sum(axis=0), dbp.sum(axis=0)
+    return da, dwp.sum(axis=(0, 1)), dbp.sum(axis=(0, 1))
 
 
 # -- custom-vjp wiring --------------------------------------------------------
 
 
-def _supported(x, w, b) -> bool:
-    if not on_tpu_platform():
+def _supported(x, res, w, b) -> bool:
+    if not can_emit_mosaic():
         return False
-    if str(x.dtype) not in ("float32", "bfloat16"):
+    if any(str(a.dtype) not in ("float32", "bfloat16") for a in (x, res)):
         return False
     h = x.shape[-1]
-    return (h % _LANES == 0 and h <= _MAX_H
+    return (x.shape == res.shape and h % _LANES == 0 and h <= _MAX_H
             and w.shape == (h,) and b.shape == (h,))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _ln_res(x, res, w, b, eps):
-    if _supported(x, w, b):
+    if _supported(x, res, w, b):
         x2 = x.reshape(-1, x.shape[-1])
         y, _, _ = _pallas_fwd(x2, res.reshape(x2.shape), w, b, eps)
         return y.reshape(x.shape)
@@ -256,7 +266,7 @@ def _ln_res(x, res, w, b, eps):
 
 
 def _ln_res_fwd(x, res, w, b, eps):
-    if _supported(x, w, b):
+    if _supported(x, res, w, b):
         x2 = x.reshape(-1, x.shape[-1])
         r2 = res.reshape(x2.shape)
         y, mean, rstd = _pallas_fwd(x2, r2, w, b, eps)
@@ -272,7 +282,8 @@ def _ln_res_bwd(eps, saved, g):
             x.reshape(-1, h), res.reshape(-1, h), w, mean, rstd,
             g.reshape(-1, h))
         da = da.reshape(x.shape)
-        return da, da, dw.astype(w.dtype), db.astype(b.dtype)
+        return (da.astype(x.dtype), da.astype(res.dtype),
+                dw.astype(w.dtype), db.astype(b.dtype))
     _, vjp = jax.vjp(lambda x, r, w, b: _reference(x, r, w, b, eps),
                      x, res, w, b)
     return vjp(g)

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from ..._internal_tuning import register_schedule, resolve_schedule
-from ._platform import on_tpu_platform
+from ._platform import can_emit_mosaic, on_tpu_platform
 
 __all__ = ["fused_momentum_update"]
 
@@ -166,6 +166,7 @@ def _pallas_update(param, grad, velocity, lr, mu, wd, nesterov,
 
     new_p, new_v = pl.pallas_call(
         kernel,
+        name="momentum_update",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1), lambda i: (0, 0),
@@ -200,6 +201,6 @@ def fused_momentum_update(param, grad, velocity, lr, momentum=0.9,
     mu = float(momentum)
     wd = float(weight_decay)
     nesterov = bool(use_nesterov)
-    if on_tpu_platform() and _supported(param, grad, velocity):
+    if can_emit_mosaic() and _supported(param, grad, velocity):
         return _pallas_update(param, grad, velocity, lr, mu, wd, nesterov)
     return _jnp_update(param, grad, velocity, lr, mu, wd, nesterov)

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..._internal_tuning import register_schedule, resolve_schedule
-from ._platform import on_tpu_platform
+from ._platform import can_emit_mosaic, on_tpu_platform
 
 __all__ = ["max_pool2d_backward", "max_pool_backward_supported"]
 
@@ -260,7 +260,7 @@ def max_pool_backward_supported(x_shape, dtype, ks, st, p, ceil_extra,
                                 data_format):
     """Gate for the pallas path: TPU backend, NCHW 4D floating input,
     symmetric padding (no ceil_mode tail), spatial dims known."""
-    if not on_tpu_platform():
+    if not can_emit_mosaic():
         return False
     if data_format != "NCHW" or len(x_shape) != 4:
         return False

@@ -25,11 +25,13 @@ this is it:
   compile) and captured into the cost model so MFU comes from what XLA
   actually built.
 - **Demote-to-jit** — the AOT executable is stricter than ``jax.jit``
-  (aval/layout drift raises where jit silently recompiles): a failed
-  AOT dispatch demotes the entry to the jit path and retries — but
-  NEVER after donation consumed input buffers, and the stale
-  CostRecord is dropped so the MFU ledger can't credit pre-drift
-  numbers against jit's recompile.
+  (aval/layout drift raises ``TypeError``/``ValueError`` where jit
+  silently recompiles): such a dispatch demotes the entry to the jit
+  path and retries — but NEVER after donation consumed input buffers,
+  and the stale CostRecord is dropped so the MFU ledger can't credit
+  pre-drift numbers against jit's recompile. Nothing else is caught: a
+  lowering or compile error (Mosaic's included) and a device run-time
+  error surface with their own message.
 """
 from __future__ import annotations
 
@@ -261,32 +263,31 @@ class CompiledStore:
         with entry.lock:
             if entry.attempted:
                 return
-            try:
-                # trace + XLA compile are badput in the goodput ledger's
-                # taxonomy: a span here covers both, and the ledger
-                # deducts it from the enclosing step frame's compute.
-                # The named_scope prefixes every op stamp the traced
-                # function emits (executor._exec_one's opprof stamps)
-                # with this store's label, so a device-trace row reads
-                # executor/matmul#0/3/... and attribution can tell which
-                # runtime (executor, serving replica, ...) issued the op.
-                with _goodput.span("compile"), _sched_capture() as cap, \
-                        jax.named_scope(self.label):
-                    lowered = entry.jitted.lower(*args)
-                # the trace just ran: record the schedules it baked in
-                entry.resolved_schedules = dict(cap.log or {})
-                with _goodput.span("compile"):
-                    entry.aot = lowered.compile()
-                entry.record = _cost.capture(
-                    self.cost_label, lowered=lowered, compiled=entry.aot,
-                    key=entry.cache_key, cache_key=entry.cache_key,
-                    **(capture_meta or {}))
-                _flight().record_event(
-                    "runtime_compile", label=self.label,
-                    cache_key=entry.cache_key,
-                    flops=entry.record.flops if entry.record else 0.0)
-            except Exception:
-                entry.aot = None  # backend without the AOT surface: jit
+            # trace + XLA compile are badput in the goodput ledger's
+            # taxonomy: a span here covers both, and the ledger deducts
+            # it from the enclosing step frame's compute. The
+            # named_scope prefixes every op stamp the traced function
+            # emits (executor._exec_one's opprof stamps) with this
+            # store's label, so a device-trace row reads
+            # executor/matmul#0/3/... and attribution can tell which
+            # runtime (executor, serving replica, ...) issued the op.
+            # A lowering or compile error propagates from here with its
+            # message; the entry stays unattempted.
+            with _goodput.span("compile"), _sched_capture() as cap, \
+                    jax.named_scope(self.label):
+                lowered = entry.jitted.lower(*args)
+            # the trace just ran: record the schedules it baked in
+            entry.resolved_schedules = dict(cap.log or {})
+            with _goodput.span("compile"):
+                entry.aot = lowered.compile()
+            entry.record = _cost.capture(
+                self.cost_label, lowered=lowered, compiled=entry.aot,
+                key=entry.cache_key, cache_key=entry.cache_key,
+                **(capture_meta or {}))
+            _flight().record_event(
+                "runtime_compile", label=self.label,
+                cache_key=entry.cache_key,
+                flops=entry.record.flops if entry.record else 0.0)
             entry.attempted = True
 
     def dispatch(self, entry, *args, donated=(), capture_meta=None):
@@ -305,15 +306,10 @@ class CompiledStore:
             self._aot_compile(entry, args, capture_meta)
         runner = entry.aot if entry.aot is not None else entry.jitted
         try:
-            if entry.resolved_schedules is None:
-                # AOT lowering was unavailable: the jit fallback's first
-                # call traces here — capture its schedule resolutions
-                with _sched_capture() as cap:
-                    out = runner(*args)
-                entry.resolved_schedules = dict(cap.log or {})
-            else:
-                out = runner(*args)
-        except Exception:
+            out = runner(*args)
+        except (TypeError, ValueError):
+            # what a Compiled raises for avals / shardings it was not
+            # built for; anything else (a device error) is not drift
             consumed = donated() if callable(donated) else donated
             if runner is entry.jitted or any_deleted(consumed):
                 raise

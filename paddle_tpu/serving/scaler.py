@@ -179,6 +179,12 @@ class SubprocessLauncher:
     entrypoint does only after warmup, so a returned URL is READY) and
     returns a :class:`LaunchedBackend`; ``terminate()`` SIGTERMs it
     (graceful drain) and escalates to SIGKILL past the timeout.
+
+    Children inherit this process's environment, and a TPU chip belongs
+    to one process at a time: several backends on one TPU host, or a
+    parent that has touched jax on it, are unsupported (the child fails
+    or hangs). Pass ``env={"JAX_PLATFORMS": "cpu"}`` for the CPU
+    emulation; one chip per backend is ROADMAP R5.
     """
 
     def __init__(self, model_dir, host="127.0.0.1", replicas=None,

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..flags import flag, watch_flag
+from ..flags import flag
 from ..framework import random as _random
 from ..monitor import flight_recorder as _flight
 from ..monitor import tracing as _tracing
@@ -212,65 +212,6 @@ class _LazyFetchList(list):
 
     def __reduce__(self):  # pickle ships numpy, never device handles
         return (list, (list(self._materialize_all()),))
-
-
-# last FLAGS_persistent_compile_cache_dir value applied to jax.config
-# (None = never applied), and the ambient jax cache settings saved before
-# the first override so clearing the flag restores them all (a host app —
-# or the test suite's conftest — may have configured its own cache)
-_persistent_cache_applied = [None]
-_ambient_cache_config = [None]
-
-_CACHE_CONFIG_KEYS = (
-    "jax_compilation_cache_dir",
-    "jax_persistent_cache_min_compile_time_secs",
-)
-
-
-def _sync_persistent_cache():
-    """Apply FLAGS_persistent_compile_cache_dir to jax's persistent
-    compilation cache so repeated process starts skip XLA recompilation.
-    Checked only on jit-entry misses — zero cost in the dispatch loop.
-    An unset flag never touches ambient jax config."""
-    d = flag("persistent_compile_cache_dir")
-    if d == _persistent_cache_applied[0]:
-        return
-    if not d and _persistent_cache_applied[0] is None:
-        _persistent_cache_applied[0] = d  # flag never set: hands off
-        return
-    try:
-        if not _persistent_cache_applied[0]:
-            _ambient_cache_config[0] = {
-                k: getattr(jax.config, k) for k in _CACHE_CONFIG_KEYS}
-        if d:
-            jax.config.update("jax_compilation_cache_dir", d)
-            # modest floor: low enough to capture every whole-block
-            # executor compile, high enough that the process's tiny
-            # per-op eager jits don't each pay a disk write (jax.config
-            # is global — this affects ALL compiles in the process)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.1)
-        else:  # flag cleared: hand the whole cache config back untouched
-            for k, v in _ambient_cache_config[0].items():
-                jax.config.update(k, v)
-        # jax latches its cache handle at the first compile; re-pointing
-        # the dir after any compile has happened needs an explicit reset
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception as e:  # older jax without the persistent-cache config
-        import warnings
-
-        warnings.warn(
-            f"persistent_compile_cache_dir={d!r} could not be applied to "
-            f"this jax ({type(e).__name__}: {e}); compiles will not be "
-            "cached across process starts", RuntimeWarning, stacklevel=2)
-    _persistent_cache_applied[0] = d
-
-
-# set_flags must take effect immediately — clearing the flag restores the
-# ambient jax cache config right away, not at the next jit-cache miss
-watch_flag("persistent_compile_cache_dir", lambda _v: _sync_persistent_cache())
 
 
 def _feed_shape(v):
@@ -865,7 +806,6 @@ class Executor:
                 persist_in, donate_enabled,
             )
         def _build():
-            _sync_persistent_cache()
             # donation POLICY (shared flag semantics, one compile key):
             # donate the persistables the program statically writes
             # (params, optimizer state) — XLA aliases each update into

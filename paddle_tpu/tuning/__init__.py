@@ -13,8 +13,8 @@ Three pieces, one contract:
   pruned before any compile) per ``device_kind``; under
   ``FLAGS_kernel_autotune=search`` resolve-misses enqueue background
   tuning.
-- :mod:`.cache` — winners persist in a versioned JSON file next to
-  ``FLAGS_persistent_compile_cache_dir``, keyed by (kernel,
+- :mod:`.cache` — winners live in memory, and in a versioned JSON
+  file when ``reset_tuning_cache(path)`` names one, keyed by (kernel,
   device_kind, shape-bucket, dtype, schedule-space version); corrupt /
   wrong-version / foreign-device content degrades to defaults with one
   warning + ``autotune::cache_reject``, never a crash.
@@ -26,7 +26,6 @@ from .cache import (  # noqa: F401
     CACHE_FILE_NAME,
     CACHE_SCHEMA_VERSION,
     TuningCache,
-    cache_path,
     reset_tuning_cache,
     schedule_token,
     tuned_table,
@@ -57,7 +56,6 @@ __all__ = [
     "ScheduleSpace",
     "TuneResult",
     "TuningCache",
-    "cache_path",
     "drain_background",
     "enqueue_search",
     "next_pow2",

@@ -1,9 +1,11 @@
-"""Persistent kernel-tuning cache: versioned JSON next to the XLA
-persistent compile cache.
+"""Kernel-tuning cache: tuned schedules in memory, persisted as
+versioned JSON only when the caller names a file.
 
-One file (``kernel_tuning_cache.json`` inside
-``FLAGS_persistent_compile_cache_dir``; in-memory only when that flag
-is empty) holds every tuned winner, keyed by
+Tuned winners live in memory unless an explicit path is handed to
+:class:`TuningCache` / :func:`reset_tuning_cache`: which schedule a
+kernel compiles with must follow from the committed code and what the
+caller asked for, never from a file left behind in a cache directory.
+The file holds every tuned winner, keyed by
 ``(kernel, device_kind, shape-bucket, dtype, schedule-space version)``
 — entries for other device kinds coexist in the same file (a cache
 tuned on v5e travels to a v4 host without poisoning it: the v4 lookups
@@ -31,25 +33,15 @@ import os
 import threading
 import warnings
 
-from ..flags import flag, watch_flag
+from ..flags import flag
 from ..profiler import bump_counter
 
 __all__ = ["CACHE_SCHEMA_VERSION", "CACHE_FILE_NAME", "TuningCache",
-           "tuning_cache", "reset_tuning_cache", "cache_path",
-           "schedule_token", "tuned_table"]
+           "tuning_cache", "reset_tuning_cache", "schedule_token",
+           "tuned_table"]
 
 CACHE_SCHEMA_VERSION = 1
 CACHE_FILE_NAME = "kernel_tuning_cache.json"
-
-
-def cache_path() -> str | None:
-    """Where the tuning cache persists: next to the XLA persistent
-    compile cache (``FLAGS_persistent_compile_cache_dir``); ``None``
-    (in-memory only) when that flag is empty."""
-    root = str(flag("persistent_compile_cache_dir") or "").strip()
-    if not root:
-        return None
-    return os.path.join(root, CACHE_FILE_NAME)
 
 
 def _flight():
@@ -78,9 +70,8 @@ class TuningCache:
     persistence, generation-counted for the runtime token."""
 
     def __init__(self, path=None):
-        # path=None defers to cache_path() (the flag) at first load;
-        # an explicit path pins it (tests, the smoke's fresh-process leg)
-        self._explicit_path = path
+        # path=None: in-memory only; a path loads from and saves to it
+        self.path = path
         self._entries: dict[str, dict] = {}
         self._loaded = False
         self._lock = threading.RLock()
@@ -88,11 +79,6 @@ class TuningCache:
         self._stale_warned: set = set()  # one reject per stale key
 
     # -- identity ------------------------------------------------------------
-
-    @property
-    def path(self) -> str | None:
-        return (self._explicit_path if self._explicit_path is not None
-                else cache_path())
 
     @property
     def generation(self) -> int:
@@ -250,15 +236,12 @@ def tuning_cache() -> TuningCache:
 
 
 def reset_tuning_cache(path=None) -> TuningCache:
-    """Swap in a fresh cache (tests; also the flag-watch hook so a
-    ``set_flags`` changing the cache dir re-resolves the path)."""
+    """Swap in a fresh cache: in memory, or backed by the file at
+    ``path`` (loaded at first lookup, rewritten at every put)."""
     with _cache_lock:
         _cache_epoch[0] += 1
         _cache[0] = TuningCache(path)
         return _cache[0]
-
-
-watch_flag("persistent_compile_cache_dir", lambda _v: reset_tuning_cache())
 
 
 def schedule_token() -> tuple:

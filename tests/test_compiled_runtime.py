@@ -162,8 +162,14 @@ def test_concurrent_cold_signature_pays_one_compile():
 
 
 class _RaisingAot:
+    """What a jax ``Compiled`` does when called with avals it was not
+    built for: raises TypeError."""
+
+    def __init__(self, exc=TypeError):
+        self.exc = exc
+
     def __call__(self, *args):
-        raise RuntimeError("aval drift")
+        raise self.exc("aval drift")
 
 
 def test_demotion_falls_back_to_jit_and_drops_record():
@@ -196,9 +202,44 @@ def test_no_retry_after_donation_consumed():
         def is_deleted(self):
             return True
 
-    with pytest.raises(RuntimeError, match="aval drift"):
+    with pytest.raises(TypeError, match="aval drift"):
         store.dispatch(entry, jnp.ones((4,)), donated=[_Dead()])
     assert isinstance(entry.aot, _RaisingAot)  # NOT demoted: error surfaced
+
+
+def test_device_error_is_not_drift_and_is_not_retried():
+    """Only aval/sharding drift demotes. A run-time failure of the
+    executable (RuntimeError: out of memory, a device fault) surfaces —
+    retrying it through jax.jit would recompile the whole program just to
+    fail the same way."""
+    store = _make_store()
+    entry, _ = store.get_or_build("sig", lambda: (_jitted(), None))
+    entry.attempted = True
+    entry.aot = _RaisingAot(RuntimeError)
+    with pytest.raises(RuntimeError, match="aval drift"):
+        store.dispatch(entry, jnp.ones((4,)))
+    assert isinstance(entry.aot, _RaisingAot)
+    assert "rt_test::aot_demote" not in profiler.counters()
+
+
+def test_compile_error_surfaces_with_its_message():
+    """A lowering/compile failure (on the chip: Mosaic refusing a kernel)
+    must reach the caller with its text — never a silent jit fallback —
+    and the entry stays cold so the next dispatch reports it again."""
+    store = _make_store()
+
+    class Refusing:
+        def lower(self, *args):
+            raise ValueError("Mosaic: block shape refused")
+
+        def __call__(self, *args):
+            raise AssertionError("the jit path must not run")
+
+    entry, _ = store.get_or_build("sig", lambda: (Refusing(), None))
+    for _ in range(2):
+        with pytest.raises(ValueError, match="block shape refused"):
+            store.dispatch(entry, jnp.ones((4,)))
+    assert not entry.attempted and entry.aot is None
 
 
 def test_donation_check_is_lazy_callable():

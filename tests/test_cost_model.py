@@ -96,20 +96,40 @@ def test_matmul_flops_golden_and_mfu_math():
 def test_device_peaks_table_and_flag_override():
     v4 = cost_model.device_peaks(kind="TPU v4")
     assert v4["flops"] == 275e12 and v4["nominal"] is False
+    # what the v5e reports for device_kind (chip run, PR 21)
     v5e = cost_model.device_peaks(kind="TPU v5 lite")
-    assert v5e["flops"] == 197e12
-    unknown = cost_model.device_peaks(kind="warp-drive-9000")
-    assert unknown["nominal"] is True
+    assert v5e["flops"] == 197e12 and v5e["hbm_bytes"] == 16.9e9
+    cpu = cost_model.device_peaks(kind="cpu")
+    assert cpu["nominal"] is True
+    assert cost_model.device_peaks()["kind"] == "cpu"  # the suite's device
     paddle.set_flags({"device_peaks": "flops=5e13, hbm_bw=2e12"})
     try:
-        p = cost_model.device_peaks(kind="warp-drive-9000")
-        # any subset overrides; the rest keeps the fallback values
+        p = cost_model.device_peaks(kind="cpu")
+        # any subset overrides; the rest keeps the table's values
         assert p["flops"] == 5e13 and p["hbm_bw"] == 2e12
-        assert p["ici_bw"] == unknown["ici_bw"]
+        assert p["ici_bw"] == cpu["ici_bw"]
         assert p["nominal"] is False
         # garbage entries degrade, never raise
         paddle.set_flags({"device_peaks": "flops=oops,junk,=3"})
         assert cost_model.device_peaks(kind="TPU v4")["flops"] == 275e12
+    finally:
+        paddle.set_flags({"device_peaks": ""})
+
+
+def test_unknown_accelerator_kind_is_an_error():
+    """An accelerator the table does not know must not get made-up
+    peaks: the error names the kind. Only a complete FLAGS_device_peaks
+    (new silicon, all four numbers asserted) stands in for a table row."""
+    with pytest.raises(ValueError, match="warp-drive-9000"):
+        cost_model.device_peaks(kind="warp-drive-9000")
+    paddle.set_flags({"device_peaks": "flops=5e13, hbm_bw=2e12"})
+    try:
+        with pytest.raises(ValueError, match="warp-drive-9000"):
+            cost_model.device_peaks(kind="warp-drive-9000")
+        paddle.set_flags({"device_peaks":
+                          "flops=5e13,hbm_bw=2e12,ici_bw=1e11,hbm_bytes=3e10"})
+        p = cost_model.device_peaks(kind="warp-drive-9000")
+        assert p["hbm_bytes"] == 3e10 and p["nominal"] is False
     finally:
         paddle.set_flags({"device_peaks": ""})
 

@@ -264,26 +264,6 @@ def test_return_numpy_false_returns_lazy_tensors():
     assert np.asarray(res[0]).shape == ()  # __array__ is the sync point
 
 
-# -- persistent compile cache ------------------------------------------------
-
-
-def test_persistent_compile_cache_flag(tmp_path):
-    import jax
-
-    ambient = jax.config.jax_compilation_cache_dir  # conftest's .jax_cache
-    cache_dir = str(tmp_path / "xla_cache")
-    set_flags({"persistent_compile_cache_dir": cache_dir})
-    try:
-        exe, loss, X, Y = _build_train_step()
-        exe.run(feed={"x": X, "y": Y}, fetch_list=[loss])
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-    finally:
-        # set_flags alone restores the ambient configuration immediately
-        # (the executor watches the flag) — no executor call needed
-        set_flags({"persistent_compile_cache_dir": ""})
-        assert jax.config.jax_compilation_cache_dir == ambient
-
-
 # -- bench smoke -------------------------------------------------------------
 
 

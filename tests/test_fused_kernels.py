@@ -202,13 +202,13 @@ def test_layernorm_block_rows_scale_with_h(monkeypatch):
     assert lnr._block_rows(1024, 8192) == 64
     assert lnr._block_rows(1024, 16384) == 32
     assert lnr._block_rows(4, 256) == 4  # tiny inputs: one short tile
-    monkeypatch.setattr(lnr, "on_tpu_platform", lambda: True)
+    monkeypatch.setattr(lnr, "can_emit_mosaic", lambda: True)
     ok = jnp.zeros((2, lnr._MAX_H), jnp.float32)
     wok = jnp.zeros((lnr._MAX_H,), jnp.float32)
-    assert lnr._supported(ok, wok, wok)
+    assert lnr._supported(ok, ok, wok, wok)
     big = jnp.zeros((2, lnr._MAX_H * 2), jnp.float32)
     wbig = jnp.zeros((lnr._MAX_H * 2,), jnp.float32)
-    assert not lnr._supported(big, wbig, wbig)
+    assert not lnr._supported(big, big, wbig, wbig)
 
 
 def test_layernorm_residual_tensor_autograd_matches_unfused():

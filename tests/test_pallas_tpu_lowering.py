@@ -1,0 +1,149 @@
+"""Every default-on pallas kernel must lower for the TPU platform.
+
+Interpret-mode tests never apply the Pallas->Mosaic lowering rules (block
+shapes, memory spaces), so a kernel can pass them and still be refused at
+trace time on the chip. Lowering with ``lowering_platforms=("tpu",)``
+runs those rules on a CPU-only host: no device is needed and nothing is
+executed. Shapes are chip_smoke.py's BERT-base / ResNet-50 ones plus a
+ragged tail each. The Mosaic compiler proper (VMEM limits, op support)
+only runs on the chip — see .claude/skills/verify/SKILL.md for the AOT
+recipe that reaches it from the sandbox.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+# ops/pallas/__init__.py re-exports functions under the module names
+lnr = importlib.import_module("paddle_tpu.ops.pallas.layernorm_residual")
+cbr = importlib.import_module("paddle_tpu.ops.pallas.conv_bn_relu")
+fla = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
+opu = importlib.import_module("paddle_tpu.ops.pallas.optimizer_update")
+
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+
+def _sds(shape, dtype=F32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _lower_for_tpu(fn, *args):
+    """Trace ``fn`` on abstract operands and lower it for TPU; returns
+    the module text. The suite runs with x64 on and the chip with it
+    off, so tracing happens with x64 off (Mosaic has no float64)."""
+    with jax.enable_x64(False):
+        text = jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("rows,h,xdt,rdt", [
+    (128 * 128, 768, BF16, BF16),   # BERT-base phase 1 / 2: 16384 rows
+    (128 * 128, 768, BF16, F32),    # first layer: f32 embeddings residual
+    (1000, 768, F32, F32),          # ragged last row tile
+])
+def test_layernorm_residual_fwd_bwd(rows, h, xdt, rdt):
+    x, r = _sds((rows, h), xdt), _sds((rows, h), rdt)
+    w, col = _sds((h,)), _sds((rows, 1))
+    text = _lower_for_tpu(
+        lambda x, r, w, b: lnr._pallas_fwd(x, r, w, b, 1e-5), x, r, w, w)
+    assert '"layernorm_residual_fwd"' in text
+    text = _lower_for_tpu(lnr._pallas_bwd, x, r, w, col, col, x)
+    assert '"layernorm_residual_bwd"' in text
+
+
+@pytest.mark.parametrize("b,h,l,d,rate,names", [
+    # BERT-base phase 2: key-padding bias + attention dropout
+    (32, 12, 512, 64, 0.1, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+    # a 128-multiple that is no 256-multiple: blocks shrink to 128
+    (2, 12, 384, 64, 0.0, ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")),
+    # whole sequence in one tile: the all-heads-per-program kernels
+    (8, 12, 128, 64, 0.1, ("flash_fwd_small", "flash_bwd_small")),
+])
+def test_flash_attention_fwd_bwd(b, h, l, d, rate, names):
+    q = _sds((b, h, l, d), BF16)
+    bias = _sds((b, 1, 1, l), BF16)
+    seed = _sds((), jnp.int32)
+    scale = d ** -0.5
+
+    def fwd_bwd(q, k, v, bias, seed, g):
+        out, lse = fla._pallas_fwd(q, k, v, bias, seed, False, scale, rate)
+        return fla._pallas_bwd(q, k, v, bias, seed, False, scale, rate,
+                               out, lse, g)
+
+    text = _lower_for_tpu(fwd_bwd, q, q, q, bias, seed, q)
+    for name in names:
+        assert f'"{name}"' in text
+
+
+def test_flash_attention_causal():
+    q = _sds((2, 12, 1024, 64), BF16)
+    text = _lower_for_tpu(
+        lambda q, k, v: fla._pallas_fwd(q, k, v, None, jnp.int32(0), True,
+                                        0.125, 0.0), q, q, q)
+    assert '"flash_fwd"' in text
+
+
+@pytest.mark.parametrize("n,cin,hw,cout,k,stride,pad", [
+    (128, 3, 224, 64, 7, 2, 3),     # ResNet-50 stem
+    (128, 64, 56, 64, 1, 1, 0),     # bottleneck conv1 (pointwise)
+    (128, 128, 56, 128, 3, 2, 1),   # stage-2 entry, strided 3x3
+    (128, 512, 7, 512, 3, 1, 1),    # last stage: K = 4608
+    (2, 16, 7, 24, 3, 1, 1),        # ragged: 98 rows, 24 channels
+])
+def test_conv_bn_relu_train_fwd_bwd_and_eval(n, cin, hw, cout, k, stride,
+                                             pad):
+    x = _sds((n, cin, hw, hw), BF16)
+    w = _sds((cout, cin, k, k), BF16)
+    vec = _sds((cout,))
+    kw = dict(stride=stride, padding=pad, momentum=0.9, eps=1e-5,
+              data_format="NCHW", force=True)
+
+    def train_loss(x, w, gamma, beta, mean, var):
+        y, _, _ = cbr._fused(x, w, gamma, beta, mean, var, training=True,
+                             **kw)
+        return y.astype(F32).sum()
+
+    text = _lower_for_tpu(
+        jax.value_and_grad(train_loss, argnums=(0, 1, 2, 3)),
+        x, w, vec, vec, vec, vec)
+    for name in ("conv_mm_stats", "conv_centered_sumsq", "conv_bn_relu",
+                 "conv_bn_bwd_partials", "conv_bn_bwd_dco"):
+        assert f'"{name}"' in text
+    text = _lower_for_tpu(
+        lambda *a: cbr._fused(*a, training=False, **kw)[0],
+        x, w, vec, vec, vec, vec)
+    assert '"conv_mm_affine_relu"' in text
+
+
+@pytest.mark.parametrize("shape", [
+    (1000, 2048),     # ResNet-50 classifier weight
+    (64, 3, 7, 7),    # stem weight: 9408 elements, padded to whole tiles
+])
+def test_momentum_update(shape):
+    p = _sds(shape)
+    text = _lower_for_tpu(
+        lambda p, g, v, lr: opu._pallas_update(p, g, v, lr, 0.9, 1e-4,
+                                               False),
+        p, p, p, _sds((), F32))
+    assert '"momentum_update"' in text
+
+
+def test_gate_closes_under_a_multi_device_mesh(monkeypatch):
+    """jax refuses to partition a Mosaic call automatically, so under a
+    mesh of more than one device every kernel hands its op to XLA: a
+    sharded trainer with default flags must trace (found by AOT-compiling
+    the dp=2 x tp=2 BERT step; it raised NotImplementedError)."""
+    from paddle_tpu import parallel
+    from paddle_tpu.ops.pallas import _platform
+
+    monkeypatch.setattr(_platform, "on_tpu_platform", lambda: True)
+    x, w = jnp.zeros((256, 128), BF16), jnp.zeros((128,))
+    assert _platform.can_emit_mosaic() and lnr._supported(x, x, w, w)
+    with parallel.mesh_scope(parallel.create_mesh(dp=2, tp=2)):
+        assert not _platform.can_emit_mosaic()
+        assert not lnr._supported(x, x, w, w)
+    with parallel.mesh_scope(parallel.create_mesh(dp=1)):
+        assert _platform.can_emit_mosaic()

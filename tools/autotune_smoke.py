@@ -11,10 +11,10 @@ TPU):
    the non-default schedules the tuner may pick.
 2. **Offline search works** — tuning the two kernels measures the
    default point, prunes invalid candidates before any compile, and
-   records a winner in the versioned JSON cache next to
-   FLAGS_persistent_compile_cache_dir.
+   records a winner in the versioned JSON cache file handed to
+   ``tuning.reset_tuning_cache(path)``.
 3. **Warm cache = zero search** — a FRESH process pointed at the same
-   cache dir resolves the tuned schedules with autotune::search == 0
+   cache file resolves the tuned schedules with autotune::search == 0
    and autotune::cache_hit > 0 (the steady-state-pays-nothing
    contract), and the resolved params equal the parent's winners.
 4. **Corruption degrades, never crashes** — a truncated cache file in
@@ -97,8 +97,9 @@ def _tune_and_persist(cache_dir):
     from paddle_tpu import profiler, tuning
     from paddle_tpu.flags import set_flags
 
-    set_flags({"persistent_compile_cache_dir": cache_dir,
-               "kernel_autotune": "search"})
+    path = os.path.join(cache_dir, tuning.CACHE_FILE_NAME)
+    set_flags({"kernel_autotune": "search"})
+    tuning.reset_tuning_cache(path)
     tuner = tuning.KernelTuner(measure_n=2)
     winners = {}
     res = tuner.tune("layernorm_residual",
@@ -112,7 +113,6 @@ def _tune_and_persist(cache_dir):
                      candidates=[{"tile_m": 64}, {"tile_m": 128}],
                      **CBR_INFO)
     winners["conv_bn_relu"] = res.params
-    path = os.path.join(cache_dir, tuning.CACHE_FILE_NAME)
     assert os.path.exists(path), "tuning cache file not written"
     with open(path) as f:
         raw = json.load(f)
@@ -134,6 +134,7 @@ sys.path.insert(0, {root!r})
 import paddle_tpu
 from paddle_tpu import profiler, tuning
 
+tuning.reset_tuning_cache({path!r})
 ln = tuning.resolve("layernorm_residual", **{ln_info!r})
 cbr = tuning.resolve("conv_bn_relu", **{cbr_info!r})
 c = profiler.counters()
@@ -150,13 +151,16 @@ print(json.dumps({{
 
 
 def _fresh_process(cache_dir, extra_env=None):
+    from paddle_tpu import tuning
+
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
-               FLAGS_persistent_compile_cache_dir=cache_dir,
                FLAGS_kernel_autotune="search")
     env.update(extra_env or {})
-    code = _CHILD.format(root=root, ln_info=LN_INFO, cbr_info=CBR_INFO)
+    code = _CHILD.format(
+        root=root, ln_info=LN_INFO, cbr_info=CBR_INFO,
+        path=os.path.join(cache_dir, tuning.CACHE_FILE_NAME))
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=180)
     assert out.returncode == 0, (out.stdout, out.stderr)
