@@ -30,6 +30,7 @@ import jax.numpy as jnp
 # TrainStepFn.__init__)
 _step_fn_counter = itertools.count()
 
+from ..profiler import RecordEvent
 from . import autograd
 from .random import default_generator
 from .tensor import Tensor
@@ -433,25 +434,31 @@ class TrainStepFn:
         return pure
 
     def __call__(self, *batch):
-        batch = tuple(
-            b._array if isinstance(b, Tensor) else jnp.asarray(b) for b in batch
-        )
+        # the span names parallel/train.py's sharded step uses; no outer
+        # train::step here (callers wrap their own, and a wrapper would
+        # take every device gap in the benchmark's attribution)
+        with RecordEvent("train::shard_batch"):  # H2D of a host batch
+            batch = tuple(
+                b._array if isinstance(b, Tensor) else jnp.asarray(b)
+                for b in batch
+            )
         if not getattr(self, "_usage_checked", False):
             self._freeze_unused_params(batch)
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         self._rng, sub = jax.random.split(self._rng)
         from ..flags import flag
 
-        if flag("check_nan_inf"):
-            # FLAGS_check_nan_inf (platform/flags.cc:44 →
-            # details/nan_inf_utils_detail.cc): the reference scans every
-            # op's outputs post-run; the XLA-native equivalent is checkify
-            # float_checks — every primitive inside the compiled step gets
-            # an instrumented NaN check that reports the producing
-            # operation's source location.
-            metrics = self._run_checked(batch, lr, sub)
-        else:
-            metrics = self._dispatch(batch, lr, sub)
+        with RecordEvent("train::step_dispatch"):
+            if flag("check_nan_inf"):
+                # FLAGS_check_nan_inf (platform/flags.cc:44 →
+                # details/nan_inf_utils_detail.cc): the reference scans
+                # every op's outputs post-run; the XLA-native equivalent is
+                # checkify float_checks — every primitive inside the
+                # compiled step gets an instrumented NaN check that reports
+                # the producing operation's source location.
+                metrics = self._run_checked(batch, lr, sub)
+            else:
+                metrics = self._dispatch(batch, lr, sub)
         if flag("benchmark"):
             # FLAGS_benchmark: synchronous dispatch for exact timings
             jax.block_until_ready(metrics)

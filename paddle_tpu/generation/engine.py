@@ -59,6 +59,7 @@ from __future__ import annotations
 
 import itertools
 import threading
+import time
 from collections import OrderedDict, deque
 
 import numpy as np
@@ -71,7 +72,8 @@ from ..flags import flag
 from ..framework.jit import functional_call
 from ..monitor import flight_recorder as _flight
 from ..monitor import tracing as _tracing
-from ..profiler import RecordEvent, counters as _counters
+from ..profiler import RecordEvent, add_span as _add_span
+from ..profiler import counters as _counters
 from . import cache as _cache
 from . import paging as _paging
 from .sampling import sample_logits
@@ -290,6 +292,11 @@ class GenerationEngine:
         # registry — two engines may share avals but not weights)
         self._instance = next(_engine_counter)
         self.warmed = False
+        # a driver that splits its loop thread's time by phase (the
+        # scheduler's stall record) puts its own dict here: each call's
+        # enqueue and fetch nanoseconds are added to it (_fetched). Not a
+        # parameter of step()/admit(): callers and tests wrap those
+        self.phase_split = None
         # the serving-wide warmup-snapshot discipline; the continuous
         # batcher notes growth through this same watch
         self.watch = CompileWatch(
@@ -384,6 +391,14 @@ class GenerationEngine:
         if self.speculative:
             n += _cache.cache_nbytes(self._kv_draft)
         return n
+
+    def device_memory_stats(self) -> dict:
+        """Allocator state of the device that holds the cache, where the
+        backend reports it (the CPU backend reports nothing)."""
+        stats = next(iter(self._kv[0].devices())).memory_stats() or {}
+        return {k: int(stats[k]) for k in (
+            "bytes_in_use", "bytes_reserved", "largest_free_block_bytes")
+            if k in stats}
 
     def kv_bytes_per_token(self) -> int:
         """Cache bytes one decoded token occupies across all layers."""
@@ -542,16 +557,21 @@ class GenerationEngine:
 
     # -- compile accounting ---------------------------------------------------
 
-    def _dispatch(self, label, jitted, args):
+    def _dispatch(self, label, jitted, make_args):
         """Run one compiled step through the shared compiled-callable
         runtime: new signatures are AOT-compiled and cost-captured (MFU
         in ``/statz``) under the one policy every dispatch site shares,
         and every compile is COUNTED (``generation::compile``, the
-        store's miss counter)."""
+        store's miss counter). ``make_args`` builds the argument tuple,
+        so that the state walk, the small host-to-device puts and the
+        signature over every leaf are one ``generation::args`` span
+        inside the caller's."""
         store = self._stores[label]
-        leaves = jax.tree_util.tree_leaves(args)
-        sig = (self._instance,) + tuple(
-            (tuple(x.shape), str(x.dtype)) for x in leaves)
+        with RecordEvent("generation::args"):
+            args = make_args()
+            leaves = jax.tree_util.tree_leaves(args)
+            sig = (self._instance,) + tuple(
+                (tuple(x.shape), str(x.dtype)) for x in leaves)
         entry, disposition = store.get_or_build(
             sig, lambda: (jitted, None))
         # the slot-admission / dispatch span (if one is current) learns
@@ -559,6 +579,24 @@ class GenerationEngine:
         # a /tracez reader needs (the runtime adds cache_key + flops)
         _tracing.annotate(program_cache=disposition)
         return store.dispatch(entry, *args)
+
+    def _fetched(self, phase, t0_ns, value, to_host):
+        """``value`` on the host, the wait for it timed as the span
+        ``<phase>_fetch``: ``generation::prefill`` / ``::decode`` close
+        when the program is enqueued, this one closes when its result
+        has arrived, so a slow device reads slow here. ``t0_ns`` is when
+        the enqueue phase began; both phases' nanoseconds go to the
+        driver's :attr:`phase_split`, if it gave one."""
+        t1 = time.perf_counter_ns()
+        out = to_host(value)
+        t2 = time.perf_counter_ns()
+        fetch = phase + "_fetch"
+        _add_span(fetch, t1, t2)
+        split = self.phase_split
+        if split is not None:
+            split[phase] = split.get(phase, 0) + t1 - t0_ns
+            split[fetch] = split.get(fetch, 0) + t2 - t1
+        return out
 
     def extra_compiles(self) -> int:
         """Compiles since warmup — steady state must keep this at 0."""
@@ -955,17 +993,20 @@ class GenerationEngine:
         temp = (self.default_temperature if temperature is None
                 else float(temperature))
         ctr = self._next_key_step()
+        t0 = time.perf_counter_ns()
         with RecordEvent("generation::prefill"):
             if self.speculative:
-                out = self._dispatch("prefill", self._spec_prefill_jit, (
-                    self._state(), self._draft_state(), self._kv,
-                    self._kv_draft, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(padded[None]), jnp.asarray(n, jnp.int32),
-                    jnp.asarray(temp, jnp.float32),
-                    jnp.asarray(ctr, jnp.int32)))
+                out = self._dispatch(
+                    "prefill", self._spec_prefill_jit, lambda: (
+                        self._state(), self._draft_state(), self._kv,
+                        self._kv_draft, jnp.asarray(slot, jnp.int32),
+                        jnp.asarray(padded[None]),
+                        jnp.asarray(n, jnp.int32),
+                        jnp.asarray(temp, jnp.float32),
+                        jnp.asarray(ctr, jnp.int32)))
                 self._kv, self._kv_draft, tok = out
             else:
-                out = self._dispatch("prefill", self._prefill_jit, (
+                out = self._dispatch("prefill", self._prefill_jit, lambda: (
                     self._state(), self._kv,
                     jnp.asarray(slot, jnp.int32),
                     jnp.asarray(padded[None]),
@@ -973,7 +1014,7 @@ class GenerationEngine:
                     jnp.asarray(temp, jnp.float32),
                     jnp.asarray(ctr, jnp.int32)))
                 self._kv, tok = out
-        return int(tok)
+        return self._fetched("generation::prefill", t0, tok, int)
 
     # -- paged layout: host-side page management ------------------------------
     #
@@ -1114,17 +1155,19 @@ class GenerationEngine:
         temp = (self.default_temperature if temperature is None
                 else float(temperature))
         ctr = self._next_key_step()
+        t0 = time.perf_counter_ns()
         with RecordEvent("generation::prefill"):
-            out = self._dispatch("prefill", self._paged_prefill_jit, (
-                self._state(), self._kv, jnp.asarray(slot, jnp.int32),
-                jnp.asarray(padded[None]),
-                jnp.asarray(shared_len, jnp.int32),
-                jnp.asarray(len(suffix), jnp.int32),
-                jnp.asarray(n, jnp.int32),
-                jnp.asarray(temp, jnp.float32),
-                jnp.asarray(ctr, jnp.int32)))
+            out = self._dispatch(
+                "prefill", self._paged_prefill_jit, lambda: (
+                    self._state(), self._kv, jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(padded[None]),
+                    jnp.asarray(shared_len, jnp.int32),
+                    jnp.asarray(len(suffix), jnp.int32),
+                    jnp.asarray(n, jnp.int32),
+                    jnp.asarray(temp, jnp.float32),
+                    jnp.asarray(ctr, jnp.int32)))
         self._kv, tok = out
-        return int(tok)
+        return self._fetched("generation::prefill", t0, tok, int)
 
     def _note_prefix(self, tenant, prompt_tokens, shared_tokens,
                      matched_pages):
@@ -1408,7 +1451,7 @@ class GenerationEngine:
         ctr = self._next_key_step()
         with RecordEvent("generation::prefill_export"):
             planes, tok = self._dispatch(
-                "prefill", self._prefill_export_jit, (
+                "prefill", self._prefill_export_jit, lambda: (
                     self._state(), jnp.asarray(padded[None]),
                     jnp.asarray(n, jnp.int32),
                     jnp.asarray(temp, jnp.float32),
@@ -1421,7 +1464,7 @@ class GenerationEngine:
         padded, n = self._padded_prompt(prompt)
         with RecordEvent("generation::draft_prefill"):
             self._kv_draft = self._dispatch(
-                "prefill", self._draft_prefill_jit, (
+                "prefill", self._draft_prefill_jit, lambda: (
                     self._draft_state(), self._kv_draft,
                     jnp.asarray(slot, jnp.int32),
                     jnp.asarray(padded[None]),
@@ -1493,25 +1536,20 @@ class GenerationEngine:
             # compiled step, so the jitted scatter only ever writes
             # pages private to their slot (or the trash page)
             self._prepare_decode_writes()
-            with RecordEvent("generation::decode"):
-                out = self._dispatch("decode", self._paged_decode_jit, (
-                    self._state(), self._kv,
-                    jnp.asarray(np.asarray(tokens, np.int32)),
-                    jnp.asarray(np.asarray(temps, np.float32)),
-                    jnp.asarray(ctr, jnp.int32)))
-            self._kv, nxt = out
-            for s, live in enumerate(self._slot_live):
-                if live:
-                    self._pos_host[s] += 1
-            return np.asarray(nxt)
+        jitted = self._paged_decode_jit if self.paged else self._decode_jit
+        t0 = time.perf_counter_ns()
         with RecordEvent("generation::decode"):
-            out = self._dispatch("decode", self._decode_jit, (
+            out = self._dispatch("decode", jitted, lambda: (
                 self._state(), self._kv,
                 jnp.asarray(np.asarray(tokens, np.int32)),
                 jnp.asarray(np.asarray(temps, np.float32)),
                 jnp.asarray(ctr, jnp.int32)))
         self._kv, nxt = out
-        return np.asarray(nxt)
+        if self.paged:
+            for s, live in enumerate(self._slot_live):
+                if live:
+                    self._pos_host[s] += 1
+        return self._fetched("generation::decode", t0, nxt, np.asarray)
 
     def spec_step(self, tokens, temps, busy=None):
         """One speculative round for every slot: draft program (k
@@ -1527,18 +1565,22 @@ class GenerationEngine:
                 "with draft_model= (FLAGS_speculative_enabled)")
         toks = jnp.asarray(np.asarray(tokens, np.int32))
         pos = self._kv[-1]
+        t0 = time.perf_counter_ns()
         with RecordEvent("generation::draft"):
             self._kv_draft, proposals = self._dispatch(
-                "draft", self._draft_jit, (
+                "draft", self._draft_jit, lambda: (
                     self._draft_state(), self._kv_draft, pos, toks))
         ctr = self._next_key_step()
         with RecordEvent("generation::verify"):
-            out = self._dispatch("verify", self._verify_jit, (
+            out = self._dispatch("verify", self._verify_jit, lambda: (
                 self._state(), self._kv, toks, proposals,
                 jnp.asarray(np.asarray(temps, np.float32)),
                 jnp.asarray(ctr, jnp.int32)))
         self._kv, ts, counts = out
-        counts = np.asarray(counts)
+        # the round's two enqueues count as the decode phase; the wait
+        # for both programs is the one fetch
+        ts, counts = self._fetched(
+            "generation::decode", t0, (ts, counts), jax.device_get)
         n_busy = self.slots if busy is None else len(busy)
         if n_busy:
             accepted = int(counts.sum() - self.slots if busy is None
@@ -1553,7 +1595,7 @@ class GenerationEngine:
             _mcounter("generation/spec_proposed_total").inc(
                 self.draft_k * n_busy)
             _mcounter("generation/spec_accepted_total").inc(accepted)
-        return np.asarray(ts), counts
+        return ts, counts
 
     def spec_stats(self) -> dict:
         """Speculative acceptance accounting since the last reset/

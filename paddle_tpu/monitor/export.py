@@ -245,6 +245,7 @@ def export_merged_chrome_trace(path, device_trace_dir=None) -> str:
     events = [{"name": "process_name", "ph": "M", "pid": os.getpid(),
                "args": {"name": "paddle_tpu host"}}]
     events.extend(host)
+    events.extend(profiler.counter_samples())
     events.extend(_align_clock_bases(
         host, _device_trace_events(device_trace_dir)))
     # the tail-sampled traces ride along: a p99 outlier's span tree

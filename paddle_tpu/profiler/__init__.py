@@ -21,6 +21,10 @@ import time
 __all__ = [
     "RecordEvent",
     "record_event",
+    "enabled",
+    "add_span",
+    "record_counter",
+    "counter_samples",
     "start_profiler",
     "stop_profiler",
     "profiler",
@@ -35,6 +39,10 @@ __all__ = [
 
 _state = threading.local()
 _events = []
+# timestamped counter samples (chrome ph "C"), on the spans' clock but in
+# a list of their own: host_events() readers index ev["dur"] on every
+# event, and a sample has none
+_samples = []
 _events_lock = threading.Lock()
 _enabled = [False]
 _device_trace_dir = [None]
@@ -79,6 +87,19 @@ def _now_us():
     return time.perf_counter_ns() / 1e3
 
 
+def _emit_span(name, ts_us, dur_us):
+    ev = {
+        "name": name,
+        "ph": "X",
+        "ts": ts_us,
+        "dur": dur_us,
+        "pid": os.getpid(),
+        "tid": threading.get_ident() % 100000,
+    }
+    with _events_lock:
+        _events.append(ev)
+
+
 class RecordEvent:
     """RAII named range (platform/profiler.h:126). Usable as context
     manager or begin()/end() pair."""
@@ -103,16 +124,7 @@ class RecordEvent:
     def end(self):
         if not self._began_enabled or self._begin is None:
             return
-        ev = {
-            "name": self.name,
-            "ph": "X",
-            "ts": self._begin,
-            "dur": _now_us() - self._begin,
-            "pid": os.getpid(),
-            "tid": threading.get_ident() % 100000,
-        }
-        with _events_lock:
-            _events.append(ev)
+        _emit_span(self.name, self._begin, _now_us() - self._begin)
         self._begin = None
 
     def __enter__(self):
@@ -127,6 +139,48 @@ class RecordEvent:
 def record_event(name):
     with RecordEvent(name):
         yield
+
+
+def enabled() -> bool:
+    """Whether spans and counter samples are being kept: lets a caller
+    skip computing a value it would only hand to :func:`record_counter`."""
+    return _enabled[0]
+
+
+def add_span(name, t0_ns, t1_ns):
+    """Record a span from two ``time.perf_counter_ns()`` reads the caller
+    took anyway (a loop that keeps its own phase split reads the clock
+    once per boundary and hands each pair here). Same event list and
+    clock as :class:`RecordEvent`; one boolean when the profiler is off."""
+    if _enabled[0]:
+        _emit_span(name, t0_ns / 1e3, (t1_ns - t0_ns) / 1e3)
+
+
+def record_counter(name, value):
+    """Append one timestamped sample of a quantity the caller owns (live
+    slots, queue depth) to the timeline: a chrome counter event
+    (``ph: "C"``) on the spans' clock. One boolean when the profiler is
+    off. Not to be confused with :func:`bump_counter`'s always-on
+    monotonic counts, which carry no time."""
+    if not _enabled[0]:
+        return
+    ev = {
+        "name": name,
+        "ph": "C",
+        "ts": _now_us(),
+        "pid": os.getpid(),
+        "tid": threading.get_ident() % 100000,
+        "args": {"value": value},
+    }
+    with _events_lock:
+        _samples.append(ev)
+
+
+def counter_samples():
+    """Snapshot of the :func:`record_counter` samples, in time order per
+    thread. Kept out of :func:`host_events` (a sample has no ``dur``)."""
+    with _events_lock:
+        return list(_samples)
 
 
 def _note_double_start(**fields):
@@ -291,15 +345,17 @@ def host_events():
 def reset_profiler():
     with _events_lock:
         _events.clear()
+        _samples.clear()
 
 
 def export_chrome_tracing(path):
-    """Write collected host events as a chrome://tracing JSON file."""
+    """Write collected host events and counter samples as a
+    chrome://tracing JSON file."""
     d = os.path.dirname(path)
     if d:
         os.makedirs(d, exist_ok=True)
     with _events_lock:
-        trace = {"traceEvents": list(_events)}
+        trace = {"traceEvents": list(_events) + list(_samples)}
     with open(path, "w") as f:
         json.dump(trace, f)
     return path

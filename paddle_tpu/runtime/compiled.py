@@ -41,7 +41,7 @@ import threading
 import jax
 
 from ..flags import flag
-from ..profiler import bump_counter
+from ..profiler import RecordEvent, bump_counter
 
 __all__ = ["CompiledEntry", "CompiledStore", "CompileWatch",
            "any_deleted", "cache_capacity"]
@@ -212,8 +212,11 @@ class CompiledStore:
         ``<label>::schedule_refresh`` — so the swap is a clean
         recompile, never a stale trace. Signatures that resolve no
         tuned kernel are immune (no fleet-wide recompile waves).
+
+        The lookup is the ``runtime::lookup`` span, nested inside
+        whatever span the dispatch site holds.
         """
-        with self._lock:
+        with RecordEvent("runtime::lookup"), self._lock:
             entry = self._entries.get(sig)
             refresh_gen = 0
             if entry is not None and _schedules_stale(entry):
@@ -306,7 +309,9 @@ class CompiledStore:
             self._aot_compile(entry, args, capture_meta)
         runner = entry.aot if entry.aot is not None else entry.jitted
         try:
-            out = runner(*args)
+            # the enqueue alone: returns before the device finishes
+            with RecordEvent("runtime::launch"):
+                out = runner(*args)
         except (TypeError, ValueError):
             # what a Compiled raises for avals / shardings it was not
             # built for; anything else (a device error) is not drift

@@ -39,6 +39,7 @@ from ..generation.handoff import PageSlab
 from ..monitor import counter, gauge, histogram
 from ..monitor import flight_recorder as _flight
 from ..monitor import tracing as _tracing
+from .. import profiler as _profiler
 from .batcher import (
     DeadlineExceededError,
     QueueFullError,
@@ -46,6 +47,11 @@ from .batcher import (
 )
 
 __all__ = ["ContinuousBatcher", "GenerationRequest"]
+
+# One loop iteration (pick through deliver, idle wait excluded) longer
+# than this leaves a ``generation_stall`` flight event with its split.
+# A constant, not a flag: a decode step is tens of milliseconds.
+_STALL_NS = 1_000_000_000
 
 
 class GenerationRequest:
@@ -139,6 +145,11 @@ class ContinuousBatcher:
 
         self._last = np.zeros(s, np.int32)
         self._temps = np.zeros(s, np.float32)
+        # the loop thread's phase clock (perf_counter_ns, the profiler's):
+        # where its current phase and its current iteration began, and
+        # the ns each phase took in this iteration
+        self._t_ns = self._t_iter_ns = 0
+        self._split = {}
         # the engine owns the warmup-snapshot watch (armed by warmup());
         # the loop notes growth through it after every step
         self._watch = engine.watch
@@ -372,58 +383,81 @@ class ContinuousBatcher:
             prompt_tokens=req.prompt_len, tokens=len(req.tokens))
         req.done()
 
+    def _mark(self, name):
+        """Close the loop thread's current phase at this instant as span
+        ``name``; the next phase begins here. One clock read serves the
+        profiler (a span, when it is on) and the iteration's split
+        (always: the stall record needs it)."""
+        now = time.perf_counter_ns()
+        _profiler.add_span(name, self._t_ns, now)
+        self._split[name] = self._split.get(name, 0) + now - self._t_ns
+        self._t_ns = now
+
+    def _pick(self):
+        """Expire what waited too long, then take the queue's head if a
+        slot is vacant and the engine has room for it. Returns
+        ``(request, slot, midbatch, admission span)`` or None."""
+        engine = self.engine
+        with self._lock:
+            now = self._clock()
+            self._pop_expired_locked(now)
+            if not self._q:
+                return None
+            free = next((s for s, r in enumerate(self._slots)
+                         if r is None), None)
+            if free is None:
+                return None
+            # paged layout: a vacant slot is NOT capacity — the
+            # page pool is. Leave the head queued until enough
+            # free or evictable pages exist (slots release pages
+            # as sequences finish); ring layout always passes.
+            head = self._q[0]
+            if not engine.has_capacity(
+                    head.prompt if head.handoff is None
+                    and head.prompt is not None
+                    else head.prompt_len):
+                return None
+            req = self._q.popleft()
+            self._m_depth.set(len(self._q))
+        midbatch = self.live_slots > 0
+        # queue-wait is knowable only now: record it backwards into
+        # the member trace, then time the prefill as a
+        # slot-admission span carrying the bucket-padding waste the
+        # p99 post-mortem needs (engine._dispatch annotates it with
+        # the cache disposition + FLOPs while it is current)
+        _tracing.record_interval(
+            "serving::queue_wait", req.trace, req.t_submit, self._clock(),
+            prompt_tokens=req.prompt_len)
+        if req.handoff is not None:
+            # a prefill-tier forward already happened elsewhere;
+            # admission is one functional cache insert
+            asp = _tracing.begin_span(
+                "serving::slot_admission", slot=free,
+                midbatch=midbatch, handoff=True,
+                prompt_tokens=req.prompt_len)
+        else:
+            bucket = engine.bucket_for(len(req.prompt))
+            asp = _tracing.begin_span(
+                "serving::slot_admission", slot=free,
+                midbatch=midbatch,
+                bucket=bucket, prompt_tokens=req.prompt_len,
+                padded_tokens=bucket - len(req.prompt),
+                fill=round(len(req.prompt) / bucket, 4))
+        return req, free, midbatch, asp
+
     def _admit_ready(self):
         """Fill vacant slots from the queue (the continuous-batching
         move: admission happens between decode steps, never tearing the
-        running batch down)."""
+        running batch down). Phases: ``serving::pick`` up to the call
+        into the engine, the engine's own ``generation::prefill`` and
+        ``::prefill_fetch``, then ``serving::install``."""
         engine = self.engine
         while True:
-            with self._lock:
-                now = self._clock()
-                self._pop_expired_locked(now)
-                if not self._q:
-                    return
-                free = next((s for s, r in enumerate(self._slots)
-                             if r is None), None)
-                if free is None:
-                    return
-                # paged layout: a vacant slot is NOT capacity — the
-                # page pool is. Leave the head queued until enough
-                # free or evictable pages exist (slots release pages
-                # as sequences finish); ring layout always passes.
-                head = self._q[0]
-                if not engine.has_capacity(
-                        head.prompt if head.handoff is None
-                        and head.prompt is not None
-                        else head.prompt_len):
-                    return
-                req = self._q.popleft()
-                self._m_depth.set(len(self._q))
-            midbatch = self.live_slots > 0
-            t_admit = self._clock()
-            # queue-wait is knowable only now: record it backwards into
-            # the member trace, then time the prefill as a
-            # slot-admission span carrying the bucket-padding waste the
-            # p99 post-mortem needs (engine._dispatch annotates it with
-            # the cache disposition + FLOPs while it is current)
-            _tracing.record_interval(
-                "serving::queue_wait", req.trace, req.t_submit, t_admit,
-                prompt_tokens=req.prompt_len)
-            if req.handoff is not None:
-                # a prefill-tier forward already happened elsewhere;
-                # admission is one functional cache insert
-                asp = _tracing.begin_span(
-                    "serving::slot_admission", slot=free,
-                    midbatch=midbatch, handoff=True,
-                    prompt_tokens=req.prompt_len)
-            else:
-                bucket = engine.bucket_for(len(req.prompt))
-                asp = _tracing.begin_span(
-                    "serving::slot_admission", slot=free,
-                    midbatch=midbatch,
-                    bucket=bucket, prompt_tokens=req.prompt_len,
-                    padded_tokens=bucket - len(req.prompt),
-                    fill=round(len(req.prompt) / bucket, 4))
+            picked = self._pick()
+            self._mark("serving::pick")
+            if picked is None:
+                return
+            req, free, midbatch, asp = picked
             try:
                 with _tracing.use_span(asp):
                     if isinstance(req.handoff, PageSlab):
@@ -443,59 +477,119 @@ class ContinuousBatcher:
                                            req.temperature,
                                            tenant=req.tenant)
             except Exception as e:  # noqa: BLE001 — the loop must survive
+                self._t_ns = time.perf_counter_ns()
                 asp.set_error(f"{type(e).__name__}: {e}")
                 _tracing.record_fanin(asp, [req.trace])
                 _tracing.flag_trace(req.trace, "error")
                 self._m_errors.inc()
                 req.done(error=e)
                 continue
-            _tracing.record_fanin(asp, [req.trace])
-            with self._lock:
-                if self._closed and not self._drain:
-                    # stop(drain=False) landed while this request was in
-                    # flight between the queue pop and slot install — it
-                    # was promised a failure, not a quiet completion
-                    self._m_errors.inc()
-                    req.done(error=ServingClosedError(
-                        "generation scheduler shut down before the "
-                        "request reached a decode slot"))
-                    continue
-            req.t_first_token = self._clock()
-            self._h_ttft.labels(kind=self.kind, tenant=req.tenant).observe(
-                (req.t_first_token - req.t_submit) * 1e3)
-            if midbatch:
-                self._m_midbatch.inc()
+            # the engine's spans cover its call; install begins here
+            self._t_ns = time.perf_counter_ns()
+            self._install(req, free, tok, midbatch, asp)
+            self._mark("serving::install")
+
+    def _install(self, req, free, tok, midbatch, asp):
+        """After the first token is back: close the admission span,
+        observe TTFT, deliver the token, and seat the request in its
+        slot (or complete it, if one token was all it asked for)."""
+        _tracing.record_fanin(asp, [req.trace])
+        with self._lock:
+            if self._closed and not self._drain:
+                # stop(drain=False) landed while this request was in
+                # flight between the queue pop and slot install — it
+                # was promised a failure, not a quiet completion
+                self._m_errors.inc()
+                req.done(error=ServingClosedError(
+                    "generation scheduler shut down before the "
+                    "request reached a decode slot"))
+                return
+        req.t_first_token = self._clock()
+        self._h_ttft.labels(kind=self.kind, tenant=req.tenant).observe(
+            (req.t_first_token - req.t_submit) * 1e3)
+        if midbatch:
+            self._m_midbatch.inc()
+        _flight.record_event(
+            "generation_admit", slot=free, midbatch=midbatch,
+            prompt_tokens=req.prompt_len,
+            queued_ms=round(
+                (req.t_first_token - req.t_submit) * 1e3, 3))
+        self._deliver(req, tok)
+        reason = self._finished_reason(req)
+        if reason is not None:
+            self.engine.release_slot(free)
+            self._complete(req, reason)
+            return
+        self._slots[free] = req
+        self._last[free] = tok
+        self._temps[free] = (
+            self.engine.default_temperature
+            if req.temperature is None else float(req.temperature))
+        self._m_busy.set(self.live_slots)
+
+    def _sample_counters(self, busy):
+        """Once an iteration, after admission and before the step: the
+        state the step runs with, as samples on the profiler's timeline.
+        One boolean when the profiler is off: nothing is counted then."""
+        if not _profiler.enabled():
+            return
+        _profiler.record_counter("serving::slots_busy", len(busy))
+        _profiler.record_counter("serving::kv_live_tokens", sum(
+            self._slots[s].prompt_len + len(self._slots[s].tokens)
+            for s in busy))
+
+    def _end_iteration(self):
+        """Close one pass of the loop, and leave a ``generation_stall``
+        flight event if the pass (idle wait excluded) stood still for
+        over a second — which phase held it (the engine added its own
+        to the split), and what the device's allocator looked like."""
+        split = self._split
+        total = (self._t_ns - self._t_iter_ns
+                 - split.pop("serving::idle_wait", 0))
+        self._t_iter_ns = self._t_ns
+        if total > _STALL_NS:
+            # what lies between the phases: the engine's host-side
+            # preparation around its spans
+            split["other"] = total - sum(split.values())
+            try:
+                # asked of a device that may be the one in trouble: the
+                # record goes out without the allocator's fields then
+                memory = self.engine.device_memory_stats()
+            except Exception:  # noqa: BLE001 — the loop must survive
+                memory = {}
             _flight.record_event(
-                "generation_admit", slot=free, midbatch=midbatch,
-                prompt_tokens=req.prompt_len,
-                queued_ms=round(
-                    (req.t_first_token - req.t_submit) * 1e3, 3))
-            self._deliver(req, tok)
-            reason = self._finished_reason(req)
-            if reason is not None:
-                engine.release_slot(free)
-                self._complete(req, reason)
-                continue
-            self._slots[free] = req
-            self._last[free] = tok
-            self._temps[free] = (
-                self.engine.default_temperature
-                if req.temperature is None else float(req.temperature))
-            self._m_busy.set(self.live_slots)
+                "generation_stall", iteration_ms=round(total / 1e6, 3),
+                phases_ms={k: round(v / 1e6, 3) for k, v in split.items()},
+                live_slots=self.live_slots, queue_depth=len(self._q),
+                **memory)
+        split.clear()
 
     def _loop(self):
+        # The loop thread's timeline is a PARTITION into sibling phases:
+        # serving::pick, generation::prefill, ::prefill_fetch,
+        # serving::install, generation::decode, ::decode_fetch,
+        # serving::deliver, serving::idle_wait. Never wrap them in an
+        # iteration span: the benchmark's idle_gaps gives each device gap
+        # to the span that covers most of it, so a wrapper would take
+        # every gap. Only generation::args, runtime::lookup and
+        # runtime::launch nest, inside the enqueue spans.
         engine = self.engine
+        engine.phase_split = self._split
+        self._t_ns = self._t_iter_ns = time.perf_counter_ns()
         while True:
             self._admit_ready()
             busy = [s for s, r in enumerate(self._slots) if r is not None]
+            self._sample_counters(busy)
             if not busy:
                 with self._lock:
                     if self._closed and not self._q:
                         break
                     if not self._q:
                         self._not_empty.wait(0.05)
+                self._mark("serving::idle_wait")
+                self._end_iteration()
                 continue
-            t0 = self._clock()
+            t0 = self._t_ns
             try:
                 if engine.speculative:
                     # one draft+verify round: every busy slot emits
@@ -506,6 +600,7 @@ class ContinuousBatcher:
                 else:
                     nxt = engine.step(self._last, self._temps)
             except Exception as e:  # noqa: BLE001 — fail THESE, keep serving
+                self._t_ns = time.perf_counter_ns()
                 for s in busy:
                     req, self._slots[s] = self._slots[s], None
                     engine.release_slot(s)
@@ -522,8 +617,12 @@ class ContinuousBatcher:
                 _flight.record_event(
                     "generation_step_error", slots=len(busy),
                     error=f"{type(e).__name__}: {e}"[:300])
+                self._mark("serving::deliver")
+                self._end_iteration()
                 continue
-            dt_ms = (self._clock() - t0) * 1e3
+            # the engine's spans cover its call; deliver begins here
+            self._t_ns = time.perf_counter_ns()
+            dt_ms = (self._t_ns - t0) / 1e6
             if self._watch.armed:
                 self._watch.note(slots=len(busy))
             emitted = 0
@@ -562,6 +661,8 @@ class ContinuousBatcher:
             else:
                 h_token.observe(dt_ms)
             self._m_busy.set(self.live_slots)
+            self._mark("serving::deliver")
+            self._end_iteration()
         # drained exit: nothing queued, nothing active
         self._m_busy.set(self.live_slots)
 
