@@ -52,7 +52,11 @@ class Generator:
 
     def _ensure(self):
         if self._key is None:
-            self._key = jax.random.key(self._seed, impl=prng_impl())
+            # never a tracer: the first RNG use may come from inside a
+            # trace (a program lowered before any eager draw), and a key
+            # made there would leak into this global state. Same value.
+            with jax.ensure_compile_time_eval():
+                self._key = jax.random.key(self._seed, impl=prng_impl())
 
     def manual_seed(self, seed: int):
         self._seed = seed

@@ -42,15 +42,15 @@ from ..nn.transformer import (  # noqa: F401
     causal_mask,
 )
 from .cache import (  # noqa: F401
+    CacheLostError,
     cache_nbytes,
     decode_mask,
     init_cache,
-    insert_slot,
     insert_slot_kv,
     kv_bytes_per_token,
     layer_caches,
     prefill_mask,
-    stack_layer_caches,
+    unzip_layer_caches,
 )
 from .cache import pad_slot_arrays, verify_mask  # noqa: F401
 from .engine import COMPILE_COUNTER, GenerationEngine  # noqa: F401
@@ -77,11 +77,11 @@ from .paging import (  # noqa: F401
 from .sampling import decode_loop, sample_logits, top_k_filter  # noqa: F401
 
 __all__ = [
-    "GenerationEngine", "COMPILE_COUNTER", "StaticCache",
+    "GenerationEngine", "COMPILE_COUNTER", "CacheLostError", "StaticCache",
     "QuantizedStaticCache", "PagedStaticCache", "QuantizedPagedCache",
     "causal_mask",
     "sample_logits", "top_k_filter", "decode_loop",
-    "init_cache", "layer_caches", "stack_layer_caches", "insert_slot",
+    "init_cache", "layer_caches", "unzip_layer_caches",
     "insert_slot_kv", "cache_nbytes", "kv_bytes_per_token",
     "decode_mask", "prefill_mask", "verify_mask", "pad_slot_arrays",
     "HandoffError", "pack_kv_slab", "unpack_kv_slab",

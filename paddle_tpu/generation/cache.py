@@ -6,9 +6,12 @@ functional index updates, ring-wrapping at capacity ``C``. This module
 holds the ENGINE-side pieces — the stacked whole-model cache pytree and
 the mask composition that makes the static window numerically exact:
 
-- an all-layers cache is a ``[L, B, H, C, D]`` pair plus one shared
-  ``pos [B]`` vector, so slot-level operations (insert a prefilled
-  sequence, reset a vacated slot) are single indexed updates;
+- an all-layers cache is one ``[B, H, C, D]`` array per layer and plane
+  (K, V, and at int8 their scale planes) plus one shared ``pos [B]``
+  vector. Nothing is stacked: the compiled programs take the cache
+  donated and return it, so every layer's array is written where it
+  lies (a decode step writes ``B`` rows into it, an admission one
+  slot) and no program copies a layer, let alone the cache;
 - ``decode_mask``/``prefill_mask`` compose the causal constraint with
   cache validity (entries beyond ``pos`` are zeros, never attended) into
   one additive mask per step. Because the ring keeps exactly the last
@@ -37,13 +40,18 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
-from ..nn.transformer import QuantizedStaticCache, StaticCache
+from ..errors import UnavailableError
+from ..nn.transformer import (QuantizedStaticCache, StaticCache,
+                              update_slice_in_range)
 
 __all__ = [
-    "init_cache", "layer_caches", "stack_layer_caches", "insert_slot",
-    "insert_slot_kv", "fresh_layer_caches", "cache_nbytes",
+    "CacheLostError",
+    "init_cache", "layer_caches", "unzip_layer_caches", "insert_slot_planes",
+    "insert_slot_kv",
+    "fresh_layer_caches", "cache_nbytes",
     "kv_bytes_per_token", "decode_mask", "prefill_mask", "verify_mask",
     "pad_slot_arrays",
 ]
@@ -54,50 +62,55 @@ NEG_INF = -1e9
 KV_CACHE_DTYPES = ("float32", "int8")
 
 
+class CacheLostError(UnavailableError):
+    """A compiled call failed after it had consumed the donated cache:
+    every slot's context went with it. The engine has already put a
+    zeroed ring in its place; whoever drives the engine fails every
+    live sequence, not only the one whose call raised."""
+
+
 def init_cache(num_layers, batch, num_heads, cache_len, head_dim,
                dtype="float32"):
     """Zeroed whole-model cache.
 
-    ``dtype="float32"``: ``(k [L,B,H,C,D], v [...], pos [B])`` — the
-    historical 3-tuple. ``dtype="int8"``: a 5-tuple that additionally
-    carries the per-head dynamic scale planes ``(k, v, k_scale
-    [L,B,H,C], v_scale [...], pos)`` with int8 K/V storage
-    (:class:`nn.QuantizedStaticCache` per layer). Every helper below
-    dispatches on the tuple arity, so engine code is dtype-agnostic.
+    ``dtype="float32"``: ``(k, v, pos [B])``, where ``k`` and ``v`` are
+    tuples of one ``[B, H, C, D]`` array per layer. ``dtype="int8"``: a
+    5-tuple that additionally carries the per-head dynamic scale planes
+    ``(k, v, k_scale, v_scale, pos)`` (``[B, H, C]`` per layer) with
+    int8 K/V storage (:class:`nn.QuantizedStaticCache` per layer). Every
+    helper below dispatches on the tuple arity, so engine code is
+    dtype-agnostic.
     """
-    shape = (int(num_layers), int(batch), int(num_heads), int(cache_len),
-             int(head_dim))
+    shape = (int(batch), int(num_heads), int(cache_len), int(head_dim))
     pos = jnp.zeros((int(batch),), jnp.int32)
+
+    def plane(shape, dtype):
+        return tuple(jnp.zeros(shape, dtype) for _ in range(int(num_layers)))
+
     if str(dtype) == "int8":
-        return (jnp.zeros(shape, jnp.int8), jnp.zeros(shape, jnp.int8),
-                jnp.zeros(shape[:-1], jnp.float32),
-                jnp.zeros(shape[:-1], jnp.float32), pos)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype), pos
+        return (plane(shape, jnp.int8), plane(shape, jnp.int8),
+                plane(shape[:-1], jnp.float32),
+                plane(shape[:-1], jnp.float32), pos)
+    return plane(shape, dtype), plane(shape, dtype), pos
 
 
 def layer_caches(*kv):
-    """Slice the stacked cache into per-layer views (``pos`` is shared —
+    """The whole-model cache as per-layer caches (``pos`` is shared —
     every layer writes the same step): :class:`StaticCache` for the
     3-tuple form, :class:`nn.QuantizedStaticCache` for the 5-tuple."""
     if len(kv) == 1:  # whole-cache tuple passed as one argument
         kv = tuple(kv[0])
-    pos, arrays = kv[-1], kv[:-1]
-    cls = StaticCache if len(arrays) == 2 else QuantizedStaticCache
-    return [cls(*(a[i] for a in arrays), pos)
-            for i in range(arrays[0].shape[0])]
+    pos, planes = kv[-1], kv[:-1]
+    cls = StaticCache if len(planes) == 2 else QuantizedStaticCache
+    return [cls(*arrays, pos) for arrays in zip(*planes)]
 
 
-def stack_layer_caches(caches):
-    """Re-stack per-layer caches returned by the model into the
-    whole-model arrays: ``(k, v)`` for :class:`StaticCache` layers,
-    ``(k, v, k_scale, v_scale)`` for quantized ones."""
-    if isinstance(caches[0], QuantizedStaticCache):
-        return (jnp.stack([c.k for c in caches]),
-                jnp.stack([c.v for c in caches]),
-                jnp.stack([c.k_scale for c in caches]),
-                jnp.stack([c.v_scale for c in caches]))
-    return (jnp.stack([c.k for c in caches]),
-            jnp.stack([c.v for c in caches]))
+def unzip_layer_caches(caches):
+    """The planes of the per-layer caches a forward returned, each a
+    tuple over layers: ``(k, v)`` for :class:`StaticCache` layers,
+    ``(k, v, k_scale, v_scale)`` for quantized ones. The inverse of
+    :func:`layer_caches`, less ``pos``."""
+    return tuple(zip(*(tuple(c)[:-1] for c in caches)))
 
 
 def fresh_layer_caches(num_layers, batch, num_heads, cache_len, head_dim,
@@ -109,25 +122,32 @@ def fresh_layer_caches(num_layers, batch, num_heads, cache_len, head_dim,
                                     cache_len, head_dim, dtype))
 
 
-def insert_slot(ck, cv, pos, slot, new_k, new_v, length):
-    """Install one prefilled sequence (``new_k/new_v [L, H, C, D]``)
-    into decode slot ``slot`` and set its position to ``length`` — the
-    admission write of continuous batching, a functional indexed update
-    so the batch program never recompiles when a slot turns over."""
-    ck = ck.at[:, slot].set(new_k)
-    cv = cv.at[:, slot].set(new_v)
-    return ck, cv, pos.at[slot].set(length)
+def insert_slot_planes(planes, slot, new_planes):
+    """Write one sequence's entries into row ``slot`` of every layer's
+    array, plane by plane: one ``dynamic_update_slice`` per layer and
+    plane, which a program that was given the cache donated does in
+    place. ``new_planes`` holds per plane the slot's entries layer by
+    layer: a sequence of ``[H, C, D]`` (scales ``[H, C]``) arrays, or
+    one stacked ``[L, H, C, D]`` array (the handoff slab's form)."""
+    slot = jnp.asarray(slot, jnp.int32)
+    zero = np.zeros((), np.int32)
+
+    def put(a, n):
+        return update_slice_in_range(
+            a, jax.lax.expand_dims(n, (0,)), slot, *(zero,) * (a.ndim - 1))
+
+    return tuple(tuple(put(a, new[i]) for i, a in enumerate(plane))
+                 for plane, new in zip(planes, new_planes))
 
 
-def insert_slot_kv(kv, slot, new_arrays, length):
-    """Arity-generic :func:`insert_slot`: ``kv`` is the whole-model
-    cache tuple (3 or 5 arrays, ``pos`` last) and ``new_arrays`` the
-    matching per-slot planes (``[L, H, C, D]`` values, ``[L, H, C]``
-    scales) from a prefill's :func:`stack_layer_caches`."""
-    pos = kv[-1]
-    updated = tuple(a.at[:, slot].set(n)
-                    for a, n in zip(kv[:-1], new_arrays))
-    return updated + (pos.at[slot].set(length),)
+def insert_slot_kv(kv, slot, new_planes, length):
+    """Install one prefilled sequence into decode slot ``slot`` and set
+    its position to ``length`` — the admission write of continuous
+    batching, so the batch program never recompiles when a slot turns
+    over. ``kv`` is the whole-model cache tuple (``pos`` last);
+    ``new_planes`` as :func:`insert_slot_planes` takes them."""
+    return insert_slot_planes(kv[:-1], slot, new_planes) + (
+        kv[-1].at[slot].set(length),)
 
 
 def cache_nbytes(kv) -> int:
@@ -135,7 +155,7 @@ def cache_nbytes(kv) -> int:
     positions) — the numerator of the int8-vs-f32 HBM claim, measured
     on the REAL arrays rather than derived."""
     return int(sum(int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
-                   for a in kv))
+                   for a in jax.tree_util.tree_leaves(kv)))
 
 
 def kv_bytes_per_token(num_layers, num_heads, head_dim,
@@ -189,23 +209,24 @@ def verify_mask(pos, cache_len, span, window=None, dtype="float32"):
     return jnp.where(keep, 0.0, NEG_INF).astype(dtype)[:, None]
 
 
-def pad_slot_arrays(arrays, store):
+def pad_slot_arrays(arrays, store, axis=2):
     """Zero-pad per-slot cache planes (``[L, H, C, D]`` values /
-    ``[L, H, C]`` scales) from window width ``C`` up to a wider ring
-    ``store`` along the cache axis — a prefill tier's KV slab (always
+    ``[L, H, C]`` scales; ``axis=1`` for one layer's ``[H, C, D]`` /
+    ``[H, C]``) from window width ``C`` up to a wider ring ``store``
+    along the cache axis — a prefill tier's KV slab (always
     window-wide) landing in a decode tier whose ring carries the
     speculative scratch margin. Entries past the prompt are never-
     written zeros on both sides, so padding is exact."""
     out = []
     for a in arrays:
-        c = a.shape[2]
+        c = a.shape[axis]
         if c > int(store):
             raise ValueError(
                 f"slot plane cache axis {c} exceeds the target store "
                 f"{store}")
         if c < int(store):
             pad = [(0, 0)] * a.ndim
-            pad[2] = (0, int(store) - c)
+            pad[axis] = (0, int(store) - c)
             a = jnp.pad(a, pad)
         out.append(a)
     return tuple(out)

@@ -73,14 +73,18 @@ from ..framework.jit import functional_call
 from ..monitor import flight_recorder as _flight
 from ..monitor import tracing as _tracing
 from ..profiler import RecordEvent, add_span as _add_span
+from ..profiler import bump_counter as _bump_counter
 from ..profiler import counters as _counters
+from ..runtime.compiled import any_deleted
 from . import cache as _cache
 from . import paging as _paging
 from .sampling import sample_logits
 
-__all__ = ["GenerationEngine", "COMPILE_COUNTER"]
+__all__ = ["GenerationEngine", "COMPILE_COUNTER", "CACHE_LOST_COUNTER"]
 
 COMPILE_COUNTER = "generation::compile"
+# calls that failed after they had consumed the donated cache (_dispatch)
+CACHE_LOST_COUNTER = "generation::cache_lost"
 
 # deterministic engine instance ids (cache-key stability; see __init__)
 _engine_counter = itertools.count()
@@ -92,11 +96,19 @@ class GenerationEngine:
     ``model`` must expose ``forward(input_ids, position_ids,
     attention_mask, caches) -> (logits, caches)`` with per-layer
     :class:`nn.StaticCache` support plus ``cache_spec()`` (GPTForCausalLM
-    is the reference implementation). The engine owns the stacked ring
-    cache for ``slots`` concurrent sequences and exposes the two
-    scheduler primitives: :meth:`admit` (prefill a prompt into a vacant
-    slot, returns the first sampled token) and :meth:`step` (decode one
-    token for every slot).
+    is the reference implementation). The engine owns the ring cache
+    for ``slots`` concurrent sequences and exposes the two scheduler
+    primitives: :meth:`admit` (prefill a prompt into a vacant slot,
+    returns the first sampled token) and :meth:`step` (decode one token
+    for every slot).
+
+    **The ring cache has one owner.** Its arrays are reachable from
+    ``self._kv`` (and ``self._kv_draft``) alone; every ring program that
+    takes the cache is given it donated and its result is assigned back,
+    so the arrays are written where they lie. Nothing may hold a cache
+    array across such a call: afterwards the old ones are deleted. A
+    call that fails after it consumed the cache loses every slot
+    (:meth:`_dispatch`).
     """
 
     def __init__(self, model, *, slots=None, cache_len=None,
@@ -268,15 +280,19 @@ class GenerationEngine:
         # live arrays per call (cheap, and parameter updates flow in)
         self._named = None
         self._draft_named = None
-        self._prefill_jit = jax.jit(self._prefill_pure)
-        self._spec_prefill_jit = jax.jit(self._spec_prefill_pure)
-        self._decode_jit = jax.jit(self._decode_pure)
+        # the ring programs take their cache donated (kv, kv_draft)
+        self._prefill_jit = jax.jit(self._prefill_pure, donate_argnums=(1,))
+        self._spec_prefill_jit = jax.jit(self._spec_prefill_pure,
+                                         donate_argnums=(2, 3))
+        self._decode_jit = jax.jit(self._decode_pure, donate_argnums=(1,))
         self._paged_prefill_jit = jax.jit(self._paged_prefill_pure)
         self._paged_decode_jit = jax.jit(self._paged_decode_pure)
         self._prefill_export_jit = jax.jit(self._prefill_export_pure)
-        self._draft_jit = jax.jit(self._draft_chain_pure)
-        self._verify_jit = jax.jit(self._verify_pure)
-        self._draft_prefill_jit = jax.jit(self._draft_prefill_pure)
+        self._draft_jit = jax.jit(self._draft_chain_pure,
+                                  donate_argnums=(1,))
+        self._verify_jit = jax.jit(self._verify_pure, donate_argnums=(1,))
+        self._draft_prefill_jit = jax.jit(self._draft_prefill_pure,
+                                          donate_argnums=(1,))
         # compiled prefill/decode programs live in the SHARED compiled-
         # callable runtime: AOT compile + cost capture (decode MFU in the
         # /statz ledger) + the flag-governed LRU bound, with every new
@@ -395,7 +411,7 @@ class GenerationEngine:
     def device_memory_stats(self) -> dict:
         """Allocator state of the device that holds the cache, where the
         backend reports it (the CPU backend reports nothing)."""
-        stats = next(iter(self._kv[0].devices())).memory_stats() or {}
+        stats = next(iter(self._kv[-1].devices())).memory_stats() or {}
         return {k: int(stats[k]) for k in (
             "bytes_in_use", "bytes_reserved", "largest_free_block_bytes")
             if k in stats}
@@ -569,16 +585,41 @@ class GenerationEngine:
         store = self._stores[label]
         with RecordEvent("generation::args"):
             args = make_args()
-            leaves = jax.tree_util.tree_leaves(args)
-            sig = (self._instance,) + tuple(
-                (tuple(x.shape), str(x.dtype)) for x in leaves)
+            sig = self._signature(args)
         entry, disposition = store.get_or_build(
             sig, lambda: (jitted, None))
         # the slot-admission / dispatch span (if one is current) learns
         # whether this call compiled — the compile-vs-execute attribution
         # a /tracez reader needs (the runtime adds cache_key + flops)
         _tracing.annotate(program_cache=disposition)
-        return store.dispatch(entry, *args)
+        try:
+            return store.dispatch(entry, *args, donated=self._cache_leaves)
+        except Exception as e:
+            # a donated cache that the failed call consumed is gone for
+            # every slot, not only for the caller's
+            if not any_deleted(self._cache_leaves()):
+                raise
+            self.reset()
+            _bump_counter(CACHE_LOST_COUNTER)
+            error = f"{type(e).__name__}: {e}"
+            _flight.record_event("generation_cache_lost", program=label,
+                                 slots=self.slots, error=error[:300])
+            raise _cache.CacheLostError(
+                f"the {label} program failed after it had consumed the "
+                "donated KV cache: every slot's context is lost, the "
+                f"ring was rebuilt empty ({error})") from e
+
+    def _signature(self, args):
+        """The compiled-store key of one call: this engine and the
+        shape and dtype of every argument leaf."""
+        return (self._instance,) + tuple(
+            (tuple(x.shape), str(x.dtype))
+            for x in jax.tree_util.tree_leaves(args))
+
+    def _cache_leaves(self):
+        """Every array of the cache: what a ring program may consume."""
+        return jax.tree_util.tree_leaves(
+            (self._kv, self._kv_draft if self.speculative else ()))
 
     def _fetched(self, phase, t0_ns, value, to_host):
         """``value`` on the host, the wait for it timed as the span
@@ -659,56 +700,98 @@ class GenerationEngine:
         return self
 
     def _warmup_drive(self, kind):
+        """Every program of ``kind``, compiled as a pipeline and run
+        once: each is traced and lowered here while the ones before it
+        compile (or load from the persistent cache) on their worker
+        threads, then each warm-up step runs through the normal entry
+        point, whose first dispatch waits for its executable."""
         with RecordEvent("generation::warmup"):
-            if kind in ("generate",):
-                for bucket in self.prefill_buckets:
-                    self.admit(0, [self.pad_id] * int(bucket))
-            elif kind == "prefill":
+            if kind == "prefill":
                 # a prefill tier never decodes: shrink the untouched
                 # decode (and draft) rings to one slot — this tier's
                 # HBM belongs to prefill activations, not a ring
                 # nobody writes (its selling point in disaggregation)
                 self._ring_slots = 1
                 self.reset()
-                for bucket in self.prefill_buckets:
-                    self.prefill_export([self.pad_id] * int(bucket))
-            elif kind == "decode" and self.speculative:
-                for bucket in self.prefill_buckets:
-                    self._admit_draft(0, [self.pad_id] * int(bucket))
-            if kind != "prefill":
-                if kind == "decode":
-                    # pre-drive the handoff admission: the eager
-                    # pad/insert ops pay their one-time op compiles NOW
-                    # (per plane shape), not on the first live slab —
-                    # that cold cost is exactly the TTFT tail the
-                    # disaggregation bench measures
-                    self.admit_prefilled(
-                        0, self._fresh_slot_planes(), 1, 0,
-                        prompt=[self.pad_id] if self.speculative
-                        else None)
-                if self.speculative:
-                    self.spec_step(np.zeros(self.slots, np.int32),
-                                   np.zeros(self.slots, np.float32))
-                else:
-                    self.step(np.zeros(self.slots, np.int32),
-                              np.zeros(self.slots, np.float32))
+            plan = self._warmup_plan(kind)
+            if not self.paged:
+                # not for the paged layout: its admission allocates
+                # pages while it builds the arguments
+                for calls, _ in plan:
+                    for call in calls:
+                        self._precompile(*call)
+            for _, run in plan:
+                run()
+
+    def _precompile(self, label, jitted, make_args):
+        """Start compiling one program ahead of its first dispatch
+        (``CompiledStore.precompile``)."""
+        args = make_args()
+        self._stores[label].precompile(
+            self._signature(args), lambda: (jitted, None), args)
+
+    def _warmup_plan(self, kind):
+        """``[(calls, run)]``: ``run()`` is one warm-up step through the
+        normal entry point, ``calls`` the ``(label, jitted, make_args)``
+        of the programs it dispatches. The order is immaterial to the
+        time: the compiles (or loads) share the machine and end within
+        a second of each other (PERF.md, PR 26)."""
+        temp = self.default_temperature
+        zeros_i = np.zeros(self.slots, np.int32)
+        zeros_f = np.zeros(self.slots, np.float32)
+        plan = []
+        if kind != "prefill" and self.speculative:
+            toks = jnp.asarray(zeros_i)
+            proposals = jnp.zeros((self.slots, self.draft_k), jnp.int32)
+            plan.append((
+                [self._draft_call(toks),
+                 self._verify_call(toks, proposals, zeros_f, 0)],
+                lambda: self.spec_step(zeros_i, zeros_f)))
+        elif kind != "prefill":
+            plan.append(([self._decode_call(zeros_i, zeros_f, 0)],
+                         lambda: self.step(zeros_i, zeros_f)))
+        for bucket in self.prefill_buckets:
+            prompt = [self.pad_id] * int(bucket)
+            padded, n = self._padded_prompt(prompt)
+            if kind == "generate":
+                plan.append((
+                    [self._prefill_call(0, padded, n, temp, 0)],
+                    lambda p=prompt: self.admit(0, p)))
+            elif kind == "prefill":
+                plan.append((
+                    [self._export_call(padded, n, temp, 0)],
+                    lambda p=prompt: self.prefill_export(p)))
+            elif self.speculative:
+                plan.append((
+                    [self._draft_prefill_call(0, padded, n)],
+                    lambda p=prompt: self._admit_draft(0, p)))
+        if kind == "decode":
+            # pre-drive the handoff admission: the eager pad/insert ops
+            # pay their one-time op compiles NOW (per plane shape), not
+            # on the first live slab — that cold cost is exactly the
+            # TTFT tail the disaggregation bench measures
+            plan.append(([], lambda: self.admit_prefilled(
+                0, self._fresh_slot_planes(), 1, 0,
+                prompt=[self.pad_id] if self.speculative else None)))
+        return plan
 
     def _fresh_slot_planes(self):
         """Zeroed window-width per-slot planes (a synthetic empty slab
         — warmup's stand-in for a real handoff)."""
         return tuple(
-            a[:, 0] for a in _cache.init_cache(
+            jnp.stack([a[0] for a in plane]) for plane in _cache.init_cache(
                 self._num_layers, 1, self._num_heads, self.cache_len,
                 self._head_dim, dtype=self.kv_cache_dtype)[:-1])
 
     # -- pure steps (jitted) --------------------------------------------------
 
     def _prefill_forward(self, model, state, layers, heads, head_dim,
-                         tokens, length):
+                         tokens, length, store):
         """One bucketed prefill forward into window-width fresh caches:
-        returns (logits ``[1, P, V]``, per-slot planes ``[L, H, C, D]``
-        (+scales)). Shared by target prefill, draft prefill, and the
-        prefill-export program."""
+        returns (logits ``[1, P, V]``, the slot's planes, each a tuple
+        over layers of ``[H, C, D]`` (scales ``[H, C]``), zero-padded up
+        to the ring store). Shared by target prefill, draft prefill,
+        and the prefill-export program (window == store there)."""
         p = tokens.shape[1]
         fresh = _cache.fresh_layer_caches(
             layers, 1, heads, self.cache_len, head_dim,
@@ -718,8 +801,9 @@ class GenerationEngine:
         (logits, new_caches), _ = functional_call(
             model, state, tokens,
             position_ids=pos_ids, attention_mask=mask, caches=fresh)
-        stacked = _cache.stack_layer_caches(new_caches)
-        return logits, tuple(a[:, 0] for a in stacked)
+        return logits, tuple(
+            _cache.pad_slot_arrays([a[0] for a in plane], store, axis=1)
+            for plane in _cache.unzip_layer_caches(new_caches))
 
     def _sample_first(self, logits, length, temp, ctr):
         """Sample the first generated token from the last REAL prompt
@@ -741,10 +825,8 @@ class GenerationEngine:
         """
         logits, planes = self._prefill_forward(
             self.model, state, self._num_layers, self._num_heads,
-            self._head_dim, tokens, length)
-        kv = _cache.insert_slot_kv(
-            kv, slot, _cache.pad_slot_arrays(planes, self.store_len),
-            length)
+            self._head_dim, tokens, length, self.store_len)
+        kv = _cache.insert_slot_kv(kv, slot, planes, length)
         tok = self._sample_first(logits, length, temp, ctr)
         return kv, tok
 
@@ -756,17 +838,13 @@ class GenerationEngine:
         first draft chain runs."""
         logits, planes = self._prefill_forward(
             self.model, state, self._num_layers, self._num_heads,
-            self._head_dim, tokens, length)
-        kv = _cache.insert_slot_kv(
-            kv, slot, _cache.pad_slot_arrays(planes, self.store_len),
-            length)
+            self._head_dim, tokens, length, self.store_len)
+        kv = _cache.insert_slot_kv(kv, slot, planes, length)
         _, dplanes = self._prefill_forward(
             self.draft_model, dstate, self._draft_layers,
-            self._draft_heads, self._draft_dim, tokens, length)
-        kv_draft = tuple(
-            a.at[:, slot].set(n) for a, n in zip(
-                kv_draft,
-                _cache.pad_slot_arrays(dplanes, self.store_len)))
+            self._draft_heads, self._draft_dim, tokens, length,
+            self.store_len)
+        kv_draft = _cache.insert_slot_planes(kv_draft, slot, dplanes)
         tok = self._sample_first(logits, length, temp, ctr)
         return kv, kv_draft, tok
 
@@ -777,9 +855,9 @@ class GenerationEngine:
         slab with :meth:`admit_prefilled`."""
         logits, planes = self._prefill_forward(
             self.model, state, self._num_layers, self._num_heads,
-            self._head_dim, tokens, length)
+            self._head_dim, tokens, length, self.cache_len)
         tok = self._sample_first(logits, length, temp, ctr)
-        return planes, tok
+        return tuple(jnp.stack(plane) for plane in planes), tok
 
     def _draft_prefill_pure(self, dstate, kv_draft, slot, tokens,
                             length):
@@ -788,11 +866,9 @@ class GenerationEngine:
         draft's view of the prompt before it can speculate on it."""
         _, dplanes = self._prefill_forward(
             self.draft_model, dstate, self._draft_layers,
-            self._draft_heads, self._draft_dim, tokens, length)
-        return tuple(
-            a.at[:, slot].set(n) for a, n in zip(
-                kv_draft,
-                _cache.pad_slot_arrays(dplanes, self.store_len)))
+            self._draft_heads, self._draft_dim, tokens, length,
+            self.store_len)
+        return _cache.insert_slot_planes(kv_draft, slot, dplanes)
 
     def _decode_pure(self, state, kv, tokens, temps, ctr):
         """One decode step for EVERY slot: ``tokens [S]`` (each slot's
@@ -806,7 +882,7 @@ class GenerationEngine:
         (logits, new_caches), _ = functional_call(
             self.model, state, tokens[:, None],
             position_ids=pos_ids, attention_mask=mask, caches=caches)
-        kv = _cache.stack_layer_caches(new_caches) + (pos + 1,)
+        kv = _cache.unzip_layer_caches(new_caches) + (pos + 1,)
         key = jax.random.fold_in(self._base_key, ctr)
         nxt = sample_logits(logits[:, 0], key, temps, self.top_k)
         return kv, nxt
@@ -887,7 +963,7 @@ class GenerationEngine:
             if j < self.draft_k:
                 proposals.append(nxt)
             cur = nxt
-        return (_cache.stack_layer_caches(caches),
+        return (_cache.unzip_layer_caches(caches),
                 jnp.stack(proposals, axis=1))
 
     def _verify_pure(self, state, kv, tokens, proposals, temps, ctr):
@@ -929,7 +1005,7 @@ class GenerationEngine:
         # it or the second round re-compiles everything downstream
         accepted = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
         counts = (accepted + 1).astype(jnp.int32)
-        kv = _cache.stack_layer_caches(new_caches) + (
+        kv = _cache.unzip_layer_caches(new_caches) + (
             (pos + counts).astype(jnp.int32),)
         return kv, ts, counts
 
@@ -995,26 +1071,61 @@ class GenerationEngine:
         ctr = self._next_key_step()
         t0 = time.perf_counter_ns()
         with RecordEvent("generation::prefill"):
+            out = self._dispatch(
+                *self._prefill_call(slot, padded, n, temp, ctr))
             if self.speculative:
-                out = self._dispatch(
-                    "prefill", self._spec_prefill_jit, lambda: (
-                        self._state(), self._draft_state(), self._kv,
-                        self._kv_draft, jnp.asarray(slot, jnp.int32),
-                        jnp.asarray(padded[None]),
-                        jnp.asarray(n, jnp.int32),
-                        jnp.asarray(temp, jnp.float32),
-                        jnp.asarray(ctr, jnp.int32)))
                 self._kv, self._kv_draft, tok = out
             else:
-                out = self._dispatch("prefill", self._prefill_jit, lambda: (
-                    self._state(), self._kv,
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(padded[None]),
-                    jnp.asarray(n, jnp.int32),
-                    jnp.asarray(temp, jnp.float32),
-                    jnp.asarray(ctr, jnp.int32)))
                 self._kv, tok = out
         return self._fetched("generation::prefill", t0, tok, int)
+
+    # Each ring program's (label, jitted, make_args): what its entry
+    # point hands to _dispatch, and warm-up to _precompile. make_args
+    # reads the cache when it is called, never before.
+
+    @staticmethod
+    def _prompt_args(padded, n, temp, ctr):
+        return (jnp.asarray(padded[None]), jnp.asarray(n, jnp.int32),
+                jnp.asarray(temp, jnp.float32), jnp.asarray(ctr, jnp.int32))
+
+    def _prefill_call(self, slot, padded, n, temp, ctr):
+        if self.speculative:
+            return "prefill", self._spec_prefill_jit, lambda: (
+                self._state(), self._draft_state(), self._kv,
+                self._kv_draft, jnp.asarray(slot, jnp.int32),
+                *self._prompt_args(padded, n, temp, ctr))
+        return "prefill", self._prefill_jit, lambda: (
+            self._state(), self._kv, jnp.asarray(slot, jnp.int32),
+            *self._prompt_args(padded, n, temp, ctr))
+
+    def _export_call(self, padded, n, temp, ctr):
+        return "prefill", self._prefill_export_jit, lambda: (
+            self._state(), *self._prompt_args(padded, n, temp, ctr))
+
+    def _draft_prefill_call(self, slot, padded, n):
+        return "prefill", self._draft_prefill_jit, lambda: (
+            self._draft_state(), self._kv_draft,
+            jnp.asarray(slot, jnp.int32), jnp.asarray(padded[None]),
+            jnp.asarray(n, jnp.int32))
+
+    def _decode_call(self, tokens, temps, ctr):
+        jitted = self._paged_decode_jit if self.paged else self._decode_jit
+        return "decode", jitted, lambda: (
+            self._state(), self._kv,
+            jnp.asarray(np.asarray(tokens, np.int32)),
+            jnp.asarray(np.asarray(temps, np.float32)),
+            jnp.asarray(ctr, jnp.int32))
+
+    def _draft_call(self, toks):
+        # the draft shares the target's position vector (reset())
+        return "draft", self._draft_jit, lambda: (
+            self._draft_state(), self._kv_draft, self._kv[-1], toks)
+
+    def _verify_call(self, toks, proposals, temps, ctr):
+        return "verify", self._verify_jit, lambda: (
+            self._state(), self._kv, toks, proposals,
+            jnp.asarray(np.asarray(temps, np.float32)),
+            jnp.asarray(ctr, jnp.int32))
 
     # -- paged layout: host-side page management ------------------------------
     #
@@ -1451,11 +1562,7 @@ class GenerationEngine:
         ctr = self._next_key_step()
         with RecordEvent("generation::prefill_export"):
             planes, tok = self._dispatch(
-                "prefill", self._prefill_export_jit, lambda: (
-                    self._state(), jnp.asarray(padded[None]),
-                    jnp.asarray(n, jnp.int32),
-                    jnp.asarray(temp, jnp.float32),
-                    jnp.asarray(ctr, jnp.int32)))
+                *self._export_call(padded, n, temp, ctr))
         return planes, n, int(tok)
 
     def _admit_draft(self, slot, prompt):
@@ -1464,11 +1571,7 @@ class GenerationEngine:
         padded, n = self._padded_prompt(prompt)
         with RecordEvent("generation::draft_prefill"):
             self._kv_draft = self._dispatch(
-                "prefill", self._draft_prefill_jit, lambda: (
-                    self._draft_state(), self._kv_draft,
-                    jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(padded[None]),
-                    jnp.asarray(n, jnp.int32)))
+                *self._draft_prefill_call(slot, padded, n))
 
     def admit_prefilled(self, slot, planes, length, first_token,
                         prompt=None) -> int:
@@ -1508,13 +1611,14 @@ class GenerationEngine:
                 "(kv_cache_dtype mismatch between tiers?)")
         padded = _cache.pad_slot_arrays(
             tuple(jnp.asarray(p) for p in planes), self.store_len)
-        for a, p in zip(self._kv[:-1], padded):
-            if tuple(p.shape) != tuple(a.shape[:1] + a.shape[2:]) \
+        for plane, p in zip(self._kv[:-1], padded):
+            a = plane[0]
+            if tuple(p.shape) != (len(plane),) + tuple(a.shape[1:]) \
                     or p.dtype != a.dtype:
                 raise InvalidArgumentError(
                     f"handoff slab plane {tuple(p.shape)}/{p.dtype} does "
                     f"not fit this engine's cache "
-                    f"{tuple(a.shape)}/{a.dtype}")
+                    f"{len(plane)} x {tuple(a.shape)}/{a.dtype}")
         if self.speculative:
             if prompt is None:
                 raise InvalidArgumentError(
@@ -1522,6 +1626,7 @@ class GenerationEngine:
                     "with the KV slab (the draft ring must be prefilled)")
             self._admit_draft(slot, prompt)
         with RecordEvent("generation::admit_prefilled"):
+            # eager and undonated: each layer's array is copied once
             self._kv = _cache.insert_slot_kv(
                 self._kv, slot, padded, length)
         return int(first_token)
@@ -1536,14 +1641,9 @@ class GenerationEngine:
             # compiled step, so the jitted scatter only ever writes
             # pages private to their slot (or the trash page)
             self._prepare_decode_writes()
-        jitted = self._paged_decode_jit if self.paged else self._decode_jit
         t0 = time.perf_counter_ns()
         with RecordEvent("generation::decode"):
-            out = self._dispatch("decode", jitted, lambda: (
-                self._state(), self._kv,
-                jnp.asarray(np.asarray(tokens, np.int32)),
-                jnp.asarray(np.asarray(temps, np.float32)),
-                jnp.asarray(ctr, jnp.int32)))
+            out = self._dispatch(*self._decode_call(tokens, temps, ctr))
         self._kv, nxt = out
         if self.paged:
             for s, live in enumerate(self._slot_live):
@@ -1564,18 +1664,14 @@ class GenerationEngine:
                 "spec_step needs a draft model; construct the engine "
                 "with draft_model= (FLAGS_speculative_enabled)")
         toks = jnp.asarray(np.asarray(tokens, np.int32))
-        pos = self._kv[-1]
         t0 = time.perf_counter_ns()
         with RecordEvent("generation::draft"):
             self._kv_draft, proposals = self._dispatch(
-                "draft", self._draft_jit, lambda: (
-                    self._draft_state(), self._kv_draft, pos, toks))
+                *self._draft_call(toks))
         ctr = self._next_key_step()
         with RecordEvent("generation::verify"):
-            out = self._dispatch("verify", self._verify_jit, lambda: (
-                self._state(), self._kv, toks, proposals,
-                jnp.asarray(np.asarray(temps, np.float32)),
-                jnp.asarray(ctr, jnp.int32)))
+            out = self._dispatch(
+                *self._verify_call(toks, proposals, temps, ctr))
         self._kv, ts, counts = out
         # the round's two enqueues count as the decode phase; the wait
         # for both programs is the one fetch

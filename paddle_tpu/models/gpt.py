@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+import jax
 import jax.numpy as jnp
 
 from .. import ops
@@ -122,15 +123,70 @@ class GPTModel(Layer):
             self.word_embeddings(input_ids)
             + self.position_embeddings(position_ids)
         )
-        new_caches = []
-        for i, layer in enumerate(self.layers):
-            if caches is None:
+        if caches is None:
+            for layer in self.layers:
                 x = layer(x, tgt_mask=attention_mask)
-            else:
-                x, c = layer(x, tgt_mask=attention_mask, cache=caches[i])
+            return self.norm_f(x)
+        if isinstance(x._array, jax.core.Tracer) and self._layers_alike():
+            x, new_caches = self._cached_stack_traced_once(
+                x, attention_mask, caches)
+        else:
+            new_caches = []
+            for layer, cache in zip(self.layers, caches):
+                x, c = layer(x, tgt_mask=attention_mask, cache=cache)
                 new_caches.append(c)
-        x = self.norm_f(x)
-        return x if caches is None else (x, new_caches)
+        return self.norm_f(x), new_caches
+
+    def _layers_alike(self):
+        """Is every layer layer 0 again, so that one trace of its
+        forward stands for all? Same sublayer classes in eval mode,
+        same parameter shapes, no buffer and no hook (either may carry
+        what a shared trace would take from layer 0 alone)."""
+        def shape(layer):
+            subs = list(layer.named_sublayers(include_self=True))
+            if any(s.training or s._forward_pre_hooks
+                   or s._forward_post_hooks for _, s in subs) \
+                    or any(True for _ in layer.named_buffers()):
+                return None
+            return ([(n, type(s)) for n, s in subs],
+                    [(n, tuple(p.shape), str(p.dtype))
+                     for n, p in layer.named_parameters()])
+
+        first = shape(self.layers[0])
+        return first is not None and all(
+            shape(layer) == first for layer in self.layers[1:])
+
+    def _cached_stack_traced_once(self, x, mask, caches):
+        """The cached forward through every layer, inside a trace:
+        layer 0's forward is traced once, as a function of a layer's
+        parameters, and called once per layer, so a program of N equal
+        layers traces and lowers one of them (gpt2-large's 36: 1.3-1.6 s
+        a program became 0.4-0.7 on the v5e's host, and a server warms
+        six; PERF.md, PR 26).
+        XLA inlines the calls: the compiled program is what the loop
+        over the layers gives."""
+        first = self.layers[0]
+        slots = [p for _, p in first.named_parameters()]
+
+        @jax.jit  # a new one per outer trace: the trace's side effects
+        def one(arrays, x, mask, cache):  # (schedule log) happen in each
+            saved = [p._array for p in slots]
+            try:
+                for p, a in zip(slots, arrays):
+                    p._array = a
+                y, c = first(Tensor._from_array(x),
+                             tgt_mask=Tensor._from_array(mask), cache=cache)
+            finally:
+                for p, a in zip(slots, saved):
+                    p._array = a
+            return y._array, c
+
+        x, mask, new_caches = x._array, mask._array, []
+        for layer, cache in zip(self.layers, caches):
+            x, c = one([p._array for _, p in layer.named_parameters()],
+                       x, mask, cache)
+            new_caches.append(c)
+        return Tensor._from_array(x), new_caches
 
 
 class GPTForCausalLM(Layer):

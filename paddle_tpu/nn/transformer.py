@@ -11,6 +11,7 @@ from typing import Any, NamedTuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from .. import ops
@@ -186,6 +187,37 @@ KV_QUANT_BNT = 127.0
 KV_QUANT_EPS = 1e-8
 
 
+def update_slice_in_range(operand, update, *start):
+    """``lax.dynamic_update_slice`` for start indices known to lie in
+    range, given one per axis as scalars of one integer type: the primitive bound
+    directly. The wrapper spends about 3.5 ms of trace time a call on
+    normalising indices that might be negative, and the cache programs
+    trace one of these per layer and plane (admission) or per row
+    (decode)."""
+    return jax.lax.dynamic_update_slice_p.bind(operand, update, *start)
+
+
+def _write_rows(cache, new, idx):
+    """The decode step's ring write: row ``b`` of ``new`` (``[B, H, 1,
+    D]`` values or ``[B, H, 1]`` scales) goes to ring index ``idx[b]``
+    of ``cache`` (``[B, H, C, D]`` / ``[B, H, C]``). One
+    ``dynamic_update_slice`` per row, unrolled: it works in whatever
+    layout the compiler gave the array, so a donated cache is updated
+    in place. One scatter over all rows is the same mathematics, but
+    XLA:TPU keeps the cache with ``C`` minor and wants ``D`` minor for
+    the scatter: it copies the whole array there and back, every layer
+    and step; a ``fori_loop`` over the rows moves the array into and
+    out of fast memory around the loop (PERF.md, PR 26)."""
+    zero = np.zeros((), idx.dtype)
+    tail = (zero,) * (cache.ndim - 3)
+    for b in range(cache.shape[0]):
+        cache = update_slice_in_range(
+            cache, jax.lax.slice_in_dim(new, b, b + 1),
+            np.asarray(b, idx.dtype), zero,
+            jax.lax.index_in_dim(idx, b, keepdims=False), *tail)
+    return cache
+
+
 def quantize_kv(x):
     """``[..., D]`` float → (int8 values, f32 abs-max scales ``[...]``).
 
@@ -353,8 +385,10 @@ class MultiHeadAttention(Layer):
         """Write the freshly projected K/V into the ring cache.
 
         Decode (Lq == 1): every row writes its own ring index
-        ``pos % C`` — a batched scatter, so co-batched sequences at
-        different positions share one program. Multi-token (Lq > 1,
+        ``pos % C``, one ``dynamic_update_slice`` per row
+        (:func:`_write_rows`), so co-batched sequences at different
+        positions share one program and a donated cache is written
+        where it lies. Multi-token (Lq > 1,
         prefill and speculative verify): each row writes its span at
         its OWN offset ``(pos + t) % C`` — the same batched scatter
         over a ``[B, T]`` index plane, so per-slot positions may differ
@@ -372,10 +406,9 @@ class MultiHeadAttention(Layer):
         vn = vn.astype(vc.dtype)
         c = kc.shape[2]
         if kn.shape[2] == 1:
-            rows = jnp.arange(kc.shape[0])
             idx = jnp.mod(pos, c)
-            kc = kc.at[rows, :, idx, :].set(kn[:, :, 0, :])
-            vc = vc.at[rows, :, idx, :].set(vn[:, :, 0, :])
+            kc = _write_rows(kc, kn, idx)
+            vc = _write_rows(vc, vn, idx)
         else:
             t = kn.shape[2]
             rows = jnp.arange(kc.shape[0])[:, None]
@@ -406,12 +439,11 @@ class MultiHeadAttention(Layer):
         vq, vsc = quantize_kv(vn)
         c = kc.shape[2]
         if kn.shape[2] == 1:
-            rows = jnp.arange(kc.shape[0])
             idx = jnp.mod(pos, c)
-            kc = kc.at[rows, :, idx, :].set(kq[:, :, 0, :])
-            vc = vc.at[rows, :, idx, :].set(vq[:, :, 0, :])
-            ks = ks.at[rows, :, idx].set(ksc[:, :, 0])
-            vs = vs.at[rows, :, idx].set(vsc[:, :, 0])
+            kc = _write_rows(kc, kq, idx)
+            vc = _write_rows(vc, vq, idx)
+            ks = _write_rows(ks, ksc, idx)
+            vs = _write_rows(vs, vsc, idx)
         else:
             t = kn.shape[2]
             rows = jnp.arange(kc.shape[0])[:, None]

@@ -23,7 +23,11 @@ this is it:
   first call would pay, done once per entry under a double-checked
   per-entry lock (N serving workers racing one cold signature pay ONE
   compile) and captured into the cost model so MFU comes from what XLA
-  actually built.
+  actually built. A site that knows its programs before it runs any
+  starts them with :meth:`CompiledStore.precompile`: traced on the
+  caller's thread, compiled (or loaded from the persistent cache) on
+  a worker thread each, so the programs' compiles overlap each other
+  and the caller's next trace.
 - **Demote-to-jit** — the AOT executable is stricter than ``jax.jit``
   (aval/layout drift raises ``TypeError``/``ValueError`` where jit
   silently recompiles): such a dispatch demotes the entry to the jit
@@ -35,8 +39,10 @@ this is it:
 """
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import threading
+from concurrent.futures import Future
 
 import jax
 
@@ -259,39 +265,111 @@ class CompiledStore:
         memory_analysis land in the cost-model registry — utilization
         from what XLA actually built, not an estimate. Double-checked
         under the per-entry lock: a second worker on the same cold
-        signature waits for the executable instead of recompiling."""
-        from ..monitor import cost_model as _cost
+        signature, or the first dispatch of a precompiled
+        one, waits for the executable instead of recompiling, and that
+        wait is this thread's compile time in the goodput ledger. A
+        lowering or compile error propagates from here with its
+        message; the entry stays unattempted."""
         from ..monitor import goodput as _goodput
 
-        with entry.lock:
-            if entry.attempted:
-                return
-            # trace + XLA compile are badput in the goodput ledger's
-            # taxonomy: a span here covers both, and the ledger deducts
-            # it from the enclosing step frame's compute. The
-            # named_scope prefixes every op stamp the traced function
-            # emits (executor._exec_one's opprof stamps) with this
-            # store's label, so a device-trace row reads
-            # executor/matmul#0/3/... and attribution can tell which
-            # runtime (executor, serving replica, ...) issued the op.
-            # A lowering or compile error propagates from here with its
-            # message; the entry stays unattempted.
-            with _goodput.span("compile"), _sched_capture() as cap, \
-                    jax.named_scope(self.label):
-                lowered = entry.jitted.lower(*args)
-            # the trace just ran: record the schedules it baked in
-            entry.resolved_schedules = dict(cap.log or {})
-            with _goodput.span("compile"):
-                entry.aot = lowered.compile()
-            entry.record = _cost.capture(
-                self.cost_label, lowered=lowered, compiled=entry.aot,
-                key=entry.cache_key, cache_key=entry.cache_key,
-                **(capture_meta or {}))
-            _flight().record_event(
-                "runtime_compile", label=self.label,
-                cache_key=entry.cache_key,
-                flops=entry.record.flops if entry.record else 0.0)
-            entry.attempted = True
+        with _goodput.span("compile"):
+            entry.lock.acquire()
+        try:
+            if not entry.attempted:
+                self._compile(entry, self._lower(entry, args), capture_meta,
+                              _goodput.span("compile"))
+        finally:
+            entry.lock.release()
+
+    def _lower(self, entry, args):
+        """Trace and lower (entry's lock held). Tracing swaps live
+        module state and is not reentrant: the dispatching thread's
+        work."""
+        from ..monitor import goodput as _goodput
+
+        # trace + XLA compile are badput in the goodput ledger's
+        # taxonomy: a span here covers the trace, :meth:`_compile`'s
+        # caller brings the one for the compile, and the ledger deducts
+        # both from the enclosing step frame's compute. The
+        # named_scope prefixes every op stamp the traced function
+        # emits (executor._exec_one's opprof stamps) with this
+        # store's label, so a device-trace row reads
+        # executor/matmul#0/3/... and attribution can tell which
+        # runtime (executor, serving replica, ...) issued the op.
+        with _goodput.span("compile"), _sched_capture() as cap, \
+                jax.named_scope(self.label):
+            lowered = entry.jitted.lower(*args)
+        # the trace just ran: record the schedules it baked in
+        entry.resolved_schedules = dict(cap.log or {})
+        return lowered
+
+    def _compile(self, entry, lowered, capture_meta, span):
+        """XLA compile (or the persistent cache's load) under ``span``,
+        then the cost capture (entry's lock held); any thread's work."""
+        from ..monitor import cost_model as _cost
+
+        with span:
+            entry.aot = lowered.compile()
+        entry.record = _cost.capture(
+            self.cost_label, lowered=lowered, compiled=entry.aot,
+            key=entry.cache_key, cache_key=entry.cache_key,
+            **(capture_meta or {}))
+        _flight().record_event(
+            "runtime_compile", label=self.label,
+            cache_key=entry.cache_key,
+            flops=entry.record.flops if entry.record else 0.0)
+        entry.attempted = True
+
+    def precompile(self, sig, build, args, capture_meta=None):
+        """Start ``sig``'s one-time compile ahead of its first dispatch
+        and return a future that is done when the executable is there.
+
+        Lookup (a miss is counted as any other), trace and lowering
+        happen here, on the caller's thread: tracing swaps live module
+        state and is not reentrant. The compile or cache load and the
+        cost capture run on a worker thread of their own, which holds
+        the entry's lock until it is done, so the first :meth:`dispatch`
+        waits for the executable and compiles nothing. A compile that
+        fails leaves the entry unattempted: the future is still done,
+        and that dispatch compiles again and raises on its own thread.
+        ``args`` are only traced, never run or consumed.
+
+        The worker books nothing in the goodput ledger: that ledger
+        counts wall time once, and several programs compile at once.
+        What the compile costs the caller is its wait for the entry's
+        lock at the first dispatch, booked there.
+
+        A thread per program (a site has as many as it has programs):
+        on the v5e the runtime loads one executable at a time however
+        many threads ask, and a lone worker's loads took twice as long
+        as the same loads made from several threads or from the
+        caller's own (one chip call's timing; PERF.md, PR 26)."""
+        entry, _ = self.get_or_build(sig, build)
+        done = Future()
+        entry.lock.acquire()
+        if entry.attempted:
+            entry.lock.release()
+            done.set_result(entry)
+            return done
+
+        def work():
+            try:
+                self._compile(entry, lowered, capture_meta,
+                              contextlib.nullcontext())
+            except Exception:  # noqa: BLE001 — the dispatch raises it
+                entry.aot = entry.record = None
+            finally:
+                entry.lock.release()
+                done.set_result(entry)
+
+        try:
+            lowered = self._lower(entry, args)
+            threading.Thread(target=work, daemon=True,
+                             name=f"precompile-{entry.cache_key}").start()
+        except BaseException:
+            entry.lock.release()
+            raise
+        return done
 
     def dispatch(self, entry, *args, donated=(), capture_meta=None):
         """Run one compiled call through the shared discipline.

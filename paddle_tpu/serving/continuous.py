@@ -35,6 +35,7 @@ from collections import deque
 
 from ..errors import InvalidArgumentError
 from ..flags import flag
+from ..generation.cache import CacheLostError
 from ..generation.handoff import PageSlab
 from ..monitor import counter, gauge, histogram
 from ..monitor import flight_recorder as _flight
@@ -483,6 +484,9 @@ class ContinuousBatcher:
                 _tracing.flag_trace(req.trace, "error")
                 self._m_errors.inc()
                 req.done(error=e)
+                if isinstance(e, CacheLostError):
+                    # the failed prefill took every slot's context
+                    self._fail_live(e)
                 continue
             # the engine's spans cover its call; install begins here
             self._t_ns = time.perf_counter_ns()
@@ -526,6 +530,28 @@ class ContinuousBatcher:
             self.engine.default_temperature
             if req.temperature is None else float(req.temperature))
         self._m_busy.set(self.live_slots)
+
+    def _fail_live(self, e):
+        """Fail every request that holds a slot and vacate the slots:
+        a decode step that raised, or any call that lost the cache
+        (:class:`CacheLostError`), leaves none of them a context."""
+        busy = [s for s, r in enumerate(self._slots) if r is not None]
+        for s in busy:
+            req, self._slots[s] = self._slots[s], None
+            self.engine.release_slot(s)
+            self._m_errors.inc()
+            _tracing.record_interval(
+                "serving::decode", req.trace,
+                req.t_first_token if req.t_first_token is not None
+                else req.t_submit,
+                error=f"{type(e).__name__}: {e}",
+                tokens=len(req.tokens))
+            _tracing.flag_trace(req.trace, "error")
+            req.done(error=e)
+        self._m_busy.set(0)
+        _flight.record_event(
+            "generation_step_error", slots=len(busy),
+            error=f"{type(e).__name__}: {e}"[:300])
 
     def _sample_counters(self, busy):
         """Once an iteration, after admission and before the step: the
@@ -601,22 +627,7 @@ class ContinuousBatcher:
                     nxt = engine.step(self._last, self._temps)
             except Exception as e:  # noqa: BLE001 — fail THESE, keep serving
                 self._t_ns = time.perf_counter_ns()
-                for s in busy:
-                    req, self._slots[s] = self._slots[s], None
-                    engine.release_slot(s)
-                    self._m_errors.inc()
-                    _tracing.record_interval(
-                        "serving::decode", req.trace,
-                        req.t_first_token if req.t_first_token is not None
-                        else req.t_submit,
-                        error=f"{type(e).__name__}: {e}",
-                        tokens=len(req.tokens))
-                    _tracing.flag_trace(req.trace, "error")
-                    req.done(error=e)
-                self._m_busy.set(0)
-                _flight.record_event(
-                    "generation_step_error", slots=len(busy),
-                    error=f"{type(e).__name__}: {e}"[:300])
+                self._fail_live(e)
                 self._mark("serving::deliver")
                 self._end_iteration()
                 continue

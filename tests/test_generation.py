@@ -178,7 +178,7 @@ def _incremental_logits(m, ids, cache_len):
             np.asarray([[tok]], "int32"),
             position_ids=np.asarray([[t]], "int32"),
             attention_mask=jnp.asarray(mask), caches=caches)
-        ck, cv = C.stack_layer_caches(new_caches)
+        ck, cv = C.unzip_layer_caches(new_caches)
         pos = pos + 1
         outs.append(np.asarray(logits.numpy())[0, 0])
     return np.stack(outs)

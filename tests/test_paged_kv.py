@@ -23,6 +23,7 @@ import struct
 import zlib
 from urllib.request import Request, urlopen
 
+import jax
 import numpy as np
 import pytest
 
@@ -254,7 +255,7 @@ def test_hbm_required_byte_exact_both_layouts(model, dtype):
                 _paged(model, kv_cache_dtype=dtype)):
         predicted = eng.hbm_required_bytes() - eng.param_nbytes()
         real = sum(int(np.prod(a.shape)) * np.dtype(a.dtype).itemsize
-                   for a in eng._kv)
+                   for a in jax.tree_util.tree_leaves(eng._kv))
         assert predicted == real == eng.cache_nbytes(), \
             (eng.kv_cache_layout, dtype)
 

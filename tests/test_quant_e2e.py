@@ -81,8 +81,12 @@ def test_int8_cache_state_shapes_and_bytes():
     kv = init_cache(2, 4, 2, 8, 16, dtype="int8")
     assert len(kv) == 5
     k, v, ks, vs, pos = kv
-    assert str(k.dtype) == "int8" and k.shape == (2, 4, 2, 8, 16)
-    assert ks.shape == (2, 4, 2, 8) and str(ks.dtype) == "float32"
+    # one leaf per layer and plane: nothing is stacked
+    assert len(k) == len(v) == len(ks) == len(vs) == 2
+    assert all(str(a.dtype) == "int8" and a.shape == (4, 2, 8, 16)
+               for a in k + v)
+    assert all(a.shape == (4, 2, 8) and str(a.dtype) == "float32"
+               for a in ks + vs)
     caches = layer_caches(*kv)
     assert all(isinstance(c, QuantizedStaticCache) for c in caches)
     fp = init_cache(2, 4, 2, 8, 16)
@@ -105,7 +109,7 @@ def _incremental_logits(m, ids, cache_len, dtype):
             np.asarray([[tok]], "int32"),
             position_ids=np.asarray([[t]], "int32"),
             attention_mask=jnp.asarray(mask), caches=caches)
-        kv = C.stack_layer_caches(new_caches) + (kv[-1] + 1,)
+        kv = C.unzip_layer_caches(new_caches) + (kv[-1] + 1,)
         outs.append(np.asarray(logits.numpy())[0, 0])
     return np.stack(outs)
 
