@@ -14,6 +14,10 @@ Legs, run in sequence by one process that holds the chip throughout:
   batch 128 x 224 x 224: the fused conv+bn+relu and momentum kernels.
 - ``gpt``      a GPT-2-width ``GenerationServer`` answering real HTTP
   ``POST /generate`` requests, compared with the same engine offline.
+- ``hybrid``   the hybrid MoE decoder (linear attention beside grouped-
+  query attention, 40 of 320 routed experts held, bfloat16) at its
+  published widths and two layers through the same server: a K/V ring
+  and a recurrent state in one cache.
 - ``bert4``    the BERT trainer's first phase on a dp=2 x tp=2 mesh,
   when the process sees four or more devices.
 
@@ -58,6 +62,15 @@ CHIP = {
                "size": 224, "steps": 6},
     "gpt": {"config": {}, "engine": {}, "prompt_lens": (5, 20, 48, 100),
             "max_new_tokens": 16},
+    # published widths, two layers (one of each kind), the share of one
+    # chip in eight: 40 of 320 experts, an eighth of the vocabulary
+    "hybrid": {"config": dict(num_hidden_layers=2, gqa_layers=(0,),
+                              experts_held=(0, 40), vocab_held=24576,
+                              dtype="bfloat16"),
+               "engine": dict(slots=4, cache_len=512,
+                              prefill_buckets=(64, 128),
+                              kv_cache_dtype="bfloat16"),
+               "prompt_lens": (5, 20, 48, 100), "max_new_tokens": 16},
 }
 
 TINY = {
@@ -79,6 +92,16 @@ TINY = {
                            max_position_embeddings=128),
             "engine": dict(slots=2, cache_len=32, prefill_buckets=(4, 8)),
             "prompt_lens": (1, 3, 8, 5), "max_new_tokens": 6},
+    "hybrid": {"config": dict(
+        vocab_size=97, vocab_held=64, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        gqa_layers=(0,), linear_attn_config={
+            "short_conv_kernel_size": 4, "head_dim": 8, "num_heads": 4},
+        moe_intermediate_size=16, n_routed_experts=16,
+        num_experts_per_tok=4, experts_held=(4, 8), initializer_range=0.2),
+        "engine": dict(slots=2, cache_len=32, prefill_buckets=(4, 8),
+                       kv_cache_dtype="float32"),
+        "prompt_lens": (1, 3, 8, 5), "max_new_tokens": 6},
 }
 
 # Mosaic calls a compiled step must contain on one chip. Under a mesh the
@@ -490,24 +513,15 @@ def _post_generate(url, payload):
     return r.status, json.loads(raw)["tokens"]
 
 
-def leg_gpt(preset) -> dict:
-    import paddle_tpu as paddle
+def _serve_leg(engine, prompts, max_new) -> dict:
+    """A started GenerationServer over ``engine`` (warm-up compiles
+    exactly the ladder + 1 decode), the prompts in flight together over
+    HTTP, then the same engine offline: token for token."""
     from paddle_tpu import profiler
-    from paddle_tpu.generation import COMPILE_COUNTER, GenerationEngine
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.generation import COMPILE_COUNTER
     from paddle_tpu.serving import GenerationServer
 
-    p = preset["gpt"]
-    cfg = GPTConfig(hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
-                    **p["config"])
-    paddle.seed(11)
-    engine = GenerationEngine(GPTForCausalLM(cfg), **p["engine"])
-    rng = np.random.RandomState(0)
-    prompts = [[int(t) for t in rng.randint(3, cfg.vocab_size, size=n)]
-               for n in p["prompt_lens"]]
-    max_new = p["max_new_tokens"]
     ladder = len(engine.prefill_buckets)
-
     srv = GenerationServer(engine, port=0)
     c0 = profiler.counters().get(COMPILE_COUNTER, 0)
     t0 = time.perf_counter()
@@ -545,11 +559,55 @@ def leg_gpt(preset) -> dict:
                  f"served {tokens} != offline {ref}")
     _require(engine.extra_compiles() == 0,
              f"{engine.extra_compiles()} extra compiles")
-    return {"layers": cfg.num_hidden_layers, "hidden": cfg.hidden_size,
-            "vocab": cfg.vocab_size, "requests": len(prompts),
+    return {"requests": len(prompts),
             "tokens_served": sum(len(t) for _, t in served),
             "warmup_compiles": warm, "compile_s": round(compile_s, 1),
             "run_s": round(run_s, 2), "peak_bytes": _peak_bytes()}
+
+
+def _prompts(lens, vocab):
+    rng = np.random.RandomState(0)
+    return [[int(t) for t in rng.randint(3, vocab, size=n)] for n in lens]
+
+
+def leg_gpt(preset) -> dict:
+    import paddle_tpu as paddle
+    from paddle_tpu.generation import GenerationEngine
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    p = preset["gpt"]
+    cfg = GPTConfig(hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0,
+                    **p["config"])
+    paddle.seed(11)
+    engine = GenerationEngine(GPTForCausalLM(cfg), **p["engine"])
+    out = _serve_leg(engine, _prompts(p["prompt_lens"], cfg.vocab_size),
+                     p["max_new_tokens"])
+    return dict({"layers": cfg.num_hidden_layers, "hidden": cfg.hidden_size,
+                 "vocab": cfg.vocab_size}, **out)
+
+
+def leg_hybrid(preset) -> dict:
+    """The hybrid MoE decoder (linear attention beside GQA, routed
+    experts of which this chip holds a share) through the same server:
+    a K/V ring and a recurrent state in one cache."""
+    import paddle_tpu as paddle
+    from paddle_tpu.generation import GenerationEngine
+    from paddle_tpu.models import HybridMoEConfig, HybridMoEForCausalLM
+
+    p = preset["hybrid"]
+    cfg = HybridMoEConfig(**p["config"])
+    paddle.seed(13)
+    engine = GenerationEngine(HybridMoEForCausalLM(cfg), temperature=0.0,
+                              top_k=0, kv_cache_layout="ring", **p["engine"])
+    kinds = [type(k).__name__ for k in engine.model.cache_spec()]
+    _require(set(kinds) == {"KVKind", "StateKind"},
+             f"expected layers of both kinds, got {kinds}")
+    out = _serve_leg(engine, _prompts(p["prompt_lens"], cfg.vocab_held),
+                     p["max_new_tokens"])
+    return dict({"layers": cfg.num_hidden_layers, "hidden": cfg.hidden_size,
+                 "experts_held": list(cfg.experts_held),
+                 "state_bytes": engine.state_nbytes(),
+                 "cache_bytes": engine.cache_nbytes()}, **out)
 
 
 # -- driver -------------------------------------------------------------------
@@ -562,7 +620,8 @@ def run_legs(preset) -> dict:
 
     legs = {}
     for name, leg in (("kernels", leg_kernels), ("bert", leg_bert),
-                      ("resnet", leg_resnet), ("gpt", leg_gpt)):
+                      ("resnet", leg_resnet), ("gpt", leg_gpt),
+                      ("hybrid", leg_hybrid)):
         legs[name] = leg(preset)
         print(f"leg {name} on 1 device: {json.dumps(legs[name])}",
               flush=True)

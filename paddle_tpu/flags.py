@@ -357,8 +357,9 @@ define_flag("generation_kv_cache_len", 256,
 # for the continuous batcher, certified against the full-forward parity
 # goldens at the envelope documented in README "Quantization".
 define_flag("generation_kv_cache_dtype", "float32",
-            "KV cache storage dtype for decoding: float32 | int8 "
-            "(int8: per-head dynamic scales, ~4x fewer cache bytes)")
+            "KV cache storage dtype for decoding: float32 | bfloat16 "
+            "(ring layout only) | int8 (per-head dynamic scales, ~4x "
+            "fewer cache bytes)")
 
 # generation/paging.py + nn/transformer.py PagedStaticCache — physical
 # layout of the decode KV store. "ring" is the historical per-slot

@@ -38,14 +38,16 @@ across the ``k+1`` in-flight positions with the window constraint.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from ..errors import UnavailableError
-from ..nn.transformer import (QuantizedStaticCache, StaticCache,
-                              update_slice_in_range)
+from ..nn.transformer import (QuantizedStaticCache, RecurrentCache,
+                              StaticCache, update_slice_in_range)
 
 __all__ = [
     "CacheLostError",
@@ -54,12 +56,15 @@ __all__ = [
     "fresh_layer_caches", "cache_nbytes",
     "kv_bytes_per_token", "decode_mask", "prefill_mask", "verify_mask",
     "pad_slot_arrays",
+    "kv", "state", "KVKind", "StateKind", "is_layer_kinds",
+    "init_kinds_cache", "kinds_layer_caches", "unzip_kinds_caches",
+    "kinds_slot_nbytes", "kinds_bytes_per_token",
 ]
 
 NEG_INF = -1e9
 
 #: storage dtypes the KV cache supports (FLAGS_generation_kv_cache_dtype)
-KV_CACHE_DTYPES = ("float32", "int8")
+KV_CACHE_DTYPES = ("float32", "bfloat16", "int8")
 
 
 class CacheLostError(UnavailableError):
@@ -164,8 +169,115 @@ def kv_bytes_per_token(num_layers, num_heads, head_dim,
     values (+ their scale entries at int8). The ``decode_throughput``
     bench row reports this per mode; slots-at-equal-HBM is its ratio."""
     per_vec = (int(head_dim) + 4 if str(dtype) == "int8"
-               else int(head_dim) * 4)
+               else int(head_dim) * jnp.dtype(dtype).itemsize)
     return 2 * int(num_layers) * int(num_heads) * per_vec
+
+
+# -- storage kinds chosen per layer -----------------------------------------
+#
+# A model whose layers do not all keep the same thing per slot (softmax
+# attention beside a recurrence) answers ``cache_spec()`` with a list,
+# one kind a layer. The whole-model cache is then
+# ``(layer_0_arrays, ..., layer_{L-1}_arrays, pos)``: per layer the tuple
+# of that kind's arrays, every one with the slot axis first, and the one
+# shared ``pos [B]`` last, as in the all-alike tuples above. It is still
+# one pytree with one owner, donated whole and written in place, and
+# :func:`insert_slot_kv` / :func:`insert_slot_planes` write a slot into
+# it as they stand (their "planes" are this form's layers). A kind says
+# what its layer keeps (``arrays``), which per-layer cache the model's
+# forward is handed (``wrap``), and what a slot costs (``slot_nbytes``).
+
+
+class KVKind(NamedTuple):
+    """A softmax-attention layer: a ``[B, heads, store, head_dim]`` K and
+    V ring (:class:`nn.StaticCache`), ``heads`` the K/V heads."""
+
+    heads: int
+    head_dim: int
+
+    def arrays(self, batch, store, dtype):
+        shape = (int(batch), self.heads, int(store), self.head_dim)
+        return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+    def wrap(self, arrays, pos):
+        return StaticCache(*arrays, pos)
+
+    def bytes_per_token(self, dtype):
+        return 2 * self.heads * self.head_dim * jnp.dtype(dtype).itemsize
+
+    def slot_nbytes(self, store, dtype):
+        return int(store) * self.bytes_per_token(dtype)
+
+
+class StateKind(NamedTuple):
+    """A recurrent layer: per slot the arrays of ``shapes`` / ``dtypes``
+    (state, then convolution tail: :class:`nn.RecurrentCache`), none
+    with a cache-length axis."""
+
+    shapes: tuple
+    dtypes: tuple
+
+    def arrays(self, batch, store, dtype):
+        return tuple(jnp.zeros((int(batch),) + tuple(s), d)
+                     for s, d in zip(self.shapes, self.dtypes))
+
+    def wrap(self, arrays, pos):
+        return RecurrentCache(*arrays, pos)
+
+    def bytes_per_token(self, dtype):
+        return 0
+
+    def slot_nbytes(self, store, dtype):
+        return sum(int(np.prod(s)) * jnp.dtype(d).itemsize
+                   for s, d in zip(self.shapes, self.dtypes))
+
+
+def kv(heads, head_dim):
+    """The kind of a layer that keeps K/V rows for ``heads`` K/V heads."""
+    return KVKind(int(heads), int(head_dim))
+
+
+def state(shapes, dtypes):
+    """The kind of a layer that keeps a constant per-slot state."""
+    return StateKind(tuple(tuple(int(n) for n in s) for s in shapes),
+                     tuple(str(d) for d in dtypes))
+
+
+def is_layer_kinds(spec):
+    """Is this ``cache_spec()`` a per-layer list of kinds (and not the
+    ``(layers, heads, head_dim)`` of a model whose layers are alike)?"""
+    return all(isinstance(k, (KVKind, StateKind)) for k in spec) \
+        and len(spec) > 0
+
+
+def init_kinds_cache(kinds, batch, store, dtype="float32"):
+    """Zeroed whole-model cache of a per-layer list of kinds; ``dtype``
+    is the ring's (a state kind names its own)."""
+    return tuple(k.arrays(batch, store, dtype) for k in kinds) + (
+        jnp.zeros((int(batch),), jnp.int32),)
+
+
+def kinds_layer_caches(kinds, kv):
+    """The per-layer caches a forward takes, from the whole-model cache
+    of :func:`init_kinds_cache`'s form."""
+    return [k.wrap(arrays, kv[-1]) for k, arrays in zip(kinds, kv[:-1])]
+
+
+def unzip_kinds_caches(caches):
+    """The inverse of :func:`kinds_layer_caches`, less ``pos``."""
+    return tuple(tuple(c)[:-1] for c in caches)
+
+
+def kinds_bytes_per_token(kinds, dtype="float32") -> int:
+    """Cache bytes one more token costs a slot: the K/V layers' rows; a
+    state layer adds nothing."""
+    return sum(k.bytes_per_token(dtype) for k in kinds)
+
+
+def kinds_slot_nbytes(kinds, store, dtype="float32") -> int:
+    """Cache bytes one slot costs: ``store`` rows in every K/V layer and
+    a constant in every state layer (``pos`` aside)."""
+    return sum(k.slot_nbytes(store, dtype) for k in kinds)
 
 
 def decode_mask(pos, cache_len, window=None, dtype="float32"):

@@ -75,6 +75,8 @@ from ..monitor import tracing as _tracing
 from ..profiler import RecordEvent, add_span as _add_span
 from ..profiler import bump_counter as _bump_counter
 from ..profiler import counters as _counters
+from ..profiler import enabled as _profiler_enabled
+from ..profiler import record_counter as _record_counter
 from ..runtime.compiled import any_deleted
 from . import cache as _cache
 from . import paging as _paging
@@ -171,8 +173,21 @@ class GenerationEngine:
                 f"generation_kv_cache_dtype must be one of "
                 f"{_cache.KV_CACHE_DTYPES}, got {self.kv_cache_dtype!r}")
         spec = model.cache_spec()
-        self._num_layers, self._num_heads, self._head_dim = (
-            int(spec[0]), int(spec[1]), int(spec[2]))
+        # a model whose layers keep different things per slot answers
+        # with one storage kind a layer (generation/cache.py); a model
+        # whose layers are alike with (layers, heads, head_dim), and
+        # takes the code paths it always took
+        self._kinds = tuple(spec) if _cache.is_layer_kinds(spec) else None
+        if self._kinds is not None:
+            self._num_layers, self._num_heads, self._head_dim = (
+                len(self._kinds), None, None)
+            if self.kv_cache_dtype == "int8":
+                self._refuse_for_kinds('kv_cache_dtype="int8"')
+            if draft_model is not None:
+                self._refuse_for_kinds("a draft model")
+        else:
+            self._num_layers, self._num_heads, self._head_dim = (
+                int(spec[0]), int(spec[1]), int(spec[2]))
         # speculative decoding: a draft model makes the engine run
         # draft/verify rounds instead of single-token decode steps. The
         # physical ring store widens by draft_k scratch entries so the
@@ -225,6 +240,12 @@ class GenerationEngine:
                 f"kv_cache_layout must be ring | paged, got "
                 f"{self.kv_cache_layout!r}")
         self.paged = self.kv_cache_layout == "paged"
+        if self.paged:
+            self._refuse_for_kinds('kv_cache_layout="paged"')
+            if self.kv_cache_dtype == "bfloat16":
+                raise InvalidArgumentError(
+                    "kv_cache_dtype=bfloat16 is the ring layout's; the "
+                    "page pool stores float32 or int8")
         if self.paged and self.speculative:
             raise InvalidArgumentError(
                 "speculative decoding does not compose with "
@@ -320,6 +341,21 @@ class GenerationEngine:
             metric="serving/gen_unexpected_compiles",
             event="generation_unexpected_compile")
 
+    def _refuse_for_kinds(self, what):
+        """What a model with per-layer storage kinds cannot use yet
+        refuses by name: an int8 ring, the paged layout, a draft model,
+        the prefill/decode handoff. Each would need a form of its own
+        for the kinds it does not know (a state layer has no rows to
+        quantise, page or ship)."""
+        if self._kinds is None:
+            return
+        names = sorted({type(k).__name__ for k in self._kinds})
+        raise InvalidArgumentError(
+            f"{what} is not available for a model whose cache_spec() is "
+            f"a per-layer list of kinds ({', '.join(names)}): the ring "
+            "layout in float32 or bfloat16, without a draft model or a "
+            "prefill/decode handoff, is what such a cache supports")
+
     # -- functional state -----------------------------------------------------
 
     @staticmethod
@@ -377,6 +413,10 @@ class GenerationEngine:
             # per-tenant prefix accounting (prompt vs shared tokens)
             self._prefix_tenants = {}
             self._pool_gauges()
+        elif self._kinds is not None:
+            self._kv = _cache.init_kinds_cache(
+                self._kinds, ring_slots, self.store_len,
+                dtype=self.kv_cache_dtype)
         else:
             self._kv = _cache.init_cache(
                 self._num_layers, ring_slots, self._num_heads,
@@ -417,7 +457,11 @@ class GenerationEngine:
             if k in stats}
 
     def kv_bytes_per_token(self) -> int:
-        """Cache bytes one decoded token occupies across all layers."""
+        """Cache bytes one decoded token occupies across all layers (a
+        state layer's share is nothing: its slot costs a constant)."""
+        if self._kinds is not None:
+            return _cache.kinds_bytes_per_token(self._kinds,
+                                                self.kv_cache_dtype)
         return _cache.kv_bytes_per_token(
             self._num_layers, self._num_heads, self._head_dim,
             self.kv_cache_dtype)
@@ -479,6 +523,11 @@ class GenerationEngine:
         if self.paged:
             return (self._pages_per_slot * self.page_nbytes(dtype)
                     + self._pages_per_slot * 4 + 4)
+        if self._kinds is not None:
+            # store_len rows in every K/V layer, a constant in every
+            # state layer, the position word
+            return _cache.kinds_slot_nbytes(self._kinds, self.store_len,
+                                            dtype) + 4
         per = self.store_len * _cache.kv_bytes_per_token(
             self._num_layers, self._num_heads, self._head_dim, dtype) + 4
         if self.speculative:
@@ -555,7 +604,8 @@ class GenerationEngine:
             f"{_fmt_bytes(self.slot_nbytes())}/slot) against "
             f"{_fmt_bytes(budget)} HBM; suggest_decode_slots("
             f"{budget}) = {fits}"
-            + ("" if self.kv_cache_dtype == "int8" else
+            + ("" if self.kv_cache_dtype == "int8"
+               or self._kinds is not None else
                f" (int8 KV would fit "
                f"{self.suggest_decode_slots(budget, 'int8')})"))
         _flight.record_event(
@@ -674,6 +724,8 @@ class GenerationEngine:
         if self.warmed:
             return self
         self.expected_compiles(kind)  # validates the kind loudly
+        if kind != "generate":
+            self._refuse_for_kinds(f"backend kind {kind!r}")
         # warmup must compile EVERY ladder bucket: with the prefix index
         # live, bucket N's pad prompt would share bucket N-1's pages and
         # prefill only a suffix — a smaller, already-compiled shape —
@@ -823,12 +875,48 @@ class GenerationEngine:
         the ring store), and samples the first generated token from the
         last REAL prompt position.
         """
+        if self._kinds is not None:
+            return self._kinds_prefill_pure(state, kv, slot, tokens,
+                                            length, temp, ctr)
         logits, planes = self._prefill_forward(
             self.model, state, self._num_layers, self._num_heads,
             self._head_dim, tokens, length, self.store_len)
         kv = _cache.insert_slot_kv(kv, slot, planes, length)
         tok = self._sample_first(logits, length, temp, ctr)
         return kv, tok
+
+    def _kinds_prefill_pure(self, state, kv, slot, tokens, length, temp,
+                            ctr):
+        """:meth:`_prefill_pure` for a per-layer list of kinds: the
+        forward runs from position 0 into fresh one-row caches of every
+        kind, and what each layer then holds - K/V rows, or the state
+        after the last real token and the convolution's tail - is
+        written into the slot. The model is given the additive
+        key-padding mask ``[1, 1, 1, P]`` and applies causality itself,
+        by blocks. Also returns the model's routing statistics, if it
+        keeps any."""
+        p = tokens.shape[1]
+        fresh = _cache.kinds_layer_caches(
+            self._kinds, _cache.init_kinds_cache(
+                self._kinds, 1, self.store_len, self.kv_cache_dtype))
+        mask = jnp.where(jnp.arange(p) < length, 0.0,
+                         _cache.NEG_INF).astype(jnp.float32)[None, None, None]
+        (logits, new_caches), _ = functional_call(
+            self.model, state, tokens,
+            position_ids=jnp.arange(p, dtype=jnp.int32)[None],
+            attention_mask=mask, caches=fresh)
+        rows = tuple(tuple(a[0] for a in arrays)
+                     for arrays in _cache.unzip_kinds_caches(new_caches))
+        kv = _cache.insert_slot_kv(kv, slot, rows, length)
+        tok = self._sample_first(logits, length, temp, ctr)
+        return kv, tok, self._model_stats()
+
+    def _model_stats(self):
+        """The routing statistics of the forward just traced (a pytree
+        of small arrays the program returns beside its result; fetched
+        only while the profiler is on), or ``None``."""
+        stats = getattr(self.model, "routing_stats", None)
+        return None if stats is None else stats()
 
     def _spec_prefill_pure(self, state, dstate, kv, kv_draft, slot,
                            tokens, length, temp, ctr):
@@ -874,7 +962,9 @@ class GenerationEngine:
         """One decode step for EVERY slot: ``tokens [S]`` (each slot's
         last token) -> next token per slot. Static shapes throughout —
         this is the program whose compile count is exactly 1."""
-        caches = _cache.layer_caches(*kv)
+        kinds = self._kinds
+        caches = (_cache.layer_caches(*kv) if kinds is None
+                  else _cache.kinds_layer_caches(kinds, kv))
         pos = kv[-1]
         pos_ids = jnp.minimum(pos, self.max_positions - 1)[:, None]
         mask = _cache.decode_mask(pos, self.store_len,
@@ -882,9 +972,12 @@ class GenerationEngine:
         (logits, new_caches), _ = functional_call(
             self.model, state, tokens[:, None],
             position_ids=pos_ids, attention_mask=mask, caches=caches)
-        kv = _cache.unzip_layer_caches(new_caches) + (pos + 1,)
+        kv = (_cache.unzip_layer_caches(new_caches) if kinds is None
+              else _cache.unzip_kinds_caches(new_caches)) + (pos + 1,)
         key = jax.random.fold_in(self._base_key, ctr)
         nxt = sample_logits(logits[:, 0], key, temps, self.top_k)
+        if kinds is not None:
+            return kv, nxt, self._model_stats()
         return kv, nxt
 
     def _paged_prefill_pure(self, state, kv, slot, tokens, shared_len,
@@ -1075,9 +1168,42 @@ class GenerationEngine:
                 *self._prefill_call(slot, padded, n, temp, ctr))
             if self.speculative:
                 self._kv, self._kv_draft, tok = out
+            elif self._kinds is not None:
+                self._kv, tok, stats = out
             else:
                 self._kv, tok = out
-        return self._fetched("generation::prefill", t0, tok, int)
+        tok = self._fetched("generation::prefill", t0, tok, int)
+        if self._kinds is not None:
+            self._sample_stats(stats, prefill=True)
+        return tok
+
+    def _sample_stats(self, stats, prefill=False):
+        """While the profiler is on, fetch the routing statistics a
+        program returned and put them on its timeline as counter
+        samples: ``moe::expert_load`` (per held expert, prompt and
+        decode alike), and for a decode step ``moe::pairs_here`` and
+        ``moe::experts_hit`` (one value an expert layer) with
+        ``generation::state_bytes`` (what the state layers' leaves
+        hold, of :meth:`cache_nbytes`). Off, the arrays are dropped
+        where they lie: no transfer, one boolean."""
+        if not _profiler_enabled():
+            return
+        if stats is not None:
+            stats = jax.device_get(stats)
+            _record_counter("moe::expert_load", stats["load"].tolist())
+            if not prefill:
+                _record_counter("moe::pairs_here", stats["pairs"].tolist())
+                _record_counter("moe::experts_hit", stats["hit"].tolist())
+        if not prefill:
+            _record_counter("generation::state_bytes", self.state_nbytes())
+
+    def state_nbytes(self) -> int:
+        """Device bytes of the state layers' leaves (all slots): the
+        part of :meth:`cache_nbytes` that does not grow with
+        ``cache_len``."""
+        return sum(_cache.cache_nbytes(arrays)
+                   for kind, arrays in zip(self._kinds or (), self._kv)
+                   if isinstance(kind, _cache.StateKind))
 
     # Each ring program's (label, jitted, make_args): what its entry
     # point hands to _dispatch, and warm-up to _precompile. make_args
@@ -1446,6 +1572,7 @@ class GenerationEngine:
         a prefix-cache peer for the whole fleet."""
         from .handoff import HandoffError
 
+        self._refuse_for_kinds("admit_prefilled_pages")
         if not self.paged:
             raise InvalidArgumentError(
                 "page-granular handoff needs kv_cache_layout=paged on "
@@ -1556,6 +1683,7 @@ class GenerationEngine:
         int8), the true prompt length, and the first sampled token.
         The slab ships to a decode tier (:mod:`generation.handoff`)
         whose :meth:`admit_prefilled` lands it in a free slot."""
+        self._refuse_for_kinds("prefill_export")
         padded, n = self._padded_prompt(prompt)
         temp = (self.default_temperature if temperature is None
                 else float(temperature))
@@ -1582,6 +1710,7 @@ class GenerationEngine:
         returned unchanged for scheduler uniformity. A speculative
         engine additionally needs the PROMPT tokens (the slab is
         target-only) to build the draft's view via a draft prefill."""
+        self._refuse_for_kinds("admit_prefilled")
         length = int(length)
         if not 1 <= length <= self.cache_len:
             raise InvalidArgumentError(
@@ -1644,12 +1773,18 @@ class GenerationEngine:
         t0 = time.perf_counter_ns()
         with RecordEvent("generation::decode"):
             out = self._dispatch(*self._decode_call(tokens, temps, ctr))
-        self._kv, nxt = out
+        if self._kinds is not None:
+            self._kv, nxt, stats = out
+        else:
+            self._kv, nxt = out
         if self.paged:
             for s, live in enumerate(self._slot_live):
                 if live:
                     self._pos_host[s] += 1
-        return self._fetched("generation::decode", t0, nxt, np.asarray)
+        nxt = self._fetched("generation::decode", t0, nxt, np.asarray)
+        if self._kinds is not None:
+            self._sample_stats(stats)
+        return nxt
 
     def spec_step(self, tokens, temps, busy=None):
         """One speculative round for every slot: draft program (k

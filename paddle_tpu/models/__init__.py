@@ -43,6 +43,10 @@ from .gpt import (  # noqa: F401
     save_gpt_model,
     truncated_draft,
 )
+from .solar_open2 import (  # noqa: F401
+    HybridMoEConfig,
+    HybridMoEForCausalLM,
+)
 from .se_resnext import (  # noqa: F401
     SEResNeXt,
     se_resnext50_32x4d,

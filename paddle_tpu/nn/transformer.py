@@ -108,6 +108,25 @@ class StaticCache(NamedTuple):
     pos: Any
 
 
+class RecurrentCache(NamedTuple):
+    """Per-slot state of ONE linear-attention layer: what a recurrence
+    keeps in place of a growing K/V window.
+
+    ``state`` is ``[B, H, Dk, Dv]`` float32 (the running outer-product
+    memory of :class:`nn.GatedDeltaAttention`), ``conv_tail`` is ``[B,
+    K-1, channels]``: the last ``K-1`` inputs of the layer's short
+    causal convolution, and ``pos`` is the same shared ``[B]`` vector
+    every layer's cache carries. No leaf has a cache-length axis: a slot
+    costs the same bytes at any context length. A decode step reads and
+    rewrites ``state`` whole; a prefill yields the state after the
+    prompt's last REAL token (right-padding does not advance it).
+    """
+
+    state: Any
+    conv_tail: Any
+    pos: Any
+
+
 class QuantizedStaticCache(NamedTuple):
     """:class:`StaticCache` at int8 storage with per-head dynamic scales.
 

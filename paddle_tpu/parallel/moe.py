@@ -9,6 +9,11 @@ and GSPMD lowers the dispatch einsums to all-to-alls over ICI.
 The dense-dispatch formulation (einsum with a [G, S, E, C] combine tensor
 instead of gather/scatter) is the canonical TPU design: static shapes,
 MXU-friendly, no sorting kernels.
+
+:class:`RoutedExperts` is the serving-side layer: top-k routing over all
+the experts of the model by a layer that is told which of them it holds
+(one chip's share of an expert-parallel group), dropless, grouped matrix
+products over the experts held, plus a shared expert.
 """
 from __future__ import annotations
 
@@ -27,7 +32,7 @@ from ..nn.layers import Linear
 from .mesh import get_mesh
 from .sharding import ShardingRules, with_sharding_constraint
 
-__all__ = ["MoELayer", "SwitchFFN"]
+__all__ = ["MoELayer", "SwitchFFN", "RoutedExperts"]
 
 
 class SwitchFFN(Layer):
@@ -182,3 +187,130 @@ class SwitchFFN(Layer):
 
 
 MoELayer = SwitchFFN  # alias
+
+
+class RoutedExperts(Layer):
+    """Top-k routed gated-MLP experts plus a shared expert, as ONE member
+    of an expert-parallel group computes them.
+
+    The router scores ALL ``num_experts`` (``score``: ``sigmoid`` or
+    ``softmax``), the ``top_k`` largest are chosen, and their weights are
+    renormalised over the chosen (``norm_topk_prob``) and scaled by
+    ``routed_scaling_factor``. Of the experts this layer holds
+    ``held = (first, count)``: its weights are three stacked leaves
+    ``[count, ...]``. It computes its own experts' part of the result::
+
+        y_here = sum_{i in top_k, i held} w_i E_i(x) + E_shared(x)
+
+    with ``w_i`` normalised over all the chosen, held or not, and
+    ``E(x) = W_down(SiLU(W_gate x) * W_up x)``. With every expert held
+    that is the whole layer; the shares of a group add up to it, the
+    shared expert counted once (tests/test_routed_experts.py). No token
+    is dropped and every shape is static: the token-expert pairs are
+    sorted by expert, those that fall elsewhere last, and the three
+    products run as grouped matrix products over the experts held
+    (``jax.lax.ragged_dot``: XLA:TPU's grouped kernel visits only the
+    row tiles of groups that have rows, so a decode step reads the
+    weights of the experts that were hit and no others). The same path
+    serves a prompt of thousands of tokens and a decode step of a few
+    dozen.
+
+    ``forward`` takes and returns arrays ``[..., hidden]``; after it,
+    :attr:`last_load` holds, per held expert, how many pairs it was given
+    (a traced value inside a trace: the caller's program may return it).
+    """
+
+    def __init__(self, hidden_size, expert_width, num_experts, top_k,
+                 held=None, shared_width=0, score="sigmoid",
+                 norm_topk_prob=True, routed_scaling_factor=1.0,
+                 initializer_range=0.02, dtype="float32"):
+        super().__init__()
+        from ..errors import InvalidArgumentError
+        from ..nn.linear_attention import normal_or_zeros
+
+        first, count = (0, num_experts) if held is None else map(int, held)
+        if not 0 <= first < first + count <= num_experts \
+                or not 1 <= top_k <= num_experts:
+            raise InvalidArgumentError(
+                f"held=({first}, {count}) / top_k={top_k} do not fit "
+                f"{num_experts} experts")
+        if score not in ("sigmoid", "softmax"):
+            raise InvalidArgumentError(f"unknown router score {score!r}")
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.first, self.count = first, count
+        self.score, self.norm_topk_prob = score, bool(norm_topk_prob)
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        h, f, std = int(hidden_size), int(expert_width), initializer_range
+
+        def param(name, shape):
+            setattr(self, name, Parameter.from_array(
+                normal_or_zeros(shape, std, dtype), name=name))
+
+        param("router", (h, self.num_experts))
+        param("w_gate", (count, h, f))
+        param("w_up", (count, h, f))
+        param("w_down", (count, f, h))
+        self.shared_width = int(shared_width)
+        if self.shared_width:
+            param("shared_gate", (h, self.shared_width))
+            param("shared_up", (h, self.shared_width))
+            param("shared_down", (self.shared_width, h))
+        self.last_load = None
+
+    def route(self, x):
+        """``(idx [T, k], w [T, k])``: the chosen experts of each token
+        and their weights, in float32 from float32 scores."""
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            self.router._array.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        scores = (jax.nn.sigmoid(logits) if self.score == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        w, idx = jax.lax.top_k(scores, self.top_k)
+        if self.norm_topk_prob:
+            w = w / w.sum(-1, keepdims=True)
+        return idx, w * self.routed_scaling_factor
+
+    def forward(self, x, valid=None):
+        """``x [..., hidden]``; ``valid [...]`` bool marks the tokens
+        that count in :attr:`last_load` (padding is computed but not
+        counted)."""
+        shape = x.shape
+        x = x.reshape(-1, shape[-1])
+        t, k, n = x.shape[0], self.top_k, self.count
+        with jax.named_scope("moe_experts"):
+            idx, w = self.route(x)
+            local = idx - self.first
+            here = (local >= 0) & (local < n)
+            # pairs in expert order, those of other chips' experts last
+            group = jnp.where(here, local, n).reshape(-1).astype(jnp.int32)
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
+            xs = x[order // k]
+            gate = jax.lax.ragged_dot(xs, self.w_gate._array, sizes)
+            up = jax.lax.ragged_dot(xs, self.w_up._array, sizes)
+            out = jax.lax.ragged_dot(
+                (jax.nn.silu(gate.astype(jnp.float32))
+                 * up.astype(jnp.float32)).astype(x.dtype),
+                self.w_down._array, sizes)
+            # back to (token, choice) order; rows past the last group are
+            # whatever the kernel left there and are masked, not scaled
+            back = jnp.zeros_like(order).at[order].set(
+                jnp.arange(t * k, dtype=order.dtype))
+            pair = out[back].reshape(t, k, -1).astype(jnp.float32)
+            y = jnp.where(here[..., None], pair * w[..., None], 0.0).sum(1)
+            if valid is None:
+                self.last_load = sizes
+            else:
+                counted = here & valid.reshape(-1)[:, None]
+                self.last_load = jnp.zeros((n + 1,), jnp.int32).at[
+                    jnp.where(counted, local, n).reshape(-1)].add(1)[:n]
+            if self.shared_width:
+                hid = jax.nn.silu(jnp.matmul(
+                    x, self.shared_gate._array,
+                    preferred_element_type=jnp.float32)) * jnp.matmul(
+                    x, self.shared_up._array,
+                    preferred_element_type=jnp.float32)
+                y = y + jnp.matmul(hid.astype(x.dtype),
+                                   self.shared_down._array,
+                                   preferred_element_type=jnp.float32)
+            return y.astype(x.dtype).reshape(shape)

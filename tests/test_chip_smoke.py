@@ -19,7 +19,8 @@ _spec.loader.exec_module(chip_smoke)
 def test_every_leg_passes_at_the_tiny_preset():
     legs = chip_smoke.run_legs(chip_smoke.TINY)
     # the suite's 8 virtual devices bring the four-device leg in as well
-    assert list(legs) == ["kernels", "bert", "resnet", "gpt", "bert4"]
+    assert list(legs) == ["kernels", "bert", "resnet", "gpt", "hybrid",
+                          "bert4"]
     json.dumps(legs)  # what main prints per leg
     assert legs["kernels"]["pallas"] is False
     for run in legs["bert"]["phases"] + [legs["bert4"]]:
@@ -27,6 +28,8 @@ def test_every_leg_passes_at_the_tiny_preset():
     assert [p["flash"] for p in legs["bert"]["phases"]] == [False, True]
     assert legs["gpt"]["warmup_compiles"] == 3  # ladder of 2, + 1 decode
     assert legs["gpt"]["tokens_served"] >= legs["gpt"]["requests"]
+    assert legs["hybrid"]["warmup_compiles"] == 3
+    assert 0 < legs["hybrid"]["state_bytes"] < legs["hybrid"]["cache_bytes"]
     assert len(legs["bert4"]["devices"]) == 4
 
 
