@@ -58,8 +58,10 @@ slot loop inline for offline use (bench, tests, parity goldens).
 from __future__ import annotations
 
 import itertools
+import operator
 import threading
 import time
+import weakref
 from collections import OrderedDict, deque
 
 import numpy as np
@@ -82,14 +84,77 @@ from . import cache as _cache
 from . import paging as _paging
 from .sampling import sample_logits
 
-__all__ = ["GenerationEngine", "COMPILE_COUNTER", "CACHE_LOST_COUNTER"]
+__all__ = ["GenerationEngine", "COMPILE_COUNTER", "CACHE_LOST_COUNTER",
+           "STATE_REBUILT_COUNTER"]
 
 COMPILE_COUNTER = "generation::compile"
 # calls that failed after they had consumed the donated cache (_dispatch)
 CACHE_LOST_COUNTER = "generation::cache_lost"
+# times a kept state signature was derived again because somebody had
+# rebound a parameter's or a buffer's array (_KeptState)
+STATE_REBUILT_COUNTER = "generation::state_rebuilt"
 
 # deterministic engine instance ids (cache-key stability; see __init__)
 _engine_counter = itertools.count()
+
+
+def _leaf_signature(tree):
+    """Shape and dtype of every leaf of ``tree``, in leaf order: its
+    part of a compiled-store key."""
+    return tuple((tuple(x.shape), str(x.dtype))
+                 for x in jax.tree_util.tree_leaves(tree))
+
+
+class _KeptState:
+    """One model's functional state ``{"params", "frozen", "buffers"}``
+    as the programs take it, and the signature of its leaves, kept
+    between calls. The module tree is walked once. Every call reads
+    each tensor's live array (so parameter updates flow in) and
+    compares it, by identity, with the one the signature was made
+    from: ``set_value``, ``set_state_dict`` and a dtype cast all
+    rebind ``_array``. Only when one differs is the signature derived
+    again (``generation::state_rebuilt``). The arrays are remembered
+    weakly: the tensors own them, and an engine must not keep weights
+    alive that their model has let go."""
+
+    _live = operator.attrgetter("_array")
+
+    def __init__(self, model):
+        trainable, frozen = [], []
+        for n, p in model.named_parameters():
+            (trainable if getattr(p, "trainable", True)
+             else frozen).append((n, p))
+        buffers = [(n, b) for n, b in model.named_buffers()
+                   if b is not None]
+        self._groups = [
+            (group, [n for n, _ in pairs], [t for _, t in pairs])
+            for group, pairs in (("params", trainable), ("frozen", frozen),
+                                 ("buffers", buffers))]
+        self._seen = self._signature = None
+        self._tree = lambda: None
+
+    def current(self):
+        """The state pytree of the arrays the tensors hold now."""
+        tree = {group: OrderedDict(zip(names, map(self._live, tensors)))
+                for group, names, tensors in self._groups}
+        arrays = [a for part in tree.values() for a in part.values()]
+        if self._seen is None or not all(map(
+                operator.is_, arrays, map(operator.call, self._seen))):
+            if self._seen is not None:
+                _bump_counter(STATE_REBUILT_COUNTER)
+            self._signature = _leaf_signature(tree)
+            self._seen = [weakref.ref(a) for a in arrays]
+        self._tree = weakref.ref(tree["params"])
+        return tree
+
+    def signature_of(self, arg):
+        """The kept signature, if ``arg`` is the pytree that
+        :meth:`current` returned last; else None."""
+        tree = self._tree()
+        if tree is not None and type(arg) is dict \
+                and arg.get("params") is tree:
+            return self._signature
+        return None
 
 
 class GenerationEngine:
@@ -297,10 +362,9 @@ class GenerationEngine:
         # collapse later buckets onto already-compiled suffix shapes)
         self._prefix_enabled = True
         self.reset()
-        # eval_step-style snapshot: walk the module tree once, read the
-        # live arrays per call (cheap, and parameter updates flow in)
-        self._named = None
-        self._draft_named = None
+        self._state_kept = _KeptState(model)
+        self._draft_state_kept = (_KeptState(draft_model)
+                                  if self.speculative else None)
         # the ring programs take their cache donated (kv, kv_draft)
         self._prefill_jit = jax.jit(self._prefill_pure, donate_argnums=(1,))
         self._spec_prefill_jit = jax.jit(self._spec_prefill_pure,
@@ -358,36 +422,11 @@ class GenerationEngine:
 
     # -- functional state -----------------------------------------------------
 
-    @staticmethod
-    def _snapshot_named(model):
-        return {
-            "params": [(n, p, getattr(p, "trainable", True))
-                       for n, p in model.named_parameters()],
-            "buffers": [(n, b) for n, b in model.named_buffers()
-                        if b is not None],
-        }
-
-    @staticmethod
-    def _named_state(named):
-        params, frozen = OrderedDict(), OrderedDict()
-        for n, p, trainable in named["params"]:
-            (params if trainable else frozen)[n] = p._array
-        return {
-            "params": params,
-            "frozen": frozen,
-            "buffers": OrderedDict(
-                (n, b._array) for n, b in named["buffers"]),
-        }
-
     def _state(self):
-        if self._named is None:
-            self._named = self._snapshot_named(self.model)
-        return self._named_state(self._named)
+        return self._state_kept.current()
 
     def _draft_state(self):
-        if self._draft_named is None:
-            self._draft_named = self._snapshot_named(self.draft_model)
-        return self._named_state(self._draft_named)
+        return self._draft_state_kept.current()
 
     def reset(self):
         """Zero every slot (all caches empty, positions 0). A paged
@@ -422,6 +461,7 @@ class GenerationEngine:
                 self._num_layers, ring_slots, self._num_heads,
                 self.store_len, self._head_dim,
                 dtype=self.kv_cache_dtype)
+        self._kv_draft = None
         if self.speculative:
             # draft ring arrays only — the draft mirrors the target's
             # committed token history exactly, so ONE shared pos vector
@@ -430,6 +470,10 @@ class GenerationEngine:
                 self._draft_layers, ring_slots, self._draft_heads,
                 self.store_len, self._draft_dim,
                 dtype=self.kv_cache_dtype)[:-1]
+        # every program hands the cache on with the shapes and dtypes it
+        # has here, so this is its part of every later call's signature
+        self._kv_signature = _leaf_signature(self._kv)
+        self._kv_draft_signature = _leaf_signature(self._kv_draft)
         # the decode-capacity denominators, as registry gauges: what the
         # KV cache costs in HBM lands in /metrics next to the hbm/*
         # gauges it competes with (int8 mode shows the ~4x cut directly)
@@ -629,9 +673,12 @@ class GenerationEngine:
         in ``/statz``) under the one policy every dispatch site shares,
         and every compile is COUNTED (``generation::compile``, the
         store's miss counter). ``make_args`` builds the argument tuple,
-        so that the state walk, the small host-to-device puts and the
-        signature over every leaf are one ``generation::args`` span
-        inside the caller's."""
+        so that the look at the kept state and the signature are one
+        ``generation::args`` span inside the caller's. The call's own
+        few arrays go to the executable as host arrays and its call
+        places them (``runtime::launch``): on the v5e's host that took
+        half a millisecond less a step than a ``jnp.asarray`` each
+        (PERF.md, PR 28)."""
         store = self._stores[label]
         with RecordEvent("generation::args"):
             args = make_args()
@@ -661,15 +708,28 @@ class GenerationEngine:
 
     def _signature(self, args):
         """The compiled-store key of one call: this engine and the
-        shape and dtype of every argument leaf."""
-        return (self._instance,) + tuple(
-            (tuple(x.shape), str(x.dtype))
-            for x in jax.tree_util.tree_leaves(args))
+        shape and dtype of every argument leaf, in leaf order. An
+        argument that is a kept state or a cache brings the signature
+        kept with it (:class:`_KeptState`, :meth:`reset`); only the
+        call's own few arrays are looked at."""
+        sig = (self._instance,)
+        for arg in args:
+            sig += self._kept_signature(arg) or _leaf_signature(arg)
+        return sig
+
+    def _kept_signature(self, arg):
+        if arg is self._kv:
+            return self._kv_signature
+        if arg is self._kv_draft:
+            return self._kv_draft_signature
+        kept = self._state_kept.signature_of(arg)
+        if kept is None and self.speculative:
+            kept = self._draft_state_kept.signature_of(arg)
+        return kept
 
     def _cache_leaves(self):
         """Every array of the cache: what a ring program may consume."""
-        return jax.tree_util.tree_leaves(
-            (self._kv, self._kv_draft if self.speculative else ()))
+        return jax.tree_util.tree_leaves((self._kv, self._kv_draft))
 
     def _fetched(self, phase, t0_ns, value, to_host):
         """``value`` on the host, the wait for it timed as the span
@@ -1211,17 +1271,16 @@ class GenerationEngine:
 
     @staticmethod
     def _prompt_args(padded, n, temp, ctr):
-        return (jnp.asarray(padded[None]), jnp.asarray(n, jnp.int32),
-                jnp.asarray(temp, jnp.float32), jnp.asarray(ctr, jnp.int32))
+        return (padded[None], np.int32(n), np.float32(temp), np.int32(ctr))
 
     def _prefill_call(self, slot, padded, n, temp, ctr):
         if self.speculative:
             return "prefill", self._spec_prefill_jit, lambda: (
                 self._state(), self._draft_state(), self._kv,
-                self._kv_draft, jnp.asarray(slot, jnp.int32),
+                self._kv_draft, np.int32(slot),
                 *self._prompt_args(padded, n, temp, ctr))
         return "prefill", self._prefill_jit, lambda: (
-            self._state(), self._kv, jnp.asarray(slot, jnp.int32),
+            self._state(), self._kv, np.int32(slot),
             *self._prompt_args(padded, n, temp, ctr))
 
     def _export_call(self, padded, n, temp, ctr):
@@ -1231,16 +1290,14 @@ class GenerationEngine:
     def _draft_prefill_call(self, slot, padded, n):
         return "prefill", self._draft_prefill_jit, lambda: (
             self._draft_state(), self._kv_draft,
-            jnp.asarray(slot, jnp.int32), jnp.asarray(padded[None]),
-            jnp.asarray(n, jnp.int32))
+            np.int32(slot), padded[None], np.int32(n))
 
     def _decode_call(self, tokens, temps, ctr):
         jitted = self._paged_decode_jit if self.paged else self._decode_jit
         return "decode", jitted, lambda: (
             self._state(), self._kv,
-            jnp.asarray(np.asarray(tokens, np.int32)),
-            jnp.asarray(np.asarray(temps, np.float32)),
-            jnp.asarray(ctr, jnp.int32))
+            np.asarray(tokens, np.int32), np.asarray(temps, np.float32),
+            np.int32(ctr))
 
     def _draft_call(self, toks):
         # the draft shares the target's position vector (reset())
@@ -1250,8 +1307,7 @@ class GenerationEngine:
     def _verify_call(self, toks, proposals, temps, ctr):
         return "verify", self._verify_jit, lambda: (
             self._state(), self._kv, toks, proposals,
-            jnp.asarray(np.asarray(temps, np.float32)),
-            jnp.asarray(ctr, jnp.int32))
+            np.asarray(temps, np.float32), np.int32(ctr))
 
     # -- paged layout: host-side page management ------------------------------
     #
@@ -1396,13 +1452,9 @@ class GenerationEngine:
         with RecordEvent("generation::prefill"):
             out = self._dispatch(
                 "prefill", self._paged_prefill_jit, lambda: (
-                    self._state(), self._kv, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(padded[None]),
-                    jnp.asarray(shared_len, jnp.int32),
-                    jnp.asarray(len(suffix), jnp.int32),
-                    jnp.asarray(n, jnp.int32),
-                    jnp.asarray(temp, jnp.float32),
-                    jnp.asarray(ctr, jnp.int32)))
+                    self._state(), self._kv, np.int32(slot), padded[None],
+                    np.int32(shared_len), np.int32(len(suffix)),
+                    np.int32(n), np.float32(temp), np.int32(ctr)))
         self._kv, tok = out
         return self._fetched("generation::prefill", t0, tok, int)
 

@@ -120,6 +120,14 @@ def test_only_the_three_sub_spans_nest_inside_the_enqueue_spans(
         assert len(inner) == len(outer)
         for s, e, _ in inner:
             assert any(o[0] <= s and e <= o[1] + 1e-3 for o in outer)
+    # after warm-up generation::args is a short span (the state and its
+    # signature are kept), not a missing one: one in every enqueue span
+    args = [s for s in spans if s[2] == "generation::args"]
+    for kind in ("generation::decode", "generation::prefill"):
+        enqueues = [o for o in outer if o[2] == kind]
+        assert enqueues and all(
+            sum(o[0] <= s and e <= o[1] + 1e-3 for s, e, _ in args) == 1
+            for o in enqueues)
 
 
 def test_decode_fetch_closes_after_the_tokens_are_on_the_host(
