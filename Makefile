@@ -24,7 +24,7 @@ DIST_FLAGS := -n auto --dist loadfile
 endif
 endif
 
-.PHONY: test test-fast test-seq bench check lint trace-smoke debugz-smoke mfu-smoke serve-smoke gen-smoke router-smoke chaos-smoke tracez-smoke kernel-smoke quant-smoke spec-smoke memplan-smoke autotune-smoke ir-opt-smoke slo-smoke goodput-smoke opprof-smoke paged-smoke chip-smoke bench-trend
+.PHONY: test test-fast test-seq check lint trace-smoke debugz-smoke mfu-smoke serve-smoke gen-smoke router-smoke chaos-smoke tracez-smoke kernel-smoke quant-smoke spec-smoke memplan-smoke autotune-smoke ir-opt-smoke slo-smoke goodput-smoke opprof-smoke paged-smoke chip-smoke
 
 lint:  # graphlint gate: pure-AST framework lint, waivers must justify every exception
 	python tools/graphlint.py --check
@@ -37,9 +37,6 @@ test-fast:
 
 test-seq:  # force sequential (timing baselines)
 	python -m pytest tests/ -q
-
-bench:
-	python bench.py
 
 trace-smoke:  # 3-step train under the monitor; both exporters must work
 	JAX_PLATFORMS=cpu python tools/trace_smoke.py
@@ -74,7 +71,7 @@ quant-smoke:  # int8 end-to-end: kernel parity, int8 serving, int8 KV cache, qua
 spec-smoke:  # speculative decoding: greedy parity, draft+verify compile counts, 2-process prefill->decode handoff
 	JAX_PLATFORMS=cpu python tools/spec_decode_smoke.py
 
-memplan-smoke:  # static peak-HBM planner: accuracy envelope, strict admission, <1% dispatch overhead
+memplan-smoke:  # static peak-HBM planner: accuracy envelope, strict admission, donation-safety golden
 	JAX_PLATFORMS=cpu python tools/memplan_smoke.py
 
 autotune-smoke:  # kernel autotuner: parity under tuned schedules, search + cache round-trip, zero re-search warm
@@ -89,7 +86,7 @@ slo-smoke:  # fleet SLO plane: wedged backend pages via burn rate, /fleetz == po
 goodput-smoke:  # goodput ledger: >=0.8 steady-state, 2% conservation, kill -9 resume continues lifetime ledger
 	JAX_PLATFORMS=cpu python tools/goodput_smoke.py
 
-opprof-smoke:  # per-op attribution: >=0.9 coverage, time-accuracy envelope, measured fusion win, /profilez, <1% idle
+opprof-smoke:  # per-op attribution: >=0.9 coverage, time-accuracy envelope, measured fusion win, /profilez
 	JAX_PLATFORMS=cpu python tools/opprof_smoke.py
 
 paged-smoke:  # paged KV: ring parity at bounded compiles, shared-prefix FLOPs+TTFT win, >=1.3x slots at equal HBM, strict pool admission
@@ -97,9 +94,6 @@ paged-smoke:  # paged KV: ring parity at bounded compiles, shared-prefix FLOPs+T
 
 chip-smoke:  # the main path once on the TPU, full width, one process; exits 1 without a chip (no JAX_PLATFORMS here on purpose)
 	python chip_smoke.py
-
-bench-trend:  # compare the two newest BENCH_r*.json, warn on >20% headline regressions
-	python tools/bench_trend.py
 
 check:
 	python tools/graphlint.py --check

@@ -471,9 +471,9 @@ def leg_resnet(preset) -> dict:
     p = preset["resnet"]
     paddle.seed(0)
     model = getattr(models, p["make"])(num_classes=p["classes"])
-    # lr is a traced scalar, so the program is bench.py's; its value is
-    # not: at 0.1 the first steps on ONE fixed batch overshoot (8.7 ->
-    # 12.7 in five steps, ResNet-50 on the CPU), at 0.02 they fall
+    # lr is a traced scalar, so its value changes no program: at 0.1 the
+    # first steps on ONE fixed batch overshoot (8.7 -> 12.7 in five
+    # steps, ResNet-50 on the CPU), at 0.02 they fall
     optimizer = opt.Momentum(learning_rate=0.02, momentum=0.9,
                              parameters=model.parameters())
 

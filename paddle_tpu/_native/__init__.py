@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import itertools
 import os
 import pickle
 import subprocess
@@ -46,6 +47,17 @@ def _build(name: str, src_file: str) -> str:
     subprocess.run(cmd, check=True, capture_output=True)
     os.replace(tmp, so_path)
     return so_path
+
+
+_ring_seq = itertools.count()
+
+
+def ring_name(kind="ring"):
+    """A shared-memory name no other ring of this process has had: the
+    pid and a per-process count. (``id(obj) & 0xFFFF`` is not one: two
+    live objects 64 KiB apart agree in it, and ``shmring_open`` then maps
+    both owners onto one SPSC ring.)"""
+    return f"/ptpu_{kind}_{os.getpid()}_{next(_ring_seq)}"
 
 
 class ShmRing:
@@ -81,7 +93,7 @@ class ShmRing:
 
     def __init__(self, name=None, capacity=64 << 20, owner=True):
         lib = self._load()
-        self.name = name or f"/ptpu_ring_{os.getpid()}_{id(self) & 0xFFFF}"
+        self.name = name or ring_name()
         self.capacity = capacity
         self._owner = owner
         self._handle = lib.shmring_open(

@@ -30,8 +30,9 @@ __all__ = ["auto_cast", "amp_guard", "GradScaler", "AmpScaler", "decorate",
 
 # fp16_lists.py white list: matmul-class ops that benefit from MXU dtype.
 # "linear" is the workhorse: every nn.Linear dispatches it, and leaving
-# it off the list silently ran all transformer MLPs in f32 (caught by
-# tools/bert_dots.py: 225 of 300 BERT-step dots were f32).
+# it off the list silently ran all transformer MLPs in f32 (225 of 300
+# BERT-step dots; tests/test_amp.py pins the dot dtypes of a compiled
+# Linear train step).
 WHITE_LIST = {
     "matmul", "mul", "bmm", "addmm", "einsum", "linear",
     "conv1d", "conv2d", "conv2d_transpose", "conv3d",

@@ -11,7 +11,7 @@ Two halves (ISSUE 13, in the TVM/compiler-first spirit of PAPERS.md):
   op index, op type, and variable instead of an opaque trace error.
   ``Executor.run`` verifies automatically behind ``FLAGS_program_verify``
   (the verdict is cached per program version, so steady-state dispatch
-  pays one dict lookup — bench.py ``executor_dispatch.program_verify``).
+  pays one dict lookup).
 - :mod:`lint` — AST lint rules encoding recurring review findings
   (stale trace-time flag reads, unlocked shared-counter mutation, host
   syncs in decode/dispatch hot loops, weak-typed python-scalar captures,

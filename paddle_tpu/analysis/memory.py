@@ -36,8 +36,7 @@ table (``monitor.cost_model.device_peaks()["hbm_bytes"]``, overridable
 via ``FLAGS_device_peaks``) before any lower/compile, failing loudly
 with the high-water op and top tensors named instead of OOMing
 mid-compile. Verdicts cache per program version (same LRU discipline as
-the PR-13 verifier cache) so steady-state dispatch pays a dict lookup —
-certified by the ``executor_dispatch.memplan`` bench sub-row.
+the PR-13 verifier cache) so steady-state dispatch pays a dict lookup.
 
 After each real compile the planner is *closed against reality*:
 :func:`note_actual` compares the prediction with XLA's own
@@ -712,7 +711,7 @@ def check_memory_budget(program, feed_names=(), fetch_names=(),
     The verdict caches on the program per (version, feeds, fetches,
     shapes, level, budget) with the same LRU discipline as the PR-13
     verifier cache, so ``Executor.run``'s steady state pays one dict
-    lookup (bench.py ``executor_dispatch.memplan``). ``strict`` raises
+    lookup. ``strict`` raises
     :class:`MemoryBudgetError` (over budget) or :class:`DonationError`
     (use-after-donation); ``warn`` records the same verdicts as
     ``memory_budget`` flight events and a Python warning, but admits.
@@ -849,7 +848,7 @@ def note_actual(record, plan) -> Optional[float]:
     output + temp − alias) and ledger the ``plan_accuracy`` ratio —
     onto the CostRecord itself (``/costz``), the
     ``memplan/plan_accuracy`` gauge (``/statz``), and the bounded
-    :func:`accuracy_records` table the bench/smoke read. Returns the
+    :func:`accuracy_records` table the smoke reads. Returns the
     ratio, or ``None`` when either side is unavailable."""
     if record is None or plan is None or record.partial:
         return None

@@ -207,7 +207,7 @@ def wire_bytes_per_step(snapshot_before, snapshot_after) -> int:
     """Sum the per-execution gradient-sync wire bytes between two
     ``monitor.registry_snapshot()``s (all ``collective/*/
     traced_algo_bytes`` deltas) — the ledger arithmetic the quant smoke
-    and bench use to certify the fp32→int8 byte cut."""
+    uses to certify the fp32→int8 byte cut."""
     total = 0
     for name, m in snapshot_after.items():
         if not name.endswith("/traced_algo_bytes"):

@@ -124,8 +124,7 @@ define_flag("check_nan_inf", False,
 # VerifyError naming the offending op index/type/var instead of an
 # opaque XLA trace error. Values: off | on | strict ("strict" promotes
 # dead-code findings to errors). The verdict is cached per program
-# version, so steady-state dispatch pays a dict lookup (<1%, bench.py
-# executor_dispatch.program_verify sub-row).
+# version, so steady-state dispatch pays a dict lookup.
 define_flag("program_verify", "on",
             "verify program IR before lowering: off | on | strict "
             "(strict also fails on dead ops/vars)")
@@ -139,9 +138,9 @@ define_flag("program_verify", "on",
 # and liveness-unsafe donations (DonationError) BEFORE compiling;
 # "warn" records the same verdicts as memory_budget flight events and
 # a Python warning but admits. Verdicts cache per program version —
-# steady-state dispatch pays a dict lookup (<1%, bench.py
-# executor_dispatch.memplan). The generation engine applies the same
-# budget to its slots x cache-len x dtype geometry at construction.
+# steady-state dispatch pays a dict lookup. The generation engine
+# applies the same budget to its slots x cache-len x dtype geometry at
+# construction.
 define_flag("memory_budget_check", "warn",
             "static peak-HBM admission before compile: off | warn | "
             "strict (strict rejects over-budget programs and unsafe "
@@ -175,18 +174,6 @@ define_flag("benchmark", False,
 define_flag("call_stack_level", 1,
             "error verbosity: 0 message, 1 +op context, 2 +python stack")
 
-# TPU pallas fused max-pool backward (ops/pallas/pool_backward.py) — the
-# role of the reference's hand-written MaxPool2dGradFunctor CUDA kernel
-# (operators/math/pooling.cu). OFF by default: the kernel is numerically
-# exact (first-max parity with select_and_scatter, tested), but ordered
-# A/B at the ResNet-50 stem shape measured XLA's select_and_scatter at
-# 4.7 ms vs 24 ms for the kernel — per-program pallas dispatch overhead
-# dominates at the block sizes the kernel's VMEM footprint allows (lane-
-# dim stride work must run as one-hot MXU matmuls, tripling the working
-# set). Kept behind the flag for future backends/shapes.
-define_flag("use_pallas_pool_bwd", False,
-            "fused pallas kernel for max-pool backward on TPU")
-
 # static/executor.py — buffer donation for persistables on the compiled
 # whole-block step: parameters/optimizer state update in place (XLA input/
 # output aliasing) instead of doubling HBM traffic each step, matching the
@@ -209,8 +196,8 @@ define_flag("monitor_interval", 100,
 # subsystem reports into (executor runs, collectives with per-group seq
 # numbers, PS RPCs, dataloader lifecycle, flag changes, XLA compiles);
 # dumped on unhandled exception / SIGUSR1 / watchdog trip. Recording is
-# lock-cheap (<2% on the dispatch micro-bench, bench.py
-# flight_recorder_overhead); disable only to rule instrumentation out.
+# one flag read, one dict build and one short lock hold an event;
+# disable only to rule instrumentation out.
 define_flag("flight_recorder", True,
             "record structured runtime events into the in-memory ring "
             "buffer for crash/hang post-mortems")
@@ -244,9 +231,8 @@ define_flag("debug_port", 0,
 
 # monitor/tracing.py — distributed request tracing: contextvar trace
 # context, traceparent propagation router->backend, spans through the
-# serving/executor path, step-scoped training traces. Span creation is
-# cheap (bench.py tracing_overhead < 2%); disable only to rule the
-# instrumentation out of a measurement.
+# serving/executor path, step-scoped training traces. Disable only to
+# rule the instrumentation out of a measurement.
 define_flag("trace_enabled", True,
             "record per-request trace spans (traceparent propagation, "
             "/tracez, /statz slowest table)")

@@ -166,8 +166,8 @@ def cache_nbytes(kv) -> int:
 def kv_bytes_per_token(num_layers, num_heads, head_dim,
                        dtype="float32") -> int:
     """Cache bytes one decoded token occupies across all layers: K + V
-    values (+ their scale entries at int8). The ``decode_throughput``
-    bench row reports this per mode; slots-at-equal-HBM is its ratio."""
+    values (+ their scale entries at int8); slots-at-equal-HBM between
+    two modes is the ratio of theirs."""
     per_vec = (int(head_dim) + 4 if str(dtype) == "int8"
                else int(head_dim) * jnp.dtype(dtype).itemsize)
     return 2 * int(num_layers) * int(num_heads) * per_vec

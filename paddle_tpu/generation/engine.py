@@ -53,7 +53,7 @@ own program set (``expected_compiles(kind)``).
 The engine is single-threaded by design (one decode stream per model
 replica); :mod:`paddle_tpu.serving.continuous` drives it from a slot
 scheduler for continuous batching, and :meth:`generate` runs the same
-slot loop inline for offline use (bench, tests, parity goldens).
+slot loop inline for offline use (tests, parity goldens).
 """
 from __future__ import annotations
 
@@ -880,8 +880,8 @@ class GenerationEngine:
         if kind == "decode":
             # pre-drive the handoff admission: the eager pad/insert ops
             # pay their one-time op compiles NOW (per plane shape), not
-            # on the first live slab — that cold cost is exactly the
-            # TTFT tail the disaggregation bench measures
+            # on the first live slab, where that cold cost would be the
+            # first request's TTFT
             plan.append(([], lambda: self.admit_prefilled(
                 0, self._fresh_slot_planes(), 1, 0,
                 prompt=[self.pad_id] if self.speculative else None)))
@@ -1905,9 +1905,8 @@ class GenerationEngine:
         engine's slots: a finished sequence vacates its slot and the next
         prompt is admitted at the next step. ``continuous=False`` is the
         static baseline (a new group is admitted only when EVERY slot has
-        drained — what tearing the batch down costs; bench.py's
-        ``decode_throughput`` row measures the difference). Returns one
-        token list per prompt (EOS included when hit)."""
+        drained — what tearing the batch down costs). Returns one token
+        list per prompt (EOS included when hit)."""
         max_new = (self.default_max_new_tokens if max_new_tokens is None
                    else int(max_new_tokens))
         for prompt in prompts:

@@ -16,7 +16,6 @@ from __future__ import annotations
 import atexit
 import itertools
 import multiprocessing as mp
-import os
 import queue as queue_mod
 import threading
 import time
@@ -113,11 +112,11 @@ class _MultiprocessIter:
         ring_cap = 64 << 20
         if loader.use_shared_memory:
             try:
-                from .._native import ShmRing, available
+                from .._native import ShmRing, available, ring_name
 
                 if available():
-                    for i in range(loader.num_workers):
-                        name = f"/ptpu_dl_{os.getpid()}_{id(self) & 0xFFFF}_{i}"
+                    for _ in range(loader.num_workers):
+                        name = ring_name("dl")
                         self.rings[name] = ShmRing(
                             name, capacity=ring_cap, owner=True
                         )
@@ -220,8 +219,7 @@ class _DevicePrefetcher:
     computes and the only consumer-visible input wait is a genuine
     underrun (visible as the monitor's ``input_wait_ratio``). With the
     flag off, the legacy synchronous refill runs inline in ``__next__``
-    (the consumer pays parse + enqueue on the step path) — the A/B the
-    bench's ``input_overlap`` sub-metric measures. Shared by the
+    (the consumer pays parse + enqueue on the step path). Shared by the
     DataLoader's buffer reader and Executor.train_from_dataset (via
     DatasetBase._iter_device_batches)."""
 

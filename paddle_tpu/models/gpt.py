@@ -264,8 +264,8 @@ def truncated_draft(model: "GPTForCausalLM",
     Because the residual stream is dominated by the embedding path, the
     truncated stack's argmax agrees with the full model's far more
     often than chance — a distillation-free draft in the
-    self-speculative-decoding spirit, and the default draft the bench
-    and smoke use. For production the draft is any separately trained
+    self-speculative-decoding spirit, and the default draft the smoke
+    uses. For production the draft is any separately trained
     small GPT sharing the vocab (``--draft-dir``).
     """
     import dataclasses

@@ -31,8 +31,7 @@ VLOG-on-crash breadcrumbs played for the fluid runtime):
 
 Recording rides hot paths always-on (``FLAGS_flight_recorder``), so the
 per-event cost budget is one flag read, one dict build, and one short
-lock hold — measured by bench.py's ``flight_recorder_overhead`` row
-(<2% on the executor-dispatch micro-bench).
+lock hold.
 """
 from __future__ import annotations
 

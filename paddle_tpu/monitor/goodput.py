@@ -123,7 +123,7 @@ _NULL_SPAN = _NullSpan()
 class GoodputLedger:
     """Exclusive-phase wall-time accounting with restart continuity.
 
-    ``dir=None`` keeps the ledger in-memory (unit tests, the bench row);
+    ``dir=None`` keeps the ledger in-memory (unit tests);
     ``clock`` is injectable (tests drive a fake clock). All mutators are
     lock-protected: phase notes arrive from the step thread, the async
     checkpoint writer, and the debug-server scrape thread concurrently.

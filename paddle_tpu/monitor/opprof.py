@@ -44,9 +44,8 @@ as its coverage ratio. Report both; trust trace shares when coverage
 >= 0.9, replay deltas for A/B kernel decisions.
 
 Overhead contract: stamping happens at jax *trace* time only (once per
-compile) — the steady-state dispatch path never formats a stamp, so
-profiling-idle overhead is ~0 (bench.py ``opprof_overhead``). Replay and
-trace parsing run only on demand.
+compile) — the steady-state dispatch path never formats a stamp. Replay
+and trace parsing run only on demand.
 """
 from __future__ import annotations
 

@@ -79,7 +79,7 @@ def enabled() -> bool:
         return True
 
 
-# id generation is on the per-span hot path (bench.py tracing_overhead):
+# id generation is on the per-span hot path:
 # a per-thread PRNG seeded once from os.urandom replaces a urandom
 # syscall per id with ~0.5µs of Mersenne twister — span ids need
 # uniqueness, not crypto strength

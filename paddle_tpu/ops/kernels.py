@@ -772,30 +772,6 @@ def conv1d(x, w, *, stride=1, padding=0, dilation=1, groups=1):
     return out[:, :, 0, :]
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
-def _max_pool_fused(x, ks, st, p, window, strides, pads):
-    return lax.reduce_window(x, -jnp.inf, lax.max, window, strides, pads)
-
-
-def _max_pool_fused_fwd(x, ks, st, p, window, strides, pads):
-    y = lax.reduce_window(x, -jnp.inf, lax.max, window, strides, pads)
-    return y, (x, y)
-
-
-def _max_pool_fused_bwd(ks, st, p, window, strides, pads, res, dy):
-    from .pallas.pool_backward import max_pool2d_backward
-
-    x, y = res
-    dx = max_pool2d_backward(
-        x, y, dy.astype(y.dtype), kernel=tuple(ks), stride=tuple(st),
-        padding=tuple(p),
-    )
-    return (dx,)
-
-
-_max_pool_fused.defvjp(_max_pool_fused_fwd, _max_pool_fused_bwd)
-
-
 @register_op("pool2d")
 def pool2d(x, *, kernel_size, stride=None, padding=0, pooling_type="max",
            ceil_mode=False, exclusive=True, adaptive=False, data_format="NCHW"):
@@ -826,18 +802,6 @@ def pool2d(x, *, kernel_size, stride=None, padding=0, pooling_type="max",
         pads = ((0, 0), hp, wp, (0, 0))
     if pooling_type == "max":
         init = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min
-        from ..flags import flag as _flag
-
-        if _flag("use_pallas_pool_bwd"):
-            from .pallas.pool_backward import max_pool_backward_supported
-
-            ceil_extra = (hp[1] - p[0], wp[1] - p[1])
-            if max_pool_backward_supported(
-                    x.shape, x.dtype, ks, st, p, ceil_extra, data_format):
-                # fused pallas backward (ops/pallas/pool_backward.py)
-                # replaces XLA's select_and_scatter lowering — identical
-                # first-max subgradient, one HBM pass
-                return _max_pool_fused(x, ks, st, p, window, strides, pads)
         return lax.reduce_window(x, init, lax.max, window, strides, pads)
     # avg
     summed = lax.reduce_window(x, 0.0, lax.add, window, strides, pads)

@@ -60,7 +60,7 @@ def device_trace_dir():
 # Always-on monotonic counters (unlike timed events, which only record while
 # the profiler is enabled): the executor's plan-cache hit/miss, jit-cache
 # hit/miss, and donation accounting are cheap integer bumps that tests and
-# bench.py read directly — the role of the reference's STAT_* registry
+# the benchmark read directly — the role of the reference's STAT_* registry
 # (platform/monitor.h) rather than the timeline.
 _counters: dict[str, int] = {}
 _counters_lock = threading.Lock()

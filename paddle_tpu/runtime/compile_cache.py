@@ -1,6 +1,6 @@
 """Where XLA's persistent compilation cache lives.
 
-One rule for every entry point (bench, examples, serving backends,
+One rule for every entry point (benchmark, examples, serving backends,
 ``chip_smoke.py``, the tests): if ``JAX_COMPILATION_CACHE_DIR`` is set,
 jax reads it itself and this module sets nothing; otherwise the cache is
 ``<checkout>/.jax_cache``, derived from the package's own location. The

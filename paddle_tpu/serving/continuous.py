@@ -12,8 +12,7 @@ VACATES its slot mid-batch, and the next queued request is admitted into
 the vacant slot at the very next step (a prefill + one functional
 indexed cache write — no recompile, the decode program's shapes are slot
 -count-static). Under mixed-length traffic the slots stay full, which is
-where the throughput comes from (bench.py ``decode_throughput`` measures
-continuous vs static on exactly that sweep).
+where the throughput comes from.
 
 Admission reuses the serving queue contracts: bounded queue with
 :class:`QueueFullError` backpressure (HTTP 429), deadlines that expire
@@ -37,7 +36,7 @@ from ..errors import InvalidArgumentError
 from ..flags import flag
 from ..generation.cache import CacheLostError
 from ..generation.handoff import PageSlab
-from ..monitor import counter, gauge, histogram
+from ..monitor import counter, histogram
 from ..monitor import flight_recorder as _flight
 from ..monitor import tracing as _tracing
 from .. import profiler as _profiler
@@ -162,8 +161,6 @@ class ContinuousBatcher:
         self._m_errors = counter("serving/gen_errors_total")
         self._m_tokens = counter("serving/gen_tokens_total")
         self._m_midbatch = counter("serving/gen_midbatch_admissions_total")
-        self._m_depth = gauge("serving/gen_queue_depth")
-        self._m_busy = gauge("serving/gen_slots_busy")
         self._h_token = histogram("serving/gen_token_ms")
         self._h_ttft = histogram("serving/gen_ttft_ms")
         self._h_e2e = histogram("serving/gen_e2e_ms")
@@ -226,7 +223,6 @@ class ContinuousBatcher:
                     f"generation queue full ({self.queue_capacity} "
                     "requests queued); backpressure — retry with backoff")
             self._q.append(req)
-            self._m_depth.set(len(self._q))
             self._not_empty.notify()
         self._m_requests.inc()
         return req
@@ -328,7 +324,6 @@ class ContinuousBatcher:
     def _pop_expired_locked(self, now):
         while self._q and self._q[0].expired(now):
             req = self._q.popleft()
-            self._m_depth.set(len(self._q))
             self._m_expired.inc()
             _flight.record_event(
                 "generation_deadline_expired",
@@ -419,7 +414,6 @@ class ContinuousBatcher:
                     else head.prompt_len):
                 return None
             req = self._q.popleft()
-            self._m_depth.set(len(self._q))
         midbatch = self.live_slots > 0
         # queue-wait is knowable only now: record it backwards into
         # the member trace, then time the prefill as a
@@ -529,7 +523,6 @@ class ContinuousBatcher:
         self._temps[free] = (
             self.engine.default_temperature
             if req.temperature is None else float(req.temperature))
-        self._m_busy.set(self.live_slots)
 
     def _fail_live(self, e):
         """Fail every request that holds a slot and vacate the slots:
@@ -548,7 +541,6 @@ class ContinuousBatcher:
                 tokens=len(req.tokens))
             _tracing.flag_trace(req.trace, "error")
             req.done(error=e)
-        self._m_busy.set(0)
         _flight.record_event(
             "generation_step_error", slots=len(busy),
             error=f"{type(e).__name__}: {e}"[:300])
@@ -671,11 +663,9 @@ class ContinuousBatcher:
                 h_token.observe(dt_ms * len(busy) / emitted)
             else:
                 h_token.observe(dt_ms)
-            self._m_busy.set(self.live_slots)
             self._mark("serving::deliver")
             self._end_iteration()
         # drained exit: nothing queued, nothing active
-        self._m_busy.set(self.live_slots)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -693,7 +683,6 @@ class ContinuousBatcher:
             if not drain:
                 dropped = list(self._q)
                 self._q.clear()
-            self._m_depth.set(len(self._q))
             self._not_empty.notify_all()
         for req in dropped:
             self._m_errors.inc()
@@ -724,7 +713,6 @@ class ContinuousBatcher:
         with self._lock:
             dropped = list(self._q)
             self._q.clear()
-            self._m_depth.set(len(self._q))
         for s, req in enumerate(self._slots):
             if req is not None:
                 self._slots[s] = None
@@ -735,7 +723,6 @@ class ContinuousBatcher:
             if not req.finished:
                 self._m_errors.inc()
                 req.done(error=ServingClosedError(why))
-        self._m_busy.set(0)
 
     @property
     def alive(self) -> int:

@@ -38,12 +38,11 @@ launcher calls this after booting a process) and leave via
 
 The router is also runnable as its own process —
 ``python -m paddle_tpu.serving.router --backend URL [--backend URL ...]``
-— which is how a production fleet (and the ``router_throughput`` bench)
-deploys it: proxying is pure-Python byte shuffling, so co-hosting the
-router inside a busy client or backend process would serialize the whole
-fleet behind that process's GIL. (The in-process object form stays the
-right one for tests and for the autoscaler, which drives
-``add_backend``/``remove_backend`` directly.)
+— which is how a production fleet deploys it: proxying is pure-Python
+byte shuffling, so co-hosting the router inside a busy client or backend
+process would serialize the whole fleet behind that process's GIL. (The
+in-process object form stays the right one for tests and for the
+autoscaler, which drives ``add_backend``/``remove_backend`` directly.)
 """
 from __future__ import annotations
 

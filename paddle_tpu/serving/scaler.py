@@ -212,8 +212,8 @@ class SubprocessLauncher:
         # "6-11", ...): on a single box, XLA:CPU spreads one backend's
         # intra-op threads across EVERY core, so co-hosted backends
         # fight for the same silicon — disjoint core sets make each
-        # process behave like its own host (what the router_throughput
-        # scaling bench emulates). Multi-host fleets don't need it.
+        # process behave like its own host. Multi-host fleets don't
+        # need it.
         self.cpu_sets = list(cpu_sets) if cpu_sets else []
         self._launches = 0
 

@@ -83,7 +83,7 @@ def _json_default(o):
 #: - ``slot_occupancy`` float|None — generate: busy decode slots ratio
 #: - ``compiles``    {"expected": int, "unexpected": int,
 #:                    "jit_misses": int} — per-process compile
-#:                    accounting (the bench's per-backend assertion)
+#:                    accounting
 LOADZ_SCHEMA_VERSION = 1
 
 

@@ -750,7 +750,7 @@ class Executor:
         # high-water op and top tensors named instead of OOMing
         # mid-compile. Verdicts cache per program version (the verifier-
         # cache discipline), so steady state pays feed-shape tuples plus
-        # one dict lookup (bench.py executor_dispatch.memplan, <1%).
+        # one dict lookup.
         mem_plan = None
         budget_level = str(flag("memory_budget_check")).strip().lower()
         if budget_level not in ("", "0", "off", "false", "no"):

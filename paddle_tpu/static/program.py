@@ -264,7 +264,7 @@ class Program:
         level) — any mutation through ``append_op``/``_create_block``
         bumps ``_version`` and re-verifies — so ``Executor.run``'s
         automatic call (``FLAGS_program_verify``) costs one dict lookup
-        in steady state (bench.py ``executor_dispatch.program_verify``).
+        in steady state.
         """
         fetch_names = tuple(
             v if isinstance(v, str) else v.name for v in (fetch_list or ()))

@@ -38,6 +38,17 @@ def _seed_rng():
 
 
 @pytest.fixture(autouse=True)
+def _dygraph_mode_restored():
+    """Static mode is a process-global switch: a test that enables it and
+    returns (or fails) before ``disable_static()`` must not hand it to the
+    next test, whose eager ops would then build a program instead."""
+    yield
+    from paddle_tpu import static
+
+    static.disable_static()
+
+
+@pytest.fixture(autouse=True)
 def _reset_telemetry():
     """Telemetry state is process-global (profiler counters, monitor
     registry): zero it after every test so bump_counter/metric state

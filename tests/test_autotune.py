@@ -5,7 +5,7 @@ The tuner itself is certified with a DETERMINISTIC fake timer — the
 selection pipeline (candidate enumeration, pre-compile pruning,
 best-of-N, cache write, resolve swap-in) runs with zero real compiles
 and scripted timings, so every assertion is exact. Real-measurement
-paths are covered by tools/autotune_smoke.py and the bench.
+paths are covered by tools/autotune_smoke.py.
 """
 import json
 import os
@@ -25,7 +25,6 @@ from paddle_tpu.tuning.cache import TuningCache
 # reach the kernel modules (package re-exports shadow the names)
 from paddle_tpu.ops.pallas import layernorm_residual as _  # noqa: F401
 from paddle_tpu.ops.pallas import conv_bn_relu as _  # noqa: F401
-from paddle_tpu.ops.pallas import pool_backward as _  # noqa: F401
 
 lnr = sys.modules["paddle_tpu.ops.pallas.layernorm_residual"]
 ou = sys.modules["paddle_tpu.ops.pallas.optimizer_update"]
@@ -378,13 +377,6 @@ def test_migrated_kernel_defaults_are_byte_identical():
     p = tuning.resolve("conv_bn_relu", m=4096, k=1152, c=256,
                        dtype="float32")
     assert (p["tile_m"], p["tile_n"]) == (256, 256)
-    # pool_backward: the halve-to-fit-then-divide row policy
-    pb = sys.modules["paddle_tpu.ops.pallas.pool_backward"]
-    for (r, h, w, oh, ow) in [(8192, 112, 112, 56, 56), (24, 8, 8, 4, 4)]:
-        assert tuning.resolve("pool_backward", r=r, h=h, w=w, oh=oh,
-                              ow=ow, ph=0, pw=0,
-                              dtype="float32")["block_rows"] \
-            == pb._default_block_rows(r, h, w, oh, ow, 0, 0)
 
 
 def test_numerics_neutral_under_non_default_schedules():

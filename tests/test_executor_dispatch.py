@@ -262,25 +262,3 @@ def test_return_numpy_false_returns_lazy_tensors():
                   return_numpy=False)
     assert isinstance(res[0], Tensor)
     assert np.asarray(res[0]).shape == ()  # __array__ is the sync point
-
-
-# -- bench smoke -------------------------------------------------------------
-
-
-def test_bench_executor_dispatch_smoke():
-    """bench.py's dispatch micro-bench certifies the zero-rewalk contract:
-    plan-cache hit counter == N-1 after N identical runs."""
-    import importlib
-    import sys
-
-    sys.path.insert(0, str(__import__("pathlib").Path(__file__).parents[1]))
-    try:
-        bench = importlib.import_module("bench")
-        row = bench.bench_executor_dispatch(iters=8)
-    finally:
-        sys.path.pop(0)
-    c = row["counters"]
-    assert c["executor::plan_cache_hit"] == row["runs"] - 1
-    assert c["executor::plan_cache_miss"] == 1
-    assert c["executor::donated_buffers"] > 0
-    assert row["value"] > 0

@@ -1,5 +1,5 @@
 """Flash attention tests (CPU fallback path; the pallas kernel itself is
-exercised on TPU by bench/perf runs)."""
+exercised on TPU by chip_smoke.py)."""
 import numpy as np
 
 import jax

@@ -42,6 +42,27 @@ def _flags_restored():
                "use_fused_conv_bn": True, "io_prefetch_overlap": True})
 
 
+# -- shared platform gate -----------------------------------------------------
+
+
+def test_platform_gate_shared_across_pallas_kernels():
+    """Every pallas dispatch gate consumes the ONE shared platform
+    predicate (ops/pallas/_platform.py) so they cannot drift."""
+    import importlib
+
+    from paddle_tpu.ops.pallas import _platform
+
+    # the package re-exports the kernel FUNCTIONS; get the modules
+    for name in ("flash_attention", "int8_matmul", "layernorm_residual",
+                 "optimizer_update", "conv_bn_relu"):
+        mod = importlib.import_module("paddle_tpu.ops.pallas." + name)
+        assert mod.can_emit_mosaic is _platform.can_emit_mosaic, name
+    # on the CPU test backend the gate rejects the pallas path
+    if jax.devices()[0].platform == "cpu":
+        assert _platform.on_tpu_platform() is False
+        assert _platform.can_emit_mosaic() is False
+
+
 # -- fused momentum update ----------------------------------------------------
 
 
@@ -110,7 +131,7 @@ def test_momentum_fused_with_grad_clip_keeps_decay_before_clip(
 
 def test_momentum_fused_inside_compiled_train_step(_flags_restored):
     """The fused update traces into TrainStepFn: same loss trajectory
-    with the flag on and off (the ResNet bench's configuration)."""
+    with the flag on and off (the resnet50 cell's configuration)."""
     from paddle_tpu.framework import jit as fjit
 
     def run():
@@ -434,7 +455,7 @@ def test_resnet_conv_bn_flag_is_bit_exact_off_tpu(training,
 
 
 def test_conv_bn_relu_trains_through_compiled_step(_flags_restored):
-    """The fused triple traces into TrainStepFn (the ResNet bench's
+    """The fused triple traces into TrainStepFn (the resnet50 cell's
     configuration): identical loss trajectory flag on/off, and it
     actually trains."""
     from paddle_tpu.framework import jit as fjit

@@ -1,6 +1,6 @@
 """Training goodput ledger: exclusive-phase accounting, sidecar
-restart continuity, lost-work attribution, metric/line/trace surfaces,
-and the bench-trend comparator.
+restart continuity, lost-work attribution, and metric/line/trace
+surfaces.
 
 Acceptance pins (ISSUE 18): phases exclusive and conserving (idle is
 the residual), overlap deduction inside step frames, background gating
@@ -9,10 +9,9 @@ start, note_resume pricing recomputation as lost_work (not compute),
 aborted-step badput with a step_aborted flight event, the
 ``# TYPE io_input_wait_ms_total counter`` migration with the legacy
 gauge alias, parser goldens for the [monitor:train] and
-[monitor:goodput] lines (incl. the _fmt_util scientific branch), the
-goodput SLO gating, and bench_trend's direction-aware regression calls.
+[monitor:goodput] lines (incl. the _fmt_util scientific branch), and
+the goodput SLO gating.
 """
-import importlib.util
 import json
 import os
 import re
@@ -27,8 +26,6 @@ from paddle_tpu.monitor import goodput as gp
 from paddle_tpu.monitor import registry as _reg
 from paddle_tpu.monitor import slo as slo_mod
 from paddle_tpu.monitor.training_monitor import _fmt_util
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 class FakeClock:
@@ -374,45 +371,3 @@ def test_goodput_slo_gating():
     finally:
         set_flags({"goodput_slo_target": prev})
         slo_mod.reset_engine()
-
-
-# -- bench trend comparator (satellite 5) -----------------------------------
-
-def _load_bench_trend():
-    path = os.path.join(REPO, "tools", "bench_trend.py")
-    spec = importlib.util.spec_from_file_location("bench_trend", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_trend_direction_aware():
-    bt = _load_bench_trend()
-    old = {"parsed": {"metric": "tps", "value": 100.0,
-                      "sub": {"metric": "x_overhead", "value": 1.0}}}
-    # throughput -58% down = regression; overhead -58% down = improved
-    new = {"parsed": {"metric": "tps", "value": 42.0,
-                      "sub": {"metric": "x_overhead", "value": 0.42}}}
-    lines, regs = bt.compare(old, new, threshold=0.20)
-    assert [r[0] for r in regs] == ["tps"]
-    assert any("improved" in l and "x_overhead" in l for l in lines)
-    # overhead rising past threshold regresses; throughput rising doesn't
-    worse = {"parsed": {"metric": "tps", "value": 130.0,
-                        "sub": {"metric": "x_overhead", "value": 1.5}}}
-    _, regs2 = bt.compare(old, worse, threshold=0.20)
-    assert [r[0] for r in regs2] == ["x_overhead"]
-    # a dropped headline row is reported as a regression
-    _, regs3 = bt.compare(old, {"parsed": {"metric": "tps",
-                                           "value": 100.0}}, 0.20)
-    assert ("x_overhead", 1.0, None) in regs3
-
-
-def test_bench_trend_pairs_newest_two(tmp_path):
-    bt = _load_bench_trend()
-    for n in (1, 2, 10):
-        with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
-            json.dump({"parsed": {"metric": "m", "value": float(n)}}, f)
-    pair = bt.find_latest_pair(str(tmp_path))
-    assert [os.path.basename(p) for p in pair] == [
-        "BENCH_r02.json", "BENCH_r10.json"]
-    assert bt.find_latest_pair(str(tmp_path / "missing" )) is None

@@ -1,7 +1,6 @@
 """HLO-cost-backed model summary (the contrib/model_stat.py:1 role,
 strictly better: FLOPs/bytes come from XLA's own cost analysis of each
-layer's lowered HLO — the same machinery tools/hlo_resnet.py uses for
-the committed ResNet gap censuses — not a hand-maintained formula)."""
+layer's lowered HLO, not a hand-maintained formula)."""
 import io
 import contextlib
 
@@ -36,8 +35,7 @@ def test_summary_cost_requires_input_size():
 
 
 def test_resnet50_totals_match_hlo_census():
-    """Pins the ResNet-50 numbers the perf campaign is built on
-    (tools/hlo_resnet.py censuses): 25.557M params; forward cost at
+    """Pins the ResNet-50 numbers: 25.557M params; forward cost at
     batch 1 ~= 8.0 GFLOP (2x the published 4.09 GMACs — XLA counts
     multiply+add separately). The per-layer sum must also agree with an
     independent whole-model lowering within fusion slack."""
@@ -53,7 +51,7 @@ def test_resnet50_totals_match_hlo_census():
     assert r["total_params"] == 25_557_032
     assert 7.0e9 <= r["total_flops"] <= 9.0e9, r["total_flops"]
 
-    # independent whole-model census (the hlo_resnet.py method)
+    # independent whole-model census
     state = fjit.capture_state(net)
 
     def fwd(state, x):

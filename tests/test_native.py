@@ -55,6 +55,37 @@ def test_ring_too_large_record():
         r.close()
 
 
+def test_ring_names_distinct_where_ids_agree(monkeypatch):
+    """Two live objects 64 KiB apart agree in ``id(obj) & 0xFFFF``; rings
+    named by it were then ONE segment with two producers. Forced here by
+    making every id agree: default-named rings and the rings of two live
+    DataLoader iterators must still be distinct segments."""
+    import paddle_tpu._native as native
+    from paddle_tpu.io import DataLoader, TensorDataset
+    from paddle_tpu.io import dataloader as dl_mod
+
+    for mod in (native, dl_mod):
+        monkeypatch.setattr(mod, "id", lambda obj: 0x10000, raising=False)
+    a, b = ShmRing(capacity=4096), ShmRing(capacity=4096)
+    try:
+        assert a.name != b.name
+        a.push_bytes(b"only in a")
+        assert b.empty()
+    finally:
+        a.close()
+        b.close()
+    ds = TensorDataset([np.arange(8, dtype=np.float32)])
+    its = [iter(DataLoader(ds, batch_size=2, num_workers=2,
+                           use_shared_memory=True, use_buffer_reader=False))
+           for _ in range(2)]
+    try:
+        names = [n for it in its for n in it.rings]
+        assert len(names) == 4 and len(set(names)) == 4
+    finally:
+        for it in its:
+            it.shutdown()
+
+
 def _producer(name, n):
     ring = ShmRing(name, capacity=1 << 20, owner=False)
     for i in range(n):

@@ -2,14 +2,13 @@
 # CI driver (paddle/scripts/paddle_build.sh role: cmake_gen/build/run_test
 # collapsed to what this runtime needs).
 #
-# Usage: tools/build_and_test.sh [fast|full|bench|check] [NSHARDS]
+# Usage: tools/build_and_test.sh [fast|full|check] [NSHARDS]
 #   fast  - unit tests minus slow/subprocess ones
 #   full  - entire suite (default); pass NSHARDS>1 to split the test
 #           FILES across that many parallel pytest processes (xdist-safe
 #           by construction: file granularity, no shared-scope state
 #           crosses processes; compile-heavy files dominate wall time so
 #           sharding gives near-linear speedup)
-#   bench - bench.py smoke on the current backend
 #   check - static gates: graphlint (framework-aware AST lint, waiver-
 #           gated) + op coverage + API spec + graft entry self-test
 #           + debugz smoke (debug server endpoints + flight-recorder dump)
@@ -33,7 +32,7 @@
 #           + memplan smoke (static peak-HBM planner: plan-vs-XLA
 #             accuracy envelope on BERT/ResNet/GPT smoke programs,
 #             strict pre-compile admission naming the high-water op,
-#             donation-safety golden, <1% steady-state dispatch cost)
+#             donation-safety golden)
 #           + autotune smoke (kernel autotuner: pallas-vs-jnp parity on
 #             layernorm + conv+bn+relu under default AND tuned
 #             schedules, offline search with pre-compile pruning, the
@@ -55,14 +54,12 @@
 #           + opprof smoke (per-op device-time attribution: >= 0.9
 #             stamped-scope coverage + time-accuracy envelope on the
 #             BERT/ResNet/GPT smokes, measured fused-conv win,
-#             /profilez end to end, idle stamping < 1% of dispatch)
+#             /profilez end to end)
 #           + paged smoke (paged KV: ring-vs-paged greedy parity at
 #             bounded compiles, 90%-shared-prefix burst with the
 #             prefill-FLOPs/TTFT win, >= 1.3x slots at equal HBM on a
 #             constrained pool, strict memplan refusing an over-budget
 #             pool before allocation)
-#           + bench trend (two newest BENCH_r*.json, >20% headline
-#             regressions warned)
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -117,9 +114,6 @@ case "$MODE" in
       python -m pytest tests/ -q
     fi
     ;;
-  bench)
-    python bench.py
-    ;;
   check)
     # graphlint gate first: pure AST (no jax), fails on any unwaived
     # finding or stale waiver (tools/graphlint_waivers.txt)
@@ -167,8 +161,7 @@ case "$MODE" in
     # memplan smoke: static liveness planner within the ±25% envelope of
     # XLA memory_analysis on BERT/ResNet/GPT smoke programs, strict mode
     # rejecting an over-budget program BEFORE compile with the
-    # high-water op named, the donated-then-read golden rejected, and
-    # the admission gate under 1% of the steady-state dispatch period
+    # high-water op named, and the donated-then-read golden rejected
     JAX_PLATFORMS=cpu python tools/memplan_smoke.py
     # autotune smoke: kernel autotuner — layernorm + conv+bn+relu parity
     # under default and tuned schedules (fwd+bwd), offline search with
@@ -202,8 +195,7 @@ case "$MODE" in
     # >= 0.9 and per-program time-accuracy inside the documented
     # envelope, top-op sanity (matmul/conv family leads by FLOPs), the
     # conv+bn+relu fusion win measured per op (not asserted from
-    # theory), /profilez served end to end, and idle stamping under 1%
-    # of the steady-state dispatch period
+    # theory), and /profilez served end to end
     JAX_PLATFORMS=cpu python tools/opprof_smoke.py
     # paged smoke: paged KV subsystem — ring-vs-paged greedy parity on
     # a mixed 8-prompt burst at exactly ladder+1 compiles, a
@@ -214,12 +206,9 @@ case "$MODE" in
     # HBM), and strict memplan refusing an over-budget pool at engine
     # construction, before any device allocation
     JAX_PLATFORMS=cpu python tools/paged_smoke.py
-    # bench trend: two newest BENCH_r*.json compared, >20% headline
-    # regressions warned (non-fatal: CPU-runner noise)
-    python tools/bench_trend.py
     ;;
   *)
-    echo "unknown mode: $MODE (fast|full|bench|check)" >&2
+    echo "unknown mode: $MODE (fast|full|check)" >&2
     exit 2
     ;;
 esac
