@@ -49,9 +49,11 @@ CHIP = {
     "kernels": {
         "layernorm": (128 * 128, 768),
         "flash": (32, 12, 512, 64),
-        # (n, cin, hw, cout, k, stride, pad): stem, a 3x3, a pointwise
-        "conv": ((128, 3, 224, 64, 7, 2, 3), (128, 256, 14, 256, 3, 1, 1),
-                 (128, 64, 56, 64, 1, 1, 0)),
+        # (n, cin, hw, cout, k, stride, pad): the pointwise triples of
+        # stages 1, 3 and 4 (all the kernels take) and, as the one that
+        # must go to XLA's convolution, a 3x3
+        "conv": ((128, 256, 56, 64, 1, 1, 0), (128, 1024, 14, 256, 1, 1, 0),
+                 (128, 2048, 7, 512, 1, 1, 0), (128, 256, 14, 256, 3, 1, 1)),
         "momentum": (1000, 2048),
     },
     # AdamW's first steps overshoot on a fixed batch (11.18, 14.17, 12.76,
@@ -77,7 +79,7 @@ TINY = {
     "kernels": {
         "layernorm": (200, 128),
         "flash": (2, 2, 128, 64),
-        "conv": ((2, 3, 16, 8, 3, 2, 1),),
+        "conv": ((2, 24, 16, 8, 1, 1, 0), (2, 3, 16, 8, 3, 2, 1)),
         "momentum": (40, 50),
     },
     "bert": {"config": dict(vocab_size=1024, hidden_size=128,
@@ -313,7 +315,8 @@ def leg_kernels(preset) -> dict:
         mean, var = jnp.zeros((cout,)), jnp.ones((cout,))
         kw = dict(stride=stride, padding=padding, training=True,
                   momentum=0.9, data_format="NCHW")
-        _require(cbr._supported(x, wt, stride, padding, "NCHW", 1, 1) == tpu,
+        _require(cbr._supported(x, wt, stride, padding, "NCHW", 1, 1)
+                 == (tpu and (ksz, stride, padding) == (1, 1, 0)),
                  "conv predicate")
 
         def fused(x, wt, gamma, beta):

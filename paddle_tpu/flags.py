@@ -713,17 +713,23 @@ define_flag("goodput_slo_target", 0.0,
             "burn-rate engine; 0 disables")
 
 # models/resnet.py + nn/layers.py fused_conv_bn_relu + ops/pallas/
-# conv_bn_relu.py — fuse the vision path's conv -> batch_norm -> relu
-# triple into pallas kernels on TPU: the conv contraction runs as a
-# tiled MXU matmul whose epilogue applies the BN affine + relu in VMEM
-# (eval: one pass; training: matmul+stats pass, then normalize+relu
-# pass), so the pre-activation never round-trips HBM. The jnp fallback
-# calls the IDENTICAL conv2d/batch_norm/relu op kernels in the same
-# order, so the flag never changes numerics off-TPU — the same
-# discipline as the PR-10 fused kernels.
+# conv_bn_relu.py — fuse the vision path's POINTWISE conv -> batch_norm
+# -> relu triples (1x1, stride 1, no padding: each bottleneck block's
+# first conv) into pallas kernels on TPU: the input is the matmul's
+# left operand as it stands, the contraction runs as a tiled MXU matmul
+# and the BN affine + relu apply in VMEM (eval: one pass; training:
+# matmul+stats pass, then normalize+relu pass). A triple whose conv has
+# a spatial extent (3x3, 7x7, strided, padded) runs the fallback, flag
+# on or off: its im2col traffic cost more than XLA's convolution does
+# (PERF.md Findings PR 32). The fallback calls the IDENTICAL
+# conv2d/batch_norm/relu op kernels in the same order, so the flag
+# never changes numerics off-TPU — the same discipline as the PR-10
+# fused kernels.
 define_flag("use_fused_conv_bn", True,
             "fused pallas conv+batch_norm+relu on TPU for the vision "
-            "path (jnp fallback elsewhere; identical op sequence)")
+            "path's pointwise (1x1, stride 1, unpadded) convs; every "
+            "other conv and every other platform runs the identical "
+            "unfused op sequence")
 
 # monitor/opprof.py profile_program — per-op replay measurement
 # discipline: each op's jitted kernel is warmed `opprof_warmup` times,

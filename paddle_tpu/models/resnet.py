@@ -42,9 +42,10 @@ class BasicBlock(Layer):
 
     def forward(self, x):
         identity = x
-        # conv->bn->relu triples route through the fused pallas kernel
-        # (FLAGS_use_fused_conv_bn); bn2 feeds the residual add, not a
-        # relu, so it stays on the unfused path
+        # conv->bn->relu triples go through fused_conv_bn_relu
+        # (FLAGS_use_fused_conv_bn), whose pallas path takes pointwise
+        # convs only: this 3x3 runs XLA's convolution either way. bn2
+        # feeds the residual add, not a relu, so it stays unfused
         out = fused_conv_bn_relu(self.conv1, self.bn1, x)
         out = self.bn2(self.conv2(out))
         if self.downsample is not None:
@@ -69,8 +70,10 @@ class BottleneckBlock(Layer):
 
     def forward(self, x):
         identity = x
-        # 2 of the 3 convs per bottleneck carry a bn+relu epilogue —
-        # both fuse; bn3 feeds the residual add and stays unfused
+        # 2 of the 3 convs per bottleneck carry a bn+relu epilogue: the
+        # pointwise conv1 takes the pallas kernels, the 3x3 conv2 XLA's
+        # convolution (ops/pallas/conv_bn_relu.py); bn3 feeds the
+        # residual add and stays unfused
         out = fused_conv_bn_relu(self.conv1, self.bn1, x)
         out = fused_conv_bn_relu(self.conv2, self.bn2, out)
         out = self.bn3(self.conv3(out))

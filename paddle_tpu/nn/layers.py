@@ -188,12 +188,14 @@ class AdaptiveMaxPool2D(Layer):
 
 def fused_conv_bn_relu(conv, bn, x):
     """``relu(bn(conv(x)))`` through the fused pallas conv+bn+relu
-    kernel (``FLAGS_use_fused_conv_bn``) when the triple is admissible:
+    kernels (``FLAGS_use_fused_conv_bn``) when the triple is admissible:
     a bias-free, ungrouped, undilated Conv2D feeding a matching
-    BatchNorm2D — the vision models' hot sequence. The jnp fallback
-    (and the unfused path here) executes the identical op kernels in
-    the same order, so this is a scheduling choice, never a numeric
-    one — the ``_residual_norm`` discipline applied to conv nets.
+    BatchNorm2D. Of those the kernels take the pointwise convs (1x1,
+    stride 1, no padding: a bottleneck block's first); a conv with a
+    spatial extent runs the fallback, as does every conv off the TPU.
+    The fallback (and the unfused path here) executes the identical op
+    kernels in the same order, so this is a scheduling choice, never a
+    numeric one — the ``_residual_norm`` discipline applied to conv nets.
 
     Running statistics update exactly as ``F.batch_norm`` does in
     training (detached blend into the layer buffers).

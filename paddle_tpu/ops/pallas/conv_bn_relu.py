@@ -1,22 +1,23 @@
 """Fused conv2d + batch_norm + relu (TPU pallas kernels, fwd + bwd).
 
-The ResNet hot path is the ``conv -> bn -> relu`` triple (three per
-bottleneck block, ~50 per forward): op-by-op that is an HBM round trip
-for the conv output, two more for the statistics and the normalized
-activation, and one for the relu. Here the conv contraction runs as a
-tiled MXU matmul whose epilogue applies the BN affine + relu in the
-same VMEM pass:
+The path takes POINTWISE convolutions only: kernel 1x1, stride 1, no
+padding (in ResNet-50 each bottleneck block's first conv, sixteen a
+forward). Only there is the conv's matmul form its own input: a move
+of the channel axis and a reshape give ``x2 [N*H*W, Cin]``, and
+``x2 @ w2 [Cin, Cout]`` runs as a tiled MXU matmul whose epilogue does
+the batch-norm work in the same VMEM pass. A conv with a spatial extent
+(KxK, strided, padded) would first need its im2col patch matrix
+written, re-tiled, padded, kept for the backward pass and scattered
+back: on the chip that traffic cost more than the whole of XLA's
+convolution, which reads the input in place (PERF.md, Findings PR 32).
+Such a conv takes the fallback below, flag on or off, forced or not.
 
-- the conv lowers to matmul form ONCE outside the kernels (1x1
-  stride-1 convs reshape directly; KxK convs go through
-  ``lax.conv_general_dilated_patches`` — the classical im2col, whose
-  VJP gives the dx scatter for free), then
-- **eval**: ONE kernel computes ``relu((patches @ w) * scale + shift)``
-  per [TM, TN] tile — the pre-activation never exists in HBM. scale /
+- **eval**: ONE kernel computes ``relu((x2 @ w2) * scale + shift)``
+  per [TM, TN] tile, so the pre-activation never exists in HBM. scale /
   shift fold gamma/beta with the running statistics.
 - **training**: kernel 1 computes the matmul AND per-tile partial
   channel sums in the same pass; kernel 2 reduces the CENTERED
-  sum-of-squares (two-pass variance — the one-pass E[x^2]-mean^2 form
+  sum-of-squares (two-pass variance: the one-pass E[x^2]-mean^2 form
   catastrophically cancels for large-mean channels, see
   ``_centered_sumsq_kernel``); kernel 3 is one elementwise
   normalize+relu pass.
@@ -24,14 +25,15 @@ same VMEM pass:
   saved conv output and emits per-tile partials of ``sum(dy)`` and
   ``sum(dy * co)`` (one pass); kernel B2 applies the folded BN
   backward ``d_co = k1*dy - k3*co - b0`` elementwise. The matmul
-  gradients finish through ``jnp.dot`` (MXU via XLA) and the patch
-  VJP — the same "kernels do the fused pointwise work, jnp finishes
-  the reductions" discipline as layernorm_residual's dw/db.
+  gradients finish through ``jnp.dot`` (MXU via XLA): the same
+  "kernels do the fused pointwise work, jnp finishes the reductions"
+  discipline as layernorm_residual's dw/db.
 
-Off-TPU (and for unadmitted shapes) the fallback calls the IDENTICAL
-registered op kernels (``conv2d`` -> ``batch_norm`` -> relu) in the
-same order, so ``FLAGS_use_fused_conv_bn`` never changes numerics off
-the pallas path — the same flag discipline as the PR-10 kernels.
+The fallback (off-TPU, unadmitted shapes, every spatial conv) calls the
+IDENTICAL registered op kernels (``conv2d`` -> ``batch_norm`` -> relu)
+in the same order, so ``FLAGS_use_fused_conv_bn`` never changes
+numerics off the pallas path: the same flag discipline as the PR-10
+kernels.
 
 Tile geometry (TM, TN) resolves through the kernel autotuner
 (``tuning.resolve("conv_bn_relu", ...)``) with the historical 256/256
@@ -43,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..._internal_tuning import register_schedule, resolve_schedule
@@ -88,8 +91,6 @@ def _conv_vmem_ok(info, c) -> bool:
 
 
 def _tuning_bench(info):
-    import numpy as np
-
     m, k, c = int(info["m"]), int(info["k"]), int(info["c"])
     dtype = str(info.get("dtype", "float32"))
     rng = np.random.RandomState(0)
@@ -149,56 +150,19 @@ def _reference(x, w, gamma, beta, mean, var, *, stride, padding, training,
     return jax.nn.relu(y), new_mean, new_var
 
 
-# -- conv -> matmul lowering --------------------------------------------------
-
-
-def _norm_padding(padding):
-    """Normalize int / (ph, pw) / 4-list padding to [(t, b), (l, r)];
-    None for forms the fused path does not admit (SAME/VALID strings,
-    per-edge pair-of-pairs fall back)."""
-    if isinstance(padding, str):
-        return None
-    if isinstance(padding, (list, tuple)):
-        if len(padding) == 2 and all(
-                isinstance(p, (list, tuple)) for p in padding):
-            return [tuple(padding[0]), tuple(padding[1])]
-        if len(padding) == 2:
-            return [(padding[0], padding[0]), (padding[1], padding[1])]
-        if len(padding) == 4:
-            return [(padding[0], padding[1]), (padding[2], padding[3])]
-        return None
-    return [(int(padding), int(padding))] * 2
+# -- matmul operands ----------------------------------------------------------
 
 
 def _pair(v):
     return tuple(v) if isinstance(v, (list, tuple)) else (int(v), int(v))
 
 
-def _as_matmul(x, w, stride, pad, data_format):
-    """Lower the conv to ``patches2d [M, K] @ w2 [K, Cout]``.
-
-    Returns (patches2d, w2, (n, oh, ow)). The patch features are
-    ordered (cin, kh, kw) — exactly the OIHW weight's trailing-axes
-    flattening, verified by the interpret parity tests.
-    """
-    if data_format == "NHWC":
-        x = jnp.moveaxis(x, -1, 1)
-    n, cin, h, wd = x.shape
-    cout, _, kh, kw = w.shape
-    sh, sw = _pair(stride)
-    oh = (h + pad[0][0] + pad[0][1] - kh) // sh + 1
-    ow = (wd + pad[1][0] + pad[1][1] - kw) // sw + 1
-    if (kh, kw) == (1, 1) and (sh, sw) == (1, 1) \
-            and pad == [(0, 0), (0, 0)]:
-        # pointwise conv (2 of 3 convs per bottleneck block): the
-        # "patches" ARE the input, channels-last
-        p2 = jnp.moveaxis(x, 1, -1).reshape(n * h * wd, cin)
-    else:
-        p = lax.conv_general_dilated_patches(
-            x, (kh, kw), (sh, sw), pad)           # [N, Cin*KH*KW, OH, OW]
-        p2 = jnp.moveaxis(p, 1, -1).reshape(n * oh * ow, cin * kh * kw)
-    w2 = w.reshape(cout, cin * kh * kw).T          # [K, Cout], (i, kh, kw)
-    return p2, w2, (n, oh, ow)
+def _pointwise(w, stride, padding):
+    """Kernel 1x1, stride 1, no padding (an int, a pair, a 4-list or a
+    pair of pairs, all zero): the one conv whose matmul form is its
+    input. SAME/VALID strings fall back like every spatial conv."""
+    return (tuple(w.shape[2:]) == (1, 1) and _pair(stride) == (1, 1)
+            and not isinstance(padding, str) and not np.any(padding))
 
 
 def _pad_mat(a, rows, cols):
@@ -614,9 +578,7 @@ def _supported(x, w, stride, padding, data_format, dilation, groups):
         return False
     if groups != 1 or _pair(dilation) != (1, 1):
         return False
-    if x.ndim != 4 or w.ndim != 4:
-        return False
-    if _norm_padding(padding) is None:
+    if x.ndim != 4 or not _pointwise(w, stride, padding):
         return False
     if data_format not in ("NCHW", "NHWC"):
         return False
@@ -627,15 +589,20 @@ def _supported(x, w, stride, padding, data_format, dilation, groups):
 
 def _fused(x, w, gamma, beta, mean, var, *, stride, padding, training,
            momentum, eps, data_format, interpret=False, force=False):
-    if not force and not _supported(x, w, stride, padding, data_format,
-                                    1, 1):
+    # force skips the platform, dtype and size gates (the CPU tests' way
+    # in), never the pointwise one: there is no lowering for the rest
+    if not (_supported(x, w, stride, padding, data_format, 1, 1)
+            or force and _pointwise(w, stride, padding)):
         return _reference(x, w, gamma, beta, mean, var, stride=stride,
                           padding=padding, training=training,
                           momentum=momentum, eps=eps,
                           data_format=data_format)
-    pad = _norm_padding(padding)
-    p2, w2, (n, oh, ow) = _as_matmul(x, w, stride, pad, data_format)
+    if data_format == "NCHW":
+        x = jnp.moveaxis(x, 1, -1)
+    *lead, cin = x.shape
     cout = w.shape[0]
+    p2 = x.reshape(-1, cin)     # the "patches" ARE the input, channels-last
+    w2 = w.reshape(cout, cin).T                    # [K, Cout]
     gf = gamma.astype(jnp.float32)
     bf = beta.astype(jnp.float32)
     if training:
@@ -650,7 +617,7 @@ def _fused(x, w, gamma, beta, mean, var, *, stride, padding, training,
                         var.astype(jnp.float32), float(eps),
                         bool(interpret))
         new_mean, new_var = mean, var
-    y = y2.reshape(n, oh, ow, cout)
+    y = y2.reshape(*lead, cout)
     if data_format == "NCHW":
         y = jnp.moveaxis(y, -1, 1)
     return y, new_mean, new_var
@@ -665,9 +632,9 @@ def conv_bn_relu(x, weight, gamma, beta, running_mean, running_var, *,
     batch_norm running-stat semantics (``running = momentum*running +
     (1-momentum)*batch``; unchanged in eval mode). Accepts Tensors
     (autograd-tracked through the op tape) or raw arrays; pallas on TPU
-    for admitted shapes, the identical unfused op sequence elsewhere.
-    The conv must be bias-free, ungrouped, undilated (the vision-path
-    triple this fusion targets).
+    for pointwise convs of admitted shapes, the identical unfused op
+    sequence for every other conv and everywhere else. The conv must be
+    bias-free, ungrouped, undilated.
     """
     from ...framework.tensor import Tensor
 

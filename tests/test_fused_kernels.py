@@ -301,9 +301,8 @@ def test_pre_norm_layer_unaffected_by_flag(_flags_restored):
 # -- fused conv + batch_norm + relu -------------------------------------------
 
 
-def _cbr_operands(cin=3, cout=8, kh=3, df="NCHW", seed=0):
+def _cbr_operands(cin=3, cout=8, kh=1, df="NCHW", seed=0, n=2, h=10):
     rng = np.random.RandomState(seed)
-    n, h = 2, 10
     shape = (n, cin, h, h) if df == "NCHW" else (n, h, h, cin)
     x = jnp.asarray(rng.randn(*shape).astype("f4"))
     w = jnp.asarray(rng.randn(cout, cin, kh, kh).astype("f4") * 0.2)
@@ -314,24 +313,45 @@ def _cbr_operands(cin=3, cout=8, kh=3, df="NCHW", seed=0):
     return x, w, gamma, beta, mean, var
 
 
+def _cbr_grads(fn, x, w, gamma, beta, mean, var, **kw):
+    def loss(x, w, g, b):
+        y, _, _ = fn(x, w, g, b, mean, var, **kw)
+        return (y * jnp.cos(y)).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2, 3))(x, w, gamma, beta)
+
+
+def _cbr_forced(*a, **k):
+    return cbr._fused(*a, interpret=True, force=True, **k)
+
+
+# ResNet-50's pointwise triples (each bottleneck block's first conv) at
+# 1/16 of their channels and a batch of 2: [N, Cin, HW, HW] -> Cout
+_POINTWISE = dict(
+    stage1=dict(cin=16, cout=4, h=56),     # [128, 256, 56, 56] -> 64
+    stage3=dict(cin=64, cout=16, h=14),    # [128, 1024, 14, 14] -> 256
+    stage4=dict(cin=128, cout=32, h=7),    # [128, 2048, 7, 7] -> 512
+)
+
+
 @pytest.mark.parametrize("case", [
-    dict(kh=3, stride=2, padding=1, df="NCHW", training=True),
-    dict(kh=1, stride=1, padding=0, df="NCHW", training=True),  # pointwise
-    dict(kh=3, stride=1, padding=1, df="NHWC", training=False),
-    dict(kh=3, stride=1, padding=1, df="NCHW", training=False),
-])
+    dict(_POINTWISE["stage1"], df="NCHW", training=True),
+    dict(_POINTWISE["stage3"], df="NCHW", training=True),
+    dict(_POINTWISE["stage4"], df="NHWC", training=False),
+    dict(_POINTWISE["stage4"], df="NCHW", training=False),
+], ids=["stage1-train", "stage3-train", "stage4-nhwc-eval", "stage4-eval"])
 def test_conv_bn_relu_interpret_parity_fwd(case):
     """Pallas (interpret) == the unfused conv2d->batch_norm->relu op
-    sequence, including the running-stat outputs, across stride /
-    padding / layout / mode."""
+    sequence, including the running-stat outputs, across the pointwise
+    shapes / layout / mode."""
     df, training = case["df"], case["training"]
-    x, w, gamma, beta, mean, var = _cbr_operands(kh=case["kh"], df=df)
-    kw = dict(stride=case["stride"], padding=case["padding"],
-              training=training, momentum=0.9, eps=1e-5, data_format=df)
+    x, w, gamma, beta, mean, var = _cbr_operands(
+        cin=case["cin"], cout=case["cout"], h=case["h"], df=df)
+    kw = dict(stride=1, padding=0, training=training, momentum=0.9,
+              eps=1e-5, data_format=df)
     ref_y, ref_m, ref_v = cbr._reference(x, w, gamma, beta, mean, var,
                                          **kw)
-    y, nm, nv = cbr._fused(x, w, gamma, beta, mean, var, interpret=True,
-                           force=True, **kw)
+    y, nm, nv = _cbr_forced(x, w, gamma, beta, mean, var, **kw)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref_y),
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(nm), np.asarray(ref_m),
@@ -368,23 +388,15 @@ def test_conv_bn_relu_large_mean_variance_is_stable():
 
 @pytest.mark.parametrize("training", [True, False])
 def test_conv_bn_relu_interpret_parity_bwd(training):
-    """Pallas backward (relu-gate recompute + folded BN backward + the
-    patch-VJP dx scatter) == autodiff of the unfused sequence."""
-    x, w, gamma, beta, mean, var = _cbr_operands(seed=1)
-    kw = dict(stride=2, padding=1, training=training, momentum=0.9,
-              eps=1e-5, data_format="NCHW")
-
-    def loss(fn, x, w, g, b):
-        y, _, _ = fn(x, w, g, b, mean, var, **kw)
-        return (y * jnp.cos(y)).sum()
-
-    ref = jax.grad(lambda *a: loss(cbr._reference, *a),
-                   argnums=(0, 1, 2, 3))(x, w, gamma, beta)
-    fused = jax.grad(
-        lambda *a: loss(
-            lambda *b, **k: cbr._fused(*b, interpret=True, force=True,
-                                       **k), *a),
-        argnums=(0, 1, 2, 3))(x, w, gamma, beta)
+    """Pallas backward (relu-gate recompute + folded BN backward, the
+    matmul grads through jnp.dot) == autodiff of the unfused sequence,
+    NHWC in training and NCHW in eval."""
+    df = "NHWC" if training else "NCHW"
+    ops = _cbr_operands(**_POINTWISE["stage3"], df=df, seed=1)
+    kw = dict(stride=1, padding=0, training=training, momentum=0.9,
+              eps=1e-5, data_format=df)
+    ref = _cbr_grads(cbr._reference, *ops, **kw)
+    fused = _cbr_grads(_cbr_forced, *ops, **kw)
     for name, a, b in zip(("dx", "dw", "dgamma", "dbeta"), ref, fused):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-4, err_msg=name)
@@ -392,41 +404,75 @@ def test_conv_bn_relu_interpret_parity_bwd(training):
 
 def test_conv_bn_relu_ragged_row_tiles_fwd_bwd():
     """Row counts that do NOT divide the 256-row tile (2*17*17=578 ->
-    three tiles, ragged tail): the reduction kernels must mask the
-    out-of-bounds tail rows (undefined content) out of the channel
-    sums — fwd stats AND bwd partials."""
-    rng = np.random.RandomState(3)
-    x = jnp.asarray(rng.randn(2, 3, 17, 17).astype("f4"))
-    w = jnp.asarray(rng.randn(8, 3, 3, 3).astype("f4") * 0.2)
-    gamma = jnp.asarray(rng.rand(8).astype("f4") + 0.5)
-    beta = jnp.asarray(rng.randn(8).astype("f4") * 0.1)
-    mean = jnp.asarray(np.zeros(8, "f4"))
-    var = jnp.asarray(np.ones(8, "f4"))
-    kw = dict(stride=1, padding=1, training=True, momentum=0.9,
+    three tiles, ragged tail) and channels off the lane tile (24 -> 40):
+    the reduction kernels must mask the out-of-bounds tail rows
+    (undefined content) out of the channel sums — fwd stats AND bwd
+    partials."""
+    x, w, gamma, beta, _, _ = _cbr_operands(cin=24, cout=40, h=17, seed=3)
+    mean, var = jnp.zeros(40), jnp.ones(40)
+    kw = dict(stride=1, padding=0, training=True, momentum=0.9,
               eps=1e-5, data_format="NCHW")
     ref_y, _, ref_v = cbr._reference(x, w, gamma, beta, mean, var, **kw)
-    y, _, nv = cbr._fused(x, w, gamma, beta, mean, var, interpret=True,
-                          force=True, **kw)
+    y, _, nv = _cbr_forced(x, w, gamma, beta, mean, var, **kw)
     assert not np.isnan(np.asarray(y)).any()
     np.testing.assert_allclose(np.asarray(y), np.asarray(ref_y),
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(nv), np.asarray(ref_v),
                                rtol=1e-3, atol=1e-4)
-
-    def loss(fn, x, w, g, b):
-        y, _, _ = fn(x, w, g, b, mean, var, **kw)
-        return (y * jnp.cos(y)).sum()
-
-    ref = jax.grad(lambda *a: loss(cbr._reference, *a),
-                   argnums=(0, 1, 2, 3))(x, w, gamma, beta)
-    fused = jax.grad(
-        lambda *a: loss(
-            lambda *b, **k: cbr._fused(*b, interpret=True, force=True,
-                                       **k), *a),
-        argnums=(0, 1, 2, 3))(x, w, gamma, beta)
+    ref = _cbr_grads(cbr._reference, x, w, gamma, beta, mean, var, **kw)
+    fused = _cbr_grads(_cbr_forced, x, w, gamma, beta, mean, var, **kw)
     for name, a, b in zip(("dx", "dw", "dgamma", "dbeta"), ref, fused):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-3, atol=2e-4, err_msg=name)
+
+
+def _primitives(jaxpr, into=None):
+    """Names of every primitive of a jaxpr, sub-jaxprs included."""
+    into = set() if into is None else into
+    for eqn in jaxpr.eqns:
+        into.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, into)
+    return into
+
+
+@pytest.mark.parametrize("kh,stride,padding,pointwise", [
+    (3, 1, 1, False),                        # a block's 3x3
+    (7, 2, 3, False),                        # the stem
+    (1, 2, 0, False),                        # strided 1x1
+    (1, 1, [0, 1, 0, 1], False),             # padded 1x1
+    (1, 1, "VALID", False),                  # a string form
+    (1, 1, 0, True),                         # pointwise
+    (1, (1, 1), [[0, 0], [0, 0]], True),     # the same, spelt out
+], ids=["3x3", "stem", "strided", "padded", "valid", "pointwise",
+        "pointwise-pairs"])
+@pytest.mark.parametrize("force", [False, True])
+def test_conv_bn_relu_dispatch_takes_pointwise_only(kh, stride, padding,
+                                                    pointwise, force,
+                                                    monkeypatch):
+    """With the platform gate open, only a 1x1 stride-1 unpadded conv
+    reaches the kernels, forced or not; every other conv IS the
+    reference: XLA's convolution, no pallas_call, bit for bit."""
+    monkeypatch.setattr(cbr, "can_emit_mosaic", lambda: True)
+    ops = _cbr_operands(cin=16, cout=128, kh=kh, n=4, h=12)
+    kw = dict(stride=stride, padding=padding, training=True, momentum=0.9,
+              eps=1e-5, data_format="NCHW")
+    assert cbr._supported(ops[0], ops[1], stride, padding, "NCHW", 1,
+                          1) == pointwise
+
+    def fused(*a):
+        return cbr._fused(*a, interpret=True, force=force, **kw)
+
+    prims = _primitives(jax.make_jaxpr(fused)(*ops).jaxpr)
+    if pointwise:
+        assert "pallas_call" in prims
+        assert "conv_general_dilated" not in prims
+    else:
+        assert "conv_general_dilated" in prims
+        assert "pallas_call" not in prims
+        ref = jax.jit(lambda *a: cbr._reference(*a, **kw))(*ops)
+        for a, b in zip(jax.jit(fused)(*ops), ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("training", [True, False])

@@ -10,6 +10,7 @@ only runs on the chip — see .claude/skills/verify/SKILL.md for the AOT
 recipe that reaches it from the sandbox.
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -86,20 +87,29 @@ def test_flash_attention_causal():
     assert '"flash_fwd"' in text
 
 
-@pytest.mark.parametrize("n,cin,hw,cout,k,stride,pad", [
-    (128, 3, 224, 64, 7, 2, 3),     # ResNet-50 stem
-    (128, 64, 56, 64, 1, 1, 0),     # bottleneck conv1 (pointwise)
-    (128, 128, 56, 128, 3, 2, 1),   # stage-2 entry, strided 3x3
-    (128, 512, 7, 512, 3, 1, 1),    # last stage: K = 4608
-    (2, 16, 7, 24, 3, 1, 1),        # ragged: 98 rows, 24 channels
+_CONV_KERNELS = ("conv_mm_stats", "conv_centered_sumsq", "conv_bn_relu",
+                 "conv_bn_bwd_partials", "conv_bn_bwd_dco")
+
+
+def _kernel_names(text):
+    return sorted(re.findall(r'kernel_name = "(\w+)"', text))
+
+
+# ResNet-50's pointwise triples at batch 128: each bottleneck block's
+# first conv is all the fused path takes
+@pytest.mark.parametrize("n,cin,hw,cout,df", [
+    (128, 256, 56, 64, "NCHW"),     # stage 1: Cout 64 pads to 128 lanes
+    (128, 1024, 14, 256, "NCHW"),   # stage 3
+    (128, 2048, 7, 512, "NCHW"),    # stage 4: 6272 rows
+    (128, 512, 28, 128, "NHWC"),    # stage 2, channels-last
+    (2, 24, 7, 40, "NCHW"),         # ragged: 98 rows, channels off the tile
 ])
-def test_conv_bn_relu_train_fwd_bwd_and_eval(n, cin, hw, cout, k, stride,
-                                             pad):
-    x = _sds((n, cin, hw, hw), BF16)
-    w = _sds((cout, cin, k, k), BF16)
+def test_conv_bn_relu_train_fwd_bwd_and_eval(n, cin, hw, cout, df):
+    x = _sds((n, cin, hw, hw) if df == "NCHW" else (n, hw, hw, cin), BF16)
+    w = _sds((cout, cin, 1, 1), BF16)
     vec = _sds((cout,))
-    kw = dict(stride=stride, padding=pad, momentum=0.9, eps=1e-5,
-              data_format="NCHW", force=True)
+    kw = dict(stride=1, padding=0, momentum=0.9, eps=1e-5, data_format=df,
+              force=True)
 
     def train_loss(x, w, gamma, beta, mean, var):
         y, _, _ = cbr._fused(x, w, gamma, beta, mean, var, training=True,
@@ -109,13 +119,51 @@ def test_conv_bn_relu_train_fwd_bwd_and_eval(n, cin, hw, cout, k, stride,
     text = _lower_for_tpu(
         jax.value_and_grad(train_loss, argnums=(0, 1, 2, 3)),
         x, w, vec, vec, vec, vec)
-    for name in ("conv_mm_stats", "conv_centered_sumsq", "conv_bn_relu",
-                 "conv_bn_bwd_partials", "conv_bn_bwd_dco"):
-        assert f'"{name}"' in text
+    assert _kernel_names(text) == sorted(_CONV_KERNELS)
+    assert "stablehlo.convolution" not in text
     text = _lower_for_tpu(
         lambda *a: cbr._fused(*a, training=False, **kw)[0],
         x, w, vec, vec, vec, vec)
-    assert '"conv_mm_affine_relu"' in text
+    assert _kernel_names(text) == ["conv_mm_affine_relu"]
+
+
+@pytest.mark.parametrize("inplanes,xla_convs", [
+    (256, 2),   # the 3x3 and the last 1x1
+    (64, 3),    # a stage's first block: the projection too
+])
+def test_bottleneck_block_step_holds_one_fused_triple(inplanes, xla_convs,
+                                                      monkeypatch):
+    """A whole bottleneck block under AMP, lowered for TPU with the
+    platform gate open: its one pointwise triple (conv1) is the five
+    kernels, once each; the 3x3, the last 1x1 and the projection are
+    XLA convolutions, as they are with the flag off."""
+    import paddle_tpu as paddle
+    import paddle_tpu.nn as nn
+    from paddle_tpu import amp
+    from paddle_tpu.framework import jit as fjit
+    from paddle_tpu.models.resnet import BottleneckBlock
+
+    monkeypatch.setattr(cbr, "can_emit_mosaic", lambda: True)
+    paddle.seed(0)
+    downsample = None if inplanes == 256 else nn.Sequential(
+        nn.Conv2D(inplanes, 256, 1, bias_attr=False), nn.BatchNorm2D(256))
+    block = BottleneckBlock(inplanes, 64, downsample=downsample)
+    block.train()
+    state = jax.tree_util.tree_map(lambda a: _sds(a.shape, a.dtype),
+                                   fjit.capture_state(block))
+    params = state.pop("params")
+
+    def loss(params, rest, x):
+        with amp.auto_cast():
+            y, _ = fjit.functional_call(block, dict(rest, params=params), x)
+        return y.astype(F32).sum()
+
+    args = (params, state, _sds((128, inplanes, 56, 56)))
+    fwd = _lower_for_tpu(loss, *args)
+    assert _kernel_names(fwd) == sorted(_CONV_KERNELS[:3])
+    assert fwd.count("stablehlo.convolution") == xla_convs
+    assert _kernel_names(_lower_for_tpu(jax.value_and_grad(loss), *args)) \
+        == sorted(_CONV_KERNELS)
 
 
 @pytest.mark.parametrize("shape", [
