@@ -18,6 +18,11 @@ Legs, run in sequence by one process that holds the chip throughout:
   query attention, 40 of 320 routed experts held, bfloat16) at its
   published widths and two layers through the same server: a K/V ring
   and a recurrent state in one cache.
+- ``window``   the window/full attention decoder (sliding window 128
+  with rotary positions beside full attention, a dense layer 0, 16 of
+  128 routed experts held, bfloat16) at its published widths and two
+  layers through the same server: rings of two lengths in one cache,
+  prompts and outputs that cross the window's wrap.
 - ``bert4``    the BERT trainer's first phase on a dp=2 x tp=2 mesh,
   when the process sees four or more devices.
 
@@ -73,6 +78,17 @@ CHIP = {
                               prefill_buckets=(64, 128),
                               kv_cache_dtype="bfloat16"),
                "prompt_lens": (5, 20, 48, 100), "max_new_tokens": 16},
+    # published widths, layer 0 (sliding, dense) and a full sparse layer,
+    # the share of one chip in eight; 120 + 16 tokens wrap a ring of 128
+    # while decoding, 200 wrapped it in the prefill
+    "window": {"config": dict(
+        num_hidden_layers=2,
+        layer_types=("sliding_attention", "full_attention"),
+        experts_held=(0, 16), vocab_held=19200, dtype="bfloat16"),
+        "engine": dict(slots=4, cache_len=512,
+                       prefill_buckets=(64, 128, 256),
+                       kv_cache_dtype="bfloat16"),
+        "prompt_lens": (5, 120, 200, 48), "max_new_tokens": 16},
 }
 
 TINY = {
@@ -104,6 +120,16 @@ TINY = {
         "engine": dict(slots=2, cache_len=32, prefill_buckets=(4, 8),
                        kv_cache_dtype="float32"),
         "prompt_lens": (1, 3, 8, 5), "max_new_tokens": 6},
+    "window": {"config": dict(
+        vocab_size=97, vocab_held=64, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        layer_types=("sliding_attention", "full_attention"),
+        sliding_window=8, intermediate_size=48, moe_intermediate_size=16,
+        num_experts=16, num_experts_per_tok=2, experts_held=(4, 4),
+        initializer_range=0.2),
+        "engine": dict(slots=2, cache_len=32, prefill_buckets=(4, 8, 16),
+                       kv_cache_dtype="float32"),
+        "prompt_lens": (1, 6, 13, 5), "max_new_tokens": 6},
 }
 
 # Mosaic calls a compiled step must contain on one chip. Under a mesh the
@@ -613,6 +639,33 @@ def leg_hybrid(preset) -> dict:
                  "cache_bytes": engine.cache_nbytes()}, **out)
 
 
+def leg_window(preset) -> dict:
+    """The window/full attention decoder through the same server: a
+    ring as long as the cache and a ring of the window's rows in one
+    cache, served past the window's wrap."""
+    import paddle_tpu as paddle
+    from paddle_tpu.generation import GenerationEngine
+    from paddle_tpu.models import ExaoneMoEConfig, ExaoneMoEForCausalLM
+
+    p = preset["window"]
+    cfg = ExaoneMoEConfig(**p["config"])
+    paddle.seed(17)
+    engine = GenerationEngine(ExaoneMoEForCausalLM(cfg), temperature=0.0,
+                              top_k=0, kv_cache_layout="ring", **p["engine"])
+    rings = [k.ring(engine.store_len) for k in engine.model.cache_spec()]
+    _require(rings == [cfg.sliding_window, engine.cache_len],
+             f"expected a window ring and a full ring, got {rings}")
+    _require(max(p["prompt_lens"]) > cfg.sliding_window,
+             "no prompt wraps the window")
+    out = _serve_leg(engine, _prompts(p["prompt_lens"], cfg.vocab_held),
+                     p["max_new_tokens"])
+    full, window, _ = engine.cache_bytes_by_kind()
+    return dict({"layers": cfg.num_hidden_layers, "hidden": cfg.hidden_size,
+                 "experts_held": list(cfg.experts_held), "rings": rings,
+                 "full_ring_bytes": full, "window_ring_bytes": window},
+                **out)
+
+
 # -- driver -------------------------------------------------------------------
 
 
@@ -624,7 +677,7 @@ def run_legs(preset) -> dict:
     legs = {}
     for name, leg in (("kernels", leg_kernels), ("bert", leg_bert),
                       ("resnet", leg_resnet), ("gpt", leg_gpt),
-                      ("hybrid", leg_hybrid)):
+                      ("hybrid", leg_hybrid), ("window", leg_window)):
         legs[name] = leg(preset)
         print(f"leg {name} on 1 device: {json.dumps(legs[name])}",
               flush=True)

@@ -58,7 +58,8 @@ __all__ = [
     "pad_slot_arrays",
     "kv", "state", "KVKind", "StateKind", "is_layer_kinds",
     "init_kinds_cache", "kinds_layer_caches", "unzip_kinds_caches",
-    "kinds_slot_nbytes", "kinds_bytes_per_token",
+    "kinds_slot_nbytes", "kinds_bytes_per_token", "kinds_ring_lengths",
+    "kinds_decode_mask",
 ]
 
 NEG_INF = -1e9
@@ -186,27 +187,44 @@ def kv_bytes_per_token(num_layers, num_heads, head_dim,
 # it as they stand (their "planes" are this form's layers). A kind says
 # what its layer keeps (``arrays``), which per-layer cache the model's
 # forward is handed (``wrap``), and what a slot costs (``slot_nbytes``).
+# The K/V rings of one cache may differ in length (a layer that attends
+# a window keeps the window's rows): every ring is written at ``pos mod
+# its own length`` and read under the decode mask of that length
+# (:func:`kinds_decode_mask`), all from the one ``pos``.
 
 
 class KVKind(NamedTuple):
-    """A softmax-attention layer: a ``[B, heads, store, head_dim]`` K and
-    V ring (:class:`nn.StaticCache`), ``heads`` the K/V heads."""
+    """A softmax-attention layer: a ``[B, heads, ring, head_dim]`` K and
+    V ring (:class:`nn.StaticCache`), ``heads`` the K/V heads. The ring
+    is the engine's ``store`` rows long, or, for a layer that attends no
+    further back than ``window`` positions, ``window`` rows whatever the
+    store is: such a layer costs a slot a constant and a token nothing
+    more once it is full."""
 
     heads: int
     head_dim: int
+    window: int | None = None
+
+    def ring(self, store):
+        """Rows of this layer's ring in a cache of ``store`` rows."""
+        return int(store) if self.window is None \
+            else min(self.window, int(store))
 
     def arrays(self, batch, store, dtype):
-        shape = (int(batch), self.heads, int(store), self.head_dim)
+        shape = (int(batch), self.heads, self.ring(store), self.head_dim)
         return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
     def wrap(self, arrays, pos):
         return StaticCache(*arrays, pos)
 
-    def bytes_per_token(self, dtype):
+    def row_nbytes(self, dtype):
         return 2 * self.heads * self.head_dim * jnp.dtype(dtype).itemsize
 
+    def bytes_per_token(self, dtype):
+        return 0 if self.window is not None else self.row_nbytes(dtype)
+
     def slot_nbytes(self, store, dtype):
-        return int(store) * self.bytes_per_token(dtype)
+        return self.ring(store) * self.row_nbytes(dtype)
 
 
 class StateKind(NamedTuple):
@@ -232,9 +250,11 @@ class StateKind(NamedTuple):
                    for s, d in zip(self.shapes, self.dtypes))
 
 
-def kv(heads, head_dim):
-    """The kind of a layer that keeps K/V rows for ``heads`` K/V heads."""
-    return KVKind(int(heads), int(head_dim))
+def kv(heads, head_dim, window=None):
+    """The kind of a layer that keeps K/V rows for ``heads`` K/V heads:
+    as many as the cache is long, or the last ``window`` of them."""
+    return KVKind(int(heads), int(head_dim),
+                  None if window is None else int(window))
 
 
 def state(shapes, dtypes):
@@ -269,15 +289,36 @@ def unzip_kinds_caches(caches):
 
 
 def kinds_bytes_per_token(kinds, dtype="float32") -> int:
-    """Cache bytes one more token costs a slot: the K/V layers' rows; a
-    state layer adds nothing."""
+    """Cache bytes one more token costs a slot: the full-length K/V
+    layers' rows; a window or state layer adds nothing."""
     return sum(k.bytes_per_token(dtype) for k in kinds)
 
 
 def kinds_slot_nbytes(kinds, store, dtype="float32") -> int:
-    """Cache bytes one slot costs: ``store`` rows in every K/V layer and
-    a constant in every state layer (``pos`` aside)."""
+    """Cache bytes one slot costs: each K/V layer's ring at its own
+    length (``store`` rows, or its window) and a constant in every state
+    layer (``pos`` aside)."""
     return sum(k.slot_nbytes(store, dtype) for k in kinds)
+
+
+def kinds_ring_lengths(kinds, store):
+    """The distinct ring lengths of the K/V layers, longest first."""
+    return sorted({k.ring(store) for k in kinds if isinstance(k, KVKind)},
+                  reverse=True)
+
+
+def kinds_decode_mask(kinds, pos, store, window=None):
+    """The decode step's mask for a per-layer list of kinds. Where every
+    K/V ring is ``store`` rows long, :func:`decode_mask` of it, as a
+    model whose rings are alike takes it. Where the rings are of several
+    lengths, ``{ring length: mask}``, each asked for once: a layer takes
+    the mask of the ring it was handed (``cache.k.shape[2]``)."""
+    lengths = kinds_ring_lengths(kinds, store)
+    if lengths in ([], [int(store)]):
+        return decode_mask(pos, store, window=window)
+    return {n: decode_mask(pos, n, window=min(
+        n, int(store) if window is None else int(window)))
+        for n in lengths}
 
 
 def decode_mask(pos, cache_len, window=None, dtype="float32"):

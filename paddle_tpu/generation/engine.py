@@ -456,6 +456,12 @@ class GenerationEngine:
             self._kv = _cache.init_kinds_cache(
                 self._kinds, ring_slots, self.store_len,
                 dtype=self.kv_cache_dtype)
+            # the host's copy of ``pos``: what generation::kv_rows_read
+            # is counted from, without a fetch
+            self._pos_host = np.zeros(ring_slots, np.int64)
+            if _profiler_enabled():
+                _record_counter("generation::cache_bytes",
+                                list(self.cache_bytes_by_kind()))
         else:
             self._kv = _cache.init_cache(
                 self._num_layers, ring_slots, self._num_heads,
@@ -568,8 +574,9 @@ class GenerationEngine:
             return (self._pages_per_slot * self.page_nbytes(dtype)
                     + self._pages_per_slot * 4 + 4)
         if self._kinds is not None:
-            # store_len rows in every K/V layer, a constant in every
-            # state layer, the position word
+            # every K/V layer's ring at its own length (store_len rows,
+            # or its window), a constant in every state layer, the
+            # position word
             return _cache.kinds_slot_nbytes(self._kinds, self.store_len,
                                             dtype) + 4
         per = self.store_len * _cache.kv_bytes_per_token(
@@ -919,9 +926,12 @@ class GenerationEngine:
 
     def _sample_first(self, logits, length, temp, ctr):
         """Sample the first generated token from the last REAL prompt
-        position of a prefill's logits."""
-        last = jax.lax.dynamic_index_in_dim(
-            logits[0], length - 1, axis=0, keepdims=False)
+        position of a prefill's logits (a model may hand back that one
+        row alone: the head over a 16 k bucket is 1.3 GB of float32 of
+        which one row is read)."""
+        last = logits[0, 0] if logits.shape[1] == 1 else \
+            jax.lax.dynamic_index_in_dim(
+                logits[0], length - 1, axis=0, keepdims=False)
         key = jax.random.fold_in(self._base_key, ctr)
         return sample_logits(last[None], key, temp[None], self.top_k)[0]
 
@@ -949,8 +959,10 @@ class GenerationEngine:
                             ctr):
         """:meth:`_prefill_pure` for a per-layer list of kinds: the
         forward runs from position 0 into fresh one-row caches of every
-        kind, and what each layer then holds - K/V rows, or the state
-        after the last real token and the convolution's tail - is
+        kind, and what each layer then holds - K/V rows (a window
+        layer's fresh ring is its window long and ends up with the last
+        ``min(length, window)`` rows at ``position mod window``), or the
+        state after the last real token and the convolution's tail - is
         written into the slot. The model is given the additive
         key-padding mask ``[1, 1, 1, P]`` and applies causality itself,
         by blocks. Also returns the model's routing statistics, if it
@@ -1027,8 +1039,12 @@ class GenerationEngine:
                   else _cache.kinds_layer_caches(kinds, kv))
         pos = kv[-1]
         pos_ids = jnp.minimum(pos, self.max_positions - 1)[:, None]
-        mask = _cache.decode_mask(pos, self.store_len,
-                                  window=self.cache_len)
+        # one mask a distinct ring length: a layer that keeps a window
+        # of rows is handed the mask of that ring, not the store's
+        mask = (_cache.decode_mask(pos, self.store_len,
+                                   window=self.cache_len) if kinds is None
+                else _cache.kinds_decode_mask(kinds, pos, self.store_len,
+                                              window=self.cache_len))
         (logits, new_caches), _ = functional_call(
             self.model, state, tokens[:, None],
             position_ids=pos_ids, attention_mask=mask, caches=caches)
@@ -1234,6 +1250,8 @@ class GenerationEngine:
                 self._kv, tok = out
         tok = self._fetched("generation::prefill", t0, tok, int)
         if self._kinds is not None:
+            with self._key_lock:  # a step's += on another thread
+                self._pos_host[slot] = n
             self._sample_stats(stats, prefill=True)
         return tok
 
@@ -1244,8 +1262,9 @@ class GenerationEngine:
         decode alike), and for a decode step ``moe::pairs_here`` and
         ``moe::experts_hit`` (one value an expert layer) with
         ``generation::state_bytes`` (what the state layers' leaves
-        hold, of :meth:`cache_nbytes`). Off, the arrays are dropped
-        where they lie: no transfer, one boolean."""
+        hold, of :meth:`cache_nbytes`) and ``generation::kv_rows_read``
+        (:meth:`kv_rows_read`). Off, the arrays are dropped where they
+        lie: no transfer, one boolean."""
         if not _profiler_enabled():
             return
         if stats is not None:
@@ -1256,6 +1275,8 @@ class GenerationEngine:
                 _record_counter("moe::experts_hit", stats["hit"].tolist())
         if not prefill:
             _record_counter("generation::state_bytes", self.state_nbytes())
+            _record_counter("generation::kv_rows_read",
+                            list(self.kv_rows_read()))
 
     def state_nbytes(self) -> int:
         """Device bytes of the state layers' leaves (all slots): the
@@ -1264,6 +1285,32 @@ class GenerationEngine:
         return sum(_cache.cache_nbytes(arrays)
                    for kind, arrays in zip(self._kinds or (), self._kv)
                    if isinstance(kind, _cache.StateKind))
+
+    def cache_bytes_by_kind(self):
+        """``(full-length rings, window rings, state)``: the device
+        bytes of :meth:`cache_nbytes` by what a layer keeps (``pos``
+        aside)."""
+        out = [0, 0, 0]
+        for kind, arrays in zip(self._kinds or (), self._kv):
+            which = 2 if isinstance(kind, _cache.StateKind) \
+                else 0 if kind.window is None else 1
+            out[which] += _cache.cache_nbytes(arrays)
+        return tuple(out)
+
+    def kv_rows_read(self):
+        """``(full-length layers, window layers)``: the ring rows a
+        decode step at the host's copy of ``pos`` has to read, summed
+        over slots and layers: ``min(pos + 1, ring)`` a slot and layer,
+        the row the step writes included. A vacant slot counts with the
+        position it was left at: the step computes it too."""
+        out = [0, 0]
+        with self._key_lock:
+            pos = self._pos_host.copy()
+        for kind in self._kinds or ():
+            if isinstance(kind, _cache.KVKind):
+                out[kind.window is not None] += int(np.minimum(
+                    pos + 1, kind.ring(self.store_len)).sum())
+        return tuple(out)
 
     # Each ring program's (label, jitted, make_args): what its entry
     # point hands to _dispatch, and warm-up to _precompile. make_args
@@ -1836,6 +1883,8 @@ class GenerationEngine:
         nxt = self._fetched("generation::decode", t0, nxt, np.asarray)
         if self._kinds is not None:
             self._sample_stats(stats)
+            with self._key_lock:
+                self._pos_host += 1
         return nxt
 
     def spec_step(self, tokens, temps, busy=None):

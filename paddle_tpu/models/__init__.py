@@ -47,6 +47,10 @@ from .solar_open2 import (  # noqa: F401
     HybridMoEConfig,
     HybridMoEForCausalLM,
 )
+from .exaone_moe import (  # noqa: F401
+    ExaoneMoEConfig,
+    ExaoneMoEForCausalLM,
+)
 from .se_resnext import (  # noqa: F401
     SEResNeXt,
     se_resnext50_32x4d,

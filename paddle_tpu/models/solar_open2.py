@@ -5,7 +5,8 @@ The architecture of the ``solar_open2`` family as its public
 ``config.json`` describes it: pre-norm residual blocks with RMSNorm and
 no biases; layers listed in ``gqa_layers`` are softmax grouped-query
 attention without any position signal and with an elementwise sigmoid
-output gate, the layers between them :class:`nn.GatedDeltaAttention`;
+output gate (:class:`nn.gqa.CachedGQAttention`, ``gated``), the layers
+between them :class:`nn.GatedDeltaAttention`;
 every layer's feed-forward is :class:`parallel.moe.RoutedExperts`
 (sigmoid router, top-k renormalised, one shared expert); a final
 RMSNorm and an untied head. :class:`HybridMoEConfig` takes the published
@@ -31,21 +32,17 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 
-import jax
 import jax.numpy as jnp
 
 from ..framework.tensor import Parameter, Tensor
 from ..generation import cache as _cache
 from ..nn.layer_base import Layer
 from ..nn.layers import LayerList
+from ..nn.gqa import CachedGQAttention, rms_norm as _rms_norm
 from ..nn.linear_attention import GatedDeltaAttention, normal_or_zeros
-from ..nn.transformer import StaticCache, _write_rows, update_slice_in_range
 from ..parallel.moe import RoutedExperts
 
 __all__ = ["HybridMoEConfig", "HybridMoEForCausalLM"]
-
-_NEG_INF = -1e9
-_PREFILL_BLOCK = 512
 
 
 @dataclass
@@ -85,88 +82,6 @@ class HybridMoEConfig:
     vocab_held: int | None = None      # rows 0 .. vocab_held-1; None: all
 
 
-def _rms_norm(x, weight, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
-    return (y * weight.astype(jnp.float32)).astype(x.dtype)
-
-
-class GatedGQAttention(Layer):
-    """Softmax grouped-query attention without positions, the output
-    gated elementwise by ``sigmoid(x Wg)``."""
-
-    def __init__(self, hidden_size, num_heads, num_kv_heads, head_dim,
-                 gated=True, initializer_range=0.02, dtype="float32"):
-        super().__init__()
-        self.num_heads, self.num_kv_heads = int(num_heads), int(num_kv_heads)
-        self.head_dim, self.gated = int(head_dim), bool(gated)
-        h, d = int(hidden_size), self.num_heads * self.head_dim
-        kvd = self.num_kv_heads * self.head_dim
-        shapes = {"wq": (h, d), "wk": (h, kvd), "wv": (h, kvd), "wo": (d, h)}
-        if self.gated:
-            shapes["wg"] = (h, d)
-        for name, shape in shapes.items():
-            setattr(self, name, Parameter.from_array(
-                normal_or_zeros(shape, initializer_range, dtype), name=name))
-
-    def _attend(self, q, k, v, bias):
-        """``q [B, Hkv, G, Tq, D]`` against ``k``/``v [B, Hkv, Tk, D]``
-        under the additive ``bias`` (broadcast to ``[B, Hkv, G, Tq,
-        Tk]``): softmax in float32."""
-        s = jnp.einsum("bhgqd,bhkd->bhgqk", q, k,
-                       preferred_element_type=jnp.float32)
-        p = jax.nn.softmax(s * self.head_dim ** -0.5 + bias, axis=-1)
-        return jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v)
-
-    def forward(self, x, cache=None, mask=None):
-        """``x [B, T, hidden]`` (an array); ``mask``: see the module's
-        docstring. Returns ``y`` or ``(y, new_cache)``."""
-        b, t, _ = x.shape
-        hq, hkv, d = self.num_heads, self.num_kv_heads, self.head_dim
-        g = hq // hkv
-        q = jnp.matmul(x, self.wq._array).reshape(b, t, hkv, g, d) \
-            .transpose(0, 2, 3, 1, 4)                    # [B, Hkv, G, T, D]
-        k, v = (jnp.matmul(x, w._array).reshape(b, t, hkv, d)
-                .transpose(0, 2, 1, 3) for w in (self.wk, self.wv))
-        if cache is not None and t == 1:
-            # decode: write this token's row into the ring, attend it
-            kc, vc, pos = cache
-            idx = jnp.mod(pos, kc.shape[2])
-            kc = _write_rows(kc, k.astype(kc.dtype), idx)
-            vc = _write_rows(vc, v.astype(vc.dtype), idx)
-            cache = StaticCache(kc, vc, pos)
-            o = self._attend(q, kc, vc, mask[:, :, None])
-        else:
-            # a whole sequence from position 0, by query blocks: block i
-            # sees keys 0 .. its own end, so no score tensor is larger
-            # than [heads, block, T] and half of them are never formed
-            pad = 0.0 if mask is None else mask[:, :, None]  # [B,1,1,1,T]
-            blocks = []
-            for lo in range(0, t, _PREFILL_BLOCK):
-                hi = min(lo + _PREFILL_BLOCK, t)
-                causal = jnp.where(
-                    jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :],
-                    0.0, _NEG_INF)
-                bias = causal + (pad[..., :hi] if mask is not None else 0.0)
-                blocks.append(self._attend(
-                    q[..., lo:hi, :], k[:, :, :hi], v[:, :, :hi], bias))
-            o = jnp.concatenate(blocks, axis=3)
-            if cache is not None:
-                kc, vc, pos = cache
-                zero = jnp.zeros((), jnp.int32)
-                kc, vc = (update_slice_in_range(
-                    c, n.astype(c.dtype), zero, zero, zero, zero)
-                    for c, n in ((kc, k), (vc, v)))
-                cache = StaticCache(kc, vc, pos)
-        o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, hq * d)
-        if self.gated:
-            o = (o.astype(jnp.float32) * jax.nn.sigmoid(jnp.matmul(
-                x, self.wg._array, preferred_element_type=jnp.float32))
-                 ).astype(x.dtype)
-        y = jnp.matmul(o, self.wo._array)
-        return y if cache is None else (y, cache)
-
-
 class HybridDecoderLayer(Layer):
     def __init__(self, cfg: HybridMoEConfig, index: int):
         super().__init__()
@@ -175,7 +90,7 @@ class HybridDecoderLayer(Layer):
         self.eps = cfg.rms_norm_eps
         self.is_gqa = index in tuple(cfg.gqa_layers)
         if self.is_gqa:
-            self.mixer = GatedGQAttention(
+            self.mixer = CachedGQAttention(
                 cfg.hidden_size, cfg.num_attention_heads,
                 cfg.num_key_value_heads, cfg.head_dim,
                 gated=cfg.use_gqa_gate, initializer_range=std, dtype=dtype)

@@ -20,7 +20,7 @@ def test_every_leg_passes_at_the_tiny_preset():
     legs = chip_smoke.run_legs(chip_smoke.TINY)
     # the suite's 8 virtual devices bring the four-device leg in as well
     assert list(legs) == ["kernels", "bert", "resnet", "gpt", "hybrid",
-                          "bert4"]
+                          "window", "bert4"]
     json.dumps(legs)  # what main prints per leg
     assert legs["kernels"]["pallas"] is False
     for run in legs["bert"]["phases"] + [legs["bert4"]]:
@@ -30,6 +30,10 @@ def test_every_leg_passes_at_the_tiny_preset():
     assert legs["gpt"]["tokens_served"] >= legs["gpt"]["requests"]
     assert legs["hybrid"]["warmup_compiles"] == 3
     assert 0 < legs["hybrid"]["state_bytes"] < legs["hybrid"]["cache_bytes"]
+    assert legs["window"]["warmup_compiles"] == 4  # ladder of 3, + 1
+    assert legs["window"]["rings"] == [8, 32]
+    assert 0 < legs["window"]["window_ring_bytes"] \
+        < legs["window"]["full_ring_bytes"]
     assert len(legs["bert4"]["devices"]) == 4
 
 

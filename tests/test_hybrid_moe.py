@@ -188,7 +188,8 @@ def test_right_padding_does_not_advance_state_or_tail():
 
 
 def _share(full, first, count):
-    m = RoutedExperts(16, 24, 32, 8, held=(first, count), shared_width=24)
+    m = RoutedExperts(16, 24, 32, 8, held=(first, count), shared_width=24,
+                      routed_scaling_factor=full.routed_scaling_factor)
     m.router._array = full.router._array
     for n in ("w_gate", "w_up", "w_down"):
         getattr(m, n)._array = getattr(full, n)._array[first:first + count]
@@ -211,10 +212,14 @@ def _dense_experts(m, x):
     return y.reshape(x.shape), shared.reshape(x.shape)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("scaling", [
+    pytest.param(1.0, id="solar_open2"), pytest.param(2.5, id="exaone_moe")])
+def test_the_shares_add_up_to_the_uncut_layer(scaling):
     """Eight members hold 4 of 32 experts each: their parts of the
-    result, the shared expert counted once, sum to the whole layer."""
+    result, the shared expert counted once, sum to the whole layer, with
+    the router's weights scaled as either served family scales them."""
     full = RoutedExperts(16, 24, 32, 8, shared_width=24,
+                         routed_scaling_factor=scaling,
                          initializer_range=0.5)
     x = jax.random.normal(jax.random.PRNGKey(1), (3, 7, 16))
     whole = full(x)
