@@ -59,7 +59,10 @@ CHIP = {
         # must go to XLA's convolution, a 3x3
         "conv": ((128, 256, 56, 64, 1, 1, 0), (128, 1024, 14, 256, 1, 1, 0),
                  (128, 2048, 7, 512, 1, 1, 0), (128, 256, 14, 256, 3, 1, 1)),
+        # a matrix (the kernel) and a weight with a spatial extent, which
+        # fused_momentum_update must leave to XLA (PERF.md, PR 35)
         "momentum": (1000, 2048),
+        "momentum_spatial": (256, 256, 3, 3),
     },
     # AdamW's first steps overshoot on a fixed batch (11.18, 14.17, 12.76,
     # 11.55 on the chip); by the tenth the loss is under where it began
@@ -96,7 +99,8 @@ TINY = {
         "layernorm": (200, 128),
         "flash": (2, 2, 128, 64),
         "conv": ((2, 24, 16, 8, 1, 1, 0), (2, 3, 16, 8, 3, 2, 1)),
-        "momentum": (40, 50),
+        "momentum": (40, 128),
+        "momentum_spatial": (8, 4, 3, 3),
     },
     "bert": {"config": dict(vocab_size=1024, hidden_size=128,
                             num_hidden_layers=1, num_attention_heads=4,
@@ -358,17 +362,24 @@ def leg_kernels(preset) -> dict:
         compare(f"conv{ksz}x{ksz}_c{cin}", fused, ref,
                 (x, wt, gamma, beta))
 
-    # momentum update (f32 elementwise: near exact)
-    shape = p["momentum"]
-    prm, grd, vel = normal(shape), normal(shape), normal(shape)
+    # momentum update (f32 elementwise: near exact). The matrix takes the
+    # kernel; the 3x3 weight's [rows, 128] view would be re-tiled, so it
+    # is the reference itself and its program holds no Mosaic call
     lr = jnp.float32(0.1)
-    got = jax.jit(lambda *a: opu.fused_momentum_update(
-        *a, momentum=0.9, weight_decay=1e-4))(prm, grd, vel, lr)
-    want = jax.jit(lambda *a: opu._jnp_update(*a, 0.9, 1e-4, False))(
-        prm, grd, vel, lr)
-    errs["momentum"] = max(map(_rel_err, got, want))
-    _require(errs["momentum"] <= 1e-5,
-             f"kernel momentum: rel err {errs['momentum']}")
+    for name, kernel in (("momentum", True), ("momentum_spatial", False)):
+        prm, grd, vel = (normal(p[name]) for _ in range(3))
+        _require(opu._supported(prm, grd, vel) == kernel,
+                 f"{name} predicate")
+        fused = jax.jit(lambda *a: opu.fused_momentum_update(
+            *a, momentum=0.9, weight_decay=1e-4)).lower(
+                prm, grd, vel, lr).compile()
+        _check_kernels(_mosaic_calls(fused.as_text()),
+                       {"momentum_update"} if kernel else set(), name)
+        want = jax.jit(lambda *a: opu._jnp_update(*a, 0.9, 1e-4, False))(
+            prm, grd, vel, lr)
+        errs[name] = max(map(_rel_err, fused(prm, grd, vel, lr), want))
+        _require(errs[name] <= (1e-5 if kernel else 0.0),
+                 f"kernel {name}: rel err {errs[name]}")
     return {"pallas": tpu, "rel_err": errs}
 
 

@@ -577,14 +577,18 @@ define_flag("compiled_cache_capacity", 128,
             "(executor / train step / generation); evictions counted "
             "per store as <label>::cache_evict")
 
-# optimizer/__init__.py Momentum + ops/pallas/optimizer_update.py — fuse
-# the momentum + L2 weight-decay parameter update into one pallas kernel
-# on TPU (one HBM read/write pass over param+velocity instead of the
-# op-by-op chain). The jnp fallback used elsewhere computes the identical
-# expression, so the flag is numerically free to leave on.
+# optimizer/__init__.py Momentum + ops/pallas/optimizer_update.py — run
+# the momentum + L2 weight-decay parameter update as one pallas kernel on
+# TPU for parameters whose [rows, 128] view is free (vectors, matrices,
+# pointwise conv weights; a predicate on shape and dtype). Weights with a
+# spatial extent, and everything off the TPU, take the jnp fallback: the
+# identical expression, which XLA fuses into a compiled step in the
+# weight's own layout. So the flag is numerically free to leave on; off,
+# nothing goes to the kernel.
 define_flag("use_fused_optimizer", True,
             "fused pallas momentum/weight-decay parameter update on TPU "
-            "(jnp fallback elsewhere; identical math)")
+            "for parameters whose [rows, 128] view is free (jnp fallback "
+            "for the rest and elsewhere; identical math)")
 
 # nn/transformer.py + ops/pallas/layernorm_residual.py — fuse the
 # residual-add + LayerNorm pair (the post-norm transformer's hottest

@@ -1,35 +1,39 @@
 """Fused momentum / weight-decay optimizer update (TPU pallas kernel).
 
-The Momentum update is the textbook memory-bound chain: read param,
-grad, velocity; write param, velocity — with L2 weight decay it lowers
-to four elementwise HBM passes when left to op-by-op dispatch. On TPU
-the whole update runs as ONE pallas kernel: a single VMEM pass computes
+One VMEM pass over ``[rows, 128]`` tiles computes
 
     g' = grad + wd * param
     v' = mu * v + g'
     p' = p - lr * (g' + mu * v')      (nesterov)
         | p - lr * v'                  (plain)
 
-with ``input_output_aliases`` so param and velocity update in place
-(zero extra HBM allocation — the same discipline as the executor's
-buffer donation). Off-TPU (and for shapes/dtypes the kernel does not
-admit) a jnp fallback computes the IDENTICAL expression in the same
-order, so the fused path is bit-compatible everywhere and
-``FLAGS_use_fused_optimizer`` is numerically free to leave on.
+with ``input_output_aliases`` so param and velocity update in place, and
+``lr`` (a traced scalar: the schedule feeds a fresh value every step
+without recompiling) in SMEM as ``[1, 1]``. Padding rows compute garbage
+that is never written back, which is safe for an elementwise update.
 
-Design per /opt/skills/guides/pallas_guide.md: operands flatten to
-``[R, 128]`` lane-major tiles (sublane padding per dtype), the grid
-walks row blocks, and ``lr`` (a traced scalar — the LR schedule feeds a
-fresh value every step without recompiling) rides in SMEM as ``[1, 1]``.
-Padding rows compute garbage that is never written back (masked block
-writes), which is safe because the update is purely elementwise.
+The kernel takes an operand only when its ``[rows, 128]`` view is free
+(``_flat_view_is_dense``: vectors, matrices, pointwise ``[O, I, 1, 1]``
+weights). The chip keeps an ``[O, I, 3, 3]`` weight with ``O`` and ``I``
+minor, and flattening it row-major first pads each 3 x 3 plane to a whole
+tile: 57 times the bytes, three times in and twice out (PERF.md, PR 35).
+Such operands, other dtypes and everything off the TPU take
+``_jnp_update``, the IDENTICAL expression in the same order, which XLA
+fuses into the step in the layout the weight has; so the two paths are
+bit-compatible and ``FLAGS_use_fused_optimizer`` is numerically free to
+leave on. Profiler counters ``optimizer::momentum_kernel`` /
+``optimizer::momentum_xla`` count the operands sent each way (once an
+operand while a step is traced).
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 
 from ..._internal_tuning import register_schedule, resolve_schedule
+from ...profiler import bump_counter
 from ._platform import can_emit_mosaic, on_tpu_platform
 
 __all__ = ["fused_momentum_update"]
@@ -128,11 +132,26 @@ def _kernel(lr_ref, p_ref, g_ref, v_ref, p_out, v_out, *, mu, wd,
         p_out[:] = p - lr * v
 
 
+def _flat_view_is_dense(shape, dtype) -> bool:
+    """Whether the row-major ``[rows, 128]`` view of ``shape`` is a
+    bitcast or one dense move: the row-major tiled footprint of the
+    shape without its unit dimensions is at most twice its size. False
+    for ``[512, 512, 3, 3]`` (3 x 3 in a tile: 57-114 x) and the stem's
+    ``[64, 3, 7, 7]`` (21 x); true for ``[2048]``, ``[1000, 2048]`` and
+    any ``[O, I, 1, 1]`` from 64 x 64 up."""
+    dims = [d for d in shape if d != 1]
+    tile = (_SUBLANES[str(dtype)], _LANES)[-len(dims):]
+    tiled = dims[:-len(tile)] + [
+        -(-d // t) * t for d, t in zip(dims[-len(tile):], tile)]
+    return math.prod(tiled) <= 2 * math.prod(dims)
+
+
 def _supported(param, grad, velocity) -> bool:
     if str(param.dtype) not in _SUBLANES:
         return False
     return (param.shape == grad.shape == velocity.shape
-            and param.size >= _LANES)
+            and param.size >= _LANES
+            and _flat_view_is_dense(param.shape, param.dtype))
 
 
 def _pallas_update(param, grad, velocity, lr, mu, wd, nesterov,
@@ -191,9 +210,10 @@ def fused_momentum_update(param, grad, velocity, lr, momentum=0.9,
     """One fused momentum(+L2 decay) parameter update.
 
     Returns ``(new_param, new_velocity)``. Dispatches to the pallas
-    kernel on TPU for admitted shapes/dtypes; elsewhere the jnp fallback
-    computes the identical expression (same order, same dtypes). Safe
-    inside a jitted train step (``lr`` may be a traced scalar).
+    kernel on TPU for admitted shapes/dtypes (``_supported``); elsewhere
+    the jnp fallback computes the identical expression (same order, same
+    dtypes). Safe inside a jitted train step (``lr`` may be a traced
+    scalar).
     """
     param = jnp.asarray(param)
     grad = jnp.asarray(grad, param.dtype)
@@ -202,5 +222,7 @@ def fused_momentum_update(param, grad, velocity, lr, momentum=0.9,
     wd = float(weight_decay)
     nesterov = bool(use_nesterov)
     if can_emit_mosaic() and _supported(param, grad, velocity):
+        bump_counter("optimizer::momentum_kernel")
         return _pallas_update(param, grad, velocity, lr, mu, wd, nesterov)
+    bump_counter("optimizer::momentum_xla")
     return _jnp_update(param, grad, velocity, lr, mu, wd, nesterov)

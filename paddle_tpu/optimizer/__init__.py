@@ -230,11 +230,14 @@ class SGD(Optimizer):
 class Momentum(Optimizer):
     """operators/optimizers/momentum_op.cc (+ use_nesterov).
 
-    The update runs through the fused pallas momentum/weight-decay
-    kernel (``ops/pallas/optimizer_update.py``) behind
-    ``FLAGS_use_fused_optimizer``: one VMEM pass, param/velocity updated
-    in place on TPU; the jnp fallback computes the identical expression
-    (bit-compatible), so eager and compiled steps agree everywhere.
+    Behind ``FLAGS_use_fused_optimizer`` the update goes through
+    ``ops/pallas/optimizer_update.py``: on TPU a parameter whose
+    ``[rows, 128]`` view is free (vectors, matrices, pointwise conv
+    weights) takes the fused momentum/weight-decay kernel, one VMEM
+    pass with param and velocity updated in place; a weight with a
+    spatial extent, and every parameter elsewhere, takes the jnp
+    fallback, the identical expression (bit-compatible), so eager and
+    compiled steps agree everywhere.
     """
 
     def __init__(self, learning_rate=0.001, momentum=0.9, parameters=None,

@@ -23,6 +23,7 @@ def test_every_leg_passes_at_the_tiny_preset():
                           "window", "bert4"]
     json.dumps(legs)  # what main prints per leg
     assert legs["kernels"]["pallas"] is False
+    assert legs["kernels"]["rel_err"]["momentum_spatial"] == 0.0
     for run in legs["bert"]["phases"] + [legs["bert4"]]:
         assert run["losses"][-1] < run["losses"][0]
     assert [p["flash"] for p in legs["bert"]["phases"]] == [False, True]

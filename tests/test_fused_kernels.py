@@ -84,6 +84,68 @@ def test_momentum_kernel_interpret_parity(nesterov, wd):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("nesterov", [False, True])
+@pytest.mark.parametrize("wd", [0.0, 0.01])
+@pytest.mark.parametrize("shape", [(32, 16, 3, 3), (64, 3, 7, 7)])
+def test_momentum_refused_shape_is_the_jnp_update(shape, nesterov, wd,
+                                                  monkeypatch):
+    """With the platform gate open a weight with a spatial extent never
+    reaches the kernel (on this backend its call could not even
+    compile): the public function returns ``_jnp_update``'s result bit
+    for bit, and a vector of the same size is still admitted."""
+    monkeypatch.setattr(ou, "can_emit_mosaic", lambda: True)
+    rng = np.random.RandomState(0)
+    p, g, v = (jnp.asarray(rng.randn(*shape).astype("f4"))
+               for _ in range(3))
+    assert not ou._supported(p, g, v)
+    assert ou._supported(*(a.reshape(-1) for a in (p, g, v)))
+    ref = jax.jit(lambda p, g, v, lr: ou._jnp_update(
+        p, g, v, lr, 0.9, wd, nesterov))(p, g, v, 0.1)
+    out = jax.jit(lambda p, g, v, lr: ou.fused_momentum_update(
+        p, g, v, lr, momentum=0.9, weight_decay=wd,
+        use_nesterov=nesterov))(p, g, v, 0.1)
+    for a, b in zip(out, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_momentum_counters_say_which_leaves_took_the_kernel(monkeypatch):
+    """One trace of a ResNet-18 Momentum step with the gate open: every
+    convolution with a spatial extent (the stem and sixteen 3x3) and the
+    ten batch-norm leaves of width 64 (under 128 elements) go to XLA;
+    the thirty wider batch-norm leaves, the three pointwise projections
+    and the classifier's two leaves take the kernel. With the flag off
+    nothing is counted: the optimizer never asks."""
+    from paddle_tpu import profiler
+    from paddle_tpu.framework import jit as fjit
+    from paddle_tpu.models import resnet18
+
+    monkeypatch.setattr(ou, "can_emit_mosaic", lambda: True)
+
+    def traced():
+        paddle.seed(0)
+        m = resnet18(num_classes=1000)
+        step = fjit.train_step(
+            m, popt.Momentum(learning_rate=0.01, momentum=0.9,
+                             parameters=m.parameters()),
+            lambda mm, x, y: F.cross_entropy(mm(x), y).mean())
+        before = profiler.counters()
+        jax.eval_shape(step.pure, step.state,
+                       (jnp.zeros((2, 3, 32, 32), "float32"),
+                        jnp.zeros((2,), "int32")),
+                       jnp.float32(0.01), step._rng)
+        return {k[len("optimizer::momentum_"):]: v - before.get(k, 0)
+                for k, v in profiler.counters().items()
+                if k.startswith("optimizer::momentum_")
+                and v != before.get(k, 0)}
+
+    assert traced() == {"kernel": 35, "xla": 27}
+    set_flags({"use_fused_optimizer": False})
+    try:
+        assert traced() == {}
+    finally:
+        set_flags({"use_fused_optimizer": True})
+
+
 def _momentum_net_steps(steps=4, **mom_kw):
     paddle.seed(7)
     net = nn.Linear(16, 4)

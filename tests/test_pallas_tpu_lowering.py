@@ -29,14 +29,14 @@ def _sds(shape, dtype=F32):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
-def _lower_for_tpu(fn, *args):
+def _lower_for_tpu(fn, *args, mosaic=True):
     """Trace ``fn`` on abstract operands and lower it for TPU; returns
     the module text. The suite runs with x64 on and the chip with it
     off, so tracing happens with x64 off (Mosaic has no float64)."""
     with jax.enable_x64(False):
         text = jax.jit(fn).trace(*args).lower(
             lowering_platforms=("tpu",)).as_text()
-    assert "tpu_custom_call" in text
+    assert ("tpu_custom_call" in text) == mosaic
     return text
 
 
@@ -177,6 +177,75 @@ def test_momentum_update(shape):
                                                False),
         p, p, p, _sds((), F32))
     assert '"momentum_update"' in text
+
+
+@pytest.mark.parametrize("shape,kernel", [
+    ((2048,), True),             # a batch-norm leaf
+    ((1000, 2048), True),        # the classifier, either way round
+    ((2048, 1000), True),
+    ((2048, 512, 1, 1), True),   # a pointwise weight: [2048, 512]
+    ((512, 512, 3, 3), False),   # 3 x 3 in a tile: 57 x when flattened
+    ((64, 64, 3, 3), False),
+    ((64, 3, 7, 7), False),      # the stem: 21 x
+])
+def test_fused_momentum_update_takes_dense_views_only(shape, kernel,
+                                                      monkeypatch):
+    """With the platform gate open the public function emits the kernel
+    only for an operand whose [rows, 128] view costs no padded
+    re-tiling; a weight with a spatial extent lowers to plain
+    elementwise ops on the 4-D array (PERF.md, PR 35)."""
+    monkeypatch.setattr(opu, "can_emit_mosaic", lambda: True)
+    p = _sds(shape)
+    text = _lower_for_tpu(
+        lambda p, g, v, lr: opu.fused_momentum_update(
+            p, g, v, lr, momentum=0.9, weight_decay=1e-4),
+        p, p, p, _sds((), F32), mosaic=kernel)
+    assert _kernel_names(text) == (["momentum_update"] if kernel else [])
+    if not kernel:
+        assert "stablehlo.reshape" not in text
+
+
+def test_momentum_step_holds_the_kernel_for_dense_leaves_only(monkeypatch):
+    """A Momentum train step over conv 3x3 -> bn -> conv 1x1 -> linear,
+    lowered for TPU with the gate open: one kernel for each of the five
+    leaves whose flat view is dense (by its [rows, 128] operand), none
+    for the 3x3 weight."""
+    import paddle_tpu as paddle
+    import paddle_tpu.nn as nn
+    import paddle_tpu.nn.functional as F
+    import paddle_tpu.optimizer as popt
+    from paddle_tpu.framework import jit as fjit
+
+    class Net(nn.Layer):
+        def __init__(self):
+            super().__init__()
+            self.conv3 = nn.Conv2D(16, 128, 3, padding=1, bias_attr=False)
+            self.bn = nn.BatchNorm2D(128)
+            self.conv1 = nn.Conv2D(128, 64, 1, bias_attr=False)
+            self.fc = nn.Linear(64, 256)
+
+        def forward(self, x):
+            y = self.conv1(F.relu(self.bn(self.conv3(x))))
+            return self.fc(y.mean(axis=[2, 3]))
+
+    monkeypatch.setattr(opu, "can_emit_mosaic", lambda: True)
+    paddle.seed(0)
+    net = Net()
+    step = fjit.train_step(
+        net, popt.Momentum(learning_rate=0.1, momentum=0.9,
+                           parameters=net.parameters()),
+        lambda m, x, y: F.mse_loss(m(x), y).mean())
+    state = jax.tree_util.tree_map(lambda a: _sds(a.shape, a.dtype),
+                                   step.state)
+    text = _lower_for_tpu(step.pure, state,
+                          (_sds((2, 16, 8, 8)), _sds((2, 256))),
+                          _sds(()), _sds(step._rng.shape, step._rng.dtype))
+    assert _kernel_names(text) == ["momentum_update"] * 5
+    # bn gain and bias and the linear bias pad to one (8, 128) tile;
+    # conv1 is 64 x 128, the linear weight 64 x 256
+    rows = re.findall(r'kernel_name = "momentum_update".*?'
+                      r"-> \(tensor<(\d+)x128xf32>", text)
+    assert sorted(map(int, rows)) == [8, 8, 8, 64, 128]
 
 
 def test_gate_closes_under_a_multi_device_mesh(monkeypatch):
