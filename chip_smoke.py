@@ -23,6 +23,9 @@ Legs, run in sequence by one process that holds the chip throughout:
   128 routed experts held, bfloat16) at its published widths and two
   layers through the same server: rings of two lengths in one cache,
   prompts and outputs that cross the window's wrap.
+- ``latent``   one latent attention (MLA) at longcat-flash-omni's
+  published widths: the absorbed decode step against the expanded
+  attention on the same weights and the same latent ring.
 - ``bert4``    the BERT trainer's first phase on a dp=2 x tp=2 mesh,
   when the process sees four or more devices.
 
@@ -92,6 +95,14 @@ CHIP = {
                        prefill_buckets=(64, 128, 256),
                        kv_cache_dtype="bfloat16"),
         "prompt_lens": (5, 120, 200, 48), "max_new_tokens": 16},
+    # one latent attention at the published widths of longcat-flash-omni:
+    # 600 rows prefilled (expanded), then steps over a ring of 1,024 rows
+    # in two key chunks (absorbed)
+    "latent": {"layer": dict(
+        hidden_size=6144, num_heads=64, q_rank=1536, kv_rank=512,
+        nope_dim=128, rope_dim=64, v_dim=128, rope_theta=1e7, scale_q=True,
+        scale_kv=True, prefill_block=256, key_chunk=512, dtype="bfloat16"),
+        "slots": 4, "ring": 1024, "rows": 600, "steps": 3, "tol": 3e-2},
 }
 
 TINY = {
@@ -134,6 +145,11 @@ TINY = {
         "engine": dict(slots=2, cache_len=32, prefill_buckets=(4, 8, 16),
                        kv_cache_dtype="float32"),
         "prompt_lens": (1, 6, 13, 5), "max_new_tokens": 6},
+    "latent": {"layer": dict(
+        hidden_size=32, num_heads=4, q_rank=16, kv_rank=12, nope_dim=8,
+        rope_dim=4, v_dim=6, rope_theta=1e4, scale_q=True, scale_kv=True,
+        prefill_block=4, key_chunk=8, dtype="float32"),
+        "slots": 2, "ring": 16, "rows": 11, "steps": 3, "tol": 1e-4},
 }
 
 # Mosaic calls a compiled step must contain on one chip. Under a mesh the
@@ -670,11 +686,62 @@ def leg_window(preset) -> dict:
              "no prompt wraps the window")
     out = _serve_leg(engine, _prompts(p["prompt_lens"], cfg.vocab_held),
                      p["max_new_tokens"])
-    full, window, _ = engine.cache_bytes_by_kind()
+    full, window = engine.cache_bytes_by_kind()[:2]
     return dict({"layers": cfg.num_hidden_layers, "hidden": cfg.hidden_size,
                  "experts_held": list(cfg.experts_held), "rings": rings,
                  "full_ring_bytes": full, "window_ring_bytes": window},
                 **out)
+
+
+def leg_latent(preset) -> dict:
+    """One latent attention (nn/mla.py), its two paths against each
+    other on the same weights and the same ring: rows prefilled by the
+    expanded path, then absorbed decode steps, each compared with the
+    expanded attention's row at that position recomputed from the
+    latent rows (the largest difference over the largest value)."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.generation import cache as gcache
+    from paddle_tpu.nn.mla import CachedLatentAttention
+
+    p = preset["latent"]
+    paddle.seed(23)
+    layer = CachedLatentAttention(initializer_range=0.02, **p["layer"])
+    b, ring, n, steps = p["slots"], p["ring"], p["rows"], p["steps"]
+    dtype, hidden = p["layer"]["dtype"], p["layer"]["hidden_size"]
+    x = jax.random.normal(jax.random.PRNGKey(5), (b, n + steps, hidden),
+                          jnp.float32).astype(dtype)
+    pos = jnp.broadcast_to(jnp.arange(n + steps)[None], (b, n + steps))
+    kind = gcache.latent(layer.rank, layer.rope)
+
+    @jax.jit
+    def both(x):
+        cache = kind.wrap(kind.arrays(b, ring, dtype),
+                          jnp.zeros((b,), jnp.int32))
+        _, cache = layer(x[:, :n], cache=cache, positions=pos[:, :n])
+        q_nope, q_rot, row = layer._query_and_row(x, pos)
+        worst = jnp.zeros((), jnp.float32)
+        for i in range(n, n + steps):
+            at = jnp.full((b,), i, jnp.int32)
+            got, cache = layer(x[:, i:i + 1], positions=at[:, None],
+                               cache=kind.wrap((cache.c,), at),
+                               mask=gcache.decode_mask(at, ring))
+            want = jnp.matmul(layer.expanded(
+                q_nope[:, :i + 1], q_rot[:, :i + 1], row[:, :i + 1],
+                None)[:, -1], layer.wo._array).astype(jnp.float32)
+            worst = jnp.maximum(worst, jnp.abs(
+                got[:, 0].astype(jnp.float32) - want).max()
+                / jnp.abs(want).max())
+        return worst
+
+    err = float(both(x))
+    _require(err <= p["tol"], f"absorbed latent attention is {err:.3g} of "
+             f"the expanded one's largest value off it (limit {p['tol']})")
+    return {"heads": layer.num_heads, "row": layer.rank + layer.rope,
+            "ring": ring, "rows": n, "steps": steps,
+            "absorbed_vs_expanded": err}
 
 
 # -- driver -------------------------------------------------------------------
@@ -688,7 +755,8 @@ def run_legs(preset) -> dict:
     legs = {}
     for name, leg in (("kernels", leg_kernels), ("bert", leg_bert),
                       ("resnet", leg_resnet), ("gpt", leg_gpt),
-                      ("hybrid", leg_hybrid), ("window", leg_window)):
+                      ("hybrid", leg_hybrid), ("window", leg_window),
+                      ("latent", leg_latent)):
         legs[name] = leg(preset)
         print(f"leg {name} on 1 device: {json.dumps(legs[name])}",
               flush=True)

@@ -46,8 +46,9 @@ import jax
 import jax.numpy as jnp
 
 from ..errors import UnavailableError
-from ..nn.transformer import (QuantizedStaticCache, RecurrentCache,
-                              StaticCache, update_slice_in_range)
+from ..nn.transformer import (LatentCache, QuantizedStaticCache,
+                              RecurrentCache, StaticCache,
+                              update_slice_in_range)
 
 __all__ = [
     "CacheLostError",
@@ -56,7 +57,8 @@ __all__ = [
     "fresh_layer_caches", "cache_nbytes",
     "kv_bytes_per_token", "decode_mask", "prefill_mask", "verify_mask",
     "pad_slot_arrays",
-    "kv", "state", "KVKind", "StateKind", "is_layer_kinds",
+    "kv", "state", "latent", "KVKind", "StateKind", "LatentKind",
+    "is_layer_kinds",
     "init_kinds_cache", "kinds_layer_caches", "unzip_kinds_caches",
     "kinds_slot_nbytes", "kinds_bytes_per_token", "kinds_ring_lengths",
     "kinds_decode_mask",
@@ -186,11 +188,14 @@ def kv_bytes_per_token(num_layers, num_heads, head_dim,
 # :func:`insert_slot_kv` / :func:`insert_slot_planes` write a slot into
 # it as they stand (their "planes" are this form's layers). A kind says
 # what its layer keeps (``arrays``), which per-layer cache the model's
-# forward is handed (``wrap``), and what a slot costs (``slot_nbytes``).
-# The K/V rings of one cache may differ in length (a layer that attends
-# a window keeps the window's rows): every ring is written at ``pos mod
+# forward is handed (``wrap``), how many rows its ring has (``ring``;
+# ``None``: it keeps no rows) and what a slot costs (``slot_nbytes``).
+# The rings of one cache may differ in length (a layer that attends a
+# window keeps the window's rows): every ring is written at ``pos mod
 # its own length`` and read under the decode mask of that length
-# (:func:`kinds_decode_mask`), all from the one ``pos``.
+# (:func:`kinds_decode_mask`), all from the one ``pos``. A model may
+# list more kinds than it has layers (two attentions a layer: two
+# rings), in the order its forward consumes them.
 
 
 class KVKind(NamedTuple):
@@ -239,6 +244,9 @@ class StateKind(NamedTuple):
         return tuple(jnp.zeros((int(batch),) + tuple(s), d)
                      for s, d in zip(self.shapes, self.dtypes))
 
+    def ring(self, store):
+        return None
+
     def wrap(self, arrays, pos):
         return RecurrentCache(*arrays, pos)
 
@@ -248,6 +256,37 @@ class StateKind(NamedTuple):
     def slot_nbytes(self, store, dtype):
         return sum(int(np.prod(s)) * jnp.dtype(d).itemsize
                    for s, d in zip(self.shapes, self.dtypes))
+
+
+class LatentKind(NamedTuple):
+    """A latent-attention layer: ONE ``[B, ring, rank + rope]`` plane
+    (:class:`nn.LatentCache`) and no head axis: a row is the token's
+    normalised latent, which every head's key and value are expanded
+    from (or which the absorbed query attends as it lies), then the
+    rotated key channels all heads share. The ring is the engine's
+    ``store`` rows long."""
+
+    rank: int
+    rope: int
+
+    def ring(self, store):
+        return int(store)
+
+    def arrays(self, batch, store, dtype):
+        return (jnp.zeros((int(batch), int(store), self.rank + self.rope),
+                          dtype),)
+
+    def wrap(self, arrays, pos):
+        return LatentCache(*arrays, pos)
+
+    def row_nbytes(self, dtype):
+        return (self.rank + self.rope) * jnp.dtype(dtype).itemsize
+
+    def bytes_per_token(self, dtype):
+        return self.row_nbytes(dtype)
+
+    def slot_nbytes(self, store, dtype):
+        return int(store) * self.row_nbytes(dtype)
 
 
 def kv(heads, head_dim, window=None):
@@ -263,11 +302,17 @@ def state(shapes, dtypes):
                      tuple(str(d) for d in dtypes))
 
 
+def latent(rank, rope):
+    """The kind of a layer that keeps one latent row a token: ``rank``
+    latent channels and ``rope`` rotated key channels, no heads."""
+    return LatentKind(int(rank), int(rope))
+
+
 def is_layer_kinds(spec):
     """Is this ``cache_spec()`` a per-layer list of kinds (and not the
     ``(layers, heads, head_dim)`` of a model whose layers are alike)?"""
-    return all(isinstance(k, (KVKind, StateKind)) for k in spec) \
-        and len(spec) > 0
+    return all(isinstance(k, (KVKind, StateKind, LatentKind))
+               for k in spec) and len(spec) > 0
 
 
 def init_kinds_cache(kinds, batch, store, dtype="float32"):
@@ -290,29 +335,31 @@ def unzip_kinds_caches(caches):
 
 def kinds_bytes_per_token(kinds, dtype="float32") -> int:
     """Cache bytes one more token costs a slot: the full-length K/V
-    layers' rows; a window or state layer adds nothing."""
+    layers' rows and the latent layers'; a window or state layer adds
+    nothing."""
     return sum(k.bytes_per_token(dtype) for k in kinds)
 
 
 def kinds_slot_nbytes(kinds, store, dtype="float32") -> int:
-    """Cache bytes one slot costs: each K/V layer's ring at its own
-    length (``store`` rows, or its window) and a constant in every state
-    layer (``pos`` aside)."""
+    """Cache bytes one slot costs: each K/V or latent layer's ring at
+    its own length (``store`` rows, or its window) and a constant in
+    every state layer (``pos`` aside)."""
     return sum(k.slot_nbytes(store, dtype) for k in kinds)
 
 
 def kinds_ring_lengths(kinds, store):
-    """The distinct ring lengths of the K/V layers, longest first."""
-    return sorted({k.ring(store) for k in kinds if isinstance(k, KVKind)},
-                  reverse=True)
+    """The distinct ring lengths of the layers that keep rows (K/V or
+    latent), longest first."""
+    return sorted({k.ring(store) for k in kinds} - {None}, reverse=True)
 
 
 def kinds_decode_mask(kinds, pos, store, window=None):
     """The decode step's mask for a per-layer list of kinds. Where every
-    K/V ring is ``store`` rows long, :func:`decode_mask` of it, as a
+    K/V or latent ring is ``store`` rows long, :func:`decode_mask` of it, as a
     model whose rings are alike takes it. Where the rings are of several
     lengths, ``{ring length: mask}``, each asked for once: a layer takes
-    the mask of the ring it was handed (``cache.k.shape[2]``)."""
+    the mask of the ring it was handed (``cache.k.shape[2]``; a latent
+    ring's ``cache.c.shape[1]``)."""
     lengths = kinds_ring_lengths(kinds, store)
     if lengths in ([], [int(store)]):
         return decode_mask(pos, store, window=window)

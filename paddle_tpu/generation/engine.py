@@ -1260,7 +1260,8 @@ class GenerationEngine:
         program returned and put them on its timeline as counter
         samples: ``moe::expert_load`` (per held expert, prompt and
         decode alike), and for a decode step ``moe::pairs_here`` and
-        ``moe::experts_hit`` (one value an expert layer) with
+        ``moe::experts_hit`` (one value an expert layer; where the model
+        has zero-compute experts, ``moe::zero_pairs`` beside them) with
         ``generation::state_bytes`` (what the state layers' leaves
         hold, of :meth:`cache_nbytes`) and ``generation::kv_rows_read``
         (:meth:`kv_rows_read`). Off, the arrays are dropped where they
@@ -1273,6 +1274,9 @@ class GenerationEngine:
             if not prefill:
                 _record_counter("moe::pairs_here", stats["pairs"].tolist())
                 _record_counter("moe::experts_hit", stats["hit"].tolist())
+                if "zero_pairs" in stats:
+                    _record_counter("moe::zero_pairs",
+                                    stats["zero_pairs"].tolist())
         if not prefill:
             _record_counter("generation::state_bytes", self.state_nbytes())
             _record_counter("generation::kv_rows_read",
@@ -1286,31 +1290,42 @@ class GenerationEngine:
                    for kind, arrays in zip(self._kinds or (), self._kv)
                    if isinstance(kind, _cache.StateKind))
 
+    @staticmethod
+    def _kind_place(kind):
+        """Where a kind counts in :meth:`cache_bytes_by_kind` (and, less
+        the state's place, in :meth:`kv_rows_read`): 0 a full-length K/V
+        ring, 1 a window ring, 2 a state, 3 a latent ring."""
+        if isinstance(kind, _cache.StateKind):
+            return 2
+        if isinstance(kind, _cache.LatentKind):
+            return 3
+        return 0 if kind.window is None else 1
+
     def cache_bytes_by_kind(self):
-        """``(full-length rings, window rings, state)``: the device
-        bytes of :meth:`cache_nbytes` by what a layer keeps (``pos``
-        aside)."""
-        out = [0, 0, 0]
+        """``(full-length K/V rings, window rings, state, latent
+        rings)``: the device bytes of :meth:`cache_nbytes` by what a
+        layer keeps (``pos`` aside)."""
+        out = [0, 0, 0, 0]
         for kind, arrays in zip(self._kinds or (), self._kv):
-            which = 2 if isinstance(kind, _cache.StateKind) \
-                else 0 if kind.window is None else 1
-            out[which] += _cache.cache_nbytes(arrays)
+            out[self._kind_place(kind)] += _cache.cache_nbytes(arrays)
         return tuple(out)
 
     def kv_rows_read(self):
-        """``(full-length layers, window layers)``: the ring rows a
-        decode step at the host's copy of ``pos`` has to read, summed
-        over slots and layers: ``min(pos + 1, ring)`` a slot and layer,
-        the row the step writes included. A vacant slot counts with the
-        position it was left at: the step computes it too."""
-        out = [0, 0]
+        """``(full-length K/V layers, window layers, latent layers)``:
+        the ring rows a decode step at the host's copy of ``pos`` has to
+        read, summed over slots and layers: ``min(pos + 1, ring)`` a
+        slot and layer, the row the step writes included. A vacant slot
+        counts with the position it was left at: the step computes it
+        too."""
+        out = [0, 0, 0, 0]
         with self._key_lock:
             pos = self._pos_host.copy()
         for kind in self._kinds or ():
-            if isinstance(kind, _cache.KVKind):
-                out[kind.window is not None] += int(np.minimum(
-                    pos + 1, kind.ring(self.store_len)).sum())
-        return tuple(out)
+            ring = kind.ring(self.store_len)
+            if ring is not None:
+                out[self._kind_place(kind)] += int(
+                    np.minimum(pos + 1, ring).sum())
+        return out[0], out[1], out[3]
 
     # Each ring program's (label, jitted, make_args): what its entry
     # point hands to _dispatch, and warm-up to _precompile. make_args
@@ -1450,7 +1465,29 @@ class GenerationEngine:
         only, capped so the suffix keeps >= 1 real token and its bucket
         cannot wrap), allocate private pages for the rest, register the
         prompt's full pages in the index, and dispatch the unified
-        full/suffix prefill program for the suffix's ladder bucket."""
+        full/suffix prefill program for the suffix's ladder bucket. The
+        page bookkeeping is host work of the admission and lies inside
+        its ``generation::prefill`` span, so that the loop thread's
+        phases cover it (tests/test_host_timeline.py)."""
+        with RecordEvent("generation::prefill"):
+            slot, n, shared_len, suffix, padded = self._seat_paged(
+                slot, prompt, tenant)
+            temp = (self.default_temperature if temperature is None
+                    else float(temperature))
+            ctr = self._next_key_step()
+            t0 = time.perf_counter_ns()
+            out = self._dispatch(
+                "prefill", self._paged_prefill_jit, lambda: (
+                    self._state(), self._kv, np.int32(slot), padded[None],
+                    np.int32(shared_len), np.int32(len(suffix)),
+                    np.int32(n), np.float32(temp), np.int32(ctr)))
+        self._kv, tok = out
+        return self._fetched("generation::prefill", t0, tok, int)
+
+    def _seat_paged(self, slot, prompt, tenant):
+        """The host side of a paged admission: ``(slot, prompt length,
+        shared prefix length, suffix tokens, the suffix padded to its
+        bucket)`` with the slot's page-table row written."""
         slot = int(slot)
         n = self.validate(prompt, 1)
         ps = self.page_size
@@ -1492,18 +1529,7 @@ class GenerationEngine:
         padded = np.full(self.bucket_for(len(suffix)), self.pad_id,
                          np.int32)
         padded[:len(suffix)] = np.asarray(suffix, np.int32)
-        temp = (self.default_temperature if temperature is None
-                else float(temperature))
-        ctr = self._next_key_step()
-        t0 = time.perf_counter_ns()
-        with RecordEvent("generation::prefill"):
-            out = self._dispatch(
-                "prefill", self._paged_prefill_jit, lambda: (
-                    self._state(), self._kv, np.int32(slot), padded[None],
-                    np.int32(shared_len), np.int32(len(suffix)),
-                    np.int32(n), np.float32(temp), np.int32(ctr)))
-        self._kv, tok = out
-        return self._fetched("generation::prefill", t0, tok, int)
+        return slot, n, shared_len, suffix, padded
 
     def _note_prefix(self, tenant, prompt_tokens, shared_tokens,
                      matched_pages):

@@ -51,6 +51,10 @@ from .exaone_moe import (  # noqa: F401
     ExaoneMoEConfig,
     ExaoneMoEForCausalLM,
 )
+from .longcat_flash import (  # noqa: F401
+    LongcatFlashConfig,
+    LongcatFlashForCausalLM,
+)
 from .se_resnext import (  # noqa: F401
     SEResNeXt,
     se_resnext50_32x4d,

@@ -40,7 +40,8 @@ from .layer_base import Layer
 from .linear_attention import normal_or_zeros
 from .transformer import StaticCache, _write_rows, update_slice_in_range
 
-__all__ = ["CachedGQAttention", "rms_norm", "apply_rotary"]
+__all__ = ["CachedGQAttention", "rms_norm", "apply_rotary", "attend",
+           "attend_by_chunks", "attend_keys", "attend_causal_blocks"]
 
 _NEG_INF = -1e9
 
@@ -51,17 +52,96 @@ def rms_norm(x, weight, eps):
     return (y * weight.astype(jnp.float32)).astype(x.dtype)
 
 
-def apply_rotary(x, positions, theta):
+def apply_rotary(x, positions, theta, interleaved=False):
     """``x [B, T, ..., D]`` rotated by ``positions [B, T]``: channel
-    ``i < D/2`` pairs with ``i + D/2``; float32 angles and products."""
+    ``i < D/2`` pairs with ``i + D/2``, or, ``interleaved``, channel
+    ``2i`` with ``2i + 1``; the angle of pair ``i`` is ``position x
+    theta^(-2i/D)``; float32 angles and products. A layer that rotates a
+    part of its head hands that part in."""
     d = x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = positions.astype(jnp.float32)[..., None] * freq   # [B, T, D/2]
     ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if interleaved:
+        x32 = x.astype(jnp.float32)
+        x1, x2 = x32[..., 0::2], x32[..., 1::2]
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
+
+
+def attend(q, k, v, bias, scale):
+    """``q [B, Hkv, G, Tq, D]`` against ``k [B, Hkv, Tk, D]`` and ``v
+    [B, Hkv, Tk, Dv]`` under the additive ``bias`` (broadcast to ``[B,
+    Hkv, G, Tq, Tk]``): softmax in float32."""
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", q, k,
+                   preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(s * scale + bias, axis=-1)
+    return jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v)
+
+
+def attend_by_chunks(q, k, v, bias, scale, key_chunk):
+    """:func:`attend` with the keys ``key_chunk`` at a time: per chunk
+    the scores' row maxima ``m``, ``exp(s - m)`` summed, and its product
+    with ``v`` in float32; the chunks are then weighted by ``exp(m - max
+    m)``. A chunk a row sees nothing of weighs nothing (its maximum is
+    the mask's -1e9)."""
+    parts = []
+    for k0 in range(0, k.shape[2], key_chunk):
+        k1 = min(k0 + key_chunk, k.shape[2])
+        s = jnp.einsum("bhgqd,bhkd->bhgqk", q, k[:, :, k0:k1],
+                       preferred_element_type=jnp.float32)
+        s = s * scale + bias[..., k0:k1]
+        m = s.max(-1, keepdims=True)
+        p = jnp.exp(s - m)
+        parts.append((m, p.sum(-1, keepdims=True), jnp.einsum(
+            "bhgqk,bhkd->bhgqd", p.astype(v.dtype), v[:, :, k0:k1],
+            preferred_element_type=jnp.float32)))
+    top = parts[0][0]
+    for m, _, _ in parts[1:]:
+        top = jnp.maximum(top, m)
+    total = sum(jnp.exp(m - top) * l for m, l, _ in parts)
+    out = sum(jnp.exp(m - top) * o for m, _, o in parts)
+    return (out / total).astype(v.dtype)
+
+
+def attend_keys(q, k, v, bias, scale, key_chunk=None):
+    """:func:`attend`, by chunks where there are more than ``key_chunk``
+    keys."""
+    if key_chunk is None or k.shape[2] <= key_chunk:
+        return attend(q, k, v, bias, scale)
+    return attend_by_chunks(q, k, v, bias, scale, key_chunk)
+
+
+def attend_causal_blocks(q, k, v, mask, scale, block, key_chunk=None,
+                         window=None):
+    """A whole sequence from position 0, by blocks of ``block`` queries:
+    block i sees keys 0 .. its own end (with ``window``: from ``window -
+    1`` before its start), so no score tensor is larger than ``[heads,
+    block, T]`` and half of them are never formed. ``q [B, Hkv, G, T,
+    D]``, ``k [B, Hkv, T, D]``, ``v [B, Hkv, T, Dv]``; ``mask`` is the
+    additive key-padding mask ``[B, 1, 1, T]`` or None. Returns ``[B,
+    Hkv, G, T, Dv]``."""
+    t, w = q.shape[3], window
+    pad = 0.0 if mask is None else mask[:, :, None]      # [B,1,1,1,T]
+    blocks = []
+    for lo in range(0, t, block):
+        hi = min(lo + block, t)
+        k0 = 0 if w is None else max(lo - w + 1, 0)
+        rows = jnp.arange(lo, hi)[:, None]
+        cols = jnp.arange(k0, hi)[None, :]
+        keep = rows >= cols
+        if w is not None:
+            keep = keep & (rows - cols < w)
+        bias = jnp.where(keep, 0.0, _NEG_INF) \
+            + (pad[..., k0:hi] if mask is not None else 0.0)
+        blocks.append(attend_keys(
+            q[..., lo:hi, :], k[:, :, k0:hi], v[:, :, k0:hi], bias, scale,
+            key_chunk))
+    return jnp.concatenate(blocks, axis=3)
 
 
 class CachedGQAttention(Layer):
@@ -88,39 +168,6 @@ class CachedGQAttention(Layer):
             for name in ("q_norm", "k_norm"):
                 setattr(self, name, Parameter.from_array(
                     jnp.ones((self.head_dim,), dtype), name=name))
-
-    def _attend(self, q, k, v, bias):
-        """``q [B, Hkv, G, Tq, D]`` against ``k``/``v [B, Hkv, Tk, D]``
-        under the additive ``bias`` (broadcast to ``[B, Hkv, G, Tq,
-        Tk]``): softmax in float32."""
-        s = jnp.einsum("bhgqd,bhkd->bhgqk", q, k,
-                       preferred_element_type=jnp.float32)
-        p = jax.nn.softmax(s * self.head_dim ** -0.5 + bias, axis=-1)
-        return jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v)
-
-    def _attend_by_chunks(self, q, k, v, bias):
-        """:meth:`_attend` with the keys ``key_chunk`` at a time: per
-        chunk the scores' row maxima ``m``, ``exp(s - m)`` summed, and
-        its product with ``v`` in float32; the chunks are then weighted
-        by ``exp(m - max m)``. A chunk a row sees nothing of weighs
-        nothing (its maximum is the mask's -1e9)."""
-        parts = []
-        for k0 in range(0, k.shape[2], self.key_chunk):
-            k1 = min(k0 + self.key_chunk, k.shape[2])
-            s = jnp.einsum("bhgqd,bhkd->bhgqk", q, k[:, :, k0:k1],
-                           preferred_element_type=jnp.float32)
-            s = s * self.head_dim ** -0.5 + bias[..., k0:k1]
-            m = s.max(-1, keepdims=True)
-            p = jnp.exp(s - m)
-            parts.append((m, p.sum(-1, keepdims=True), jnp.einsum(
-                "bhgqk,bhkd->bhgqd", p.astype(v.dtype), v[:, :, k0:k1],
-                preferred_element_type=jnp.float32)))
-        top = parts[0][0]
-        for m, _, _ in parts[1:]:
-            top = jnp.maximum(top, m)
-        total = sum(jnp.exp(m - top) * l for m, l, _ in parts)
-        out = sum(jnp.exp(m - top) * o for m, _, o in parts)
-        return (out / total).astype(v.dtype)
 
     def _last_rows(self, x, length, ring):
         """Of ``x [B, H, T, D]`` the rows a ring of ``ring`` rows holds
@@ -161,29 +208,10 @@ class CachedGQAttention(Layer):
             cache = StaticCache(kc, vc, pos)
             if isinstance(mask, dict):
                 mask = mask[kc.shape[2]]
-            o = self._attend(q, kc, vc, mask[:, :, None])
+            o = attend(q, kc, vc, mask[:, :, None], d ** -0.5)
         else:
-            # a whole sequence from position 0, by query blocks: block i
-            # sees keys 0 .. its own end (a window layer: from window-1
-            # before its start), so no score tensor is larger than
-            # [heads, block, T] and half of them are never formed
-            pad = 0.0 if mask is None else mask[:, :, None]  # [B,1,1,1,T]
-            blocks = []
-            for lo in range(0, t, self.prefill_block):
-                hi = min(lo + self.prefill_block, t)
-                k0 = 0 if w is None else max(lo - w + 1, 0)
-                rows = jnp.arange(lo, hi)[:, None]
-                cols = jnp.arange(k0, hi)[None, :]
-                keep = rows >= cols
-                if w is not None:
-                    keep = keep & (rows - cols < w)
-                bias = jnp.where(keep, 0.0, _NEG_INF) \
-                    + (pad[..., k0:hi] if mask is not None else 0.0)
-                attend = self._attend if self.key_chunk is None \
-                    or hi - k0 <= self.key_chunk else self._attend_by_chunks
-                blocks.append(attend(
-                    q[..., lo:hi, :], k[:, :, k0:hi], v[:, :, k0:hi], bias))
-            o = jnp.concatenate(blocks, axis=3)
+            o = attend_causal_blocks(q, k, v, mask, d ** -0.5,
+                                     self.prefill_block, self.key_chunk, w)
             if cache is not None:
                 kc, vc, pos = cache
                 ring = kc.shape[2]

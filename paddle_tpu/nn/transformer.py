@@ -127,6 +127,18 @@ class RecurrentCache(NamedTuple):
     pos: Any
 
 
+class LatentCache(NamedTuple):
+    """Ring cache of ONE latent-attention layer: ``c`` is ``[B, C, rank
+    + rope]``, one row a token and no head axis: the normalised latent
+    every head's key and value are made from, then the rotated key
+    channels all heads share. Ring semantics, functional writes and the
+    caller-owned mask are :class:`StaticCache`'s; ``pos`` is the same
+    shared ``[B]`` vector."""
+
+    c: Any
+    pos: Any
+
+
 class QuantizedStaticCache(NamedTuple):
     """:class:`StaticCache` at int8 storage with per-head dynamic scales.
 

@@ -215,14 +215,28 @@ class RoutedExperts(Layer):
     serves a prompt of thousands of tokens and a decode step of a few
     dozen.
 
+    ``zero_experts``: the router scores that many outputs more, experts
+    ``num_experts ..`` that have no weights: a chosen one returns the
+    token itself, so its term is ``w_i x``. It is the token's own chip
+    that adds it (nothing is dispatched: the pairs sort with those of
+    other chips' experts and take no row of the grouped products), once
+    a group: the shares of a group add up to the whole layer with that
+    term counted once. ``selection_bias``: a leaf ``select_bias`` as wide
+    as the router is added to the scores for the CHOICE of the ``top_k``
+    and not to the weights, which stay the chosen experts' scores (the
+    load-balancing bias of the families that train without an auxiliary
+    loss; zeros until something sets it).
+
     ``forward`` takes and returns arrays ``[..., hidden]``; after it,
     :attr:`last_load` holds, per held expert, how many pairs it was given
-    (a traced value inside a trace: the caller's program may return it).
+    and :attr:`last_zero` how many pairs chose a zero expert (traced
+    values inside a trace: the caller's program may return them).
     """
 
     def __init__(self, hidden_size, expert_width, num_experts, top_k,
                  held=None, shared_width=0, score="sigmoid",
                  norm_topk_prob=True, routed_scaling_factor=1.0,
+                 zero_experts=0, selection_bias=False,
                  initializer_range=0.02, dtype="float32"):
         super().__init__()
         from ..errors import InvalidArgumentError
@@ -246,7 +260,14 @@ class RoutedExperts(Layer):
             setattr(self, name, Parameter.from_array(
                 normal_or_zeros(shape, std, dtype), name=name))
 
-        param("router", (h, self.num_experts))
+        self.zero_experts = int(zero_experts)
+        param("router", (h, self.num_experts + self.zero_experts))
+        if selection_bias:
+            self.select_bias = Parameter.from_array(
+                jnp.zeros((self.num_experts + self.zero_experts,),
+                          jnp.float32), name="select_bias")
+        else:
+            self.select_bias = None
         param("w_gate", (count, h, f))
         param("w_up", (count, h, f))
         param("w_down", (count, f, h))
@@ -255,7 +276,7 @@ class RoutedExperts(Layer):
             param("shared_gate", (h, self.shared_width))
             param("shared_up", (h, self.shared_width))
             param("shared_down", (self.shared_width, h))
-        self.last_load = None
+        self.last_load = self.last_zero = None
 
     def route(self, x):
         """``(idx [T, k], w [T, k])``: the chosen experts of each token
@@ -265,7 +286,13 @@ class RoutedExperts(Layer):
                             precision=jax.lax.Precision.HIGHEST)
         scores = (jax.nn.sigmoid(logits) if self.score == "sigmoid"
                   else jax.nn.softmax(logits, axis=-1))
-        w, idx = jax.lax.top_k(scores, self.top_k)
+        if self.select_bias is None:
+            w, idx = jax.lax.top_k(scores, self.top_k)
+        else:
+            _, idx = jax.lax.top_k(
+                scores + self.select_bias._array.astype(jnp.float32),
+                self.top_k)
+            w = jnp.take_along_axis(scores, idx, axis=-1)
         if self.norm_topk_prob:
             w = w / w.sum(-1, keepdims=True)
         return idx, w * self.routed_scaling_factor
@@ -304,6 +331,14 @@ class RoutedExperts(Layer):
                 counted = here & valid.reshape(-1)[:, None]
                 self.last_load = jnp.zeros((n + 1,), jnp.int32).at[
                     jnp.where(counted, local, n).reshape(-1)].add(1)[:n]
+            if self.zero_experts:
+                with jax.named_scope("moe_zero"):
+                    zero = idx >= self.num_experts
+                    y = y + jnp.where(zero, w, 0.0).sum(
+                        -1, keepdims=True) * x.astype(jnp.float32)
+                    if valid is not None:
+                        zero = zero & valid.reshape(-1)[:, None]
+                    self.last_zero = zero.sum().astype(jnp.int32)
             if self.shared_width:
                 hid = jax.nn.silu(jnp.matmul(
                     x, self.shared_gate._array,

@@ -20,7 +20,7 @@ def test_every_leg_passes_at_the_tiny_preset():
     legs = chip_smoke.run_legs(chip_smoke.TINY)
     # the suite's 8 virtual devices bring the four-device leg in as well
     assert list(legs) == ["kernels", "bert", "resnet", "gpt", "hybrid",
-                          "window", "bert4"]
+                          "window", "latent", "bert4"]
     json.dumps(legs)  # what main prints per leg
     assert legs["kernels"]["pallas"] is False
     assert legs["kernels"]["rel_err"]["momentum_spatial"] == 0.0
@@ -35,6 +35,8 @@ def test_every_leg_passes_at_the_tiny_preset():
     assert legs["window"]["rings"] == [8, 32]
     assert 0 < legs["window"]["window_ring_bytes"] \
         < legs["window"]["full_ring_bytes"]
+    assert legs["latent"]["row"] == 16 and legs["latent"]["ring"] == 16
+    assert 0 < legs["latent"]["absorbed_vs_expanded"] <= 1e-4
     assert len(legs["bert4"]["devices"]) == 4
 
 

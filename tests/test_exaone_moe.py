@@ -296,7 +296,8 @@ def test_capacity_accounting_sums_each_ring_at_its_own_length(model):
     assert eng.slot_nbytes() == full_slot + window_slot + 4
     assert eng.kv_bytes_per_token() == row
     assert eng.cache_nbytes() == 3 * eng.slot_nbytes()
-    assert eng.cache_bytes_by_kind() == (3 * full_slot, 3 * window_slot, 0)
+    assert eng.cache_bytes_by_kind() == (3 * full_slot, 3 * window_slot, 0,
+                                         0)
     assert eng.hbm_required_bytes() == eng.param_nbytes() \
         + eng.cache_nbytes()
     assert eng.hbm_required_bytes(slots=5) - eng.hbm_required_bytes() \
@@ -353,6 +354,6 @@ def test_cache_counters_are_sampled_only_while_the_profiler_is_on(model):
     # slot 0 vacant at positions 0 then 1, slot 1 at 20 then 21: the
     # full layer reads pos + 1 rows, each of four window layers 8 at most
     assert got["generation::kv_rows_read"] == [
-        [1 + 21, 4 * (1 + 8)], [2 + 22, 4 * (2 + 8)]]
+        [1 + 21, 4 * (1 + 8), 0], [2 + 22, 4 * (2 + 8), 0]]
     assert len(got["moe::experts_hit"][0]) == 4      # the sparse layers
     assert len(got["moe::expert_load"]) == 3          # prompt and two steps
