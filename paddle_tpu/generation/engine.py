@@ -84,8 +84,8 @@ from . import cache as _cache
 from . import paging as _paging
 from .sampling import sample_logits
 
-__all__ = ["GenerationEngine", "COMPILE_COUNTER", "CACHE_LOST_COUNTER",
-           "STATE_REBUILT_COUNTER"]
+__all__ = ["GenerationEngine", "DecodeStep", "COMPILE_COUNTER",
+           "CACHE_LOST_COUNTER", "STATE_REBUILT_COUNTER"]
 
 COMPILE_COUNTER = "generation::compile"
 # calls that failed after they had consumed the donated cache (_dispatch)
@@ -155,6 +155,18 @@ class _KeptState:
                 and arg.get("params") is tree:
             return self._signature
         return None
+
+
+class DecodeStep:
+    """One enqueued decode step (:meth:`GenerationEngine.enqueue_step`):
+    its ``[S]`` tokens and routing statistics, still on the device, and
+    the ring rows it read, counted on the host as it was enqueued (only
+    while the profiler is on)."""
+
+    __slots__ = ("tokens", "stats", "rows_read")
+
+    def __init__(self, tokens, stats, rows_read):
+        self.tokens, self.stats, self.rows_read = tokens, stats, rows_read
 
 
 class GenerationEngine:
@@ -743,18 +755,22 @@ class GenerationEngine:
         ``<phase>_fetch``: ``generation::prefill`` / ``::decode`` close
         when the program is enqueued, this one closes when its result
         has arrived, so a slow device reads slow here. ``t0_ns`` is when
-        the enqueue phase began; both phases' nanoseconds go to the
+        the enqueue phase began (``None`` where :meth:`enqueue_step` has
+        accounted for it already); both phases' nanoseconds go to the
         driver's :attr:`phase_split`, if it gave one."""
         t1 = time.perf_counter_ns()
         out = to_host(value)
         t2 = time.perf_counter_ns()
-        fetch = phase + "_fetch"
-        _add_span(fetch, t1, t2)
+        _add_span(phase + "_fetch", t1, t2)
+        if t0_ns is not None:
+            self._note_phase(phase, t1 - t0_ns)
+        self._note_phase(phase + "_fetch", t2 - t1)
+        return out
+
+    def _note_phase(self, name, ns):
         split = self.phase_split
         if split is not None:
-            split[phase] = split.get(phase, 0) + t1 - t0_ns
-            split[fetch] = split.get(fetch, 0) + t2 - t1
-        return out
+            split[name] = split.get(name, 0) + ns
 
     def extra_compiles(self) -> int:
         """Compiles since warmup — steady state must keep this at 0."""
@@ -1252,10 +1268,10 @@ class GenerationEngine:
         if self._kinds is not None:
             with self._key_lock:  # a step's += on another thread
                 self._pos_host[slot] = n
-            self._sample_stats(stats, prefill=True)
+            self._sample_stats(stats)
         return tok
 
-    def _sample_stats(self, stats, prefill=False):
+    def _sample_stats(self, stats, rows_read=None):
         """While the profiler is on, fetch the routing statistics a
         program returned and put them on its timeline as counter
         samples: ``moe::expert_load`` (per held expert, prompt and
@@ -1264,10 +1280,12 @@ class GenerationEngine:
         has zero-compute experts, ``moe::zero_pairs`` beside them) with
         ``generation::state_bytes`` (what the state layers' leaves
         hold, of :meth:`cache_nbytes`) and ``generation::kv_rows_read``
-        (:meth:`kv_rows_read`). Off, the arrays are dropped where they
+        (``rows_read``: :meth:`kv_rows_read` as the step was enqueued;
+        ``None`` for a prompt). Off, the arrays are dropped where they
         lie: no transfer, one boolean."""
         if not _profiler_enabled():
             return
+        prefill = rows_read is None
         if stats is not None:
             stats = jax.device_get(stats)
             _record_counter("moe::expert_load", stats["load"].tolist())
@@ -1279,8 +1297,7 @@ class GenerationEngine:
                                     stats["zero_pairs"].tolist())
         if not prefill:
             _record_counter("generation::state_bytes", self.state_nbytes())
-            _record_counter("generation::kv_rows_read",
-                            list(self.kv_rows_read()))
+            _record_counter("generation::kv_rows_read", list(rows_read))
 
     def state_nbytes(self) -> int:
         """Device bytes of the state layers' leaves (all slots): the
@@ -1356,10 +1373,11 @@ class GenerationEngine:
 
     def _decode_call(self, tokens, temps, ctr):
         jitted = self._paged_decode_jit if self.paged else self._decode_jit
+        tokens = (tokens.tokens if isinstance(tokens, DecodeStep)
+                  else np.asarray(tokens, np.int32))
         return "decode", jitted, lambda: (
-            self._state(), self._kv,
-            np.asarray(tokens, np.int32), np.asarray(temps, np.float32),
-            np.int32(ctr))
+            self._state(), self._kv, tokens,
+            np.asarray(temps, np.float32), np.int32(ctr))
 
     def _draft_call(self, toks):
         # the draft shares the target's position vector (reset())
@@ -1885,10 +1903,18 @@ class GenerationEngine:
                 self._kv, slot, padded, length)
         return int(first_token)
 
-    def step(self, tokens, temps) -> np.ndarray:
-        """Decode one token for every slot. ``tokens``/``temps`` are
-        host ``[S]`` arrays (vacant slots: anything — their output is
-        ignored and their cache entries are overwritten on admission)."""
+    def enqueue_step(self, tokens, temps) -> DecodeStep:
+        """The first half of :meth:`step`: enqueue one decode step for
+        every slot and return without waiting for it. ``tokens`` is the
+        host ``[S]`` int32 array, or the :class:`DecodeStep` before this
+        one, whose tokens are then taken where they lie on the device:
+        the same compiled program either way (one shape, one dtype, one
+        store key), no transfer, and no wait for the host to see them.
+        What the host keeps of a step is counted here, because counts
+        are not data: the sampling counter is taken, the host's copy of
+        ``pos`` advances. The returned handle keeps the step's tokens
+        (and its routing statistics) on the device until
+        :meth:`fetch_step`; a handle that is dropped costs nothing."""
         ctr = self._next_key_step()
         if self.paged:
             # CoW/first-visit page turns happen on the host BEFORE the
@@ -1898,20 +1924,56 @@ class GenerationEngine:
         t0 = time.perf_counter_ns()
         with RecordEvent("generation::decode"):
             out = self._dispatch(*self._decode_call(tokens, temps, ctr))
+        stats = rows_read = None
         if self._kinds is not None:
             self._kv, nxt, stats = out
+            if _profiler_enabled():
+                rows_read = self.kv_rows_read()  # before pos moves on
+            with self._key_lock:
+                self._pos_host += 1
         else:
             self._kv, nxt = out
         if self.paged:
             for s, live in enumerate(self._slot_live):
                 if live:
                     self._pos_host[s] += 1
-        nxt = self._fetched("generation::decode", t0, nxt, np.asarray)
+        self._note_phase("generation::decode", time.perf_counter_ns() - t0)
+        return DecodeStep(nxt, stats, rows_read)
+
+    def fetch_step(self, step) -> np.ndarray:
+        """The second half of :meth:`step`: the tokens of an enqueued
+        step on the host, ``[S]`` int32. The wait is the span
+        ``generation::decode_fetch``; the step's statistics are read
+        here too, while the profiler is on."""
+        nxt = self._fetched("generation::decode", None, step.tokens,
+                            np.asarray)
         if self._kinds is not None:
-            self._sample_stats(stats)
-            with self._key_lock:
-                self._pos_host += 1
+            self._sample_stats(step.stats, step.rows_read)
         return nxt
+
+    def step(self, tokens, temps) -> np.ndarray:
+        """Decode one token for every slot: :meth:`enqueue_step` and
+        :meth:`fetch_step` back to back. ``tokens``/``temps`` are
+        host ``[S]`` arrays (vacant slots: anything — their output is
+        ignored and their cache entries are overwritten on admission)."""
+        return self.fetch_step(self.enqueue_step(tokens, temps))
+
+    @property
+    def steps_ahead(self) -> int:
+        """How many decode steps a driver may enqueue beyond the one it
+        has not fetched yet: 1 where the next step's inputs are known
+        before the last step's tokens reach the host (ring layout, no
+        draft model: the tokens pass from step to step on the device),
+        else 0. A speculative round's accepted counts are data and
+        decide the next round's tokens; a paged step turns pages on the
+        host for the slots that are live, so a slot that ended must be
+        known before the next step is enqueued. 0 also for an engine
+        whose ``step`` is not this class's own (a subclass, a wrapper
+        that alters or times whole steps): such a ``step`` must go on
+        seeing every step, so its driver may not take the halves
+        apart."""
+        whole = getattr(self.step, "__func__", None) is _WHOLE_STEP
+        return int(whole and not self.paged and not self.speculative)
 
     def spec_step(self, tokens, temps, busy=None):
         """One speculative round for every slot: draft program (k
@@ -2039,3 +2101,7 @@ class GenerationEngine:
                         del active[slot]
                         self.release_slot(slot)
         return results
+
+
+# GenerationEngine.step as the class defines it (steps_ahead)
+_WHOLE_STEP = GenerationEngine.step

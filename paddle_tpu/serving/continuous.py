@@ -25,6 +25,14 @@ growth bumps ``serving/gen_unexpected_compiles`` + a flight event.
 Per-token streaming: pass ``on_token`` to :meth:`submit` and every
 sampled token is delivered as it is decoded (the HTTP ``/generate``
 endpoint's streaming mode rides this).
+
+The loop runs one decode step ahead of what it has delivered, where the
+engine allows it (``engine.steps_ahead``): step n+1 is enqueued from
+step n's tokens on the device before step n is fetched and delivered,
+so the host's work an iteration costs the device nothing. A request
+that ends on EOS at step n has a row in step n+1 already: computed in
+vain, never delivered. An admission drains the look-ahead (its first
+token is on the host): see :meth:`ContinuousBatcher._loop`.
 """
 from __future__ import annotations
 
@@ -116,8 +124,25 @@ class GenerationRequest:
         return self.tokens
 
 
+class _Flight:
+    """One decode step between its enqueue and its delivery: the
+    requests that sat in the slots when it was enqueued, when that was,
+    the engine's handle while the tokens are on the device (``step``),
+    and the tokens once fetched: ``rows [S, n]`` with, for a
+    speculative round, ``counts [S]`` of how many of a row count."""
+
+    __slots__ = ("seated", "t0_ns", "step", "rows", "counts")
+
+    def __init__(self, seated, t0_ns):
+        self.seated, self.t0_ns = seated, t0_ns
+        self.step = self.rows = self.counts = None
+
+
 class ContinuousBatcher:
-    """Slot scheduler + decode-loop worker over one GenerationEngine."""
+    """Slot scheduler + decode-loop worker over one GenerationEngine.
+    The loop keeps ``engine.steps_ahead`` decode steps enqueued beyond
+    the one it fetches (1 for a ring layout without a draft model, else
+    0): no flag, the engine's own properties decide."""
 
     def __init__(self, engine, queue_capacity=None, clock=time.monotonic,
                  kind="generate"):
@@ -150,6 +175,10 @@ class ContinuousBatcher:
         # the ns each phase took in this iteration
         self._t_ns = self._t_iter_ns = 0
         self._split = {}
+        # the decode step that is enqueued and not fetched (the loop
+        # thread's own), and when the last step's tokens were delivered
+        self._flight = None
+        self._t_landed_ns = 0
         # the engine owns the warmup-snapshot watch (armed by warmup());
         # the loop notes growth through it after every step
         self._watch = engine.watch
@@ -445,13 +474,17 @@ class ContinuousBatcher:
         move: admission happens between decode steps, never tearing the
         running batch down). Phases: ``serving::pick`` up to the call
         into the engine, the engine's own ``generation::prefill`` and
-        ``::prefill_fetch``, then ``serving::install``."""
+        ``::prefill_fetch``, then ``serving::install``. With a decode
+        step in flight the prefill is enqueued behind it. Returns
+        whether the engine was asked for an admission at all."""
         engine = self.engine
+        admitted = False
         while True:
             picked = self._pick()
             self._mark("serving::pick")
             if picked is None:
-                return
+                return admitted
+            admitted = True
             req, free, midbatch, asp = picked
             try:
                 with _tracing.use_span(asp):
@@ -527,7 +560,10 @@ class ContinuousBatcher:
     def _fail_live(self, e):
         """Fail every request that holds a slot and vacate the slots:
         a decode step that raised, or any call that lost the cache
-        (:class:`CacheLostError`), leaves none of them a context."""
+        (:class:`CacheLostError`), leaves none of them a context. A
+        step in flight is dropped unfetched: nobody is left to take
+        its tokens."""
+        self._flight = None
         busy = [s for s, r in enumerate(self._slots) if r is not None]
         for s in busy:
             req, self._slots[s] = self._slots[s], None
@@ -545,13 +581,17 @@ class ContinuousBatcher:
             "generation_step_error", slots=len(busy),
             error=f"{type(e).__name__}: {e}"[:300])
 
-    def _sample_counters(self, busy):
+    def _sample_counters(self, ahead):
         """Once an iteration, after admission and before the step: the
-        state the step runs with, as samples on the profiler's timeline.
+        state the step runs with, as samples on the profiler's timeline
+        (``serving::steps_ahead``: 1 when this iteration enqueues its
+        step before it fetches the one in flight, else 0).
         One boolean when the profiler is off: nothing is counted then."""
         if not _profiler.enabled():
             return
+        busy = [s for s, r in enumerate(self._slots) if r is not None]
         _profiler.record_counter("serving::slots_busy", len(busy))
+        _profiler.record_counter("serving::steps_ahead", int(ahead))
         _profiler.record_counter("serving::kv_live_tokens", sum(
             self._slots[s].prompt_len + len(self._slots[s].tokens)
             for s in busy))
@@ -582,6 +622,84 @@ class ContinuousBatcher:
                 **memory)
         split.clear()
 
+    def _seated(self, flight):
+        """``{slot: request}`` of the requests that take a token of the
+        NEXT step. With a step in flight its token is counted as if it
+        were delivered: a request that it brings to ``max_new_tokens``
+        is known to end there and is left out. One that it ends by EOS
+        is not known, and stays in: its row of the next step is
+        computed in vain and dropped (:meth:`_land`)."""
+        owed = {} if flight is None else flight.seated
+        return {s: r for s, r in enumerate(self._slots)
+                if r is not None
+                and len(r.tokens) + (owed.get(s) is r) < r.max_new_tokens}
+
+    def _launch(self, seated, after, depth):
+        """Enqueue one decode step for ``seated``: from the device
+        tokens of the flight ``after``, or from the host's ``_last``.
+        At depth 0 the engine runs the step whole (``step`` /
+        ``spec_step``) and the flight comes back with its tokens."""
+        engine = self.engine
+        flight = _Flight(seated, self._t_ns)
+        if depth:
+            flight.step = engine.enqueue_step(
+                self._last if after is None else after.step, self._temps)
+        elif engine.speculative:
+            # one draft+verify round: every busy slot emits 1..k+1
+            # tokens (the scheduler truncates at its own EOS/budget,
+            # exactly like the one-token path)
+            flight.rows, flight.counts = engine.spec_step(
+                self._last, self._temps, busy=list(seated))
+        else:
+            flight.rows = engine.step(self._last, self._temps)[:, None]
+        return flight
+
+    def _land(self, flight):
+        """Deliver a fetched step's tokens to the requests that sat in
+        its slots when it was enqueued, and complete what finished. A
+        slot that has changed hands since (its request ended on EOS one
+        step earlier, was failed, or the slot was let again) computed
+        its row in vain: the token is dropped, never delivered."""
+        engine = self.engine
+        now = self._t_ns
+        # per-token latency, per STREAM (what a client waits between
+        # tokens): from the last delivery, or from this step's enqueue
+        # where the loop had come to rest between the two
+        dt_ms = (now - max(flight.t0_ns, self._t_landed_ns)) / 1e6
+        self._t_landed_ns = now
+        if self._watch.armed:
+            self._watch.note(slots=len(flight.seated))
+        emitted = 0
+        for s, req in flight.seated.items():
+            if self._slots[s] is not req:
+                continue
+            if req.finished:  # stop(drain=False) race
+                self._slots[s] = None
+                engine.release_slot(s)
+                continue
+            reason = None
+            n = 1 if flight.counts is None else int(flight.counts[s])
+            for tok in flight.rows[s, :n]:
+                self._deliver(req, tok)
+                self._last[s] = tok
+                emitted += 1
+                reason = self._finished_reason(req)
+                if reason is not None:
+                    break
+            if reason is not None:
+                self._slots[s] = None
+                engine.release_slot(s)
+                self._complete(req, reason)
+        # a speculative round amortizes its two dispatches over the
+        # mean tokens each busy stream emitted
+        # kind-labeled only: one step serves slots of mixed tenants
+        h_token = self._h_token.labels(kind=self.kind)
+        if flight.counts is not None and emitted:
+            h_token.observe(dt_ms * len(flight.seated) / emitted)
+        else:
+            h_token.observe(dt_ms)
+        self._mark("serving::deliver")
+
     def _loop(self):
         # The loop thread's timeline is a PARTITION into sibling phases:
         # serving::pick, generation::prefill, ::prefill_fetch,
@@ -591,14 +709,32 @@ class ContinuousBatcher:
         # to the span that covers most of it, so a wrapper would take
         # every gap. Only generation::args, runtime::lookup and
         # runtime::launch nest, inside the enqueue spans.
+        #
+        # The loop runs ``engine.steps_ahead`` steps ahead of what it
+        # has delivered. At 1 an iteration enqueues step n+1 from step
+        # n's tokens on the device and THEN fetches and delivers step n
+        # (pick, decode, decode_fetch, deliver), so the device has its
+        # next program before the host has seen this one's tokens. An
+        # admission's first token is on the host, so it drains the
+        # look-ahead: the prefill is enqueued behind the step in
+        # flight, which is then fetched and delivered with nothing
+        # enqueued (pick, prefill, prefill_fetch, install, pick,
+        # decode_fetch, deliver); the next iteration enqueues from the
+        # host's tokens and fetches nothing (pick, decode), and the one
+        # after looks ahead again. At 0 an iteration is what it always
+        # was: the step it enqueues is the step it fetches.
         engine = self.engine
         engine.phase_split = self._split
         self._t_ns = self._t_iter_ns = time.perf_counter_ns()
         while True:
-            self._admit_ready()
-            busy = [s for s, r in enumerate(self._slots) if r is not None]
-            self._sample_counters(busy)
-            if not busy:
+            admitted = self._admit_ready()
+            depth = engine.steps_ahead
+            flight, self._flight = self._flight, None
+            seated = self._seated(flight)
+            ahead = bool(depth and flight is not None and not admitted
+                         and seated)
+            self._sample_counters(ahead)
+            if flight is None and not seated:
                 with self._lock:
                     if self._closed and not self._q:
                         break
@@ -607,63 +743,26 @@ class ContinuousBatcher:
                 self._mark("serving::idle_wait")
                 self._end_iteration()
                 continue
-            t0 = self._t_ns
+            nxt = None
             try:
-                if engine.speculative:
-                    # one draft+verify round: every busy slot emits
-                    # 1..k+1 tokens (the scheduler truncates at its own
-                    # EOS/budget, exactly like the one-token path)
-                    ts, counts = engine.spec_step(
-                        self._last, self._temps, busy=busy)
-                else:
-                    nxt = engine.step(self._last, self._temps)
+                if flight is None or ahead:
+                    nxt = self._launch(seated, flight, depth)
+                if flight is not None:
+                    flight.rows = engine.fetch_step(flight.step)[:, None]
             except Exception as e:  # noqa: BLE001 — fail THESE, keep serving
                 self._t_ns = time.perf_counter_ns()
                 self._fail_live(e)
                 self._mark("serving::deliver")
                 self._end_iteration()
                 continue
-            # the engine's spans cover its call; deliver begins here
+            # the engine's spans cover its calls; the next phase begins here
             self._t_ns = time.perf_counter_ns()
-            dt_ms = (self._t_ns - t0) / 1e6
-            if self._watch.armed:
-                self._watch.note(slots=len(busy))
-            emitted = 0
-            for s in busy:
-                req = self._slots[s]
-                if req is None or req.finished:  # stop(drain=False) race
-                    self._slots[s] = None
-                    engine.release_slot(s)
-                    continue
-                reason = None
-                if engine.speculative:
-                    for i in range(int(counts[s])):
-                        self._deliver(req, ts[s, i])
-                        self._last[s] = ts[s, i]
-                        emitted += 1
-                        reason = self._finished_reason(req)
-                        if reason is not None:
-                            break
-                else:
-                    self._deliver(req, nxt[s])
-                    self._last[s] = nxt[s]
-                    emitted += 1
-                    reason = self._finished_reason(req)
-                if reason is not None:
-                    self._slots[s] = None
-                    engine.release_slot(s)
-                    self._complete(req, reason)
-            # per-token latency, per STREAM (what a client waits between
-            # tokens): the plain path observes the step time unchanged;
-            # a speculative round amortizes its two dispatches over the
-            # mean tokens each busy stream emitted
-            # kind-labeled only: one step serves slots of mixed tenants
-            h_token = self._h_token.labels(kind=self.kind)
-            if engine.speculative and emitted:
-                h_token.observe(dt_ms * len(busy) / emitted)
+            if flight is not None:
+                self._land(flight)
+            if nxt is not None and nxt.rows is not None:
+                self._land(nxt)
             else:
-                h_token.observe(dt_ms)
-            self._mark("serving::deliver")
+                self._flight = nxt
             self._end_iteration()
         # drained exit: nothing queued, nothing active
 

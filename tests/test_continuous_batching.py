@@ -184,6 +184,258 @@ def test_server_stop_before_start_does_not_hang(model):
     assert done, "stop() hung on a never-started server"
 
 
+# -- one step ahead -----------------------------------------------------------
+#
+# A ring engine without a draft model lets the loop enqueue step n+1 from
+# step n's device tokens before it fetches step n (engine.steps_ahead 1).
+# The reference order (depth 0) is had by giving the engine a ``step`` of
+# one's own: a replaced ``step`` is handed every step whole.
+
+def _whole_steps(eng):
+    """The same engine at depth 0: its ``step`` wrapped, not changed."""
+    eng.step = lambda tokens, temps: GenerationEngine.step(eng, tokens, temps)
+    assert eng.steps_ahead == 0
+    return eng
+
+
+def _served(eng, prompts, budgets, temperature, before_start=False):
+    """Tokens and finish reasons of the requests through a scheduler.
+    ``before_start`` queues them all before the loop runs, so that they
+    are admitted in order ahead of the first step."""
+    sched = ContinuousBatcher(eng, queue_capacity=32)
+    if not before_start:
+        sched.start()
+    try:
+        reqs = [sched.submit(p, max_new_tokens=b, temperature=temperature)
+                for p, b in zip(prompts, budgets)]
+        sched.start()
+        out = [(r.wait(timeout=120), r.finish_reason) for r in reqs]
+        assert sched.extra_compiles() == 0 and sched.live_slots == 0
+        return out
+    finally:
+        sched.stop(drain=False)
+
+
+@pytest.mark.parametrize("case", ["greedy_midbatch", "sampled_one_batch",
+                                  "greedy_eos"])
+def test_one_step_ahead_serves_the_tokens_of_whole_steps(model, case):
+    """Depth 1 against depth 0, same engine seed: greedy across
+    admissions mid-batch (look-ahead and drained iterations mixed, no
+    compile); sampled for a batch admitted before the first step, where
+    no admission reorders the sampling counters and no two enqueues
+    share one; greedy with requests that end on EOS, whose one row of
+    the step already in flight is never delivered and whose slot's next
+    occupant reads what it reads alone."""
+    from paddle_tpu import profiler
+
+    temperature = 0.9 if case == "sampled_one_batch" else 0.0
+    if case == "sampled_one_batch":
+        prompts, budgets, slots = _prompts(3, rng_seed=4), [5, 9, 7], 3
+    else:
+        prompts = _prompts(7, rng_seed=1)
+        budgets, slots = [9, 3, 6, 2, 8, 4, 5], 2
+    eos = None
+    if case == "greedy_eos":
+        solo = _engine(model, slots=1).warmup().generate(
+            prompts, max_new_tokens=9, temperature=0.0, stop_at_eos=False)
+        # a token that some reply reads for the first time past its
+        # first position and short of its budget: that request ends
+        # there, on EOS, with its row of the next step in flight
+        eos = next(t[i] for t, b in zip(solo, budgets)
+                   for i in range(1, b - 1) if t[i] not in t[:i])
+
+    def build():
+        eng = _engine(model, slots=slots).warmup()
+        if eos is not None:
+            eng.eos_id = eos
+        return eng
+
+    ahead, ctrs = build(), []
+    assert ahead.steps_ahead == 1
+    bump = ahead._next_key_step
+    ahead._next_key_step = lambda: ctrs.append(bump()) or ctrs[-1]
+    compiles = profiler.counters().get("generation::compile", 0)
+    before = case == "sampled_one_batch"
+    got = _served(ahead, prompts, budgets, temperature, before_start=before)
+    assert profiler.counters().get("generation::compile", 0) == compiles
+    want = _served(_whole_steps(build()), prompts, budgets, temperature,
+                   before_start=before)
+    assert got == want
+    assert [len(t) for t, _ in got] == budgets or eos is not None
+    assert len(ctrs) == len(set(ctrs)) > len(prompts)
+    if eos is not None:
+        reasons = [r for _, r in got]
+        assert "eos" in reasons and "length" in reasons
+        assert all(t[-1] == eos and eos not in t[:-1]
+                   for t, r in got if r == "eos")
+
+
+def test_steps_ahead_is_what_the_engine_is(model):
+    assert _engine(model).steps_ahead == 1
+    assert _engine(model, kv_cache_layout="paged",
+                   kv_page_size=8).steps_ahead == 0
+    assert _engine(model, draft_model=model, draft_k=2).steps_ahead == 0
+    assert _whole_steps(_engine(model)).steps_ahead == 0
+
+
+@pytest.fixture()
+def spans_on():
+    from paddle_tpu import profiler
+
+    profiler.reset_profiler()
+    profiler.start_profiler(state="CPU")
+    yield profiler
+    profiler.stop_profiler()
+    profiler.reset_profiler()
+
+
+def _iterations(profiler):
+    """``[(steps_ahead sample, names of the loop's phases until the next
+    sample)]``, one an iteration of the loop."""
+    samples = sorted((s["ts"], s["args"]["value"])
+                     for s in profiler.counter_samples()
+                     if s["name"] == "serving::steps_ahead")
+    phases = sorted((e["ts"], e["name"]) for e in profiler.host_events()
+                    if e["name"].startswith(("serving::", "generation::"))
+                    and e["name"] != "generation::args")
+    out = []
+    for (t, v), (t_next, _) in zip(samples, samples[1:] + [(1e30, None)]):
+        out.append((v, [n for ts, n in phases if t <= ts < t_next]))
+    return out
+
+
+def test_steps_ahead_samples_read_1_ahead_and_0_after_an_admission(
+        model, spans_on):
+    eng = _engine(model, slots=2).warmup()
+    spans_on.reset_profiler()
+    _served(eng, _prompts(5, rng_seed=1), [12, 2, 3, 2, 9], 0.0)
+    its = _iterations(spans_on)
+    assert {v for v, _ in its} == {0, 1}
+    admitted = False  # a prefill since the sample before this one
+    for v, names in its:
+        steps = [n for n in names if n.startswith("generation::decode")]
+        if v:
+            # enqueued ahead: the step goes out BEFORE the fetch of the
+            # one in flight, and no admission came before the sample
+            assert steps[:2] == ["generation::decode",
+                                 "generation::decode_fetch"]
+            assert not admitted
+        else:
+            # drained (fetch only), started again from the host's tokens
+            # (enqueue only), or idle: never both halves
+            assert len(steps) <= 1
+        # a sample follows its iteration's admissions: the phases up to
+        # the next sample end with the next iteration's
+        admitted = "generation::prefill" in names
+    assert any(v == 0 and "generation::decode_fetch" in n for v, n in its)
+
+
+def test_speculative_rounds_keep_their_order(model, spans_on):
+    plain = _engine(model, slots=1).warmup().generate(
+        [[5, 6, 7]], max_new_tokens=6, temperature=0.0)
+    eng = _engine(model, draft_model=model, draft_k=2).warmup()
+    spans_on.reset_profiler()
+    got = _served(eng, [[5, 6, 7]], [6], 0.0)
+    assert [t for t, _ in got] == plain
+    assert {v for v, _ in _iterations(spans_on)} == {0}
+
+
+class _Tokens:
+    """A decode step's device tokens, for a test's purpose: passed on to
+    the next step as they are (``real``), while the fetch raises."""
+
+    def __init__(self, real):
+        self.real = real
+
+    def __array__(self, dtype=None, copy=None):
+        raise RuntimeError("fetch failed")
+
+
+@pytest.mark.parametrize("half", ["enqueue", "fetch"])
+def test_a_step_that_raises_with_one_in_flight_fails_live_once(model, half):
+    from paddle_tpu import monitor
+    from paddle_tpu.monitor import flight_recorder
+
+    eng = _engine(model, slots=2).warmup()
+    real, decodes = eng._dispatch, []
+
+    def dispatch(label, jitted, make_args):
+        if label != "decode":
+            return real(label, jitted, make_args)
+        decodes.append(1)
+        if half == "enqueue" and len(decodes) == 3:
+            raise RuntimeError("enqueue failed")  # step 2 is in flight
+        out = real(label, jitted, lambda: tuple(
+            a.real if isinstance(a, _Tokens) else a for a in make_args()))
+        if half == "fetch" and len(decodes) == 2:
+            return out[0], _Tokens(out[1])  # fetched with step 3 enqueued
+        return out
+
+    eng._dispatch = dispatch
+    errors = monitor.counter("serving/gen_errors_total")
+    e0 = errors.value
+    flight_recorder.reset_recorder()
+    sched = ContinuousBatcher(eng, queue_capacity=8)
+    try:
+        seen = []
+        doomed = [sched.submit(p, max_new_tokens=12, temperature=0.0,
+                               on_token=seen.append)
+                  for p in ([3, 4, 5], [6, 7])]
+        sched.start()
+        for r in doomed:
+            with pytest.raises(RuntimeError, match=half + " failed"):
+                r.wait(timeout=60)
+        delivered = len(seen)
+        assert errors.value - e0 == 2  # once each
+        assert sum(e["kind"] == "generation_step_error"
+                   for e in flight_recorder.events()) == 1
+        # the loop lives, and the next request reads what it reads alone
+        nxt = sched.submit([9, 8, 7], max_new_tokens=5, temperature=0.0)
+        assert nxt.wait(timeout=60) == _engine(model, slots=1).generate(
+            [[9, 8, 7]], max_new_tokens=5, temperature=0.0)[0]
+        assert len(seen) == delivered  # nothing of the dropped steps
+        assert sched.live_slots == 0
+    finally:
+        sched.stop(drain=False)
+
+
+@pytest.mark.parametrize("how", ["stop", "deadline"])
+def test_nothing_is_delivered_to_a_request_that_has_ended(model, how):
+    """``stop(drain=False)`` with a step in flight and another behind
+    it, and a deadline that ran out in the queue: no token reaches a
+    request after it was failed."""
+    from paddle_tpu.serving import DeadlineExceededError
+
+    eng = _engine(model, slots=1).warmup()
+    sched = ContinuousBatcher(eng, queue_capacity=8).start()
+    late, seen, box = [], [], {}
+
+    def on_token(tok):
+        (late if box["req"].finished else seen).append(tok)
+
+    try:
+        box["req"] = sched.submit([3, 4, 5], max_new_tokens=20,
+                                  temperature=0.0, on_token=on_token)
+        if how == "deadline":
+            waiting = []
+            queued = sched.submit([6, 7], max_new_tokens=4, deadline_ms=1,
+                                  on_token=waiting.append)
+            with pytest.raises(DeadlineExceededError):
+                queued.wait(timeout=60)
+            assert box["req"].wait(timeout=60) == seen and len(seen) == 20
+            assert waiting == [] and queued.tokens == []
+        else:
+            while len(seen) < 3:
+                time.sleep(0.001)
+            sched.stop(drain=False)
+            with pytest.raises(ServingClosedError):
+                box["req"].wait(timeout=1)
+            assert 3 <= len(seen) < 20 and box["req"].tokens == seen
+        assert late == [] and sched.live_slots == 0
+    finally:
+        sched.stop(drain=False)
+
+
 # -- HTTP frontend ------------------------------------------------------------
 
 def _post(url, payload, timeout=120):

@@ -83,11 +83,15 @@ class _SlowTokens:
 
 
 def _slow_first_decode(eng, monkeypatch, seconds):
-    """The first decode step's tokens take ``seconds`` to arrive."""
+    """The first decode step's tokens take ``seconds`` to arrive. The
+    step enqueued behind it takes them where they are (the stand-in is
+    unwrapped on its way into the program): only the fetch waits."""
     real, fired = eng._dispatch, []
 
     def dispatch(label, jitted, make_args):
-        out = real(label, jitted, make_args)
+        out = real(label, jitted, lambda: tuple(
+            a.real if isinstance(a, _SlowTokens) else a
+            for a in make_args()))
         if label == "decode" and not fired:
             fired.append(1)
             return out[0], _SlowTokens(out[1], seconds)
@@ -221,10 +225,18 @@ def test_scheduler_samples_its_counts_once_an_iteration(model, spans_on,
     by_name = {}
     for s in profiler.counter_samples():
         by_name.setdefault(s["name"], []).append(s["args"]["value"])
-    assert set(by_name) == {"serving::slots_busy", "serving::kv_live_tokens"}
+    assert set(by_name) == {"serving::slots_busy", "serving::kv_live_tokens",
+                            "serving::steps_ahead"}
     steps = sum(1 for s in spans if s[2] == "generation::decode")
     idles = sum(1 for s in spans if s[2] == "serving::idle_wait")
-    assert abs(len(by_name["serving::slots_busy"]) - steps - idles) <= 1
+    # an iteration enqueues a step, or waits idle, or (one step ahead
+    # only) drains: it fetches the step in flight and enqueues none
+    ahead = by_name["serving::steps_ahead"]
+    assert set(ahead) == ({0, 1} if layout == "ring" else {0})
+    drains = sum(1 for s in spans
+                 if s[2] == "generation::decode_fetch") - sum(ahead)
+    assert len(ahead) == len(by_name["serving::slots_busy"])
+    assert abs(len(ahead) - steps - idles - (layout == "ring") * drains) <= 1
     assert max(by_name["serving::slots_busy"]) == 2
     # prompts of 3, 4, 5 tokens with 4 new tokens each: at most two live
     assert 0 < max(by_name["serving::kv_live_tokens"]) <= 4 + 5 + 2 * 4
