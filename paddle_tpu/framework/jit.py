@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import time
 from collections import OrderedDict
 
 import jax
@@ -30,7 +31,7 @@ import jax.numpy as jnp
 # TrainStepFn.__init__)
 _step_fn_counter = itertools.count()
 
-from ..profiler import RecordEvent
+from ..monitor import flight_recorder as _flight
 from . import autograd
 from .random import default_generator
 from .tensor import Tensor
@@ -312,6 +313,7 @@ class TrainStepFn:
         # don't collide in the global CostRecord registry
         self._instance = next(_step_fn_counter)
         self._rng = default_generator().split()
+        self._stall = _flight.StepWatch("train_step", jax.devices()[0])
 
     def _build_pure(self):
         model, optimizer, loss_fn = self.model, self.optimizer, self.loss_fn
@@ -436,35 +438,41 @@ class TrainStepFn:
     def __call__(self, *batch):
         # the span names parallel/train.py's sharded step uses; no outer
         # train::step here (callers wrap their own, and a wrapper would
-        # take every device gap in the benchmark's attribution)
-        with RecordEvent("train::shard_batch"):  # H2D of a host batch
-            batch = tuple(
-                b._array if isinstance(b, Tensor) else jnp.asarray(b)
-                for b in batch
-            )
+        # take every device gap in the benchmark's attribution). The
+        # watch times the same two phases whatever the profiler's state
+        # (its split of the call, and the train_stall record)
+        watch = self._stall
+        t0 = watch.enter()
+        batch = tuple(  # H2D of a host batch
+            b._array if isinstance(b, Tensor) else jnp.asarray(b)
+            for b in batch
+        )
+        watch.phase("train::shard_batch", t0)
         if not getattr(self, "_usage_checked", False):
             self._freeze_unused_params(batch)
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         self._rng, sub = jax.random.split(self._rng)
         from ..flags import flag
 
-        with RecordEvent("train::step_dispatch"):
-            if flag("check_nan_inf"):
-                # FLAGS_check_nan_inf (platform/flags.cc:44 →
-                # details/nan_inf_utils_detail.cc): the reference scans
-                # every op's outputs post-run; the XLA-native equivalent is
-                # checkify float_checks — every primitive inside the
-                # compiled step gets an instrumented NaN check that reports
-                # the producing operation's source location.
-                metrics = self._run_checked(batch, lr, sub)
-            else:
-                metrics = self._dispatch(batch, lr, sub)
+        t0 = time.perf_counter_ns()
+        if flag("check_nan_inf"):
+            # FLAGS_check_nan_inf (platform/flags.cc:44 →
+            # details/nan_inf_utils_detail.cc): the reference scans
+            # every op's outputs post-run; the XLA-native equivalent is
+            # checkify float_checks — every primitive inside the
+            # compiled step gets an instrumented NaN check that reports
+            # the producing operation's source location.
+            metrics = self._run_checked(batch, lr, sub)
+        else:
+            metrics = self._dispatch(batch, lr, sub)
+        watch.phase("train::step_dispatch", t0, watch.nested)
         if flag("benchmark"):
             # FLAGS_benchmark: synchronous dispatch for exact timings
             jax.block_until_ready(metrics)
         # NOTE: LR schedulers keep eager semantics — the user calls
         # scheduler.step() (per epoch or per batch) exactly as in eager mode;
         # the current value is read and fed in as a traced scalar each step.
+        watch.leave()
         return metrics
 
     def _dispatch(self, batch, lr, sub):
@@ -483,11 +491,13 @@ class TrainStepFn:
         sig = (self._instance, len(self.state["params"]),
                "gm" in self.state) + tuple(
             (tuple(b.shape), str(b.dtype)) for b in batch)
+        nested = self._stall.nested
         entry, _ = self._exec.get_or_build(
-            sig, lambda: (self.compiled, None))
+            sig, lambda: (self.compiled, None), nested=nested)
         new_state, metrics = self._exec.dispatch(
             entry, self.state, batch, lr, sub,
-            donated=lambda: jax.tree_util.tree_leaves(self.state))
+            donated=lambda: jax.tree_util.tree_leaves(self.state),
+            nested=nested)
         self.state = new_state
         return metrics
 

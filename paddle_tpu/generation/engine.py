@@ -74,7 +74,7 @@ from ..flags import flag
 from ..framework.jit import functional_call
 from ..monitor import flight_recorder as _flight
 from ..monitor import tracing as _tracing
-from ..profiler import RecordEvent, add_span as _add_span
+from ..profiler import RecordEvent, add_span as _add_span, timed_span
 from ..profiler import bump_counter as _bump_counter
 from ..profiler import counters as _counters
 from ..profiler import enabled as _profiler_enabled
@@ -159,14 +159,16 @@ class _KeptState:
 
 class DecodeStep:
     """One enqueued decode step (:meth:`GenerationEngine.enqueue_step`):
-    its ``[S]`` tokens and routing statistics, still on the device, and
-    the ring rows it read, counted on the host as it was enqueued (only
-    while the profiler is on)."""
+    its ``[S]`` tokens and routing statistics, still on the device, the
+    ring rows it read, counted on the host as it was enqueued (only
+    while the profiler is on), and the program that computes it (its
+    :class:`flight_recorder.PhaseRing`: the fetch is noted there)."""
 
-    __slots__ = ("tokens", "stats", "rows_read")
+    __slots__ = ("tokens", "stats", "rows_read", "program")
 
-    def __init__(self, tokens, stats, rows_read):
+    def __init__(self, tokens, stats, rows_read, program):
         self.tokens, self.stats, self.rows_read = tokens, stats, rows_read
+        self.program = program
 
 
 class GenerationEngine:
@@ -410,6 +412,12 @@ class GenerationEngine:
         # enqueue and fetch nanoseconds are added to it (_fetched). Not a
         # parameter of step()/admit(): callers and tests wrap those
         self.phase_split = None
+        # always on, driver or none: what each program's calls took
+        # lately (name -> PhaseRing; the ring is also the store entry's
+        # ``meta``), the program of the call in hand, and the innermost
+        # phases of the enqueue in hand, until its phase is noted
+        self._programs = {}
+        self._program = self._nested = None
         # the serving-wide warmup-snapshot discipline; the continuous
         # batcher notes growth through this same watch
         self.watch = CompileWatch(
@@ -513,10 +521,7 @@ class GenerationEngine:
     def device_memory_stats(self) -> dict:
         """Allocator state of the device that holds the cache, where the
         backend reports it (the CPU backend reports nothing)."""
-        stats = next(iter(self._kv[-1].devices())).memory_stats() or {}
-        return {k: int(stats[k]) for k in (
-            "bytes_in_use", "bytes_reserved", "largest_free_block_bytes")
-            if k in stats}
+        return _flight.allocator_stats(next(iter(self._kv[-1].devices())))
 
     def kv_bytes_per_token(self) -> int:
         """Cache bytes one decoded token occupies across all layers (a
@@ -697,19 +702,29 @@ class GenerationEngine:
         few arrays go to the executable as host arrays and its call
         places them (``runtime::launch``): on the v5e's host that took
         half a millisecond less a step than a ``jnp.asarray`` each
-        (PERF.md, PR 28)."""
+        (PERF.md, PR 28). The call's innermost phases (this span, the
+        runtime's lookup, launch and a first dispatch's compile) are
+        timed whatever the profiler's state, into the list that the
+        caller's :meth:`_note_phase` files with the program's ring."""
         store = self._stores[label]
-        with RecordEvent("generation::args"):
+        if self._nested is None:
+            self._nested = []
+        nested = self._nested
+        with timed_span("generation::args", nested):
             args = make_args()
             sig = self._signature(args)
         entry, disposition = store.get_or_build(
-            sig, lambda: (jitted, None))
+            sig, lambda: (jitted, self._program_ring(label, args)),
+            nested=nested)
+        self._program = entry.meta
+        entry.meta.runs += 1
         # the slot-admission / dispatch span (if one is current) learns
         # whether this call compiled — the compile-vs-execute attribution
         # a /tracez reader needs (the runtime adds cache_key + flops)
         _tracing.annotate(program_cache=disposition)
         try:
-            return store.dispatch(entry, *args, donated=self._cache_leaves)
+            return store.dispatch(entry, *args, donated=self._cache_leaves,
+                                  nested=nested)
         except Exception as e:
             # a donated cache that the failed call consumed is gone for
             # every slot, not only for the caller's
@@ -736,6 +751,24 @@ class GenerationEngine:
             sig += self._kept_signature(arg) or _leaf_signature(arg)
         return sig
 
+    def _program_ring(self, label, args):
+        """The ring of a program about to be built, by name: the store
+        label, and for a program that takes a prompt its bucket (every
+        prefill twin is handed the padded prompt as the one host
+        ``[1, bucket]`` array of its call): ``prefill/512``, ``decode``,
+        ``draft``, ``verify``. Twins of one name (a decode tier's draft
+        prefill beside an export) share a ring."""
+        name = label
+        for a in args:
+            if isinstance(a, np.ndarray) and a.ndim == 2 and len(a) == 1:
+                name = f"{label}/{a.shape[1]}"
+        return self._programs.setdefault(name, _flight.PhaseRing(name))
+
+    def program_rings(self):
+        """What each program's calls took lately, for a driver's stall
+        record (:func:`flight_recorder.held_among`)."""
+        return list(self._programs.values())
+
     def _kept_signature(self, arg):
         if arg is self._kv:
             return self._kv_signature
@@ -750,27 +783,38 @@ class GenerationEngine:
         """Every array of the cache: what a ring program may consume."""
         return jax.tree_util.tree_leaves((self._kv, self._kv_draft))
 
-    def _fetched(self, phase, t0_ns, value, to_host):
+    def _fetched(self, phase, t0_ns, value, to_host, program=None):
         """``value`` on the host, the wait for it timed as the span
         ``<phase>_fetch``: ``generation::prefill`` / ``::decode`` close
         when the program is enqueued, this one closes when its result
         has arrived, so a slow device reads slow here. ``t0_ns`` is when
         the enqueue phase began (``None`` where :meth:`enqueue_step` has
         accounted for it already); both phases' nanoseconds go to the
-        driver's :attr:`phase_split`, if it gave one."""
+        driver's :attr:`phase_split`, if it gave one, and to the ring of
+        ``program`` (default: the one dispatched last)."""
         t1 = time.perf_counter_ns()
         out = to_host(value)
         t2 = time.perf_counter_ns()
         _add_span(phase + "_fetch", t1, t2)
         if t0_ns is not None:
-            self._note_phase(phase, t1 - t0_ns)
-        self._note_phase(phase + "_fetch", t2 - t1)
+            self._note_phase(phase, t0_ns, t1 - t0_ns)
+        self._note_phase(phase + "_fetch", t1, t2 - t1, program)
         return out
 
-    def _note_phase(self, name, ns):
+    def _note_phase(self, name, t0_ns, ns, program=None):
+        """One sibling phase of the caller's thread, begun at ``t0_ns``:
+        added to the driver's split, and filed as one instance in the
+        program's ring. An enqueue phase takes the innermost phases that
+        :meth:`_dispatch` timed inside it along."""
         split = self.phase_split
         if split is not None:
             split[name] = split.get(name, 0) + ns
+        program = program or self._program
+        if program is not None:
+            nested = None
+            if not name.endswith("_fetch"):
+                nested, self._nested = self._nested, None
+            program.note(name, t0_ns, ns, nested)
 
     def extra_compiles(self) -> int:
         """Compiles since warmup — steady state must keep this at 0."""
@@ -863,7 +907,8 @@ class GenerationEngine:
         (``CompiledStore.precompile``)."""
         args = make_args()
         self._stores[label].precompile(
-            self._signature(args), lambda: (jitted, None), args)
+            self._signature(args),
+            lambda: (jitted, self._program_ring(label, args)), args)
 
     def _warmup_plan(self, kind):
         """``[(calls, run)]``: ``run()`` is one warm-up step through the
@@ -1271,7 +1316,7 @@ class GenerationEngine:
             self._sample_stats(stats)
         return tok
 
-    def _sample_stats(self, stats, rows_read=None):
+    def _sample_stats(self, stats, rows_read=None, program=None):
         """While the profiler is on, fetch the routing statistics a
         program returned and put them on its timeline as counter
         samples: ``moe::expert_load`` (per held expert, prompt and
@@ -1281,10 +1326,12 @@ class GenerationEngine:
         ``generation::state_bytes`` (what the state layers' leaves
         hold, of :meth:`cache_nbytes`) and ``generation::kv_rows_read``
         (``rows_read``: :meth:`kv_rows_read` as the step was enqueued;
-        ``None`` for a prompt). Off, the arrays are dropped where they
-        lie: no transfer, one boolean."""
+        ``None`` for a prompt). The whole of it is the span
+        ``generation::stats_fetch``. Off, the arrays are dropped where
+        they lie: no transfer, one boolean."""
         if not _profiler_enabled():
             return
+        t0 = time.perf_counter_ns()
         prefill = rows_read is None
         if stats is not None:
             stats = jax.device_get(stats)
@@ -1298,6 +1345,12 @@ class GenerationEngine:
         if not prefill:
             _record_counter("generation::state_bytes", self.state_nbytes())
             _record_counter("generation::kv_rows_read", list(rows_read))
+        # a sibling of the fetch spans: the transfer is the loop
+        # thread's time (3-4 ms an iteration on the chip), and only
+        # spent while the profiler is on
+        t1 = time.perf_counter_ns()
+        _add_span("generation::stats_fetch", t0, t1)
+        self._note_phase("generation::stats_fetch", t0, t1 - t0, program)
 
     def state_nbytes(self) -> int:
         """Device bytes of the state layers' leaves (all slots): the
@@ -1831,18 +1884,24 @@ class GenerationEngine:
         temp = (self.default_temperature if temperature is None
                 else float(temperature))
         ctr = self._next_key_step()
+        t0 = time.perf_counter_ns()
         with RecordEvent("generation::prefill_export"):
             planes, tok = self._dispatch(
                 *self._export_call(padded, n, temp, ctr))
+        self._note_phase("generation::prefill_export", t0,
+                         time.perf_counter_ns() - t0)
         return planes, n, int(tok)
 
     def _admit_draft(self, slot, prompt):
         """Draft-only prefill of ``prompt`` into draft slot ``slot`` —
         the decode-tier half of a speculative handoff admission."""
         padded, n = self._padded_prompt(prompt)
+        t0 = time.perf_counter_ns()
         with RecordEvent("generation::draft_prefill"):
             self._kv_draft = self._dispatch(
                 *self._draft_prefill_call(slot, padded, n))
+        self._note_phase("generation::draft_prefill", t0,
+                         time.perf_counter_ns() - t0)
 
     def admit_prefilled(self, slot, planes, length, first_token,
                         prompt=None) -> int:
@@ -1937,8 +1996,9 @@ class GenerationEngine:
             for s, live in enumerate(self._slot_live):
                 if live:
                     self._pos_host[s] += 1
-        self._note_phase("generation::decode", time.perf_counter_ns() - t0)
-        return DecodeStep(nxt, stats, rows_read)
+        self._note_phase("generation::decode", t0,
+                         time.perf_counter_ns() - t0)
+        return DecodeStep(nxt, stats, rows_read, self._program)
 
     def fetch_step(self, step) -> np.ndarray:
         """The second half of :meth:`step`: the tokens of an enqueued
@@ -1946,9 +2006,9 @@ class GenerationEngine:
         ``generation::decode_fetch``; the step's statistics are read
         here too, while the profiler is on."""
         nxt = self._fetched("generation::decode", None, step.tokens,
-                            np.asarray)
+                            np.asarray, step.program)
         if self._kinds is not None:
-            self._sample_stats(step.stats, step.rows_read)
+            self._sample_stats(step.stats, step.rows_read, step.program)
         return nxt
 
     def step(self, tokens, temps) -> np.ndarray:

@@ -29,6 +29,14 @@ VLOG-on-crash breadcrumbs played for the fluid runtime):
   deadlock and becomes "group dp diverges at seq 41: rank0 issued
   all_reduce|(1024,)|float32|sum, rank1 issued all_gather|...".
 
+- **Stall records** — each hot loop (the serving scheduler's iteration,
+  a train step's call-to-call interval) keeps an always-on split of its
+  thread's time and leaves one event when a pass stands still
+  (``generation_stall``, ``train_stall``): the program, the innermost
+  phase, the time over its usual, and whose time it was
+  (:class:`PhaseRing`, :class:`Evidence`, :func:`held_by`,
+  :class:`StepWatch`).
+
 Recording rides hot paths always-on (``FLAGS_flight_recorder``), so the
 per-event cost budget is one flag read, one dict build, and one short
 lock hold.
@@ -36,9 +44,11 @@ lock hold.
 from __future__ import annotations
 
 import collections
+import gc
 import json
 import os
 import signal
+import statistics
 import sys
 import tempfile
 import threading
@@ -46,6 +56,7 @@ import time
 import traceback
 
 from ..flags import flag
+from ..profiler import add_span as _add_span
 from . import tracing as _tracing
 
 __all__ = [
@@ -57,6 +68,8 @@ __all__ = [
     "install", "install_from_flags",
     "start_watchdog", "stop_watchdog", "watchdog",
     "thread_stacks",
+    "STALL_NS", "STALL_FACTOR", "PhaseRing", "Evidence", "StepWatch",
+    "allocator_stats", "held_among", "record_stall",
 ]
 
 # per-group collective tail length kept for desync diagnosis — long
@@ -355,6 +368,407 @@ def nan_event_action(where, detail):
     if action == "dump":
         dump_now(reason=f"check_nan_inf:{where}")
     return action
+
+
+# -- stall records: what held a hot loop ------------------------------------
+#
+# Both hot loops (the serving scheduler's iteration, a train step's
+# call-to-call interval) keep an always-on split of their thread's time
+# and leave ONE flight event when a pass stands still: ``generation_stall``
+# / ``train_stall``. The record names the longest single call or host
+# phase of the pass down to its innermost phase (``held_phase``), what
+# that phase usually takes, and whose time it was (``held_by``), decided
+# from the evidence below by :func:`held_by`. No flag, no thread: the
+# loops themselves feed it, one append a call.
+
+# A pass longer than this leaves a record. A constant, not a flag: a
+# decode step is tens of milliseconds, a train step a hundred.
+STALL_NS = 1_000_000_000
+# A held phase has LOST time only where it also took over this many
+# times its usual: an iteration of 32 honest admissions passes the
+# second and loses nothing.
+STALL_FACTOR = 4
+# the evidence baseline is read again from the loop at most this often
+_REFRESH_NS = 1_000_000_000
+_RING = 32
+
+# phases in which the thread waits in native code with the interpreter
+# released (or, for a train step's ``outside``, in the caller's code,
+# which may): other threads' CPU there is the runtime's own (a load, a
+# compile, the CPU backend's compute), not someone holding the interpreter
+_RUNTIME_PHASES = ("runtime::launch", "runtime::compile")
+_FETCH_SUFFIX = "_fetch"
+_OUTSIDE = "outside"
+
+
+class PhaseRing:
+    """What one program's calls (or one loop's host phases) took lately:
+    the last instances, each ``(start_ns, phase, ns, nested)`` on
+    ``perf_counter_ns``, ``nested`` the innermost phases inside it as
+    ``[(name, start_ns, ns)]`` or None. ``runs`` is the owner's count of
+    the program's calls. Nothing is reduced until a record is written:
+    the hot path pays one append a call."""
+
+    __slots__ = ("name", "runs", "ring")
+
+    def __init__(self, name, length=2 * _RING):
+        self.name, self.runs = name, 0
+        self.ring = collections.deque(maxlen=length)
+
+    def note(self, phase, t0_ns, ns, nested=None):
+        self.ring.append((t0_ns, phase, ns, nested))
+
+    def usual_ns(self, phase, inner, but=None):
+        """Median of what ``inner`` (an innermost phase of ``phase``)
+        took over the instances kept, ``but`` left out; None where there
+        is no other instance."""
+        took = [ns for entry in self.ring
+                if entry is not but and entry[1] == phase
+                for ns, _, name in _innermost(entry) if name == inner]
+        return statistics.median(took) if took else None
+
+    def before(self, entry):
+        """``(runs before this call, ns since the call before it began)``
+        of an instance kept; the second None for a first call."""
+        base = entry[1].removesuffix(_FETCH_SUFFIX)
+        starts = [e[0] for e in self.ring if e[1] == base]
+        later = sum(1 for t in starts if t > entry[0])
+        earlier = [t for t in starts if t < entry[0]]
+        return (max(self.runs - later - 1, 0),
+                entry[0] - max(earlier) if earlier else None)
+
+
+def _innermost(entry):
+    """``(ns, start_ns, name)`` of an instance's innermost phases: the
+    nested ones, and what is left of the phase itself."""
+    t0, phase, ns, nested = entry
+    for name, start, took in nested or ():
+        ns -= took
+        yield took, start, name
+    yield ns, t0, phase
+
+
+def held_among(entries):
+    """Of ``(entry, owner)`` pairs, the instances of one pass: its
+    longest single innermost phase as ``(ns, start_ns, name, owner,
+    entry)`` (None with no entry), and the nested phases' sums by
+    name."""
+    held, nested_ns = None, {}
+    for entry, owner in entries:
+        for ns, start, name in _innermost(entry):
+            if name != entry[1]:
+                nested_ns[name] = nested_ns.get(name, 0) + ns
+            if held is None or ns > held[0]:
+                held = (ns, start, name, owner, entry)
+    return held, nested_ns
+
+
+def allocator_stats(device) -> dict:
+    """The device allocator's state where the backend reports it (the
+    CPU backend reports nothing)."""
+    stats = device.memory_stats() or {}
+    return {k: int(stats[k]) for k in (
+        "bytes_in_use", "bytes_reserved", "largest_free_block_bytes",
+        "num_allocs") if k in stats}
+
+
+# collector pauses, process-wide: ``[total_ns, collections]`` and the
+# last few pauses as (end_ns, ns); one gc.callbacks entry, installed by
+# the first Evidence
+_gc_totals = [0, 0]
+_gc_pauses = collections.deque(maxlen=64)
+_gc_start = [0]
+
+
+def _on_gc(phase, info):
+    now = time.perf_counter_ns()
+    if phase == "start":
+        _gc_start[0] = now
+    elif _gc_start[0]:
+        took = now - _gc_start[0]
+        _gc_totals[0] += took
+        _gc_totals[1] += 1
+        _gc_pauses.append((now, took))
+
+
+def _first_line_fields(path):
+    with open(path) as f:
+        return f.readline().split()
+
+
+def _pressure_us(path):
+    # "some avg10=0.00 avg60=0.00 avg300=0.00 total=12345" (microseconds)
+    return int(_first_line_fields(path)[-1].partition("=")[2])
+
+
+class Evidence:
+    """Cheap process counters at a baseline and now: whose time a stall
+    was. The loop that owns it calls :meth:`refresh` once a pass, which
+    reads the baseline again at most once a second (so a stall's evidence
+    spans the stall and at most about a second before it: ``evidence_ms``
+    says how long); :meth:`since` reads the counters again and returns
+    the deltas, when a record is written. Both on the loop's own thread:
+    three of the sources are the calling thread's. Between the two
+    nothing is read. A source the platform lacks (no ``/proc/pressure``
+    in a container, no allocator on the CPU backend, a device that does
+    not answer) is left out, never an error.
+
+    ``allocator``: zero-argument callable returning the device
+    allocator's fields (:func:`allocator_stats`), or None."""
+
+    def __init__(self, allocator=None):
+        self._allocator = allocator
+        self._t_ns = 0
+        self._base = {}
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+    def refresh(self, now_ns):
+        if now_ns - self._t_ns >= _REFRESH_NS:
+            self._base, self._t_ns = self._read(), now_ns
+
+    def _read(self):
+        out = {"thread_cpu": time.thread_time_ns() / 1e6,
+               "process_cpu": time.process_time_ns() / 1e6,
+               "gc": _gc_totals[0] / 1e6, "gc_n": _gc_totals[1]}
+        for read in (self._schedstat, self._proc_stat, self._pressure,
+                     self._rusage, self._alloc):
+            try:
+                out.update(read())
+            except Exception:  # noqa: BLE001 — a source the platform lacks
+                continue
+        return out
+
+    @staticmethod
+    def _schedstat():
+        # "<on-cpu ns> <runnable and not on a cpu, ns> <timeslices>"
+        return {"run_delay": int(_first_line_fields(
+            "/proc/thread-self/schedstat")[1]) / 1e6}
+
+    @staticmethod
+    def _pressure():
+        out = {}
+        for what in ("cpu", "memory", "io"):
+            out[f"pressure_{what}"] = _pressure_us(
+                f"/proc/pressure/{what}") / 1e3
+        return out
+
+    def _alloc(self):
+        return {} if self._allocator is None else {
+            "alloc": self._allocator()}
+
+    @staticmethod
+    def _proc_stat():
+        # "cpu user nice system idle iowait irq softirq steal ...", in
+        # clock ticks summed over the CPUs
+        f = _first_line_fields("/proc/stat")
+        tick_ms = 1e3 / os.sysconf("SC_CLK_TCK")
+        return {"iowait": int(f[5]) * tick_ms, "steal": int(f[8]) * tick_ms}
+
+    @staticmethod
+    def _rusage():
+        import resource
+
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        return {"nvcsw": ru.ru_nvcsw, "nivcsw": ru.ru_nivcsw}
+
+    def since(self, now_ns) -> dict:
+        """The record's evidence fields: each counter's growth since the
+        baseline (``*_ms``, the two switch counts, ``gc_n``), the longest
+        collector pause in that time, and the allocator's fields now and
+        (``*_before``) at the baseline."""
+        base, now = self._base, self._read()
+        out = {"evidence_ms": round((now_ns - self._t_ns) / 1e6, 3)}
+        for key, value in now.items():
+            if key == "alloc" or key not in base:
+                continue
+            name = key if key in ("nvcsw", "nivcsw", "gc_n") else key + "_ms"
+            out[name] = round(value - base[key], 3)
+        out["gc_max_ms"] = round(max(
+            (ns for end, ns in list(_gc_pauses) if end >= self._t_ns),
+            default=0) / 1e6, 3)
+        out.update(now.get("alloc", {}))
+        out.update({k + "_before": v
+                    for k, v in base.get("alloc", {}).items()})
+        return out
+
+
+def held_by(phase, held_ms, ev) -> str:
+    """Whose time a held phase was, from the evidence of its pass. In
+    this order, "most" meaning half of ``held_ms`` or more:
+
+    - ``python``: this thread's own CPU time is most of it: its own
+      work, a tracer's, a collection it ran (``gc_ms`` says);
+    - ``interpreter``: the process's OTHER threads' CPU time is, and the
+      phase runs Python: someone else held the interpreter. Not asked of
+      ``runtime::launch`` / ``::compile`` or a ``*_fetch`` (there the
+      thread sits in the runtime with the interpreter released, and the
+      CPU of the runtime's own threads is part of the call), nor of a
+      train step's ``outside`` (the caller's code, which may do the same);
+    - ``host``: the machine did not run us: this thread was runnable and
+      not on a CPU (``run_delay_ms``), the hypervisor took the CPUs
+      (``steal_ms``, a sum over the CPUs, so divided by their number),
+      or tasks stalled on memory or I/O (the ``some`` totals; the CPU's
+      is recorded and not ruled on: ``run_delay_ms`` is this thread's
+      own share of it). Where the platform hides all of these (a
+      sandboxed kernel), no stall reads ``host``;
+    - otherwise by the phase: ``runtime`` for ``runtime::launch`` /
+      ``::compile`` (the executable's call blocked: a load, an
+      allocation, a full queue; a compile), ``device`` for a ``*_fetch``
+      (the program was still running), ``blocked`` for a host phase (a
+      lock, I/O, a stopped process; for ``outside``, whatever the
+      caller waited on: in a loop that fetches its loss, the device)."""
+    most = 0.5 * held_ms
+    fetch = phase.endswith(_FETCH_SUFFIX)
+    mine = ev.get("thread_cpu_ms", 0.0)
+    if mine >= most:
+        return "python"
+    if not (fetch or phase in _RUNTIME_PHASES or phase == _OUTSIDE) \
+            and ev.get("process_cpu_ms", 0.0) - mine >= most:
+        return "interpreter"
+    if max(ev.get("run_delay_ms", 0.0),
+           ev.get("steal_ms", 0.0) / (os.cpu_count() or 1),
+           ev.get("pressure_memory_ms", 0.0),
+           ev.get("pressure_io_ms", 0.0)) >= most:
+        return "host"
+    if phase in _RUNTIME_PHASES:
+        return "runtime"
+    return "device" if fetch else "blocked"
+
+
+def record_stall(kind, held, usual_ns, program, runs, idle_ns, evidence,
+                 **fields):
+    """Leave one stall record: the caller's own fields, then what held
+    the pass. ``held`` is ``(ns, start_ns, innermost phase)`` of its
+    longest single call or host phase, ``usual_ns`` what that phase
+    usually takes (None: nothing to compare with), ``program`` /
+    ``runs`` / ``idle_ns`` the program it belongs to, its runs before
+    this one and the time since the last began (None for a host phase
+    of no program). ``lost_ms`` is ``held_ms - usual_ms`` where the
+    phase took over a second and over :data:`STALL_FACTOR` times its
+    usual, else 0: the time to sum over records."""
+    ns, start_ns, phase = held
+    lost = (ns - usual_ns if usual_ns is not None and ns > STALL_NS
+            and ns > STALL_FACTOR * usual_ns else 0)
+    fields.update(
+        t_ns=int(start_ns), held_phase=phase, held_ms=round(ns / 1e6, 3),
+        usual_ms=None if usual_ns is None else round(usual_ns / 1e6, 3),
+        lost_ms=round(lost / 1e6, 3))
+    if program is not None:
+        fields.update(
+            program=program, program_runs=runs,
+            program_idle_s=None if idle_ns is None
+            else round(idle_ns / 1e9, 3))
+    try:
+        ev = evidence.since(time.perf_counter_ns())
+    except Exception:  # noqa: BLE001 — the loop must survive its record
+        ev = {}
+    fields.update(ev)
+    fields["held_by"] = held_by(phase, ns / 1e6, ev)
+    return record_event(kind, **fields)
+
+
+class StepWatch:
+    """A train step's always-on split of its calls, and its
+    ``train_stall`` record. The three ``__call__``s (``TrainStepFn``,
+    ``ShardedTrainStep``, ``LocalSGDTrainStep``) bracket themselves with
+    :meth:`enter` / :meth:`phase` / :meth:`leave`; ``nested`` is the
+    list the compiled-callable runtime times its lookup and launch into.
+    A pass is one call-to-call interval, return to return: ``outside``
+    (the caller's own time since the last return: its fetch, its data,
+    its checkpoint), then the call's phases.
+
+    An interval leaves a record when it passes :data:`STALL_NS` AND
+    stands :data:`STALL_FACTOR` times clear of the intervals before it:
+    of the longest of the last 32 under a second, and of the median of
+    the last 8 over a second that left none. So a loop that fetches its
+    loss every tenth step (nine enqueues of milliseconds, then most of
+    a second) leaves nothing, however long that tenth interval is, once
+    one like it has been seen; the first of them, with only short
+    intervals before it, leaves one record if it passes the second. The
+    first call (a compile) closes no interval, and an interval with
+    none kept before it leaves no record. ``usual_ms`` of the held phase follows the same rule over the
+    kept intervals' own values of that phase."""
+
+    def __init__(self, program, device=None):
+        self.program = program
+        self.runs = 0
+        self.nested = None
+        self._evidence = Evidence(
+            None if device is None else lambda: allocator_stats(device))
+        self._t_leave = self._t_enter = self._t_before = 0
+        self._phases = None
+        self._short = collections.deque(maxlen=_RING)
+        self._long = collections.deque(maxlen=8)
+
+    def enter(self):
+        """The call begins: ``outside`` ends. Returns the instant."""
+        now = time.perf_counter_ns()
+        self._t_before, self._t_enter = self._t_enter, now
+        self.nested = []
+        self._phases = []
+        if self._t_leave:
+            self._phases.append((self._t_leave, _OUTSIDE,
+                                 now - self._t_leave, None))
+        return now
+
+    def phase(self, name, t0_ns, nested=None):
+        """Close the phase ``name`` begun at ``t0_ns``: a span while the
+        profiler is on (one clock read serves both), an entry of the
+        split always. Returns the instant."""
+        now = time.perf_counter_ns()
+        _add_span(name, t0_ns, now)
+        self._phases.append((t0_ns, name, now - t0_ns, nested))
+        return now
+
+    def leave(self):
+        """The call returns: the interval closes, and is judged if it
+        passed the second."""
+        now = time.perf_counter_ns()
+        start, self._t_leave = self._t_leave, now
+        self.runs += 1
+        if start:  # the first call's compile is no interval
+            pass_ = (now - start, self._phases)
+            if pass_[0] > STALL_NS:
+                self._judge(pass_)
+            else:
+                self._short.append(pass_)
+        self._evidence.refresh(now)
+
+    def _usual(self, took):
+        """The rule's "usual" of a quantity ``took(pass)`` over the kept
+        intervals; None with nothing kept."""
+        usual = max((took(p) for p in self._short), default=0)
+        if self._long:
+            usual = max(usual, statistics.median(
+                took(p) for p in self._long))
+        return usual or None
+
+    def _judge(self, pass_):
+        interval, phases = pass_
+        usual = self._usual(lambda p: p[0])
+        if usual is None:
+            return  # nothing before it to stand clear of
+        if interval <= STALL_FACTOR * usual:
+            self._long.append(pass_)
+            return
+        (ns, t_ns, name, _, _), nested_ns = held_among(
+            (entry, None) for entry in phases)
+        parts = {}
+        for entry in phases:
+            parts[entry[1]] = parts.get(entry[1], 0) + entry[2]
+        parts["other"] = interval - sum(parts.values())
+        record_stall(
+            "train_stall", (ns, t_ns, name),
+            self._usual(lambda p: sum(
+                took for entry in p[1]
+                for took, _, inner in _innermost(entry) if inner == name)),
+            self.program, self.runs - 1,
+            self._t_enter - self._t_before if self._t_before else None,
+            self._evidence,
+            interval_ms=round(interval / 1e6, 3),
+            phases_ms={k: round(v / 1e6, 3) for k, v in parts.items()},
+            nested_ms={k: round(v / 1e6, 3) for k, v in nested_ns.items()})
 
 
 # -- progress clock / hang watchdog ------------------------------------------

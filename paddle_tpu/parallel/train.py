@@ -25,6 +25,8 @@ base/strategy_compiler.py; here the strategy configures the step builder):
 """
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
@@ -32,6 +34,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..framework import jit as fjit
 from ..framework.random import default_generator
 from ..framework.tensor import Tensor
+from ..monitor import flight_recorder as _flight
 from ..monitor import registry as _mon
 from ..profiler import RecordEvent
 from .mesh import mesh_scope
@@ -211,23 +214,26 @@ class ShardedTrainStep(fjit.TrainStepFn):
                 donate_argnums=(0,) if donate else (),
             )
         self._rng = default_generator().split()
+        self._stall = _flight.StepWatch("train_step", mesh.devices.flat[0])
 
     def __call__(self, *batch):
+        watch = self._stall
+        t0 = watch.enter()
         with RecordEvent("train::step"), mesh_scope(self.mesh):
-            with RecordEvent("train::shard_batch"):  # H2D + layout
-                arrs = tuple(
-                    b._array if isinstance(b, Tensor) else jnp.asarray(b)
-                    for b in batch
-                )
-                shardings = shard_batch(arrs, self.mesh, self.batch_axes)
-                arrs = jax.tree_util.tree_map(
-                    jax.device_put, arrs, shardings)
+            arrs = tuple(  # H2D + layout
+                b._array if isinstance(b, Tensor) else jnp.asarray(b)
+                for b in batch
+            )
+            shardings = shard_batch(arrs, self.mesh, self.batch_axes)
+            arrs = jax.tree_util.tree_map(jax.device_put, arrs, shardings)
+            watch.phase("train::shard_batch", t0)
             lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
             self._rng, sub = jax.random.split(self._rng)
-            with RecordEvent("train::step_dispatch"):
-                self.state, metrics = self.compiled(
-                    self.state, arrs, lr, sub)
+            t0 = time.perf_counter_ns()
+            self.state, metrics = self.compiled(self.state, arrs, lr, sub)
+            watch.phase("train::step_dispatch", t0)
             _mon.counter("train/sharded_steps").inc()
+        watch.leave()
         return metrics
 
 
@@ -370,24 +376,28 @@ class LocalSGDTrainStep:
             self._sharded, donate_argnums=(0,) if donate else ()
         )
         self._rng = default_generator().split()
+        self._stall = _flight.StepWatch("train_step", mesh.devices.flat[0])
 
     def __call__(self, *batch):
+        watch = self._stall
+        t0 = watch.enter()
         with RecordEvent("train::step"), mesh_scope(self.mesh):
-            with RecordEvent("train::shard_batch"):
-                arrs = tuple(
-                    b._array if isinstance(b, Tensor) else jnp.asarray(b)
-                    for b in batch
-                )
-                shardings = shard_batch(arrs, self.mesh, ("dp",))
-                arrs = jax.tree_util.tree_map(
-                    jax.device_put, arrs, shardings)
+            arrs = tuple(
+                b._array if isinstance(b, Tensor) else jnp.asarray(b)
+                for b in batch
+            )
+            shardings = shard_batch(arrs, self.mesh, ("dp",))
+            arrs = jax.tree_util.tree_map(jax.device_put, arrs, shardings)
+            watch.phase("train::shard_batch", t0)
             lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
             self._rng, sub = jax.random.split(self._rng)
-            with RecordEvent("train::step_dispatch"):
-                self.state, self._count, metrics = self.compiled(
-                    self.state, self._count, arrs, lr, sub
-                )
+            t0 = time.perf_counter_ns()
+            self.state, self._count, metrics = self.compiled(
+                self.state, self._count, arrs, lr, sub
+            )
+            watch.phase("train::step_dispatch", t0)
             _mon.counter("train/localsgd_steps").inc()
+        watch.leave()
         return metrics
 
     def sync(self, gather=True):

@@ -156,6 +156,29 @@ def add_span(name, t0_ns, t1_ns):
         _emit_span(name, t0_ns / 1e3, (t1_ns - t0_ns) / 1e3)
 
 
+class timed_span:
+    """One phase of a call, timed whatever the profiler's state: two
+    ``perf_counter_ns`` reads that make the span (while the profiler is
+    on) and an entry ``(name, start_ns, ns)`` of ``into``, the caller's
+    always-on list of its call's innermost phases (where it keeps one:
+    the stall records of ``monitor/flight_recorder.py``)."""
+
+    __slots__ = ("name", "into", "t0")
+
+    def __init__(self, name, into=None):
+        self.name, self.into = name, into
+
+    def __enter__(self):
+        self.t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter_ns()
+        add_span(self.name, self.t0, t1)
+        if self.into is not None:
+            self.into.append((self.name, self.t0, t1 - self.t0))
+        return False
+
+
 def record_counter(name, value):
     """Append one timestamped sample of a quantity the caller owns (live
     slots, queue depth) to the timeline: a chrome counter event

@@ -47,7 +47,7 @@ from concurrent.futures import Future
 import jax
 
 from ..flags import flag
-from ..profiler import RecordEvent, bump_counter
+from ..profiler import bump_counter, timed_span
 
 __all__ = ["CompiledEntry", "CompiledStore", "CompileWatch",
            "any_deleted", "cache_capacity"]
@@ -202,7 +202,7 @@ class CompiledStore:
         h = hashlib.sha1(repr(ident).encode()).hexdigest()[:10]
         return f"{self.label}#{h}"
 
-    def get_or_build(self, sig, build):
+    def get_or_build(self, sig, build, nested=None):
         """Look up (or build) the entry for ``sig``.
 
         ``build()`` -> ``(jitted_callable, meta)`` runs under the store
@@ -220,9 +220,11 @@ class CompiledStore:
         tuned kernel are immune (no fleet-wide recompile waves).
 
         The lookup is the ``runtime::lookup`` span, nested inside
-        whatever span the dispatch site holds.
+        whatever span the dispatch site holds; its two clock reads also
+        go to ``nested``, the caller's always-on list of the innermost
+        phases of its call (:class:`profiler.timed_span`).
         """
-        with RecordEvent("runtime::lookup"), self._lock:
+        with timed_span("runtime::lookup", nested), self._lock:
             entry = self._entries.get(sig)
             refresh_gen = 0
             if entry is not None and _schedules_stale(entry):
@@ -371,7 +373,8 @@ class CompiledStore:
             raise
         return done
 
-    def dispatch(self, entry, *args, donated=(), capture_meta=None):
+    def dispatch(self, entry, *args, donated=(), capture_meta=None,
+                 nested=None):
         """Run one compiled call through the shared discipline.
 
         ``donated`` names the arrays whose buffers the call may consume
@@ -379,16 +382,19 @@ class CompiledStore:
         the demote-to-jit retry is forbidden once any is consumed.
         Annotates the current trace span with the entry's ``cache_key``
         (+ FLOPs when captured) and feeds the executed-work ledger.
+        ``nested`` as in :meth:`get_or_build`: the launch, and a first
+        dispatch's compile (or its wait for one), are timed into it.
         """
         from ..monitor import cost_model as _cost
         from ..monitor import tracing as _tracing
 
         if not entry.attempted:
-            self._aot_compile(entry, args, capture_meta)
+            with timed_span("runtime::compile", nested):
+                self._aot_compile(entry, args, capture_meta)
         runner = entry.aot if entry.aot is not None else entry.jitted
         try:
             # the enqueue alone: returns before the device finishes
-            with RecordEvent("runtime::launch"):
+            with timed_span("runtime::launch", nested):
                 out = runner(*args)
         except (TypeError, ValueError):
             # what a Compiled raises for avals / shardings it was not

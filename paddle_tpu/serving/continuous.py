@@ -57,9 +57,10 @@ from .batcher import (
 __all__ = ["ContinuousBatcher", "GenerationRequest"]
 
 # One loop iteration (pick through deliver, idle wait excluded) longer
-# than this leaves a ``generation_stall`` flight event with its split.
-# A constant, not a flag: a decode step is tens of milliseconds.
-_STALL_NS = 1_000_000_000
+# than this leaves a ``generation_stall`` flight event with its split
+# and what held it. The one constant both hot loops share (the factor a
+# held call must stand over its usual to have lost time is beside it).
+_STALL_NS = _flight.STALL_NS
 
 
 class GenerationRequest:
@@ -175,6 +176,11 @@ class ContinuousBatcher:
         # the ns each phase took in this iteration
         self._t_ns = self._t_iter_ns = 0
         self._split = {}
+        # the loop's own phases as single instances (the engine keeps its
+        # calls' by program), and the evidence of whose time a stall was:
+        # made by the loop thread, whose counters it reads
+        self._host = _flight.PhaseRing("serving", 128)
+        self._evidence = None
         # the decode step that is enqueued and not fetched (the loop
         # thread's own), and when the last step's tokens were delivered
         self._flight = None
@@ -416,6 +422,7 @@ class ContinuousBatcher:
         now = time.perf_counter_ns()
         _profiler.add_span(name, self._t_ns, now)
         self._split[name] = self._split.get(name, 0) + now - self._t_ns
+        self._host.note(name, self._t_ns, now - self._t_ns)
         self._t_ns = now
 
     def _pick(self):
@@ -599,27 +606,32 @@ class ContinuousBatcher:
     def _end_iteration(self):
         """Close one pass of the loop, and leave a ``generation_stall``
         flight event if the pass (idle wait excluded) stood still for
-        over a second — which phase held it (the engine added its own
-        to the split), and what the device's allocator looked like."""
+        over a second: its split by phase (the engine added its own),
+        and for its longest single call or host phase what held it (the
+        program, the innermost phase, its usual time, whose time it
+        was: ``flight_recorder.record_stall``)."""
         split = self._split
-        total = (self._t_ns - self._t_iter_ns
-                 - split.pop("serving::idle_wait", 0))
+        began = self._t_iter_ns
+        total = self._t_ns - began - split.pop("serving::idle_wait", 0)
         self._t_iter_ns = self._t_ns
         if total > _STALL_NS:
             # what lies between the phases: the engine's host-side
             # preparation around its spans
             split["other"] = total - sum(split.values())
-            try:
-                # asked of a device that may be the one in trouble: the
-                # record goes out without the allocator's fields then
-                memory = self.engine.device_memory_stats()
-            except Exception:  # noqa: BLE001 — the loop must survive
-                memory = {}
-            _flight.record_event(
-                "generation_stall", iteration_ms=round(total / 1e6, 3),
+            rings = [self._host] + self.engine.program_rings()
+            (ns, start, phase, ring, entry), nested = _flight.held_among(
+                (entry, ring) for ring in rings for entry in ring.ring
+                if entry[0] >= began)
+            runs, idle = ring.before(entry)
+            _flight.record_stall(
+                "generation_stall", (ns, start, phase),
+                ring.usual_ns(entry[1], phase, but=entry),
+                None if ring is self._host else ring.name, runs, idle,
+                self._evidence, iteration_ms=round(total / 1e6, 3),
                 phases_ms={k: round(v / 1e6, 3) for k, v in split.items()},
-                live_slots=self.live_slots, queue_depth=len(self._q),
-                **memory)
+                nested_ms={k: round(v / 1e6, 3) for k, v in nested.items()},
+                live_slots=self.live_slots, queue_depth=len(self._q))
+        self._evidence.refresh(self._t_ns)
         split.clear()
 
     def _seated(self, flight):
@@ -725,7 +737,12 @@ class ContinuousBatcher:
         # was: the step it enqueues is the step it fetches.
         engine = self.engine
         engine.phase_split = self._split
+        # asked of a device that may be the one in trouble: a read that
+        # fails leaves the allocator's fields out of the record
+        self._evidence = _flight.Evidence(
+            lambda: engine.device_memory_stats())
         self._t_ns = self._t_iter_ns = time.perf_counter_ns()
+        self._evidence.refresh(self._t_ns)
         while True:
             admitted = self._admit_ready()
             depth = engine.steps_ahead

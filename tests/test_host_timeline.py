@@ -25,7 +25,7 @@ BUCKETS = (4, 8)
 # the disjoint siblings that partition the loop thread's time
 TOP = ("serving::pick", "generation::prefill", "generation::prefill_fetch",
        "serving::install", "generation::decode", "generation::decode_fetch",
-       "serving::deliver", "serving::idle_wait")
+       "serving::deliver", "serving::idle_wait", "generation::stats_fetch")
 NESTED = ("generation::args", "runtime::lookup", "runtime::launch")
 
 
@@ -100,13 +100,34 @@ def _slow_first_decode(eng, monkeypatch, seconds):
     monkeypatch.setattr(eng, "_dispatch", dispatch)
 
 
-@pytest.mark.parametrize("layout", ["ring", "paged"])
+def _kinds_model():
+    """A toy hybrid MoE decoder: its programs return routing statistics,
+    which the engine fetches (``generation::stats_fetch``) while the
+    profiler is on."""
+    from paddle_tpu.models import HybridMoEConfig, HybridMoEForCausalLM
+
+    paddle.seed(5)
+    m = HybridMoEForCausalLM(HybridMoEConfig(
+        vocab_size=97, vocab_held=64, hidden_size=32, num_hidden_layers=4,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        gqa_layers=(0,), linear_attn_config=dict(
+            short_conv_kernel_size=4, head_dim=8, num_heads=4),
+        kda_gate_rank=8, moe_intermediate_size=16, n_routed_experts=16,
+        num_experts_per_tok=4, experts_held=(4, 8)))
+    m.eval()
+    return m
+
+
+@pytest.mark.parametrize("layout", ["ring", "paged", "kinds"])
 def test_loop_phases_partition_the_loop_threads_time(model, spans_on, layout):
-    kw = {} if layout == "ring" else dict(kv_cache_layout="paged",
-                                          kv_page_size=8)
-    spans = _serve(_engine(model, **kw))
+    kw = dict(kv_cache_layout="paged", kv_page_size=8) \
+        if layout == "paged" else {}
+    spans = _serve(_engine(_kinds_model() if layout == "kinds" else model,
+                           **kw))
     top = [s for s in spans if s[2] in TOP]
-    assert {s[2] for s in top} == set(TOP)
+    # only a model with per-layer kinds has statistics to fetch
+    assert {s[2] for s in top} == set(TOP) - (
+        set() if layout == "kinds" else {"generation::stats_fetch"})
     for a, b in zip(top, top[1:]):
         assert b[0] >= a[1] - 1e-3, (a, b)  # siblings never overlap (us)
     covered = sum(e - s for s, e, _ in top)
