@@ -215,6 +215,11 @@ class KVKind(NamedTuple):
         return int(store) if self.window is None \
             else min(self.window, int(store))
 
+    def rows_fetched(self, live, store, dtype):
+        """Ring rows a decode step's attention brings from HBM a slot,
+        for ``live [S]`` live rows a slot: the ring is read whole."""
+        return np.full_like(live, self.ring(store))
+
     def arrays(self, batch, store, dtype):
         shape = (int(batch), self.heads, self.ring(store), self.head_dim)
         return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
@@ -271,6 +276,20 @@ class LatentKind(NamedTuple):
 
     def ring(self, store):
         return int(store)
+
+    def rows_fetched(self, live, store, dtype):
+        """Ring rows a decode step's attention brings from HBM a slot,
+        for ``live [S]`` live rows a slot: whole key blocks of the live
+        rows where the absorbed step is the decode kernel
+        (``nn.mla.decode_key_block``), the ring whole where XLA reads
+        it."""
+        from ..nn.mla import decode_key_block
+        from ..ops.pallas.mla_decode import rows_fetched
+
+        block = decode_key_block(
+            (len(live), int(store), self.rank + self.rope), dtype)
+        return np.full_like(live, int(store)) if block is None \
+            else rows_fetched(live, block)
 
     def arrays(self, batch, store, dtype):
         return (jnp.zeros((int(batch), int(store), self.rank + self.rope),

@@ -160,15 +160,16 @@ class _KeptState:
 class DecodeStep:
     """One enqueued decode step (:meth:`GenerationEngine.enqueue_step`):
     its ``[S]`` tokens and routing statistics, still on the device, the
-    ring rows it read, counted on the host as it was enqueued (only
-    while the profiler is on), and the program that computes it (its
-    :class:`flight_recorder.PhaseRing`: the fetch is noted there)."""
+    ring rows it had to read and those it brought from HBM, counted on
+    the host as it was enqueued (only while the profiler is on), and the
+    program that computes it (its :class:`flight_recorder.PhaseRing`:
+    the fetch is noted there)."""
 
-    __slots__ = ("tokens", "stats", "rows_read", "program")
+    __slots__ = ("tokens", "stats", "rows_read", "rows_fetched", "program")
 
-    def __init__(self, tokens, stats, rows_read, program):
+    def __init__(self, tokens, stats, rows_read, rows_fetched, program):
         self.tokens, self.stats, self.rows_read = tokens, stats, rows_read
-        self.program = program
+        self.rows_fetched, self.program = rows_fetched, program
 
 
 class GenerationEngine:
@@ -1316,7 +1317,8 @@ class GenerationEngine:
             self._sample_stats(stats)
         return tok
 
-    def _sample_stats(self, stats, rows_read=None, program=None):
+    def _sample_stats(self, stats, rows_read=None, rows_fetched=None,
+                      program=None):
         """While the profiler is on, fetch the routing statistics a
         program returned and put them on its timeline as counter
         samples: ``moe::expert_load`` (per held expert, prompt and
@@ -1324,9 +1326,10 @@ class GenerationEngine:
         ``moe::experts_hit`` (one value an expert layer; where the model
         has zero-compute experts, ``moe::zero_pairs`` beside them) with
         ``generation::state_bytes`` (what the state layers' leaves
-        hold, of :meth:`cache_nbytes`) and ``generation::kv_rows_read``
-        (``rows_read``: :meth:`kv_rows_read` as the step was enqueued;
-        ``None`` for a prompt). The whole of it is the span
+        hold, of :meth:`cache_nbytes`), ``generation::kv_rows_read`` and
+        ``generation::kv_rows_fetched`` (``rows_read``, ``rows_fetched``:
+        :meth:`kv_rows_read` and :meth:`kv_rows_fetched` as the step was
+        enqueued; ``None`` for a prompt). The whole of it is the span
         ``generation::stats_fetch``. Off, the arrays are dropped where
         they lie: no transfer, one boolean."""
         if not _profiler_enabled():
@@ -1345,6 +1348,8 @@ class GenerationEngine:
         if not prefill:
             _record_counter("generation::state_bytes", self.state_nbytes())
             _record_counter("generation::kv_rows_read", list(rows_read))
+            _record_counter("generation::kv_rows_fetched",
+                            list(rows_fetched))
         # a sibling of the fetch spans: the transfer is the loop
         # thread's time (3-4 ms an iteration on the chip), and only
         # spent while the profiler is on
@@ -1387,6 +1392,21 @@ class GenerationEngine:
         slot and layer, the row the step writes included. A vacant slot
         counts with the position it was left at: the step computes it
         too."""
+        return self._ring_rows(lambda kind, live: live)
+
+    def kv_rows_fetched(self):
+        """:meth:`kv_rows_read`'s three places, holding the ring rows
+        that step's attention brings from HBM
+        (``kind.rows_fetched``): the whole ring a slot and layer where
+        XLA reads it, the live rows rounded up to whole key blocks where
+        the latent decode kernel runs."""
+        return self._ring_rows(lambda kind, live: kind.rows_fetched(
+            live, self.store_len, self.kv_cache_dtype))
+
+    def _ring_rows(self, rows):
+        """``rows(kind, live [S])`` summed over slots and ring layers by
+        :meth:`_kind_place`, ``live`` the rows of a layer's ring that
+        the host's copy of ``pos`` makes live a slot."""
         out = [0, 0, 0, 0]
         with self._key_lock:
             pos = self._pos_host.copy()
@@ -1394,7 +1414,7 @@ class GenerationEngine:
             ring = kind.ring(self.store_len)
             if ring is not None:
                 out[self._kind_place(kind)] += int(
-                    np.minimum(pos + 1, ring).sum())
+                    rows(kind, np.minimum(pos + 1, ring)).sum())
         return out[0], out[1], out[3]
 
     # Each ring program's (label, jitted, make_args): what its entry
@@ -1983,11 +2003,12 @@ class GenerationEngine:
         t0 = time.perf_counter_ns()
         with RecordEvent("generation::decode"):
             out = self._dispatch(*self._decode_call(tokens, temps, ctr))
-        stats = rows_read = None
+        stats = rows_read = rows_fetched = None
         if self._kinds is not None:
             self._kv, nxt, stats = out
             if _profiler_enabled():
                 rows_read = self.kv_rows_read()  # before pos moves on
+                rows_fetched = self.kv_rows_fetched()
             with self._key_lock:
                 self._pos_host += 1
         else:
@@ -1998,7 +2019,8 @@ class GenerationEngine:
                     self._pos_host[s] += 1
         self._note_phase("generation::decode", t0,
                          time.perf_counter_ns() - t0)
-        return DecodeStep(nxt, stats, rows_read, self._program)
+        return DecodeStep(nxt, stats, rows_read, rows_fetched,
+                          self._program)
 
     def fetch_step(self, step) -> np.ndarray:
         """The second half of :meth:`step`: the tokens of an enqueued
@@ -2008,7 +2030,8 @@ class GenerationEngine:
         nxt = self._fetched("generation::decode", None, step.tokens,
                             np.asarray, step.program)
         if self._kinds is not None:
-            self._sample_stats(step.stats, step.rows_read, step.program)
+            self._sample_stats(step.stats, step.rows_read,
+                               step.rows_fetched, step.program)
         return nxt
 
     def step(self, tokens, temps) -> np.ndarray:

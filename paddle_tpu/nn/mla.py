@@ -27,7 +27,12 @@ axis), which is what makes a long ring cheap.
   rope`` and a group of all the heads, by :func:`nn.gqa.attend_keys`),
   so a step reads a ring row once for all heads. The same numbers as
   the expanded path (tests/test_longcat_flash.py); ``W_kvb`` is one
-  leaf and the absorbed path takes views of it.
+  leaf and the absorbed path takes views of it. On a TPU the scores,
+  the softmax and the sum are ONE Mosaic kernel over each slot's live
+  rows (:mod:`ops.pallas.mla_decode`, where :func:`decode_key_block`
+  says so: XLA's path reads every ring whole, twice, whatever is
+  live); profiler counters ``mla::absorbed_kernel`` /
+  ``mla::absorbed_xla`` count the attentions traced each way.
 
 Softmax, norm statistics and rotary angles are float32.
 """
@@ -37,12 +42,27 @@ import jax
 import jax.numpy as jnp
 
 from ..framework.tensor import Parameter
+from ..ops.pallas._platform import can_emit_mosaic
+from ..ops.pallas.mla_decode import (key_block, mla_decode,
+                                     mla_decode_supported)
+from ..profiler import bump_counter
 from .gqa import apply_rotary, attend_causal_blocks, attend_keys, rms_norm
 from .layer_base import Layer
 from .linear_attention import normal_or_zeros
 from .transformer import LatentCache, _write_rows, update_slice_in_range
 
-__all__ = ["CachedLatentAttention"]
+__all__ = ["CachedLatentAttention", "decode_key_block"]
+
+
+def decode_key_block(ring_shape, dtype):
+    """Keys a block of the decode kernel where an absorbed step over a
+    ``[B, ring, rank + rope]`` ring of ``dtype`` takes it here and now
+    (a TPU, no multi-device mesh in scope, a ring the kernel supports);
+    ``None`` where XLA's path runs. The layer and the engine's
+    ``generation::kv_rows_fetched`` both ask here."""
+    if can_emit_mosaic() and mla_decode_supported(ring_shape, dtype):
+        return key_block(ring_shape[1])
+    return None
 
 
 class CachedLatentAttention(Layer):
@@ -114,10 +134,13 @@ class CachedLatentAttention(Layer):
             self.prefill_block, self.key_chunk)       # [B, H, 1, T, v]
         return o[:, :, 0].transpose(0, 2, 1, 3).reshape(b, t, n * self.v_dim)
 
-    def absorbed(self, q_nope, q_rot, ring, mask):
+    def absorbed(self, q_nope, q_rot, ring, mask, pos=None):
         """One query a slot against the ring rows as they lie, ``ring
         [B, C, rank + rope]`` under the additive decode mask ``[B, 1, 1,
-        C]``: ``[B, 1, H * v]``."""
+        C]``: ``[B, 1, H * v]``. With the step's ``pos [B]`` (the mask
+        is then ``decode_mask(pos, C)``: rows below ``min(pos + 1, C)``)
+        and where :func:`decode_key_block` allows, the attention is the
+        Mosaic kernel over the live rows and the mask is not read."""
         b, n = ring.shape[0], self.num_heads
         w = self._kvb()
         q_lat = jnp.einsum("bthd,rhd->bthr", q_nope, w[..., :self.nope])
@@ -126,11 +149,19 @@ class CachedLatentAttention(Layer):
         # and the rotated channels dropped after, because a slice of the
         # ring as an operand is a copy of the ring a step (0.27 GB an
         # attention at 32 x 8,192 rows: my AOT compile, PR 36)
-        o_lat = attend_keys(
-            q[:, None], ring[:, None], ring[:, None], mask[:, :, None],
-            self.softmax_scale, self.key_chunk)  # [B, 1, H, 1, rank + rope]
-        o = jnp.einsum("bhr,rhd->bhd", o_lat[:, 0, :, 0, :self.rank],
-                       w[..., self.nope:])
+        block = None if pos is None \
+            else decode_key_block(ring.shape, ring.dtype)
+        if block is not None:
+            bump_counter("mla::absorbed_kernel")
+            o_lat = mla_decode(
+                q[:, :, 0], jnp.swapaxes(ring, 1, 2), pos + 1,
+                self.softmax_scale, block)[..., :self.rank]
+        else:
+            bump_counter("mla::absorbed_xla")
+            o_lat = attend_keys(
+                q[:, None], ring[:, None], ring[:, None], mask[:, :, None],
+                self.softmax_scale, self.key_chunk)[:, 0, :, 0, :self.rank]
+        o = jnp.einsum("bhr,rhd->bhd", o_lat, w[..., self.nope:])
         return o.reshape(b, 1, n * self.v_dim)
 
     def forward(self, x, cache=None, mask=None, positions=None):
@@ -154,7 +185,7 @@ class CachedLatentAttention(Layer):
                 cache = LatentCache(ring, pos)
                 if isinstance(mask, dict):
                     mask = mask[ring.shape[1]]
-                o = self.absorbed(q_nope, q_rot, ring, mask)
+                o = self.absorbed(q_nope, q_rot, ring, mask, pos)
         else:
             with jax.named_scope("mla_expand"):
                 o = self.expanded(q_nope, q_rot, row, mask)

@@ -322,6 +322,103 @@ def test_counters_are_sampled_only_while_the_profiler_is_on(model):
     assert len(got["moe::expert_load"]) == 2  # the prompt's and the step's
 
 
+# -- the decode kernel in the model's absorbed step ---------------------------
+
+def _with_the_kernel(monkeypatch, block=8):
+    """The absorbed step as a TPU traces it: the Mosaic kernel
+    (interpret mode here), ``block`` keys a block so that the toy ring
+    of 32 rows is four blocks."""
+    from paddle_tpu.nn import mla
+
+    monkeypatch.setattr(mla, "decode_key_block", lambda shape, dtype: block)
+
+
+def _greedy(eng, prompts, steps, watch=None):
+    """Admit ``prompts`` into slots 0.. and take ``steps`` greedy decode
+    steps; ``watch(eng)`` is called before each."""
+    toks = np.asarray([eng.admit(i, p) for i, p in enumerate(prompts)],
+                      np.int32)
+    out = [toks]
+    for _ in range(steps):
+        if watch is not None:
+            watch(eng)
+        toks = eng.step(toks, np.zeros(len(toks), np.float32))
+        out.append(toks)
+    return np.stack(out)
+
+
+def test_greedy_tokens_are_the_same_with_the_kernel_and_without(
+        model, monkeypatch):
+    """A prompt a slot (5 and 13 tokens) and 2 x ring decode steps: the
+    rings fill, wrap and are overwritten once more; the tokens with the
+    kernel forced are XLA's path's, and every attention of the decode
+    program was traced the way the engine says."""
+    from paddle_tpu import profiler
+
+    m, _ = model
+    prompts = [_tokens(5, seed=1).tolist(), _tokens(13, seed=2).tolist()]
+    layers = len(m.layers)
+
+    def traced():
+        before = profiler.counters()
+        eng = _engine(m)
+        out = _greedy(eng, prompts, 2 * CACHE_LEN)
+        after = profiler.counters()
+        return out, [after.get(k, 0) - before.get(k, 0) for k in
+                     ("mla::absorbed_kernel", "mla::absorbed_xla")]
+
+    want, counts = traced()
+    assert counts == [0, 2 * layers]
+    _with_the_kernel(monkeypatch)
+    got, counts = traced()
+    assert counts == [2 * layers, 0]
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("kernel", [False, True])
+def test_rows_fetched_are_counted_beside_the_rows_read(
+        model, monkeypatch, kernel):
+    """`generation::kv_rows_fetched` at every step of a run through the
+    wrap: never under `kv_rows_read`; the whole rings on XLA's path
+    (slots x ring x attentions), the live rows rounded up to blocks of 8
+    with the kernel, so under a block a slot and attention over."""
+    from paddle_tpu import profiler
+
+    m, _ = model
+    if kernel:
+        _with_the_kernel(monkeypatch)
+    eng = _engine(m)
+    attentions, slots = 2 * len(m.layers), 2
+    seen = []
+    _greedy(eng, [_tokens(5, seed=1).tolist(), _tokens(13, seed=2).tolist()],
+            CACHE_LEN + 4,
+            watch=lambda e: seen.append((e.kv_rows_read(),
+                                         e.kv_rows_fetched())))
+    for read, fetched in seen:
+        assert fetched[:2] == read[:2] == (0, 0)
+        assert read[2] <= fetched[2] <= slots * CACHE_LEN * attentions
+        if kernel:
+            assert fetched[2] % (8 * attentions) == 0
+            assert fetched[2] - read[2] < 8 * slots * attentions
+        else:
+            assert fetched[2] == slots * CACHE_LEN * attentions
+    assert seen[0][0][2] == attentions * (6 + 14)
+    if kernel:
+        assert seen[0][1][2] == attentions * (8 + 16)
+    profiler.reset_profiler()
+    profiler.start_profiler(state="CPU")
+    try:
+        want = eng.kv_rows_read(), eng.kv_rows_fetched()
+        eng.step(np.zeros(2, np.int32), np.zeros(2, np.float32))
+        got = {ev["name"]: ev["args"]["value"]
+               for ev in profiler.counter_samples()}
+    finally:
+        profiler.stop_profiler()
+        profiler.reset_profiler()
+    assert got["generation::kv_rows_read"] == list(want[0])
+    assert got["generation::kv_rows_fetched"] == list(want[1])
+
+
 # -- the attention layer -------------------------------------------------------
 
 def _attention(**kw):

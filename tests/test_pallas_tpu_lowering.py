@@ -21,6 +21,7 @@ lnr = importlib.import_module("paddle_tpu.ops.pallas.layernorm_residual")
 cbr = importlib.import_module("paddle_tpu.ops.pallas.conv_bn_relu")
 fla = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 opu = importlib.import_module("paddle_tpu.ops.pallas.optimizer_update")
+mld = importlib.import_module("paddle_tpu.ops.pallas.mla_decode")
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 
@@ -246,6 +247,40 @@ def test_momentum_step_holds_the_kernel_for_dense_leaves_only(monkeypatch):
     rows = re.findall(r'kernel_name = "momentum_update".*?'
                       r"-> \(tensor<(\d+)x128xf32>", text)
     assert sorted(map(int, rows)) == [8, 8, 8, 64, 128]
+
+
+@pytest.mark.parametrize("slots,ring,dtype", [
+    (32, 8192, BF16),   # longcat-flash-omni.longreply-overload's rings
+    (1, 1024, F32),     # one slot, a float32 ring of two blocks
+])
+def test_mla_decode_through_the_absorbed_step(slots, ring, dtype,
+                                              monkeypatch):
+    """`CachedLatentAttention.absorbed` at the served widths (64 heads,
+    a 512 + 64 row) with the platform gate open: one `mla_decode` call
+    whose ring operand is the transposed view, lengths as the scalar
+    prefetch. (Off the TPU the kernel would run interpreted: the
+    module's own platform test is opened too.)"""
+    from paddle_tpu.nn import mla
+
+    monkeypatch.setattr(mla, "can_emit_mosaic", lambda: True)
+    monkeypatch.setattr(mld, "on_tpu_platform", lambda: True)
+    m = mla.CachedLatentAttention(
+        hidden_size=256, num_heads=64, q_rank=64, kv_rank=512, nope_dim=128,
+        rope_dim=64, v_dim=128, key_chunk=4096, initializer_range=None,
+        dtype=dtype)
+    text = _lower_for_tpu(
+        lambda qn, qr, c, mask, pos: m.absorbed(qn, qr, c, mask, pos),
+        _sds((slots, 1, 64, 128), dtype), _sds((slots, 1, 64, 64), dtype),
+        _sds((slots, ring, 576), dtype), _sds((slots, 1, 1, ring)),
+        _sds((slots,), jnp.int32))
+    assert _kernel_names(text) == ["mla_decode"]
+    operands = re.search(r'kernel_name = "mla_decode".*?: \((.*?)\) ->',
+                         text).group(1)
+    el = "bf16" if dtype == BF16 else "f32"
+    assert operands.split(", ") == [
+        f"tensor<{slots}xi32>", f"tensor<{slots}x64x576x{el}>",
+        f"tensor<{slots}x576x{ring}x{el}>"]
+    assert mld.key_block(ring) == 512
 
 
 def test_gate_closes_under_a_multi_device_mesh(monkeypatch):
