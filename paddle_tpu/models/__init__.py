@@ -55,6 +55,10 @@ from .longcat_flash import (  # noqa: F401
     LongcatFlashConfig,
     LongcatFlashForCausalLM,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig,
+    NemotronHForCausalLM,
+)
 from .se_resnext import (  # noqa: F401
     SEResNeXt,
     se_resnext50_32x4d,

@@ -190,30 +190,54 @@ MoELayer = SwitchFFN  # alias
 
 
 class RoutedExperts(Layer):
-    """Top-k routed gated-MLP experts plus a shared expert, as ONE member
-    of an expert-parallel group computes them.
+    """Top-k routed experts plus a shared expert, as ONE member of an
+    expert-parallel group computes them.
 
     The router scores ALL ``num_experts`` (``score``: ``sigmoid`` or
     ``softmax``), the ``top_k`` largest are chosen, and their weights are
     renormalised over the chosen (``norm_topk_prob``) and scaled by
     ``routed_scaling_factor``. Of the experts this layer holds
-    ``held = (first, count)``: its weights are three stacked leaves
+    ``held = (first, count)``: its weights are stacked leaves
     ``[count, ...]``. It computes its own experts' part of the result::
 
         y_here = sum_{i in top_k, i held} w_i E_i(x) + E_shared(x)
 
-    with ``w_i`` normalised over all the chosen, held or not, and
-    ``E(x) = W_down(SiLU(W_gate x) * W_up x)``. With every expert held
-    that is the whole layer; the shares of a group add up to it, the
-    shared expert counted once (tests/test_routed_experts.py). No token
-    is dropped and every shape is static: the token-expert pairs are
-    sorted by expert, those that fall elsewhere last, and the three
-    products run as grouped matrix products over the experts held
-    (``jax.lax.ragged_dot``: XLA:TPU's grouped kernel visits only the
-    row tiles of groups that have rows, so a decode step reads the
-    weights of the experts that were hit and no others). The same path
-    serves a prompt of thousands of tokens and a decode step of a few
-    dozen.
+    with ``w_i`` normalised over all the chosen, held or not. An expert
+    is, by ``activation``, a gated MLP or a plain one::
+
+        "swiglu":  E(x) = W_down(SiLU(W_gate x) * W_up x)
+        "relu2":   E(x) = W_down(relu(W_up x)^2)
+
+    and the shared expert is of the same form at ``shared_width``. With
+    ``latent_size`` the routed experts work in a narrower width than the
+    residual stream: the token's own chip projects ``l = W_ldown x``
+    once (``hidden -> latent_size``) before the pairs are sorted, every
+    routed expert maps ``latent_size -> expert_width -> latent_size``,
+    and the weighted sum of the held experts' results is projected back
+    once, ``W_lup (sum_i w_i E_i(l))``; the router and the shared expert
+    read the full-width ``x``. What an expert-parallel group exchanges
+    is then ``latent_size`` wide. With every expert held that is the
+    whole layer; the shares of a group add up to it, the shared expert
+    counted once (tests/test_routed_experts.py). No token is dropped and
+    every shape is static: the token-expert pairs are sorted by expert,
+    those that fall elsewhere last, and the products run as grouped
+    matrix products over the experts held (``jax.lax.ragged_dot``:
+    XLA:TPU's grouped kernel visits only the row tiles of groups that
+    have rows, so a decode step reads the weights of the experts that
+    were hit and no others). The same path serves a prompt of thousands
+    of tokens and a decode step of a few dozen.
+
+    The leaves, by name (a caller that hands weights over does so by
+    these names), with ``w`` = ``latent_size or hidden_size``:
+    ``router [hidden, num_experts + zero_experts]`` always;
+    ``select_bias [num_experts + zero_experts]`` with ``selection_bias``;
+    ``w_up [count, w, expert_width]`` and ``w_down [count, expert_width,
+    w]`` always, ``w_gate [count, w, expert_width]`` with ``"swiglu"``
+    only; ``latent_down [hidden, latent_size]`` and ``latent_up
+    [latent_size, hidden]`` with ``latent_size`` only; with
+    ``shared_width``: ``shared_up [hidden, shared_width]`` and
+    ``shared_down [shared_width, hidden]``, and ``shared_gate [hidden,
+    shared_width]`` with ``"swiglu"`` only.
 
     ``zero_experts``: the router scores that many outputs more, experts
     ``num_experts ..`` that have no weights: a chosen one returns the
@@ -237,6 +261,7 @@ class RoutedExperts(Layer):
                  held=None, shared_width=0, score="sigmoid",
                  norm_topk_prob=True, routed_scaling_factor=1.0,
                  zero_experts=0, selection_bias=False,
+                 activation="swiglu", latent_size=None,
                  initializer_range=0.02, dtype="float32"):
         super().__init__()
         from ..errors import InvalidArgumentError
@@ -250,6 +275,11 @@ class RoutedExperts(Layer):
                 f"{num_experts} experts")
         if score not in ("sigmoid", "softmax"):
             raise InvalidArgumentError(f"unknown router score {score!r}")
+        if activation not in ("swiglu", "relu2"):
+            raise InvalidArgumentError(
+                f"unknown expert activation {activation!r}")
+        self.gated = activation == "swiglu"
+        self.latent_size = None if latent_size is None else int(latent_size)
         self.num_experts, self.top_k = int(num_experts), int(top_k)
         self.first, self.count = first, count
         self.score, self.norm_topk_prob = score, bool(norm_topk_prob)
@@ -268,12 +298,18 @@ class RoutedExperts(Layer):
                           jnp.float32), name="select_bias")
         else:
             self.select_bias = None
-        param("w_gate", (count, h, f))
-        param("w_up", (count, h, f))
-        param("w_down", (count, f, h))
+        width = self.latent_size or h
+        if self.latent_size:
+            param("latent_down", (h, width))
+            param("latent_up", (width, h))
+        if self.gated:
+            param("w_gate", (count, width, f))
+        param("w_up", (count, width, f))
+        param("w_down", (count, f, width))
         self.shared_width = int(shared_width)
         if self.shared_width:
-            param("shared_gate", (h, self.shared_width))
+            if self.gated:
+                param("shared_gate", (h, self.shared_width))
             param("shared_up", (h, self.shared_width))
             param("shared_down", (self.shared_width, h))
         self.last_load = self.last_zero = None
@@ -297,6 +333,33 @@ class RoutedExperts(Layer):
             w = w / w.sum(-1, keepdims=True)
         return idx, w * self.routed_scaling_factor
 
+    def in_chunks(self, x, valid=None, chunk=1024):
+        """:meth:`forward` of ``x [B, T, hidden]``, a long sequence
+        ``chunk`` tokens at a time (one loop body, so the sorted pairs
+        and their hidden rows are one chunk's at the peak);
+        :attr:`last_load` and :attr:`last_zero` are then the chunks'
+        sums. A sequence of at most one chunk, or of no whole number of
+        them, goes through whole."""
+        b, t, h = x.shape
+        if t <= chunk or t % chunk:
+            return self(x, valid=valid)
+        if valid is None:
+            valid = jnp.ones((b, t), bool)
+
+        def one(c):
+            y = self(c[0], valid=c[1])
+            return y, self.last_load, (
+                self.last_zero if self.zero_experts
+                else jnp.zeros((), jnp.int32))
+
+        out, loads, zeros = jax.lax.map(
+            one, (x.reshape(b, -1, chunk, h).swapaxes(0, 1),
+                  valid.reshape(b, -1, chunk).swapaxes(0, 1)))
+        self.last_load = loads.sum(0)
+        if self.zero_experts:
+            self.last_zero = zeros.sum(0)
+        return out.swapaxes(0, 1).reshape(b, t, h)
+
     def forward(self, x, valid=None):
         """``x [..., hidden]``; ``valid [...]`` bool marks the tokens
         that count in :attr:`last_load` (padding is computed but not
@@ -312,19 +375,31 @@ class RoutedExperts(Layer):
             group = jnp.where(here, local, n).reshape(-1).astype(jnp.int32)
             order = jnp.argsort(group, stable=True)
             sizes = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
-            xs = x[order // k]
-            gate = jax.lax.ragged_dot(xs, self.w_gate._array, sizes)
-            up = jax.lax.ragged_dot(xs, self.w_up._array, sizes)
-            out = jax.lax.ragged_dot(
-                (jax.nn.silu(gate.astype(jnp.float32))
-                 * up.astype(jnp.float32)).astype(x.dtype),
-                self.w_down._array, sizes)
+            if self.latent_size:
+                with jax.named_scope("moe_latent"):
+                    xs = jnp.matmul(x, self.latent_down._array)[order // k]
+            else:
+                xs = x[order // k]
+            if self.gated:
+                gate = jax.lax.ragged_dot(xs, self.w_gate._array, sizes)
+                up = jax.lax.ragged_dot(xs, self.w_up._array, sizes)
+                hid = jax.nn.silu(gate.astype(jnp.float32)) \
+                    * up.astype(jnp.float32)
+            else:
+                hid = jnp.square(jax.nn.relu(jax.lax.ragged_dot(
+                    xs, self.w_up._array, sizes).astype(jnp.float32)))
+            out = jax.lax.ragged_dot(hid.astype(x.dtype),
+                                     self.w_down._array, sizes)
             # back to (token, choice) order; rows past the last group are
             # whatever the kernel left there and are masked, not scaled
             back = jnp.zeros_like(order).at[order].set(
                 jnp.arange(t * k, dtype=order.dtype))
             pair = out[back].reshape(t, k, -1).astype(jnp.float32)
             y = jnp.where(here[..., None], pair * w[..., None], 0.0).sum(1)
+            if self.latent_size:
+                with jax.named_scope("moe_latent"):
+                    y = jnp.matmul(y.astype(x.dtype), self.latent_up._array,
+                                   preferred_element_type=jnp.float32)
             if valid is None:
                 self.last_load = sizes
             else:
@@ -340,11 +415,16 @@ class RoutedExperts(Layer):
                         zero = zero & valid.reshape(-1)[:, None]
                     self.last_zero = zero.sum().astype(jnp.int32)
             if self.shared_width:
-                hid = jax.nn.silu(jnp.matmul(
-                    x, self.shared_gate._array,
-                    preferred_element_type=jnp.float32)) * jnp.matmul(
-                    x, self.shared_up._array,
-                    preferred_element_type=jnp.float32)
+                if self.gated:
+                    hid = jax.nn.silu(jnp.matmul(
+                        x, self.shared_gate._array,
+                        preferred_element_type=jnp.float32)) * jnp.matmul(
+                        x, self.shared_up._array,
+                        preferred_element_type=jnp.float32)
+                else:
+                    hid = jnp.square(jax.nn.relu(jnp.matmul(
+                        x, self.shared_up._array,
+                        preferred_element_type=jnp.float32)))
                 y = y + jnp.matmul(hid.astype(x.dtype),
                                    self.shared_down._array,
                                    preferred_element_type=jnp.float32)
