@@ -1324,7 +1324,8 @@ class GenerationEngine:
         samples: ``moe::expert_load`` (per held expert, prompt and
         decode alike), and for a decode step ``moe::pairs_here`` and
         ``moe::experts_hit`` (one value an expert layer; where the model
-        has zero-compute experts, ``moe::zero_pairs`` beside them) with
+        has zero-compute experts, ``moe::zero_pairs`` beside them, and
+        where the experts' kernel ran, ``moe::tile_rows``) with
         ``generation::state_bytes`` (what the state layers' leaves
         hold, of :meth:`cache_nbytes`), ``generation::kv_rows_read`` and
         ``generation::kv_rows_fetched`` (``rows_read``, ``rows_fetched``:
@@ -1345,6 +1346,9 @@ class GenerationEngine:
                 if "zero_pairs" in stats:
                     _record_counter("moe::zero_pairs",
                                     stats["zero_pairs"].tolist())
+                if "tile_rows" in stats:
+                    _record_counter("moe::tile_rows",
+                                    stats["tile_rows"].tolist())
         if not prefill:
             _record_counter("generation::state_bytes", self.state_nbytes())
             _record_counter("generation::kv_rows_read", list(rows_read))

@@ -208,9 +208,11 @@ class NemotronHForCausalLM(Layer):
         """What the last forward routed here, per expert layer: token-
         expert pairs that landed on held experts (``pairs [L]``),
         distinct held experts that got at least one (``hit [L]``), and
-        per held expert its pairs over all layers (``load [held]``).
-        Inside a trace these are traced values of that trace; ``None``
-        for a pattern without expert layers."""
+        per held expert its pairs over all layers (``load [held]``);
+        where the experts' kernel ran, also the rows its row tiles
+        multiplied for those pairs (``tile_rows [L]``). Inside a trace
+        these are traced values of that trace; ``None`` for a pattern
+        without expert layers."""
         return self._stats
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,
@@ -225,11 +227,13 @@ class NemotronHForCausalLM(Layer):
             valid = mask[:, 0, 0, :] == 0
         x = self.embed_tokens._array[ids]
         kept = iter(caches or ())
-        new_caches, loads = [], []
+        new_caches, loads, tile_rows = [], [], []
         for layer in self.layers:
             if layer.kind == "E":
                 x = layer(x, valid=valid)
                 loads.append(layer.mixer.last_load)
+                if layer.mixer.last_tile_rows is not None:
+                    tile_rows.append(layer.mixer.last_tile_rows)
             elif caches is None:
                 x = layer(x, mask=mask, valid=valid)
             else:
@@ -239,6 +243,8 @@ class NemotronHForCausalLM(Layer):
             loads = jnp.stack(loads)
             self._stats = {"pairs": loads.sum(1), "hit": (loads > 0).sum(1),
                            "load": loads.sum(0)}
+            if tile_rows:
+                self._stats["tile_rows"] = jnp.stack(tile_rows)
         if caches is not None and t > 1:
             # a prefill is read at its last real position only
             last = (t if valid is None else valid.sum(-1)) - 1

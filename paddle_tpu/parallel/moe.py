@@ -29,6 +29,9 @@ from ..framework.tensor import Parameter, Tensor
 from ..nn import functional as F
 from ..nn.layer_base import Layer
 from ..nn.layers import Linear
+from ..ops.pallas._platform import can_emit_mosaic
+from ..ops.pallas.grouped_relu2 import (grouped_relu2,
+                                        grouped_relu2_supported)
 from .mesh import get_mesh
 from .sharding import ShardingRules, with_sharding_constraint
 
@@ -225,7 +228,10 @@ class RoutedExperts(Layer):
     XLA:TPU's grouped kernel visits only the row tiles of groups that
     have rows, so a decode step reads the weights of the experts that
     were hit and no others). The same path serves a prompt of thousands
-    of tokens and a decode step of a few dozen.
+    of tokens and a decode step of a few dozen. Where :meth:`takes_kernel`
+    says so (``"relu2"`` experts on a TPU), the two products of a layer
+    are one Mosaic kernel over the same sorted rows instead, with the
+    hidden rows in VMEM (``ops/pallas/grouped_relu2.py``).
 
     The leaves, by name (a caller that hands weights over does so by
     these names), with ``w`` = ``latent_size or hidden_size``:
@@ -254,7 +260,9 @@ class RoutedExperts(Layer):
     ``forward`` takes and returns arrays ``[..., hidden]``; after it,
     :attr:`last_load` holds, per held expert, how many pairs it was given
     and :attr:`last_zero` how many pairs chose a zero expert (traced
-    values inside a trace: the caller's program may return them).
+    values inside a trace: the caller's program may return them);
+    :attr:`last_tile_rows` the rows the kernel's row tiles multiplied
+    for those pairs, ``None`` where ``ragged_dot`` ran.
     """
 
     def __init__(self, hidden_size, expert_width, num_experts, top_k,
@@ -312,7 +320,7 @@ class RoutedExperts(Layer):
                 param("shared_gate", (h, self.shared_width))
             param("shared_up", (h, self.shared_width))
             param("shared_down", (self.shared_width, h))
-        self.last_load = self.last_zero = None
+        self.last_load = self.last_zero = self.last_tile_rows = None
 
     def route(self, x):
         """``(idx [T, k], w [T, k])``: the chosen experts of each token
@@ -333,13 +341,24 @@ class RoutedExperts(Layer):
             w = w / w.sum(-1, keepdims=True)
         return idx, w * self.routed_scaling_factor
 
+    def takes_kernel(self, xs):
+        """Whether the grouped products over the sorted rows ``xs`` run
+        as ONE Mosaic kernel here and now (``ops/pallas/grouped_relu2``):
+        experts without a gate, a TPU, no multi-device mesh in scope,
+        shapes the kernel supports. Everywhere else they are
+        ``jax.lax.ragged_dot`` calls."""
+        up = self.w_up._array
+        return (not self.gated and can_emit_mosaic()
+                and up.dtype == xs.dtype and grouped_relu2_supported(
+                    xs.shape, up.shape, self.w_down._array.shape, xs.dtype))
+
     def in_chunks(self, x, valid=None, chunk=1024):
         """:meth:`forward` of ``x [B, T, hidden]``, a long sequence
         ``chunk`` tokens at a time (one loop body, so the sorted pairs
         and their hidden rows are one chunk's at the peak);
-        :attr:`last_load` and :attr:`last_zero` are then the chunks'
-        sums. A sequence of at most one chunk, or of no whole number of
-        them, goes through whole."""
+        :attr:`last_load`, :attr:`last_zero` and :attr:`last_tile_rows`
+        are then the chunks' sums. A sequence of at most one chunk, or
+        of no whole number of them, goes through whole."""
         b, t, h = x.shape
         if t <= chunk or t % chunk:
             return self(x, valid=valid)
@@ -350,14 +369,16 @@ class RoutedExperts(Layer):
             y = self(c[0], valid=c[1])
             return y, self.last_load, (
                 self.last_zero if self.zero_experts
-                else jnp.zeros((), jnp.int32))
+                else jnp.zeros((), jnp.int32)), self.last_tile_rows
 
-        out, loads, zeros = jax.lax.map(
+        out, loads, zeros, tile_rows = jax.lax.map(
             one, (x.reshape(b, -1, chunk, h).swapaxes(0, 1),
                   valid.reshape(b, -1, chunk).swapaxes(0, 1)))
         self.last_load = loads.sum(0)
         if self.zero_experts:
             self.last_zero = zeros.sum(0)
+        if tile_rows is not None:
+            self.last_tile_rows = tile_rows.sum(0)
         return out.swapaxes(0, 1).reshape(b, t, h)
 
     def forward(self, x, valid=None):
@@ -380,16 +401,22 @@ class RoutedExperts(Layer):
                     xs = jnp.matmul(x, self.latent_down._array)[order // k]
             else:
                 xs = x[order // k]
-            if self.gated:
-                gate = jax.lax.ragged_dot(xs, self.w_gate._array, sizes)
-                up = jax.lax.ragged_dot(xs, self.w_up._array, sizes)
-                hid = jax.nn.silu(gate.astype(jnp.float32)) \
-                    * up.astype(jnp.float32)
+            self.last_tile_rows = None
+            if self.takes_kernel(xs):
+                # both products in one kernel, the hidden rows in VMEM
+                out, self.last_tile_rows = grouped_relu2(
+                    xs, self.w_up._array, self.w_down._array, sizes)
             else:
-                hid = jnp.square(jax.nn.relu(jax.lax.ragged_dot(
-                    xs, self.w_up._array, sizes).astype(jnp.float32)))
-            out = jax.lax.ragged_dot(hid.astype(x.dtype),
-                                     self.w_down._array, sizes)
+                if self.gated:
+                    gate = jax.lax.ragged_dot(xs, self.w_gate._array, sizes)
+                    up = jax.lax.ragged_dot(xs, self.w_up._array, sizes)
+                    hid = jax.nn.silu(gate.astype(jnp.float32)) \
+                        * up.astype(jnp.float32)
+                else:
+                    hid = jnp.square(jax.nn.relu(jax.lax.ragged_dot(
+                        xs, self.w_up._array, sizes).astype(jnp.float32)))
+                out = jax.lax.ragged_dot(hid.astype(x.dtype),
+                                         self.w_down._array, sizes)
             # back to (token, choice) order; rows past the last group are
             # whatever the kernel left there and are masked, not scaled
             back = jnp.zeros_like(order).at[order].set(

@@ -294,23 +294,15 @@ def _served_gaps(w, prompt, out):
     return (logits.max(-1)[:-1] - own)[len(prompt) - 1:]
 
 
-def test_continuous_batching_serves_the_references_tokens_whatever_the_neighbours(
-        model):
-    """Six slots, fourteen requests of unlike lengths through
-    ContinuousBatcher: slots fill at once, requests finish at different
-    steps, the queue's rest is admitted mid-batch into slots that were
-    used before. Every served token is the reference's argmax at its
-    position to 2e-4 of the largest logit, so no request saw a
-    neighbour's state, a previous tenant's state or tail, or a padded
-    position; nothing compiles after warm-up; the engine needed nothing
-    new for a cache with fewer entries than the model has layers."""
+def _serve_and_check(m, w, slots, lengths, budgets):
+    """``len(lengths)`` requests through ContinuousBatcher on ``slots``
+    slots: every served token is the reference's argmax at its position
+    to 2e-4 of the largest logit, nothing compiles after warm-up, and
+    some request was admitted mid-batch. Returns the engine."""
     from paddle_tpu import monitor
 
-    m, w = model
-    eng = _engine(m, slots=6).warmup()
+    eng = _engine(m, slots=slots).warmup()
     assert eng.expected_compiles() == 3 + 1 and eng.extra_compiles() == 0
-    lengths = [5, 13, 20, 8, 31, 3, 17, 9, 26, 4, 11, 16, 7, 22]
-    budgets = [9, 30, 4, 17, 6, 25, 12, 3, 20, 8, 28, 5, 14, 10]
     prompts = [_tokens(n, seed=n).tolist() for n in lengths]
     mid0 = monitor.counter("serving/gen_midbatch_admissions_total").value
     sched = ContinuousBatcher(eng, queue_capacity=32).start()
@@ -327,6 +319,61 @@ def test_continuous_batching_serves_the_references_tokens_whatever_the_neighbour
         stop = o.index(1) + 1 if 1 in o else b     # EOS ends a request
         assert len(o) == stop
         assert _served_gaps(w, p, o).max() <= 2e-4
+    return eng
+
+
+def test_continuous_batching_serves_the_references_tokens_whatever_the_neighbours(
+        model):
+    """Six slots, fourteen requests of unlike lengths through
+    ContinuousBatcher: slots fill at once, requests finish at different
+    steps, the queue's rest is admitted mid-batch into slots that were
+    used before. Every served token is the reference's argmax at its
+    position to 2e-4 of the largest logit, so no request saw a
+    neighbour's state, a previous tenant's state or tail, or a padded
+    position; nothing compiles after warm-up; the engine needed nothing
+    new for a cache with fewer entries than the model has layers."""
+    m, w = model
+    _serve_and_check(m, w, 6, [5, 13, 20, 8, 31, 3, 17, 9, 26, 4, 11, 16, 7,
+                               22],
+                     [9, 30, 4, 17, 6, 25, 12, 3, 20, 8, 28, 5, 14, 10])
+
+
+def test_the_experts_kernel_serves_the_references_tokens_too(model,
+                                                             monkeypatch):
+    """The same through the non-gated experts' kernel, which a TPU takes
+    and the CPU does not: its gate is opened here, so every expert layer
+    of the decode step and of the prefill buckets is one interpreted
+    `grouped_relu2` call (rows that are no whole row tile, widths that
+    are no whole lanes: the interpreter does not mind). The served
+    tokens are still the reference's, and a decode step's statistics
+    carry the rows the kernel's tiles multiplied, an expert layer a
+    value, no fewer than the pairs that landed here."""
+    from paddle_tpu import profiler
+    from paddle_tpu.parallel import moe
+
+    calls = []
+    monkeypatch.setattr(moe, "can_emit_mosaic", lambda: True)
+    monkeypatch.setattr(moe, "grouped_relu2_supported",
+                        lambda *a: calls.append(a) or True)
+    m, w = model
+    eng = _serve_and_check(m, w, 3, [5, 13, 20, 8, 31, 3, 17],
+                           [9, 12, 4, 17, 6, 10, 12])
+    assert len(calls) == 5 * 4        # five expert layers, four programs
+    assert "tile_rows" in m.routing_stats()
+    profiler.reset_profiler()
+    profiler.start_profiler(state="CPU")
+    try:
+        eng.reset()
+        eng.admit(1, _tokens(20).tolist())
+        eng.step(np.zeros(3, np.int32), np.zeros(3, np.float32))
+        got = {ev["name"]: ev["args"]["value"]
+               for ev in profiler.counter_samples()}
+    finally:
+        profiler.stop_profiler()
+        profiler.reset_profiler()
+    pairs, rows = got["moe::pairs_here"], got["moe::tile_rows"]
+    assert len(pairs) == len(rows) == 5
+    assert all(p <= r and r % 8 == 0 for p, r in zip(pairs, rows))
 
 
 def test_the_state_is_most_of_a_slot_and_is_accounted(model):
@@ -383,6 +430,7 @@ def test_counters_are_sampled_only_while_the_profiler_is_on(model):
     assert all(0 <= p <= 2 * 6 and 0 <= h <= 8 for p, h in zip(pairs, hit))
     assert len(got["moe::expert_load"]) == 2  # the prompt's and the step's
     assert all(len(load) == 8 for load in got["moe::expert_load"])
+    assert "moe::tile_rows" not in got        # no kernel off the chip
 
 
 def test_a_long_prompts_expert_layers_run_in_chunks(model, monkeypatch):

@@ -22,6 +22,7 @@ cbr = importlib.import_module("paddle_tpu.ops.pallas.conv_bn_relu")
 fla = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 opu = importlib.import_module("paddle_tpu.ops.pallas.optimizer_update")
 mld = importlib.import_module("paddle_tpu.ops.pallas.mla_decode")
+gr2 = importlib.import_module("paddle_tpu.ops.pallas.grouped_relu2")
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 
@@ -283,6 +284,36 @@ def test_mla_decode_through_the_absorbed_step(slots, ring, dtype,
     assert mld.key_block(ring) == 512
 
 
+@pytest.mark.parametrize("rows,tile", [(64 * 22, 16), (1024 * 22, 128)])
+def test_grouped_relu2_at_the_served_shapes(rows, tile, monkeypatch):
+    """The non-gated experts' kernel at the served cut's widths (128 held
+    experts, latent 1,024, hidden width 2,688, bfloat16) for a decode
+    step's 64 x 22 sorted pairs and for a 1,024-token chunk's: ONE call
+    named `ragged-dot-none-relu2` (the name the benchmark's readers find
+    the grouped products by); its operands are the five scalar-prefetch
+    arrays (the work items' groups and tiles, the groups' first rows and
+    ends, the number of items), the sorted rows and the two stacks of
+    weights, a whole expert a block."""
+    monkeypatch.setattr(gr2, "on_tpu_platform", lambda: True)
+    shapes = (rows, 1024), (128, 1024, 2688), (128, 2688, 1024)
+    assert gr2.grouped_relu2_supported(*shapes, "bfloat16")
+    assert gr2.row_tile(rows, 128, BF16) == tile
+    assert gr2._hidden_block(1024, 2688, BF16) == 2688
+    text = _lower_for_tpu(gr2.grouped_relu2,
+                          *(_sds(s, BF16) for s in shapes),
+                          _sds((128,), jnp.int32))
+    assert re.findall(r'kernel_name = "([\w.-]+)"', text) \
+        == ["ragged-dot-none-relu2"]
+    operands = re.search(
+        r'kernel_name = "ragged-dot-none-relu2".*?: \((.*?)\) ->',
+        text).group(1)
+    items = rows // tile + 128 - 1
+    assert operands.split(", ") == [
+        f"tensor<{items}xi32>", f"tensor<{items}xi32>", "tensor<128xi32>",
+        "tensor<128xi32>", "tensor<1xi32>", f"tensor<{rows}x1024xbf16>",
+        "tensor<128x1024x2688xbf16>", "tensor<128x2688x1024xbf16>"]
+
+
 def test_gate_closes_under_a_multi_device_mesh(monkeypatch):
     """jax refuses to partition a Mosaic call automatically, so under a
     mesh of more than one device every kernel hands its op to XLA: a
@@ -294,8 +325,15 @@ def test_gate_closes_under_a_multi_device_mesh(monkeypatch):
     monkeypatch.setattr(_platform, "on_tpu_platform", lambda: True)
     x, w = jnp.zeros((256, 128), BF16), jnp.zeros((128,))
     assert _platform.can_emit_mosaic() and lnr._supported(x, x, w, w)
+    from paddle_tpu.parallel import moe
+
+    experts = moe.RoutedExperts(32, 128, 8, 2, activation="relu2",
+                                latent_size=128, dtype=BF16)
+    rows = jnp.zeros((64, 128), BF16)
+    assert experts.takes_kernel(rows)
     with parallel.mesh_scope(parallel.create_mesh(dp=2, tp=2)):
         assert not _platform.can_emit_mosaic()
         assert not lnr._supported(x, x, w, w)
+        assert not experts.takes_kernel(rows)
     with parallel.mesh_scope(parallel.create_mesh(dp=1)):
         assert _platform.can_emit_mosaic()

@@ -1,10 +1,11 @@
 """RoutedExperts' two later options - `activation="relu2"` (an expert of
 two matrices, no gate) and `latent_size` (the routed experts work in a
 narrower width, one down- and one up-projection a layer) - and the
-proof that the defaults are what they were: the layer's forward as it
-stood before the options (PR 39's, kept below word for word) put in the
-new one's place lowers every ring program of the three families that
-use the layer to the same text.
+proof that off the chip the layer is what it was before the non-gated
+experts' kernel (ops/pallas/grouped_relu2.py, taken on a TPU only): the
+layer's forward and `in_chunks` as they stood at the parent (PR 40's,
+kept below word for word) put in the new ones' place lower every ring
+program of the four families that use the layer to the same text.
 
 The plain whole layer is the benchmark reference's
 (benchmark/configs/nemotron-3-super-120b/reference.py `_moe`, every
@@ -120,7 +121,7 @@ def test_the_latent_is_what_the_experts_see_and_the_router_does_not():
 # -- the defaults are what they were -----------------------------------------
 
 def _forward_as_it_was(self, x, valid=None):
-    """RoutedExperts.forward at PR 39 (commit 5a52977), word for word."""
+    """RoutedExperts.forward at PR 40 (commit 08a77ec), word for word."""
     shape = x.shape
     x = x.reshape(-1, shape[-1])
     t, k, n = x.shape[0], self.top_k, self.count
@@ -132,19 +133,31 @@ def _forward_as_it_was(self, x, valid=None):
         group = jnp.where(here, local, n).reshape(-1).astype(jnp.int32)
         order = jnp.argsort(group, stable=True)
         sizes = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
-        xs = x[order // k]
-        gate = jax.lax.ragged_dot(xs, self.w_gate._array, sizes)
-        up = jax.lax.ragged_dot(xs, self.w_up._array, sizes)
-        out = jax.lax.ragged_dot(
-            (jax.nn.silu(gate.astype(jnp.float32))
-             * up.astype(jnp.float32)).astype(x.dtype),
-            self.w_down._array, sizes)
+        if self.latent_size:
+            with jax.named_scope("moe_latent"):
+                xs = jnp.matmul(x, self.latent_down._array)[order // k]
+        else:
+            xs = x[order // k]
+        if self.gated:
+            gate = jax.lax.ragged_dot(xs, self.w_gate._array, sizes)
+            up = jax.lax.ragged_dot(xs, self.w_up._array, sizes)
+            hid = jax.nn.silu(gate.astype(jnp.float32)) \
+                * up.astype(jnp.float32)
+        else:
+            hid = jnp.square(jax.nn.relu(jax.lax.ragged_dot(
+                xs, self.w_up._array, sizes).astype(jnp.float32)))
+        out = jax.lax.ragged_dot(hid.astype(x.dtype),
+                                 self.w_down._array, sizes)
         # back to (token, choice) order; rows past the last group are
         # whatever the kernel left there and are masked, not scaled
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * k, dtype=order.dtype))
         pair = out[back].reshape(t, k, -1).astype(jnp.float32)
         y = jnp.where(here[..., None], pair * w[..., None], 0.0).sum(1)
+        if self.latent_size:
+            with jax.named_scope("moe_latent"):
+                y = jnp.matmul(y.astype(x.dtype), self.latent_up._array,
+                               preferred_element_type=jnp.float32)
         if valid is None:
             self.last_load = sizes
         else:
@@ -160,20 +173,49 @@ def _forward_as_it_was(self, x, valid=None):
                     zero = zero & valid.reshape(-1)[:, None]
                 self.last_zero = zero.sum().astype(jnp.int32)
         if self.shared_width:
-            hid = jax.nn.silu(jnp.matmul(
-                x, self.shared_gate._array,
-                preferred_element_type=jnp.float32)) * jnp.matmul(
-                x, self.shared_up._array,
-                preferred_element_type=jnp.float32)
+            if self.gated:
+                hid = jax.nn.silu(jnp.matmul(
+                    x, self.shared_gate._array,
+                    preferred_element_type=jnp.float32)) * jnp.matmul(
+                    x, self.shared_up._array,
+                    preferred_element_type=jnp.float32)
+            else:
+                hid = jnp.square(jax.nn.relu(jnp.matmul(
+                    x, self.shared_up._array,
+                    preferred_element_type=jnp.float32)))
             y = y + jnp.matmul(hid.astype(x.dtype),
                                self.shared_down._array,
                                preferred_element_type=jnp.float32)
         return y.astype(x.dtype).reshape(shape)
 
 
+def _in_chunks_as_it_was(self, x, valid=None, chunk=1024):
+    """RoutedExperts.in_chunks at PR 40 (commit 08a77ec), word for word."""
+    b, t, h = x.shape
+    if t <= chunk or t % chunk:
+        return self(x, valid=valid)
+    if valid is None:
+        valid = jnp.ones((b, t), bool)
+
+    def one(c):
+        y = self(c[0], valid=c[1])
+        return y, self.last_load, (
+            self.last_zero if self.zero_experts
+            else jnp.zeros((), jnp.int32))
+
+    out, loads, zeros = jax.lax.map(
+        one, (x.reshape(b, -1, chunk, h).swapaxes(0, 1),
+              valid.reshape(b, -1, chunk).swapaxes(0, 1)))
+    self.last_load = loads.sum(0)
+    if self.zero_experts:
+        self.last_zero = zeros.sum(0)
+    return out.swapaxes(0, 1).reshape(b, t, h)
+
+
 _FAMILIES = {"solar-open2": "test_hybrid_moe.py",
              "k-exaone": "test_exaone_moe.py",
-             "longcat-flash": "test_longcat_flash.py"}
+             "longcat-flash": "test_longcat_flash.py",
+             "nemotron-h": "test_nemotron_h.py"}
 
 
 def _programs(family):
@@ -193,16 +235,23 @@ def _programs(family):
 
 
 @pytest.mark.parametrize("family", list(_FAMILIES))
-def test_the_defaults_lower_the_three_families_programs_as_before(
+def test_the_defaults_lower_the_four_families_programs_as_before(
         family, monkeypatch):
-    """With the forward of PR 39 in the layer's place the decode and the
-    prefill program of each family lower to the same text, character
-    for character, as with today's forward and its default arguments;
-    and the whole model's logits are the same bits."""
+    """With the forward and `in_chunks` of PR 40 in the layer's place the
+    decode and the prefill program of each family lower to the same
+    text, character for character, as with today's (off the chip the
+    kernel's gate is closed: the three gated families never ask it, and
+    `nemotron_h`, whose prompt here is two chunks of its expert layers,
+    runs the two `ragged_dot` calls); and the whole model's logits are
+    the same bits."""
+    from paddle_tpu.models import nemotron_h
+
+    monkeypatch.setattr(nemotron_h, "_MOE_CHUNK", 8)
     now, m = _programs(family)
     toks = jnp.asarray(np.random.default_rng(0).integers(3, 64, (1, 21)))
     logits = np.asarray(m(toks)._array)
     monkeypatch.setattr(RoutedExperts, "forward", _forward_as_it_was)
+    monkeypatch.setattr(RoutedExperts, "in_chunks", _in_chunks_as_it_was)
     then, m = _programs(family)
     assert set(now) == {"decode", "prefill"}
     for name in now:
