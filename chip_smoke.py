@@ -571,13 +571,15 @@ def _post_generate(url, payload):
 
 def _serve_leg(engine, prompts, max_new) -> dict:
     """A started GenerationServer over ``engine`` (warm-up compiles
-    exactly the ladder + 1 decode), the prompts in flight together over
-    HTTP, then the same engine offline: token for token."""
+    exactly ``expected_compiles()``: the ladder + 1 decode, or, where
+    long prompts go in by chunks, the ladder's first two buckets + the
+    chunk program + 1 decode), the prompts in flight together over HTTP, then
+    the same engine offline: token for token."""
     from paddle_tpu import profiler
     from paddle_tpu.generation import COMPILE_COUNTER
     from paddle_tpu.serving import GenerationServer
 
-    ladder = len(engine.prefill_buckets)
+    expected = engine.expected_compiles()
     srv = GenerationServer(engine, port=0)
     c0 = profiler.counters().get(COMPILE_COUNTER, 0)
     t0 = time.perf_counter()
@@ -585,8 +587,8 @@ def _serve_leg(engine, prompts, max_new) -> dict:
     compile_s = time.perf_counter() - t0
     try:
         warm = profiler.counters().get(COMPILE_COUNTER, 0) - c0
-        _require(warm == ladder + 1,
-                 f"warm-up compiled {warm} programs, expected {ladder} + 1")
+        _require(warm == expected,
+                 f"warm-up compiled {warm} programs, expected {expected}")
         # all requests in flight together (continuous batching), the
         # first one streamed; a failed request raises out of map()
         def client(i):

@@ -46,9 +46,9 @@ import jax
 import jax.numpy as jnp
 
 from ..errors import UnavailableError
-from ..nn.transformer import (LatentCache, QuantizedStaticCache,
-                              RecurrentCache, StaticCache,
-                              update_slice_in_range)
+from ..nn.transformer import (ContinuedCache, LatentCache,
+                              QuantizedStaticCache, RecurrentCache,
+                              StaticCache, update_slice_in_range)
 
 __all__ = [
     "CacheLostError",
@@ -61,7 +61,7 @@ __all__ = [
     "is_layer_kinds",
     "init_kinds_cache", "kinds_layer_caches", "unzip_kinds_caches",
     "kinds_slot_nbytes", "kinds_bytes_per_token", "kinds_ring_lengths",
-    "kinds_decode_mask",
+    "kinds_decode_mask", "kinds_continue", "kinds_slot_caches",
 ]
 
 NEG_INF = -1e9
@@ -195,7 +195,10 @@ def kv_bytes_per_token(num_layers, num_heads, head_dim,
 # its own length`` and read under the decode mask of that length
 # (:func:`kinds_decode_mask`), all from the one ``pos``. A model may
 # list more kinds than it has layers (two attentions a layer: two
-# rings), in the order its forward consumes them.
+# rings), in the order its forward consumes them. A kind also says
+# whether its layer can take a prompt up again from what the slot holds
+# (``continues``): where every kind of a cache can, the engine admits a
+# long prompt a chunk at a time (:func:`kinds_continue`).
 
 
 class KVKind(NamedTuple):
@@ -209,6 +212,14 @@ class KVKind(NamedTuple):
     heads: int
     head_dim: int
     window: int | None = None
+
+    #: a chunk boundary is nothing but rows already written: the layer
+    #: attends them where they lie (:class:`nn.ContinuedCache`)
+    continues = True
+
+    def wrap_continued(self, arrays, pos):
+        """The cache of a prompt's chunk that begins at ``pos``."""
+        return ContinuedCache(*arrays, pos)
 
     def ring(self, store):
         """Rows of this layer's ring in a cache of ``store`` rows."""
@@ -245,6 +256,11 @@ class StateKind(NamedTuple):
     shapes: tuple
     dtypes: tuple
 
+    #: a chunk boundary would be a state hand-over and a convolution
+    #: tail, and a decode step between two chunks would advance a
+    #: half-filled slot's state: no continuation yet
+    continues = False
+
     def arrays(self, batch, store, dtype):
         return tuple(jnp.zeros((int(batch),) + tuple(s), d)
                      for s, d in zip(self.shapes, self.dtypes))
@@ -273,6 +289,10 @@ class LatentKind(NamedTuple):
 
     rank: int
     rope: int
+
+    #: the earlier rows would have to be expanded again for every
+    #: chunk: no continuation yet
+    continues = False
 
     def ring(self, store):
         return int(store)
@@ -345,6 +365,23 @@ def kinds_layer_caches(kinds, kv):
     """The per-layer caches a forward takes, from the whole-model cache
     of :func:`init_kinds_cache`'s form."""
     return [k.wrap(arrays, kv[-1]) for k, arrays in zip(kinds, kv[:-1])]
+
+
+def kinds_continue(kinds):
+    """Can every layer of this cache take a prompt up again from the
+    rows its slot holds, so that a prompt may go in a chunk at a time?"""
+    return bool(kinds) and all(k.continues for k in kinds)
+
+
+def kinds_slot_caches(kinds, kv, slot, start):
+    """The per-layer caches a CHUNK's forward takes: row ``slot`` of
+    every array of the whole-model cache (a copy: batch 1), each as the
+    kind's continued cache from position ``start [1]``."""
+    def row(a):
+        return jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0)
+
+    return [k.wrap_continued(tuple(row(a) for a in arrays), start)
+            for k, arrays in zip(kinds, kv[:-1])]
 
 
 def unzip_kinds_caches(caches):

@@ -69,7 +69,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..errors import InvalidArgumentError
+from ..errors import InvalidArgumentError, PreconditionNotMetError
 from ..flags import flag
 from ..framework.jit import functional_call
 from ..monitor import flight_recorder as _flight
@@ -84,8 +84,8 @@ from . import cache as _cache
 from . import paging as _paging
 from .sampling import sample_logits
 
-__all__ = ["GenerationEngine", "DecodeStep", "COMPILE_COUNTER",
-           "CACHE_LOST_COUNTER", "STATE_REBUILT_COUNTER"]
+__all__ = ["GenerationEngine", "DecodeStep", "Admission", "COMPILE_COUNTER",
+           "CACHE_LOST_COUNTER", "STATE_REBUILT_COUNTER", "CHUNKS_COUNTER"]
 
 COMPILE_COUNTER = "generation::compile"
 # calls that failed after they had consumed the donated cache (_dispatch)
@@ -93,6 +93,10 @@ CACHE_LOST_COUNTER = "generation::cache_lost"
 # times a kept state signature was derived again because somebody had
 # rebound a parameter's or a buffer's array (_KeptState)
 STATE_REBUILT_COUNTER = "generation::state_rebuilt"
+# one SAMPLE (profiler.record_counter) a prefill program enqueued: [its
+# number within its prompt, 1 if it is the prompt's last]; a prompt
+# admitted whole leaves [1, 1]
+CHUNKS_COUNTER = "generation::prefill_chunks"
 
 # deterministic engine instance ids (cache-key stability; see __init__)
 _engine_counter = itertools.count()
@@ -170,6 +174,34 @@ class DecodeStep:
     def __init__(self, tokens, stats, rows_read, rows_fetched, program):
         self.tokens, self.stats, self.rows_read = tokens, stats, rows_read
         self.rows_fetched, self.program = rows_fetched, program
+
+
+class Admission:
+    """One prompt on its way into a slot a chunk at a time
+    (:meth:`GenerationEngine.begin_admission`): the slot, the prompt and
+    its temperature, how many of its tokens are in (``lo``), how many
+    chunk programs that took (``chunks``), the decode steps enqueued
+    since the last of them (``steps``), and what the chunks left on the
+    device until the last one is fetched: the token each sampled
+    (``tok``: the last chunk's is the prompt's first), their routing
+    statistics, and the program that computes them."""
+
+    __slots__ = ("slot", "prompt", "temp", "lo", "chunks", "steps", "tok",
+                 "stats", "program")
+
+    def __init__(self, slot, prompt, temp):
+        self.slot, self.prompt, self.temp = int(slot), prompt, float(temp)
+        self.lo = self.chunks = self.steps = 0
+        self.tok = self.program = None
+        self.stats = []
+
+    @property
+    def done(self) -> bool:
+        return self.lo >= len(self.prompt)
+
+    def next_len(self, chunk) -> int:
+        """Real tokens of the next chunk of ``chunk`` tokens."""
+        return min(chunk, len(self.prompt) - self.lo)
 
 
 class GenerationEngine:
@@ -307,6 +339,22 @@ class GenerationEngine:
                     "positions")
         self.store_len = self.cache_len + (
             self.draft_k if self.speculative else 0)
+        # the unit of an admission: where every kind of the cache can
+        # take a prompt up again from the rows its slot holds, a prompt
+        # longer than the ladder's second bucket goes in that many
+        # tokens at a time, through ONE chunk program (begin_admission);
+        # else None, and a prompt is admitted whole through its bucket.
+        # The second bucket and not the first: every chunk reads the
+        # weights again, and on the chip the first (1,024 tokens in
+        # k-exaone-236b) took a tenth of the server's tokens/s for it
+        # where the second took none (PERF.md, PR 45). The chunk
+        # divides the store, so that none straddles a full ring's end
+        chunk = int(self.prefill_buckets[:2][-1])
+        self.chunk_len = chunk if (
+            self._kinds is not None and _cache.kinds_continue(self._kinds)
+            and chunk < self.prefill_buckets[-1]
+            and self.store_len % chunk == 0) else None
+        self._admission = None
         # KV layout: "ring" is the historical per-slot contiguous store;
         # "paged" draws fixed-size pages from a shared pool through
         # per-slot page tables (generation/paging.py) — same logical
@@ -384,6 +432,8 @@ class GenerationEngine:
         self._prefill_jit = jax.jit(self._prefill_pure, donate_argnums=(1,))
         self._spec_prefill_jit = jax.jit(self._spec_prefill_pure,
                                          donate_argnums=(2, 3))
+        self._prefill_chunk_jit = jax.jit(self._prefill_chunk_pure,
+                                          donate_argnums=(1,))
         self._decode_jit = jax.jit(self._decode_pure, donate_argnums=(1,))
         self._paged_prefill_jit = jax.jit(self._paged_prefill_pure)
         self._paged_decode_jit = jax.jit(self._paged_decode_pure)
@@ -402,7 +452,8 @@ class GenerationEngine:
         self._stores = {
             label: CompiledStore(f"generation_{label}",
                                  miss_counter=COMPILE_COUNTER)
-            for label in ("prefill", "decode", "draft", "verify")}
+            for label in ("prefill", "prefill_chunk", "decode", "draft",
+                          "verify")}
         # deterministic per-engine index for the cache signature (stable
         # cache_key across runs, distinct per engine in the CostRecord
         # registry — two engines may share avals but not weights)
@@ -456,6 +507,8 @@ class GenerationEngine:
         from ..monitor import registry as _mon
 
         ring_slots = getattr(self, "_ring_slots", self.slots)
+        # a prompt half in went with the rows it had
+        self._admission = None
         if self.paged:
             usable = self._pool_usable(ring_slots)
             self._kv = _paging.init_paged_cache(
@@ -826,6 +879,9 @@ class GenerationEngine:
 
         - ``generate`` (unified): one prefill per ladder bucket, plus
           either the single decode program or the draft + verify pair;
+          where prompts go in by chunks (:attr:`chunk_len`), the
+          buckets a shorter prompt takes, the chunk program and the
+          decode program: the longer buckets are never reached;
         - ``prefill`` (disaggregated prefill tier): one prefill-export
           per bucket, nothing else;
         - ``decode`` (disaggregated decode tier): the decode (or
@@ -837,7 +893,8 @@ class GenerationEngine:
         buckets = len(self.prefill_buckets)
         decode = 2 if self.speculative else 1
         if kind == "generate":
-            return buckets + decode
+            return len(self._reached_buckets()) + decode \
+                + (self.chunk_len is not None)
         if kind == "prefill":
             return buckets
         if kind == "decode":
@@ -931,7 +988,14 @@ class GenerationEngine:
         elif kind != "prefill":
             plan.append(([self._decode_call(zeros_i, zeros_f, 0)],
                          lambda: self.step(zeros_i, zeros_f)))
-        for bucket in self.prefill_buckets:
+        if kind == "generate" and self.chunk_len is not None:
+            # two chunks, the second of one token
+            prompt = [self.pad_id] * (self.chunk_len + 1)
+            plan.append((
+                [self._chunk_call(Admission(0, prompt, temp), 0)],
+                lambda p=prompt: self.admit(0, p)))
+        for bucket in (self._reached_buckets() if kind == "generate"
+                       else self.prefill_buckets):
             prompt = [self.pad_id] * int(bucket)
             padded, n = self._padded_prompt(prompt)
             if kind == "generate":
@@ -955,6 +1019,12 @@ class GenerationEngine:
                 0, self._fresh_slot_planes(), 1, 0,
                 prompt=[self.pad_id] if self.speculative else None)))
         return plan
+
+    def _reached_buckets(self):
+        """The ladder buckets an admission can take: all of them, or,
+        where longer prompts go in by chunks, those a chunk covers."""
+        return [b for b in self.prefill_buckets
+                if self.chunk_len is None or b <= self.chunk_len]
 
     def _fresh_slot_planes(self):
         """Zeroed window-width per-slot planes (a synthetic empty slab
@@ -1042,6 +1112,34 @@ class GenerationEngine:
         rows = tuple(tuple(a[0] for a in arrays)
                      for arrays in _cache.unzip_kinds_caches(new_caches))
         kv = _cache.insert_slot_kv(kv, slot, rows, length)
+        tok = self._sample_first(logits, length, temp, ctr)
+        return kv, tok, self._model_stats()
+
+    def _prefill_chunk_pure(self, state, kv, slot, tokens, lo, length, temp,
+                            ctr):
+        """One CHUNK of a prompt into decode slot ``slot``, whose rings
+        hold the prompt's rows below ``lo``: ``tokens [1, C]`` stand at
+        ``lo .. lo + C - 1`` and the first ``length`` of them are real.
+        The forward runs on the slot's own rows (each layer attends what
+        its ring holds and the chunk, and writes the chunk's rows in),
+        the rows go back into the slot and its ``pos`` becomes ``lo +
+        length`` whatever it was: a decode step that ran over the
+        half-filled slot since the last chunk moved it on by one and
+        left one row, which is overwritten here or was out of every
+        later query's band (``nn/gqa.py``). ``lo`` is data: ONE program
+        for every chunk of every prompt. The token is sampled from the
+        chunk's last real row; only the prompt's last chunk's is read."""
+        c = tokens.shape[1]
+        caches = _cache.kinds_slot_caches(self._kinds, kv, slot, lo[None])
+        mask = jnp.where(jnp.arange(c) < length, 0.0,
+                         _cache.NEG_INF).astype(jnp.float32)[None, None, None]
+        (logits, new_caches), _ = functional_call(
+            self.model, state, tokens,
+            position_ids=(lo + jnp.arange(c, dtype=jnp.int32))[None],
+            attention_mask=mask, caches=caches)
+        rows = tuple(tuple(a[0] for a in arrays)
+                     for arrays in _cache.unzip_kinds_caches(new_caches))
+        kv = _cache.insert_slot_kv(kv, slot, rows, lo + length)
         tok = self._sample_first(logits, length, temp, ctr)
         return kv, tok, self._model_stats()
 
@@ -1296,6 +1394,12 @@ class GenerationEngine:
         prefix-reuse observability; the ring layout ignores it."""
         if self.paged:
             return self._admit_paged(slot, prompt, temperature, tenant)
+        adm = self.begin_admission(slot, prompt, temperature)
+        if adm is not None:
+            # the same chunks back to back: nothing to put between them
+            while not adm.done:
+                self.enqueue_chunk(adm)
+            return self.fetch_admission(adm)
         padded, n = self._padded_prompt(prompt)
         temp = (self.default_temperature if temperature is None
                 else float(temperature))
@@ -1310,12 +1414,100 @@ class GenerationEngine:
                 self._kv, tok, stats = out
             else:
                 self._kv, tok = out
+        _record_counter(CHUNKS_COUNTER, [1, 1])
         tok = self._fetched("generation::prefill", t0, tok, int)
         if self._kinds is not None:
             with self._key_lock:  # a step's += on another thread
                 self._pos_host[slot] = n
             self._sample_stats(stats)
         return tok
+
+    def begin_admission(self, slot, prompt, temperature=None):
+        """Begin admitting ``prompt`` into ``slot`` a chunk at a time:
+        an :class:`Admission` to hand to :meth:`enqueue_chunk` until it
+        is ``done`` and then to :meth:`fetch_admission`, or ``None``
+        where this prompt goes in whole (:meth:`admit`): the cache has a
+        kind that cannot take a prompt up again (:attr:`chunk_len` is
+        None), or one chunk holds it. One prompt is in its chunks at a
+        time, and between two of its chunks at most ONE decode step may
+        run: the row that a step leaves in the half-filled slot is
+        rewritten by the next chunk in a full ring and lies outside
+        every later query's band in a window ring, but a second step's
+        row would take the place of position ``lo - window + 1``, which
+        the next chunk's first query attends. After the last chunk none
+        may run until the token is fetched: the slot is live from there
+        and a step would decode it from no token
+        (:meth:`enqueue_step` refuses both)."""
+        if self.chunk_len is None or len(prompt) <= self.chunk_len:
+            return None
+        if self._admission is not None:
+            raise PreconditionNotMetError(
+                f"slot {self._admission.slot} is in its chunks: one prompt "
+                "at a time")
+        self._admission = Admission(
+            slot, prompt, self.default_temperature if temperature is None
+            else temperature)
+        return self._admission
+
+    def _own(self, adm):
+        if adm is not self._admission:
+            raise PreconditionNotMetError(
+                "this admission was abandoned, or lost its rows with the "
+                "cache")
+
+    def enqueue_chunk(self, adm):
+        """Enqueue the next chunk of ``adm`` and return at once, the
+        program left to run behind whatever is enqueued: the span
+        ``generation::prefill``, and one sample of
+        ``generation::prefill_chunks``, ``[the chunk's number in its
+        prompt, 1 if it is the last]``. After the last one ``adm.done``
+        is true and :meth:`fetch_admission` has the first token."""
+        self._own(adm)
+        n = adm.next_len(self.chunk_len)
+        last = adm.lo + n == len(adm.prompt)
+        ctr = self._next_key_step() if last else 0
+        t0 = time.perf_counter_ns()
+        with RecordEvent("generation::prefill"):
+            self._kv, adm.tok, stats = self._dispatch(
+                *self._chunk_call(adm, ctr))
+        adm.program = self._program
+        adm.lo += n
+        adm.chunks += 1
+        adm.steps = 0
+        adm.stats.append(stats)
+        _record_counter(CHUNKS_COUNTER, [adm.chunks, int(last)])
+        with self._key_lock:  # a step's += on another thread
+            self._pos_host[adm.slot] = adm.lo
+        self._note_phase("generation::prefill", t0,
+                         time.perf_counter_ns() - t0)
+
+    def fetch_admission(self, adm) -> int:
+        """The first sampled token of a prompt whose last chunk is
+        enqueued, on the host: the wait is the span
+        ``generation::prefill_fetch``, as :meth:`admit`'s is. A driver
+        with a decode step in flight fetches that first: its tokens are
+        ready a chunk earlier."""
+        self._own(adm)
+        if not adm.done:
+            raise PreconditionNotMetError(
+                f"{len(adm.prompt) - adm.lo} prompt tokens are not in yet")
+        self._admission = None
+        tok = self._fetched("generation::prefill", None, adm.tok, int,
+                            adm.program)
+        stats = adm.stats[-1]
+        if _profiler_enabled() and stats is not None:
+            # the prompt's load is its chunks' sum, one sample as ever
+            loads = [s["load"] for s in jax.device_get(adm.stats)]
+            stats = {"load": np.sum(loads, axis=0)}
+        self._sample_stats(stats, program=adm.program)
+        return tok
+
+    def abandon_admission(self, adm):
+        """Give up a prompt between two of its chunks: its slot keeps
+        the rows it got, as a vacated slot does, until the next
+        admission writes over them."""
+        if adm is self._admission:
+            self._admission = None
 
     def _sample_stats(self, stats, rows_read=None, rows_fetched=None,
                       program=None):
@@ -1438,6 +1630,17 @@ class GenerationEngine:
         return "prefill", self._prefill_jit, lambda: (
             self._state(), self._kv, np.int32(slot),
             *self._prompt_args(padded, n, temp, ctr))
+
+    def _chunk_call(self, adm, ctr):
+        """The next chunk of ``adm``: its tokens right-padded to the
+        chunk's length, where it begins and how many are real."""
+        c, lo = self.chunk_len, adm.lo
+        n = adm.next_len(c)
+        padded = np.full(c, self.pad_id, np.int32)
+        padded[:n] = np.asarray(adm.prompt[lo:lo + n], np.int32)
+        return "prefill_chunk", self._prefill_chunk_jit, lambda: (
+            self._state(), self._kv, np.int32(adm.slot), padded[None],
+            np.int32(lo), np.int32(n), np.float32(adm.temp), np.int32(ctr))
 
     def _export_call(self, padded, n, temp, ctr):
         return "prefill", self._prefill_export_jit, lambda: (
@@ -1577,6 +1780,7 @@ class GenerationEngine:
                     np.int32(shared_len), np.int32(len(suffix)),
                     np.int32(n), np.float32(temp), np.int32(ctr)))
         self._kv, tok = out
+        _record_counter(CHUNKS_COUNTER, [1, 1])
         return self._fetched("generation::prefill", t0, tok, int)
 
     def _seat_paged(self, slot, prompt, tenant):
@@ -1998,6 +2202,18 @@ class GenerationEngine:
         ``pos`` advances. The returned handle keeps the step's tokens
         (and its routing statistics) on the device until
         :meth:`fetch_step`; a handle that is dropped costs nothing."""
+        adm = self._admission
+        if adm is not None:
+            if adm.done:
+                raise PreconditionNotMetError(
+                    f"slot {adm.slot}'s prompt is in and its first token "
+                    "is not fetched: a step would decode it from none")
+            if adm.steps:
+                raise PreconditionNotMetError(
+                    f"a second decode step since slot {adm.slot}'s last "
+                    "chunk would overwrite a window ring's row that its "
+                    "next chunk attends: run the next chunk first")
+            adm.steps += 1
         ctr = self._next_key_step()
         if self.paged:
             # CoW/first-visit page turns happen on the host BEFORE the

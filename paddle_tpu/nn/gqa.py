@@ -29,6 +29,19 @@ the additive key-padding mask ``[B, 1, 1, T]``. A window layer's block
 attends its own keys and the ``window - 1`` before them, so its cost is
 linear in the prompt, and its ring ends up holding the last ``min(length,
 window)`` real rows. Softmax and norm statistics are float32.
+
+More than one token with a :class:`ContinuedCache` is a prompt's CHUNK:
+the ring holds the prompt's rows below ``cache.pos`` (``lo``), the
+queries stand at ``lo .. lo + T - 1`` (``positions`` says so to a rotary
+layer) and ``mask`` is the chunk's own key-padding mask. A full layer
+attends ring rows ``0 .. lo`` by key chunks, none that lies wholly
+beyond ``lo`` read, then its own rows causally; a window layer takes the
+ring's rows out in position order, puts the chunk's behind them and
+bands the lot as a prefill is banded. The chunk's rows then go in at
+``position mod ring``. Whatever a ring row at or beyond ``lo`` held
+before (a decode step that ran over the half-filled slot wrote one) is
+never read: a full ring's is overwritten first, and a window ring's row
+of ``lo`` held position ``lo - ring``, which the band leaves out.
 """
 from __future__ import annotations
 
@@ -38,10 +51,12 @@ import jax.numpy as jnp
 from ..framework.tensor import Parameter
 from .layer_base import Layer
 from .linear_attention import normal_or_zeros
-from .transformer import StaticCache, _write_rows, update_slice_in_range
+from .transformer import (ContinuedCache, StaticCache, _write_rows,
+                          update_slice_in_range)
 
 __all__ = ["CachedGQAttention", "rms_norm", "apply_rotary", "attend",
-           "attend_by_chunks", "attend_keys", "attend_causal_blocks"]
+           "attend_by_chunks", "attend_keys", "attend_causal_blocks",
+           "attend_continued"]
 
 _NEG_INF = -1e9
 
@@ -117,21 +132,24 @@ def attend_keys(q, k, v, bias, scale, key_chunk=None):
 
 
 def attend_causal_blocks(q, k, v, mask, scale, block, key_chunk=None,
-                         window=None):
+                         window=None, offset=0):
     """A whole sequence from position 0, by blocks of ``block`` queries:
     block i sees keys 0 .. its own end (with ``window``: from ``window -
     1`` before its start), so no score tensor is larger than ``[heads,
     block, T]`` and half of them are never formed. ``q [B, Hkv, G, T,
     D]``, ``k [B, Hkv, T, D]``, ``v [B, Hkv, T, Dv]``; ``mask`` is the
     additive key-padding mask ``[B, 1, 1, T]`` or None. Returns ``[B,
-    Hkv, G, T, Dv]``."""
+    Hkv, G, T, Dv]``. With ``offset`` the keys begin that many rows
+    before the queries (``k``, ``v`` and ``mask`` are ``offset + T``
+    long): query i is the sequence's row ``offset + i``."""
     t, w = q.shape[3], window
     pad = 0.0 if mask is None else mask[:, :, None]      # [B,1,1,1,T]
     blocks = []
     for lo in range(0, t, block):
         hi = min(lo + block, t)
-        k0 = 0 if w is None else max(lo - w + 1, 0)
-        rows = jnp.arange(lo, hi)[:, None]
+        k0 = 0 if w is None else max(lo + offset - w + 1, 0)
+        rows = jnp.arange(lo + offset, hi + offset)[:, None]
+        hi += offset
         cols = jnp.arange(k0, hi)[None, :]
         keep = rows >= cols
         if w is not None:
@@ -139,8 +157,72 @@ def attend_causal_blocks(q, k, v, mask, scale, block, key_chunk=None,
         bias = jnp.where(keep, 0.0, _NEG_INF) \
             + (pad[..., k0:hi] if mask is not None else 0.0)
         blocks.append(attend_keys(
-            q[..., lo:hi, :], k[:, :, k0:hi], v[:, :, k0:hi], bias, scale,
-            key_chunk))
+            q[..., lo:hi - offset, :], k[:, :, k0:hi], v[:, :, k0:hi], bias,
+            scale, key_chunk))
+    return jnp.concatenate(blocks, axis=3)
+
+
+def _join(part, q, k, v, bias, scale):
+    """One more piece of keys into a running softmax: ``part`` is the
+    rows' maxima, sums and weighted values so far (float32)."""
+    m, total, out = part
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", q, k,
+                   preferred_element_type=jnp.float32) * scale + bias
+    top = jnp.maximum(m, s.max(-1, keepdims=True))
+    p, old = jnp.exp(s - top), jnp.exp(m - top)
+    return (top, old * total + p.sum(-1, keepdims=True),
+            old * out + jnp.einsum("bhgqk,bhkd->bhgqd", p.astype(v.dtype), v,
+                                   preferred_element_type=jnp.float32))
+
+
+def attend_continued(q, k, v, kc, vc, lo, mask, scale, block,
+                     key_chunk=None):
+    """A prompt's chunk against a ring that holds the prompt's rows
+    below ``lo [B]`` where their positions say (no wrap: a prompt fits
+    its full ring): ``q [B, Hkv, G, T, D]`` stands at ``lo .. lo + T -
+    1``, ``k`` / ``v [B, Hkv, T, D]`` are the chunk's own rows, ``kc`` /
+    ``vc [B, Hkv, ring, D]`` the ring, ``mask`` the chunk's additive
+    key-padding mask ``[B, 1, 1, T]`` or None. The ring is read
+    ``key_chunk`` rows at a time as far as ``lo`` reaches and no
+    further, the chunk's own rows causally, by blocks of ``block``
+    queries, all joined as one softmax: no score tensor is larger than
+    ``[heads, block, key_chunk]``."""
+    b, hkv, g, t, d = q.shape
+    ring = kc.shape[2]
+    step = min(ring, key_chunk or ring)
+    lo = lo.astype(jnp.int32)
+    spans = [(s, min(s + block, t)) for s in range(0, t, block)]
+    parts = tuple(
+        (jnp.full((b, hkv, g, e - s, 1), -1e30, jnp.float32),
+         jnp.zeros((b, hkv, g, e - s, 1), jnp.float32),
+         jnp.zeros((b, hkv, g, e - s, v.shape[-1]), jnp.float32))
+        for s, e in spans)
+
+    def past(i, parts):
+        # the last piece of a ring that is no whole number of them
+        # begins early: rows a piece before it had are masked
+        k0 = jnp.minimum(i * step, ring - step)
+        ks = jax.lax.dynamic_slice_in_dim(kc, k0, step, axis=2)
+        vs = jax.lax.dynamic_slice_in_dim(vc, k0, step, axis=2)
+        p = k0 + jnp.arange(step, dtype=jnp.int32)[None]
+        bias = jnp.where((p >= i * step) & (p < lo[:, None]), 0.0,
+                         _NEG_INF).astype(jnp.float32)[:, None, None, None]
+        return tuple(_join(part, q[..., s:e, :], ks, vs, bias, scale)
+                     for (s, e), part in zip(spans, parts))
+
+    parts = jax.lax.fori_loop(0, (lo.max() + step - 1) // step, past, parts)
+    pad = 0.0 if mask is None else mask[:, :, None]
+    blocks = []
+    for (s, e), part in zip(spans, parts):
+        rows = jnp.arange(s, e)[:, None]
+        for c0 in range(0, e, step):
+            c1 = min(c0 + step, e)
+            bias = jnp.where(rows >= jnp.arange(c0, c1)[None], 0.0,
+                             _NEG_INF).astype(jnp.float32) \
+                + (pad[..., c0:c1] if mask is not None else 0.0)
+            part = _join(part, q[..., s:e, :], k[:, :, c0:c1], v[:, :, c0:c1],
+                         bias, scale)
+        blocks.append((part[2] / part[1]).astype(v.dtype))
     return jnp.concatenate(blocks, axis=3)
 
 
@@ -181,6 +263,57 @@ class CachedGQAttention(Layer):
             x, jnp.clip(p, 0, x.shape[2] - 1)[:, None, :, None], axis=2)
         return jnp.where((p >= 0)[:, None, :, None], rows, 0)
 
+    def _rows_after(self, old, x, lo, length):
+        """The ring ``old [B, H, ring, D]`` after a chunk ``x [B, H, T,
+        D]`` whose first ``length [B]`` rows are real and stand at ``lo
+        [B]`` onwards: ring row ``j`` takes the last position below ``lo
+        + length`` that is ``j`` modulo the ring if the chunk has it,
+        and keeps what it held if not: a window ring, which wraps."""
+        ring = old.shape[2]
+        j = jnp.arange(ring, dtype=jnp.int32)[None]
+        last = (lo + length).astype(jnp.int32)[:, None] - 1
+        rel = last - jnp.mod(last - j, ring) - lo[:, None]   # [B, ring]
+        rows = jnp.take_along_axis(
+            x, jnp.clip(rel, 0, x.shape[2] - 1)[:, None, :, None], axis=2)
+        return jnp.where((rel >= 0)[:, None, :, None], rows.astype(old.dtype),
+                         old)
+
+    def _continue(self, q, k, v, cache, mask):
+        """The chunk case (module docstring): ``(o, new cache)``."""
+        kc, vc, lo = cache
+        b, _, t, d = k.shape
+        ring, w = kc.shape[2], self.window
+        if w is None:
+            o = attend_continued(q, k, v, kc, vc, lo, mask, d ** -0.5,
+                                 self.prefill_block, self.key_chunk)
+        else:
+            # the ring's rows in position order, lo - ring .. lo - 1 (the
+            # first of them is beyond every query's band), then the chunk
+            p = lo[:, None] - ring + jnp.arange(ring, dtype=jnp.int32)[None]
+            at = jnp.mod(p, ring)[:, None, :, None]
+            pad = jnp.zeros((b, 1, 1, t), jnp.float32) if mask is None \
+                else mask
+            held = jnp.where(p >= 0, 0.0, _NEG_INF).astype(
+                pad.dtype)[:, None, None, :]
+            o = attend_causal_blocks(
+                q, *(jnp.concatenate(
+                    [jnp.take_along_axis(c, at, axis=2).astype(n.dtype), n],
+                    axis=2) for c, n in ((kc, k), (vc, v))),
+                jnp.concatenate([held, pad], axis=-1), d ** -0.5,
+                self.prefill_block, self.key_chunk, w, offset=ring)
+        if w is None:
+            # chunks begin at multiples of their length, which divides
+            # the ring (the engine sees to it): lo + t <= ring
+            assert ring % t == 0, (ring, t)
+            kc, vc = (_write_rows(c, n.astype(c.dtype), lo)
+                      for c, n in ((kc, k), (vc, v)))
+        else:
+            length = jnp.full((b,), t) if mask is None \
+                else (mask[:, 0, 0, :] == 0).sum(-1)
+            kc, vc = (self._rows_after(c, n, lo, length)
+                      for c, n in ((kc, k), (vc, v)))
+        return o, StaticCache(kc, vc, lo)
+
     def forward(self, x, cache=None, mask=None, positions=None):
         """``x [B, T, hidden]`` (an array); ``mask``: see the module's
         docstring; ``positions [B, T]`` for a rotary layer. Returns
@@ -209,6 +342,8 @@ class CachedGQAttention(Layer):
             if isinstance(mask, dict):
                 mask = mask[kc.shape[2]]
             o = attend(q, kc, vc, mask[:, :, None], d ** -0.5)
+        elif isinstance(cache, ContinuedCache):
+            o, cache = self._continue(q, k, v, cache, mask)
         else:
             o = attend_causal_blocks(q, k, v, mask, d ** -0.5,
                                      self.prefill_block, self.key_chunk, w)

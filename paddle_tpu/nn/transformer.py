@@ -108,6 +108,17 @@ class StaticCache(NamedTuple):
     pos: Any
 
 
+class ContinuedCache(StaticCache):
+    """A :class:`StaticCache` as a prompt's CHUNK is handed it: the ring
+    holds the prompt's rows below ``pos``, each where its position says,
+    and the chunk's tokens stand at ``pos`` onwards. A layer that is
+    given more than one token with it attends the ring's rows and the
+    chunk's own and writes the chunk's in from there, where with a plain
+    :class:`StaticCache` it fills fresh rings from position 0."""
+
+    __slots__ = ()
+
+
 class RecurrentCache(NamedTuple):
     """Per-slot state of ONE linear-attention layer: what a recurrence
     keeps in place of a growing K/V window.

@@ -33,6 +33,16 @@ so the host's work an iteration costs the device nothing. A request
 that ends on EOS at step n has a row in step n+1 already: computed in
 vain, never delivered. An admission drains the look-ahead (its first
 token is on the host): see :meth:`ContinuousBatcher._loop`.
+
+An admission has one of two units of work. Where the engine can take a
+prompt up again from the rows its slot holds
+(``engine.begin_admission``), a long prompt goes in a CHUNK an iteration,
+the live slots' decode step between two chunks, so a stream's gap is a
+step and a chunk where it was a step and the whole prompt; a chunk that
+is not the last gives the host nothing and the look-ahead stays; the
+last one's token is waited for after the step in flight is delivered.
+One prompt is in its chunks at a time, first come first served.
+Everywhere else the unit is the whole prompt, as it always was.
 """
 from __future__ import annotations
 
@@ -125,6 +135,18 @@ class GenerationRequest:
         return self.tokens
 
 
+class _Chunked:
+    """The request whose prompt is in its chunks: where it will sit,
+    whether other slots were live when it was picked, its admission span
+    and the engine's handle."""
+
+    __slots__ = ("req", "slot", "midbatch", "span", "adm")
+
+    def __init__(self, req, slot, midbatch, span, adm):
+        self.req, self.slot, self.midbatch = req, slot, midbatch
+        self.span, self.adm = span, adm
+
+
 class _Flight:
     """One decode step between its enqueue and its delivery: the
     requests that sat in the slots when it was enqueued, when that was,
@@ -185,6 +207,9 @@ class ContinuousBatcher:
         # thread's own), and when the last step's tokens were delivered
         self._flight = None
         self._t_landed_ns = 0
+        # the request whose prompt is in its chunks (the loop thread's
+        # own; its slot is spoken for though nobody sits in it yet)
+        self._chunked = None
         # the engine owns the warmup-snapshot watch (armed by warmup());
         # the loop notes growth through it after every step
         self._watch = engine.watch
@@ -482,50 +507,146 @@ class ContinuousBatcher:
         running batch down). Phases: ``serving::pick`` up to the call
         into the engine, the engine's own ``generation::prefill`` and
         ``::prefill_fetch``, then ``serving::install``. With a decode
-        step in flight the prefill is enqueued behind it. Returns
-        whether the engine was asked for an admission at all."""
+        step in flight the prefill is enqueued behind it. Where the
+        engine takes the prompt by chunks, the request is only seated
+        (``_chunked``): the loop runs ONE chunk an iteration
+        (:meth:`_admit_chunk`), and nothing else is admitted until the
+        prompt is in. Returns whether a first token was waited for (an
+        admission that drains the look-ahead)."""
         engine = self.engine
         admitted = False
-        while True:
+        while self._chunked is None:
             picked = self._pick()
             self._mark("serving::pick")
             if picked is None:
                 return admitted
-            admitted = True
             req, free, midbatch, asp = picked
-            try:
-                with _tracing.use_span(asp):
-                    if isinstance(req.handoff, PageSlab):
-                        slab = req.handoff
-                        tok = engine.admit_prefilled_pages(
-                            free, slab.pages, slab.length,
-                            slab.first_token,
-                            page_size=slab.page_size,
+            if req.handoff is None:
+                adm = engine.begin_admission(free, req.prompt,
+                                             req.temperature)
+                if adm is not None:
+                    self._chunked = _Chunked(req, free, midbatch, asp, adm)
+                    break
+            admitted = True
+            self._admit(req, free, midbatch, asp,
+                        lambda: self._admit_whole(req, free))
+        return admitted
+
+    def _admit_whole(self, req, free):
+        """The engine's call for a prompt that goes in whole, or for one
+        prefilled elsewhere: the first token."""
+        engine = self.engine
+        if isinstance(req.handoff, PageSlab):
+            slab = req.handoff
+            return engine.admit_prefilled_pages(
+                free, slab.pages, slab.length, slab.first_token,
+                page_size=slab.page_size, tenant=req.tenant)
+        if req.handoff is not None:
+            planes, length, first = req.handoff
+            return engine.admit_prefilled(free, planes, length, first,
+                                          prompt=req.prompt)
+        return engine.admit(free, req.prompt, req.temperature,
                             tenant=req.tenant)
-                    elif req.handoff is not None:
-                        planes, length, first = req.handoff
-                        tok = engine.admit_prefilled(
-                            free, planes, length, first,
-                            prompt=req.prompt)
-                    else:
-                        tok = engine.admit(free, req.prompt,
-                                           req.temperature,
-                                           tenant=req.tenant)
-            except Exception as e:  # noqa: BLE001 — the loop must survive
-                self._t_ns = time.perf_counter_ns()
-                asp.set_error(f"{type(e).__name__}: {e}")
-                _tracing.record_fanin(asp, [req.trace])
-                _tracing.flag_trace(req.trace, "error")
-                self._m_errors.inc()
-                req.done(error=e)
-                if isinstance(e, CacheLostError):
-                    # the failed prefill took every slot's context
-                    self._fail_live(e)
-                continue
-            # the engine's spans cover its call; install begins here
+
+    def _admit(self, req, free, midbatch, asp, call):
+        """One call into the engine for ``req`` under its admission
+        span. Its first token (``None``: a chunk that is not the
+        prompt's last) installs the request; an error fails it, and
+        every live stream too if the call lost the cache. Returns
+        whether the request is still on its way in."""
+        try:
+            with _tracing.use_span(asp):
+                tok = call()
+        except Exception as e:  # noqa: BLE001 — the loop must survive
             self._t_ns = time.perf_counter_ns()
-            self._install(req, free, tok, midbatch, asp)
-            self._mark("serving::install")
+            self._give_up_chunked()
+            self._m_errors.inc()
+            self._fail_admission(req, asp, e)
+            if isinstance(e, CacheLostError):
+                # the failed prefill took every slot's context
+                self._fail_live(e)
+            return False
+        # the engine's spans cover its call; the next phase begins here
+        self._t_ns = time.perf_counter_ns()
+        if tok is None:
+            return True
+        self._install(req, free, tok, midbatch, asp)
+        self._mark("serving::install")
+        return False
+
+    def _give_up_chunked(self):
+        """Leave the prompt that is in its chunks, if one is, where it
+        stands: its slot is vacant again as it is. Returns it."""
+        c, self._chunked = self._chunked, None
+        if c is not None:
+            self.engine.abandon_admission(c.adm)
+            self.engine.release_slot(c.slot)
+        return c
+
+    def _fail_admission(self, req, asp, e):
+        """Close ``req``'s admission span on the error and fail the
+        request with it, unless somebody has failed it already."""
+        asp.set_error(f"{type(e).__name__}: {e}")
+        _tracing.record_fanin(asp, [req.trace])
+        _tracing.flag_trace(req.trace, "error")
+        if not req.finished:
+            req.done(error=e)
+
+    def _step_before_chunk(self, depth):
+        """No step is in flight (the last admission drained the
+        look-ahead) and a chunk is about to go: the live slots' step goes
+        first, from the host's tokens, so that this iteration looks ahead
+        like any other with a chunk. Behind the chunk the step would wait
+        for it, and for the last chunk of the prompt before, whose token
+        the host has only just seen: under load one prompt's chunks
+        follow another's, and every live stream's gap there would be two
+        chunks and a step."""
+        seated = self._seated(None)
+        if not seated:
+            return
+        try:
+            self._flight = self._launch(seated, None, depth)
+        except Exception as e:  # noqa: BLE001 — fail THESE, keep serving
+            self._fail_live(e)
+        self._t_ns = time.perf_counter_ns()
+
+    def _admit_chunk(self):
+        """The next chunk of the prompt that is in its chunks, enqueued
+        and left. A request that was failed from outside or whose
+        deadline passed since its last chunk is given up. Returns
+        whether it was the prompt's last chunk: an admission, which
+        drains the look-ahead. Its token is waited for at once where no
+        step is in flight, and else after that step is delivered
+        (:meth:`_loop`): the step's tokens are ready a chunk before the
+        prompt's first is."""
+        c = self._chunked
+        now = self._clock()
+        if c.req.finished or c.req.expired(now):
+            self._give_up_chunked()
+            e = c.req.error
+            if not c.req.finished:
+                self._m_expired.inc()
+                e = DeadlineExceededError(
+                    f"generation deadline passed after "
+                    f"{(now - c.req.t_submit) * 1e3:.1f}ms, "
+                    f"{c.adm.lo} of {c.req.prompt_len} prompt tokens in")
+            self._fail_admission(c.req, c.span, e)
+            self._mark("serving::pick")
+            return False
+        if not self._admit(c.req, c.slot, c.midbatch, c.span,
+                           lambda: self.engine.enqueue_chunk(c.adm)):
+            return True  # failed, and given up already
+        if c.adm.done and self._flight is None:
+            self._finish_chunked()
+        return c.adm.done
+
+    def _finish_chunked(self):
+        """Wait for the first token of the prompt whose last chunk is
+        enqueued, and install its request."""
+        c = self._chunked
+        self._admit(c.req, c.slot, c.midbatch, c.span,
+                    lambda: self.engine.fetch_admission(c.adm))
+        self._chunked = None
 
     def _install(self, req, free, tok, midbatch, asp):
         """After the first token is back: close the admission span,
@@ -571,6 +692,11 @@ class ContinuousBatcher:
         step in flight is dropped unfetched: nobody is left to take
         its tokens."""
         self._flight = None
+        c = self._give_up_chunked()
+        if c is not None:
+            # half in: its rows went with the cache, or with the step
+            self._m_errors.inc()
+            self._fail_admission(c.req, c.span, e)
         busy = [s for s, r in enumerate(self._slots) if r is not None]
         for s in busy:
             req, self._slots[s] = self._slots[s], None
@@ -734,7 +860,23 @@ class ContinuousBatcher:
         # decode_fetch, deliver); the next iteration enqueues from the
         # host's tokens and fetches nothing (pick, decode), and the one
         # after looks ahead again. At 0 an iteration is what it always
-        # was: the step it enqueues is the step it fetches.
+        # was: the step it enqueues is the step it fetches. A chunk that
+        # is not its prompt's last is enqueued and left (pick, prefill,
+        # decode, decode_fetch, deliver): on the device the order is
+        # step n, chunk, step n+1, the host fetches step n as in a plain
+        # iteration, and the look-ahead stays. The last chunk is an
+        # admission: nothing is enqueued behind it, but the step in
+        # flight is fetched and delivered BEFORE the chunk's token is
+        # waited for (pick, prefill, decode_fetch, deliver,
+        # prefill_fetch, install): the device is a chunk and a step
+        # behind the host by then, and the step's tokens are ready a
+        # chunk earlier than the prompt's first. The iteration after it
+        # has no step in flight: if it carries a chunk (the next prompt's
+        # first), the step is enqueued BEFORE the chunk and the iteration
+        # looks ahead at once (pick, decode, prefill, decode,
+        # decode_fetch, deliver). At most one step runs between two
+        # chunks of a prompt, and none between its last chunk and the
+        # fetch of its token (the engine refuses them).
         engine = self.engine
         engine.phase_split = self._split
         # asked of a device that may be the one in trouble: a read that
@@ -746,6 +888,12 @@ class ContinuousBatcher:
         while True:
             admitted = self._admit_ready()
             depth = engine.steps_ahead
+            if self._chunked is not None and not admitted:
+                # (after a whole admission the chunk waits an iteration:
+                # the step behind it would wait for both)
+                if depth and self._flight is None:
+                    self._step_before_chunk(depth)
+                admitted = self._chunked is not None and self._admit_chunk()
             flight, self._flight = self._flight, None
             seated = self._seated(flight)
             ahead = bool(depth and flight is not None and not admitted
@@ -753,9 +901,12 @@ class ContinuousBatcher:
             self._sample_counters(ahead)
             if flight is None and not seated:
                 with self._lock:
-                    if self._closed and not self._q:
+                    # a prompt in its chunks is work: neither left nor
+                    # waited on
+                    if self._closed and not self._q \
+                            and self._chunked is None:
                         break
-                    if not self._q:
+                    if not self._q and self._chunked is None:
                         self._not_empty.wait(0.05)
                 self._mark("serving::idle_wait")
                 self._end_iteration()
@@ -776,6 +927,9 @@ class ContinuousBatcher:
             self._t_ns = time.perf_counter_ns()
             if flight is not None:
                 self._land(flight)
+            if self._chunked is not None and self._chunked.adm.done:
+                # its last chunk went in behind the step just delivered
+                self._finish_chunked()
             if nxt is not None and nxt.rows is not None:
                 self._land(nxt)
             else:
@@ -835,6 +989,10 @@ class ContinuousBatcher:
                 self.engine.release_slot(s)
                 if not req.finished:
                     dropped.append(req)
+        c = self._chunked
+        if c is not None:
+            # the loop gives its slot up when it sees the request done
+            dropped.append(c.req)
         for req in dropped:
             if not req.finished:
                 self._m_errors.inc()

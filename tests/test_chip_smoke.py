@@ -31,7 +31,9 @@ def test_every_leg_passes_at_the_tiny_preset():
     assert legs["gpt"]["tokens_served"] >= legs["gpt"]["requests"]
     assert legs["hybrid"]["warmup_compiles"] == 3
     assert 0 < legs["hybrid"]["state_bytes"] < legs["hybrid"]["cache_bytes"]
-    assert legs["window"]["warmup_compiles"] == 4  # ladder of 3, + 1
+    # a cache of rings alone takes long prompts by chunks of the ladder's
+    # second bucket: two buckets, the chunk program and the decode program
+    assert legs["window"]["warmup_compiles"] == 4
     assert legs["window"]["rings"] == [8, 32]
     assert 0 < legs["window"]["window_ring_bytes"] \
         < legs["window"]["full_ring_bytes"]
