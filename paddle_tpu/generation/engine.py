@@ -971,9 +971,14 @@ class GenerationEngine:
     def _warmup_plan(self, kind):
         """``[(calls, run)]``: ``run()`` is one warm-up step through the
         normal entry point, ``calls`` the ``(label, jitted, make_args)``
-        of the programs it dispatches. The order is immaterial to the
-        time: the compiles (or loads) share the machine and end within
-        a second of each other (PERF.md, PR 26)."""
+        of the programs it dispatches; the chunk program first, where
+        there is one (the slowest to load: 3.5-3.7 s), then the prefill
+        buckets largest first, the decode program last. The programs
+        are traced one after another on this thread while the ones
+        before load on theirs, so what the warm-up waits for at its end
+        is the LAST program's load, and the other programs' first runs
+        pass behind it: the largest buckets' loads took 2.1-3.8 s
+        there, the decode program's 1.6 (PERF.md, PR 47)."""
         temp = self.default_temperature
         zeros_i = np.zeros(self.slots, np.int32)
         zeros_f = np.zeros(self.slots, np.float32)
@@ -988,12 +993,6 @@ class GenerationEngine:
         elif kind != "prefill":
             plan.append(([self._decode_call(zeros_i, zeros_f, 0)],
                          lambda: self.step(zeros_i, zeros_f)))
-        if kind == "generate" and self.chunk_len is not None:
-            # two chunks, the second of one token
-            prompt = [self.pad_id] * (self.chunk_len + 1)
-            plan.append((
-                [self._chunk_call(Admission(0, prompt, temp), 0)],
-                lambda p=prompt: self.admit(0, p)))
         for bucket in (self._reached_buckets() if kind == "generate"
                        else self.prefill_buckets):
             prompt = [self.pad_id] * int(bucket)
@@ -1010,6 +1009,12 @@ class GenerationEngine:
                 plan.append((
                     [self._draft_prefill_call(0, padded, n)],
                     lambda p=prompt: self._admit_draft(0, p)))
+        if kind == "generate" and self.chunk_len is not None:
+            # two chunks, the second of one token
+            prompt = [self.pad_id] * (self.chunk_len + 1)
+            plan.append((
+                [self._chunk_call(Admission(0, prompt, temp), 0)],
+                lambda p=prompt: self.admit(0, p)))
         if kind == "decode":
             # pre-drive the handoff admission: the eager pad/insert ops
             # pay their one-time op compiles NOW (per plane shape), not
@@ -1018,7 +1023,7 @@ class GenerationEngine:
             plan.append(([], lambda: self.admit_prefilled(
                 0, self._fresh_slot_planes(), 1, 0,
                 prompt=[self.pad_id] if self.speculative else None)))
-        return plan
+        return plan[::-1]
 
     def _reached_buckets(self):
         """The ladder buckets an admission can take: all of them, or,

@@ -47,7 +47,7 @@ from ..nn.gqa import CachedGQAttention, rms_norm
 from ..nn.layer_base import Layer
 from ..nn.layers import LayerList
 from ..nn.linear_attention import normal_or_zeros
-from ..parallel.moe import RoutedExperts
+from ..parallel.moe import RoutedExperts, routing_stats
 
 __all__ = ["ExaoneMoEConfig", "ExaoneMoEForCausalLM"]
 
@@ -164,22 +164,14 @@ class ExaoneDecoderLayer(Layer):
 
     def _ffn(self, y, valid):
         """The feed-forward, a long prompt ``_FFN_CHUNK`` tokens at a
-        time (one loop body, so the peak is one chunk's); an expert
-        layer's ``last_load`` is then the chunks' sum."""
+        time (one loop body, so the peak is one chunk's)."""
+        if not self.dense:
+            return self.moe.in_chunks(y, valid, _FFN_CHUNK)
         b, t, h = y.shape
         if t <= _FFN_CHUNK or t % _FFN_CHUNK:
-            return self.mlp(y) if self.dense else self.moe(y, valid=valid)
-        if valid is None:
-            valid = jnp.ones((b, t), bool)
-        chunks = (y.reshape(b, -1, _FFN_CHUNK, h).swapaxes(0, 1),
-                  valid.reshape(b, -1, _FFN_CHUNK).swapaxes(0, 1))
-        if self.dense:
-            out = jax.lax.map(lambda c: self.mlp(c[0]), chunks)
-        else:
-            out, loads = jax.lax.map(
-                lambda c: (self.moe(c[0], valid=c[1]), self.moe.last_load),
-                chunks)
-            self.moe.last_load = loads.sum(0)
+            return self.mlp(y)
+        out = jax.lax.map(
+            self.mlp, y.reshape(b, -1, _FFN_CHUNK, h).swapaxes(0, 1))
         return out.swapaxes(0, 1).reshape(b, t, h)
 
 
@@ -238,8 +230,10 @@ class ExaoneMoEForCausalLM(Layer):
         """What the last forward routed here, per expert layer: token-
         expert pairs that landed on held experts (``pairs [L]``),
         distinct held experts that got at least one (``hit [L]``), and
-        per held expert its pairs over all layers (``load [held]``).
-        Inside a trace these are traced values of that trace."""
+        per held expert its pairs over all layers (``load [held]``);
+        where the experts' kernel ran, also the rows its row tiles
+        multiplied for those pairs (``tile_rows [L]``). Inside a trace
+        these are traced values of that trace."""
         return self._stats
 
     def _ids(self, input_ids):
@@ -269,7 +263,7 @@ class ExaoneMoEForCausalLM(Layer):
         if mask is not None and t > 1:
             valid = mask[:, 0, 0, :] == 0
         x = self.embed_tokens._array[ids]
-        new_caches, loads = [], []
+        new_caches = []
         for i, layer in enumerate(self.layers):
             out = layer(x, cache=None if caches is None else caches[i],
                         mask=mask, positions=position_ids, valid=valid)
@@ -278,11 +272,8 @@ class ExaoneMoEForCausalLM(Layer):
             else:
                 x, c = out
                 new_caches.append(c)
-            if not layer.dense:
-                loads.append(layer.moe.last_load)
-        loads = jnp.stack(loads)
-        self._stats = {"pairs": loads.sum(1), "hit": (loads > 0).sum(1),
-                       "load": loads.sum(0)}
+        self._stats = routing_stats(
+            [layer.moe for layer in self.layers if not layer.dense])
         return x, (None if caches is None else new_caches), valid
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,

@@ -46,7 +46,6 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-import jax
 import jax.numpy as jnp
 
 from ..framework.tensor import Parameter, Tensor
@@ -56,7 +55,7 @@ from ..nn.layer_base import Layer
 from ..nn.layers import LayerList
 from ..nn.linear_attention import normal_or_zeros
 from ..nn.mla import CachedLatentAttention
-from ..parallel.moe import RoutedExperts
+from ..parallel.moe import RoutedExperts, routing_stats
 from .exaone_moe import DenseSwiGLU
 
 __all__ = ["LongcatFlashConfig", "LongcatFlashForCausalLM"]
@@ -153,29 +152,12 @@ class LongcatDecoderLayer(Layer):
 
         a = attend(0, x, self.input_norm_0)
         u = rms_norm(a, self.post_norm_0._array, self.eps)
-        s = self._experts(u, valid)
+        s = self.moe.in_chunks(u, valid, _MOE_CHUNK)
         b = a + self.mlp[0](u)
         c = attend(1, b, self.input_norm_1)
         x = c + self.mlp[1](rms_norm(c, self.post_norm_1._array, self.eps)) \
             + s
         return x if caches is None else (x, new)
-
-    def _experts(self, u, valid):
-        """The routed branch, a long prompt ``_MOE_CHUNK`` tokens at a
-        time (one loop body, so the peak is one chunk's); ``last_load``
-        and ``last_zero`` are then the chunks' sums."""
-        b, t, h = u.shape
-        if t <= _MOE_CHUNK or t % _MOE_CHUNK:
-            return self.moe(u, valid=valid)
-        if valid is None:
-            valid = jnp.ones((b, t), bool)
-        out, loads, zeros = jax.lax.map(
-            lambda c: (self.moe(c[0], valid=c[1]), self.moe.last_load,
-                       self.moe.last_zero),
-            (u.reshape(b, -1, _MOE_CHUNK, h).swapaxes(0, 1),
-             valid.reshape(b, -1, _MOE_CHUNK).swapaxes(0, 1)))
-        self.moe.last_load, self.moe.last_zero = loads.sum(0), zeros.sum(0)
-        return out.swapaxes(0, 1).reshape(b, t, h)
 
 
 class LongcatFlashForCausalLM(Layer):
@@ -214,8 +196,10 @@ class LongcatFlashForCausalLM(Layer):
         expert pairs that landed on held experts (``pairs [L]``),
         distinct held experts that got at least one (``hit [L]``), pairs
         that chose a zero-compute expert (``zero_pairs [L]``), and per
-        held expert its pairs over all layers (``load [held]``). Inside
-        a trace these are traced values of that trace."""
+        held expert its pairs over all layers (``load [held]``); where
+        the experts' kernel ran, also the rows its row tiles multiplied
+        for those pairs (``tile_rows [L]``). Inside a trace these are
+        traced values of that trace."""
         return self._stats
 
     def _head(self, x):
@@ -239,7 +223,7 @@ class LongcatFlashForCausalLM(Layer):
         if mask is not None and t > 1:
             valid = mask[:, 0, 0, :] == 0
         x = self.embed_tokens._array[ids]
-        new_caches, loads, zeros = [], [], []
+        new_caches = []
         for i, layer in enumerate(self.layers):
             out = layer(x, mask=mask, positions=position_ids, valid=valid,
                         caches=None if caches is None
@@ -249,11 +233,7 @@ class LongcatFlashForCausalLM(Layer):
             else:
                 x, pair = out
                 new_caches.extend(pair)
-            loads.append(layer.moe.last_load)
-            zeros.append(layer.moe.last_zero)
-        loads = jnp.stack(loads)
-        self._stats = {"pairs": loads.sum(1), "hit": (loads > 0).sum(1),
-                       "load": loads.sum(0), "zero_pairs": jnp.stack(zeros)}
+        self._stats = routing_stats([layer.moe for layer in self.layers])
         if caches is not None and t > 1:
             # a prefill is read at its last real position only
             last = (t if valid is None else valid.sum(-1)) - 1

@@ -55,7 +55,7 @@ from ..nn.layers import LayerList
 from ..nn.gqa import CachedGQAttention, rms_norm
 from ..nn.linear_attention import normal_or_zeros
 from ..nn.state_space import Mamba2Mixer
-from ..parallel.moe import RoutedExperts
+from ..parallel.moe import RoutedExperts, routing_stats
 
 __all__ = ["NemotronHConfig", "NemotronHForCausalLM"]
 
@@ -227,24 +227,18 @@ class NemotronHForCausalLM(Layer):
             valid = mask[:, 0, 0, :] == 0
         x = self.embed_tokens._array[ids]
         kept = iter(caches or ())
-        new_caches, loads, tile_rows = [], [], []
+        new_caches = []
         for layer in self.layers:
             if layer.kind == "E":
                 x = layer(x, valid=valid)
-                loads.append(layer.mixer.last_load)
-                if layer.mixer.last_tile_rows is not None:
-                    tile_rows.append(layer.mixer.last_tile_rows)
             elif caches is None:
                 x = layer(x, mask=mask, valid=valid)
             else:
                 x, c = layer(x, cache=next(kept), mask=mask, valid=valid)
                 new_caches.append(c)
-        if loads:
-            loads = jnp.stack(loads)
-            self._stats = {"pairs": loads.sum(1), "hit": (loads > 0).sum(1),
-                           "load": loads.sum(0)}
-            if tile_rows:
-                self._stats["tile_rows"] = jnp.stack(tile_rows)
+        experts = [layer.mixer for layer in self.layers if layer.kind == "E"]
+        if experts:
+            self._stats = routing_stats(experts)
         if caches is not None and t > 1:
             # a prefill is read at its last real position only
             last = (t if valid is None else valid.sum(-1)) - 1

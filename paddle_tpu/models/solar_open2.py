@@ -40,7 +40,7 @@ from ..nn.layer_base import Layer
 from ..nn.layers import LayerList
 from ..nn.gqa import CachedGQAttention, rms_norm as _rms_norm
 from ..nn.linear_attention import GatedDeltaAttention, normal_or_zeros
-from ..parallel.moe import RoutedExperts
+from ..parallel.moe import RoutedExperts, routing_stats
 
 __all__ = ["HybridMoEConfig", "HybridMoEForCausalLM"]
 
@@ -163,8 +163,10 @@ class HybridMoEForCausalLM(Layer):
         """What the last forward routed here, per expert layer: token-
         expert pairs that landed on held experts (``pairs [L]``),
         distinct held experts that got at least one (``hit [L]``), and
-        per held expert its pairs over all layers (``load [held]``).
-        Inside a trace these are traced values of that trace."""
+        per held expert its pairs over all layers (``load [held]``);
+        where the experts' kernel ran, also the rows its row tiles
+        multiplied for those pairs (``tile_rows [L]``). Inside a trace
+        these are traced values of that trace."""
         return self._stats
 
     def forward(self, input_ids, position_ids=None, attention_mask=None,
@@ -178,17 +180,14 @@ class HybridMoEForCausalLM(Layer):
         if mask is not None and t > 1:
             valid = mask[:, 0, 0, :] == 0
         x = self.embed_tokens._array[ids]
-        new_caches, loads = [], []
+        new_caches = []
         for i, layer in enumerate(self.layers):
             if caches is None:
                 x = layer(x, mask=mask, valid=valid)
             else:
                 x, c = layer(x, cache=caches[i], mask=mask, valid=valid)
                 new_caches.append(c)
-            loads.append(layer.moe.last_load)
-        loads = jnp.stack(loads)
-        self._stats = {"pairs": loads.sum(1), "hit": (loads > 0).sum(1),
-                       "load": loads.sum(0)}
+        self._stats = routing_stats([layer.moe for layer in self.layers])
         x = _rms_norm(x, self.norm._array, self.config.rms_norm_eps)
         logits = Tensor._from_array(jnp.matmul(
             x, self.lm_head._array, preferred_element_type=jnp.float32))

@@ -29,13 +29,15 @@ from ..framework.tensor import Parameter, Tensor
 from ..nn import functional as F
 from ..nn.layer_base import Layer
 from ..nn.layers import Linear
-from ..ops.pallas._platform import can_emit_mosaic
-from ..ops.pallas.grouped_relu2 import (grouped_relu2,
-                                        grouped_relu2_supported)
+from ..ops.pallas._platform import can_emit_mosaic, prefetch_pallas
+# the kernel's shape test keeps the name by which
+# benchmark/tests/test_moe_tile_rows_reader.py opens it
+from ..ops.pallas.grouped_experts import (
+    grouped_experts, grouped_experts_supported as grouped_relu2_supported)
 from .mesh import get_mesh
 from .sharding import ShardingRules, with_sharding_constraint
 
-__all__ = ["MoELayer", "SwitchFFN", "RoutedExperts"]
+__all__ = ["MoELayer", "SwitchFFN", "RoutedExperts", "routing_stats"]
 
 
 class SwitchFFN(Layer):
@@ -229,9 +231,10 @@ class RoutedExperts(Layer):
     have rows, so a decode step reads the weights of the experts that
     were hit and no others). The same path serves a prompt of thousands
     of tokens and a decode step of a few dozen. Where :meth:`takes_kernel`
-    says so (``"relu2"`` experts on a TPU), the two products of a layer
-    are one Mosaic kernel over the same sorted rows instead, with the
-    hidden rows in VMEM (``ops/pallas/grouped_relu2.py``).
+    says so (on a TPU, either activation), the three or two products of
+    a layer are one Mosaic kernel over the same sorted rows instead, a
+    hit expert's matrices read once and the hidden rows in VMEM
+    (``ops/pallas/grouped_experts.py``).
 
     The leaves, by name (a caller that hands weights over does so by
     these names), with ``w`` = ``latent_size or hidden_size``:
@@ -321,6 +324,7 @@ class RoutedExperts(Layer):
             param("shared_up", (h, self.shared_width))
             param("shared_down", (self.shared_width, h))
         self.last_load = self.last_zero = self.last_tile_rows = None
+        prefetch_pallas()  # the kernel's imports, while the weights are made
 
     def route(self, x):
         """``(idx [T, k], w [T, k])``: the chosen experts of each token
@@ -343,14 +347,17 @@ class RoutedExperts(Layer):
 
     def takes_kernel(self, xs):
         """Whether the grouped products over the sorted rows ``xs`` run
-        as ONE Mosaic kernel here and now (``ops/pallas/grouped_relu2``):
-        experts without a gate, a TPU, no multi-device mesh in scope,
-        shapes the kernel supports. Everywhere else they are
-        ``jax.lax.ragged_dot`` calls."""
-        up = self.w_up._array
-        return (not self.gated and can_emit_mosaic()
-                and up.dtype == xs.dtype and grouped_relu2_supported(
-                    xs.shape, up.shape, self.w_down._array.shape, xs.dtype))
+        as ONE Mosaic kernel here and now (``ops/pallas/grouped_experts``),
+        by what the call can see: a TPU, no multi-device mesh in scope,
+        weights of the rows' dtype, shapes the kernel supports (whole
+        lanes, whole sublane packs of rows, a block of the expert's
+        matrices that fits VMEM), gated experts or not. Everywhere else
+        they are ``jax.lax.ragged_dot`` calls."""
+        up, down = self.w_up._array, self.w_down._array
+        gate = (self.w_gate._array.shape,) if self.gated else ()
+        return (can_emit_mosaic() and up.dtype == xs.dtype
+                and grouped_relu2_supported(xs.shape, up.shape, down.shape,
+                                            xs.dtype, *gate))
 
     def in_chunks(self, x, valid=None, chunk=1024):
         """:meth:`forward` of ``x [B, T, hidden]``, a long sequence
@@ -403,9 +410,10 @@ class RoutedExperts(Layer):
                 xs = x[order // k]
             self.last_tile_rows = None
             if self.takes_kernel(xs):
-                # both products in one kernel, the hidden rows in VMEM
-                out, self.last_tile_rows = grouped_relu2(
-                    xs, self.w_up._array, self.w_down._array, sizes)
+                # the products in one kernel, the hidden rows in VMEM
+                out, self.last_tile_rows = grouped_experts(
+                    xs, self.w_up._array, self.w_down._array, sizes,
+                    self.w_gate._array if self.gated else None)
             else:
                 if self.gated:
                     gate = jax.lax.ragged_dot(xs, self.w_gate._array, sizes)
@@ -456,3 +464,23 @@ class RoutedExperts(Layer):
                                    self.shared_down._array,
                                    preferred_element_type=jnp.float32)
             return y.astype(x.dtype).reshape(shape)
+
+
+def routing_stats(layers):
+    """What the last forward of each of the :class:`RoutedExperts`
+    ``layers`` routed here, a value a layer: token-expert pairs that
+    landed on held experts (``pairs [L]``), distinct held experts that
+    got at least one (``hit [L]``), per held expert its pairs over all
+    layers (``load [held]``); where the layers have zero-compute
+    experts, the pairs that chose one (``zero_pairs [L]``); where the
+    experts' kernel ran, the rows its row tiles multiplied for those
+    pairs (``tile_rows [L]``). Inside a trace these are traced values
+    of that trace."""
+    loads = jnp.stack([m.last_load for m in layers])
+    stats = {"pairs": loads.sum(1), "hit": (loads > 0).sum(1),
+             "load": loads.sum(0)}
+    for name, last in (("zero_pairs", "last_zero"),
+                       ("tile_rows", "last_tile_rows")):
+        if getattr(layers[0], last) is not None:
+            stats[name] = jnp.stack([getattr(m, last) for m in layers])
+    return stats

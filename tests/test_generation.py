@@ -237,6 +237,23 @@ def test_engine_steady_state_is_compile_bound():
     assert _compiles() - before == len(eng.prefill_buckets) + 1
 
 
+def test_warmup_traces_the_largest_program_first_and_decode_last():
+    """The warm-up traces its programs one after another on the caller's
+    thread while the ones before load on theirs, so what it waits for at
+    its end is the last program's load: the largest bucket goes first
+    and the decode program, the quickest to load, last (PERF.md, PR 47);
+    each run follows its program in the same order."""
+    eng = GenerationEngine(_tiny_lm(window=32), slots=2, cache_len=32,
+                           prefill_buckets=(4, 8, 16), seed=1)
+    plan = eng._warmup_plan("generate")
+    # a prefill call's fourth argument is its padded prompt, [1, bucket]
+    sizes = [calls[0][2]()[3].shape[1] if calls[0][0] == "prefill" else 0
+             for calls, _ in plan]
+    assert [calls[0][0] for calls, _ in plan] \
+        == ["prefill", "prefill", "prefill", "decode"]
+    assert sizes == [16, 8, 4, 0]
+
+
 def test_engine_greedy_matches_full_forward():
     """Greedy engine tokens == the argmax chain of repeated full
     forwards (bucket padding and slot co-batching are numerically

@@ -658,12 +658,10 @@ def _served_gap(m, w):
 
 
 def _branch_reads_the_wrong_state(monkeypatch, m):
-    from paddle_tpu.models import longcat_flash
-
-    real = longcat_flash.LongcatDecoderLayer._experts
+    real = RoutedExperts.in_chunks
     monkeypatch.setattr(
-        longcat_flash.LongcatDecoderLayer, "_experts",
-        lambda self, u, valid: real(self, u * 1.05, valid))
+        RoutedExperts, "in_chunks",
+        lambda self, u, *a: real(self, u * 1.05, *a))
 
 
 def _row_rotated_by_the_wrong_position(monkeypatch, m):

@@ -343,7 +343,7 @@ def test_the_experts_kernel_serves_the_references_tokens_too(model,
     """The same through the non-gated experts' kernel, which a TPU takes
     and the CPU does not: its gate is opened here, so every expert layer
     of the decode step and of the prefill buckets is one interpreted
-    `grouped_relu2` call (rows that are no whole row tile, widths that
+    `grouped_experts` call (rows that are no whole row tile, widths that
     are no whole lanes: the interpreter does not mind). The served
     tokens are still the reference's, and a decode step's statistics
     carry the rows the kernel's tiles multiplied, an expert layer a

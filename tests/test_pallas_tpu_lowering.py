@@ -22,7 +22,7 @@ cbr = importlib.import_module("paddle_tpu.ops.pallas.conv_bn_relu")
 fla = importlib.import_module("paddle_tpu.ops.pallas.flash_attention")
 opu = importlib.import_module("paddle_tpu.ops.pallas.optimizer_update")
 mld = importlib.import_module("paddle_tpu.ops.pallas.mla_decode")
-gr2 = importlib.import_module("paddle_tpu.ops.pallas.grouped_relu2")
+gex = importlib.import_module("paddle_tpu.ops.pallas.grouped_experts")
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 
@@ -294,12 +294,12 @@ def test_grouped_relu2_at_the_served_shapes(rows, tile, monkeypatch):
     arrays (the work items' groups and tiles, the groups' first rows and
     ends, the number of items), the sorted rows and the two stacks of
     weights, a whole expert a block."""
-    monkeypatch.setattr(gr2, "on_tpu_platform", lambda: True)
+    monkeypatch.setattr(gex, "on_tpu_platform", lambda: True)
     shapes = (rows, 1024), (128, 1024, 2688), (128, 2688, 1024)
-    assert gr2.grouped_relu2_supported(*shapes, "bfloat16")
-    assert gr2.row_tile(rows, 128, BF16) == tile
-    assert gr2._hidden_block(1024, 2688, BF16) == 2688
-    text = _lower_for_tpu(gr2.grouped_relu2,
+    assert gex.grouped_experts_supported(*shapes, "bfloat16")
+    assert gex.row_tile(rows, 128, BF16) == tile
+    assert gex._hidden_block(1024, 2688, BF16) == 2688
+    text = _lower_for_tpu(gex.grouped_experts,
                           *(_sds(s, BF16) for s in shapes),
                           _sds((128,), jnp.int32))
     assert re.findall(r'kernel_name = "([\w.-]+)"', text) \
@@ -312,6 +312,72 @@ def test_grouped_relu2_at_the_served_shapes(rows, tile, monkeypatch):
         f"tensor<{items}xi32>", f"tensor<{items}xi32>", "tensor<128xi32>",
         "tensor<128xi32>", "tensor<1xi32>", f"tensor<{rows}x1024xbf16>",
         "tensor<128x1024x2688xbf16>", "tensor<128x2688x1024xbf16>"]
+
+
+@pytest.mark.parametrize("cell,n,w,f,rows,tile,block", [
+    ("solar-open2-250b decode", 40, 4096, 1280, 32 * 8, 16, 1280),
+    ("solar-open2-250b 2,048 prompt", 40, 4096, 1280, 2048 * 8, 128, 1280),
+    ("k-exaone-236b decode", 16, 6144, 2048, 32 * 8, 16, 1024),
+    ("k-exaone-236b 2,048 chunk", 16, 6144, 2048, 2048 * 8, 128, 1024),
+    ("longcat-flash-omni decode", 16, 6144, 2048, 32 * 12, 32, 1024),
+    ("longcat-flash-omni 1,024 chunk", 16, 6144, 2048, 1024 * 12, 128,
+     1024)])
+def test_grouped_swiglu_at_the_served_shapes(cell, n, w, f, rows, tile,
+                                             block, monkeypatch):
+    """The gated experts' kernel at the three served cuts' widths
+    (bfloat16), a decode step's sorted pairs and a prompt's: ONE call
+    named `ragged-dot-none-swiglu` (the prefix is what the benchmark's
+    readers find the grouped products by), the sorted rows and the three
+    stacks of weights behind the five scalar-prefetch arrays, gate
+    first; a whole expert a block where 31.5 MB fit VMEM twice, half of
+    one where 75.5 MB do not."""
+    monkeypatch.setattr(gex, "on_tpu_platform", lambda: True)
+    up, down = (n, w, f), (n, f, w)
+    assert gex.grouped_experts_supported((rows, w), up, down, "bfloat16", up)
+    assert gex.row_tile(rows, n, BF16) == tile
+    assert gex._hidden_block(w, f, BF16, 3) == block
+    text = _lower_for_tpu(
+        lambda xs, g, u, d, s: gex.grouped_experts(xs, u, d, s, g),
+        _sds((rows, w), BF16), _sds(up, BF16), _sds(up, BF16),
+        _sds(down, BF16), _sds((n,), jnp.int32))
+    assert re.findall(r'kernel_name = "([\w.-]+)"', text) \
+        == ["ragged-dot-none-swiglu"]
+    operands = re.search(
+        r'kernel_name = "ragged-dot-none-swiglu".*?: \((.*?)\) ->',
+        text).group(1)
+    items = rows // tile + n - 1
+    assert operands.split(", ") == [
+        f"tensor<{items}xi32>", f"tensor<{items}xi32>", f"tensor<{n}xi32>",
+        f"tensor<{n}xi32>", "tensor<1xi32>", f"tensor<{rows}x{w}xbf16>",
+        f"tensor<{n}x{w}x{f}xbf16>", f"tensor<{n}x{w}x{f}xbf16>",
+        f"tensor<{n}x{f}x{w}xbf16>"]
+
+
+@pytest.mark.parametrize("gated", [False, True])
+def test_the_expert_layers_of_a_program_share_one_lowered_kernel(
+        gated, monkeypatch):
+    """Four expert layers of one program at `solar-open2-250b`'s decode
+    shapes: the kernel's call is a `jax.jit` of its own, so the module
+    holds ONE function with ONE Mosaic call, called four times (the
+    host traces and lowers the kernel once a program, not once a
+    layer)."""
+    monkeypatch.setattr(gex, "on_tpu_platform", lambda: True)
+    n, w, f, rows = 40, 4096, 1280, 32 * 8
+    stack = (4, n, w, f)
+
+    def layers(xs, gate, up, down, sizes):
+        for i in range(4):
+            xs, _ = gex.grouped_experts(
+                xs, up[i], down[i], sizes, gate[i] if gated else None)
+        return xs
+
+    text = _lower_for_tpu(
+        layers, _sds((rows, w), BF16), _sds(stack, BF16), _sds(stack, BF16),
+        _sds((4, n, f, w), BF16), _sds((n,), jnp.int32))
+    name = "ragged-dot-none-" + ("swiglu" if gated else "relu2")
+    assert re.findall(r'kernel_name = "([\w.-]+)"', text) == [name]
+    assert len(re.findall(r"func\.func private @_call", text)) == 1
+    assert len(re.findall(r"call @_call\(", text)) == 4
 
 
 def test_gate_closes_under_a_multi_device_mesh(monkeypatch):
@@ -327,13 +393,14 @@ def test_gate_closes_under_a_multi_device_mesh(monkeypatch):
     assert _platform.can_emit_mosaic() and lnr._supported(x, x, w, w)
     from paddle_tpu.parallel import moe
 
-    experts = moe.RoutedExperts(32, 128, 8, 2, activation="relu2",
-                                latent_size=128, dtype=BF16)
+    experts = [moe.RoutedExperts(32, 128, 8, 2, activation=activation,
+                                 latent_size=128, dtype=BF16)
+               for activation in ("relu2", "swiglu")]
     rows = jnp.zeros((64, 128), BF16)
-    assert experts.takes_kernel(rows)
+    assert all(e.takes_kernel(rows) for e in experts)
     with parallel.mesh_scope(parallel.create_mesh(dp=2, tp=2)):
         assert not _platform.can_emit_mosaic()
         assert not lnr._supported(x, x, w, w)
-        assert not experts.takes_kernel(rows)
+        assert not any(e.takes_kernel(rows) for e in experts)
     with parallel.mesh_scope(parallel.create_mesh(dp=1)):
         assert _platform.can_emit_mosaic()

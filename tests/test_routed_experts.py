@@ -1,8 +1,8 @@
 """RoutedExperts' two later options - `activation="relu2"` (an expert of
 two matrices, no gate) and `latent_size` (the routed experts work in a
 narrower width, one down- and one up-projection a layer) - and the
-proof that off the chip the layer is what it was before the non-gated
-experts' kernel (ops/pallas/grouped_relu2.py, taken on a TPU only): the
+proof that off the chip the layer is what it was before the experts'
+kernel (ops/pallas/grouped_experts.py, taken on a TPU only): the
 layer's forward and `in_chunks` as they stood at the parent (PR 40's,
 kept below word for word) put in the new ones' place lower every ring
 program of the four families that use the layer to the same text.
