@@ -42,8 +42,9 @@ After each real compile the planner is *closed against reality*:
 :func:`note_actual` compares the prediction with XLA's own
 ``memory_analysis`` (argument + output + temp − alias) into a
 ``plan_accuracy`` ratio on the CostRecord, the ``memplan/plan_accuracy``
-gauge, ``/statz``, and ``tools/memplan_smoke.py``'s CI envelope — the
-planner is certified, not vibes.
+gauge and ``/statz``; ``tests/test_memplan.py`` holds the ratio to
+``ACCURACY_ENVELOPE`` on a training program (XLA:CPU's analysis; not
+measured on the chip).
 """
 from __future__ import annotations
 

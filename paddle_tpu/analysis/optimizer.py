@@ -1062,10 +1062,10 @@ def measure_pass_deltas(program, feed, fetch_names=(), *, level=None,
 
     PassStats says a fusion fired; this says what it bought: per-op-type
     measured µs before vs after (monitor.opprof replay), the per-pass
-    rewrite stats, and the whole-program speedup. The conv+bn+relu
-    fusion's win, for example, shows up as the ``fused_conv_bn_relu``
-    rows costing measurably less than the conv2d+batch_norm+relu rows
-    they replaced (tools/opprof_smoke.py asserts exactly that).
+    rewrite stats, and the whole-program ratio. The conv+bn+relu fusion,
+    for example, shows up as ``fused_conv_bn_relu`` rows after beside
+    the conv2d+batch_norm+relu rows they replaced before; which costs
+    less is the replaying device's to say (off the chip that is XLA:CPU).
 
     Inputs follow :func:`optimize_program` (feed dict + fetch names);
     the program must be runnable from ``scope`` (run it through the

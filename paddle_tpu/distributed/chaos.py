@@ -2,9 +2,9 @@
 
 Production preemption tolerance is only real if every recovery path has
 been exercised by a real process death. This module is the hook the
-chaos harness (tools/chaos_smoke.py, tests/fixtures/dist_elastic.py)
-drives: well-known code points call :func:`inject` and, when the flag
-carries a matching directive, the process is killed (``kill`` = SIGKILL
+chaos harness (tests/test_elastic_checkpoint.py,
+tests/fixtures/dist_elastic.py) drives: well-known code points call
+:func:`inject` and, when the flag carries a matching directive, the process is killed (``kill`` = SIGKILL
 to self, the genuine ``kill -9``), exits hard (``exit`` = os._exit, no
 atexit/teardown), sleeps (``delay`` — straggler emulation), or raises
 :class:`ChaosInjected` (``raise`` — in-process failure without dying).

@@ -38,8 +38,9 @@ Layout of one snapshot directory::
                          rank 0 aggregates these into the manifest
 
 ``incubate/auto_checkpoint.py`` rides the same low-level writer for its
-epoch snapshots; ``tools/chaos_smoke.py`` kills writers at every stage
-of this pipeline to prove the recovery paths.
+epoch snapshots; ``tests/test_elastic_checkpoint.py`` kills a writer
+inside this pipeline (``kill -9`` between the data files and the
+manifest) to prove the recovery path.
 """
 from __future__ import annotations
 

@@ -2,9 +2,9 @@
 
 EQuARX-style (PAPERS.md): DP gradient sync pays full fp32 wire bytes for
 values whose useful precision is far lower. This module moves gradients
-across the ICI as int8 with one f32 abs-max scale per
-``FLAGS_quantized_allreduce_block`` elements, in the classic two-phase
-shape:
+across the ICI as int8 with one f32 abs-max scale per ``BLOCK``
+elements (2048: larger blocks amortize scale wire bytes, smaller ones
+track outliers tighter), in the classic two-phase shape:
 
 1. **reduce-scatter phase** — each rank's quantized payload is
    ``alltoall``'d so every rank holds all n ranks' int8 contribution for
@@ -19,8 +19,9 @@ than the fp32 all-reduce's ``2·(n-1)/n · B`` at the default block of
 2048 (scale overhead 0.2%). Both phases route through
 :mod:`paddle_tpu.distributed.collective`, so the reduction lands in the
 SAME algorithmic-bytes ledger (``collective/<prim>/traced_algo_bytes``)
-and ``ici_bus_util`` gauges that certify every other collective — the
-quant smoke asserts the ≥3.5× cut from ledger deltas, not from a model.
+and ``ici_bus_util`` gauges that certify every other collective —
+``tests/test_quant_e2e.py`` asserts the ≥3.5× cut from ledger deltas,
+not from a model.
 
 Two execution paths, one accounting contract:
 
@@ -36,8 +37,8 @@ Two execution paths, one accounting contract:
 
 The hook into training is ``sync_grads``: ``TrainStepFn``/
 ``ShardedTrainStep`` route gradients through it when
-``FLAGS_quantized_allreduce`` is set at step CONSTRUCTION, and the BERT
-smoke asserts loss-curve convergence vs fp32 (tools/quant_smoke.py).
+``FLAGS_quantized_allreduce`` is set at step CONSTRUCTION;
+``tests/test_quant_e2e.py`` asserts loss-curve convergence vs fp32.
 """
 from __future__ import annotations
 
@@ -56,16 +57,16 @@ __all__ = [
 
 _BNT = 127.0
 _EPS = 1e-8
+BLOCK = 2048  # elements per quantization block (one f32 scale each)
 
 
 def _block_size(override=None) -> int:
-    b = int(override if override is not None
-            else flag("quantized_allreduce_block"))
+    b = int(override if override is not None else BLOCK)
     if b < 1:
         from ..errors import InvalidArgumentError
 
         raise InvalidArgumentError(
-            f"quantized_allreduce_block must be >= 1, got {b}")
+            f"the quantization block must be >= 1, got {b}")
     return b
 
 

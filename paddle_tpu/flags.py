@@ -482,44 +482,6 @@ define_flag("serving_router_request_timeout_s", 120.0,
             "router->backend response timeout once a request is "
             "dispatched (seconds)")
 
-# serving/scaler.py — period of the autoscaler's evaluate loop; each
-# tick gathers router + cluster signals and runs one decision.
-define_flag("serving_scaler_interval_s", 5.0,
-            "seconds between autoscaler evaluations of the fleet signals")
-
-# serving/scaler.py — fleet size bounds the scaler may move between.
-define_flag("serving_scaler_min_backends", 1,
-            "autoscaler floor: never drain below this many backends")
-define_flag("serving_scaler_max_backends", 4,
-            "autoscaler ceiling: never launch above this many backends")
-
-# serving/scaler.py — scale-up pressure: mean queue depth per healthy
-# backend at/above this for `serving_scaler_window` consecutive
-# evaluations triggers a launch.
-define_flag("serving_scaler_up_queue_depth", 4.0,
-            "scale up when mean backend queue depth sustains at or "
-            "above this for a full hysteresis window")
-
-# serving/scaler.py — scale-down idleness: mean queue depth per backend
-# at/below this (and no inflight pressure) for a full window triggers a
-# drain of the least-loaded backend.
-define_flag("serving_scaler_down_queue_depth", 0.25,
-            "scale down when mean backend queue depth sustains at or "
-            "below this for a full hysteresis window")
-
-# serving/scaler.py — hysteresis: consecutive same-direction evaluations
-# required before acting (one spiky tick must not flap the fleet).
-define_flag("serving_scaler_window", 3,
-            "consecutive over/under-threshold evaluations required "
-            "before the autoscaler acts")
-
-# serving/scaler.py — cooldown after any scale action; decisions are
-# suppressed until it elapses so a fresh backend's warmup window cannot
-# be misread as sustained pressure.
-define_flag("serving_scaler_cooldown_s", 30.0,
-            "seconds after a scale action during which the autoscaler "
-            "makes no further decisions")
-
 # incubate/auto_checkpoint.py + distributed/checkpoint.py — serialize and
 # fsync snapshots in a background thread instead of on the step/epoch
 # critical path. The capture itself is a device-side copy (donation-safe)
@@ -618,13 +580,6 @@ define_flag("use_int8_matmul", True,
 define_flag("quantized_allreduce", False,
             "int8-with-per-block-scales DP gradient all-reduce "
             "(~4x fewer gradient-sync wire bytes; read at step build)")
-
-# distributed/quantized.py — elements per quantization block (one f32
-# scale each). Larger blocks amortize scale wire bytes; smaller blocks
-# track outliers tighter. 2048 keeps scale overhead at 0.2% of payload.
-define_flag("quantized_allreduce_block", 2048,
-            "elements per int8 quantization block in the quantized "
-            "all-reduce (one f32 scale per block)")
 
 # io/dataloader.py _DevicePrefetcher — issue the NEXT batches' host
 # fetch + jax.device_put from a background thread while the consumer's
@@ -735,19 +690,3 @@ define_flag("use_fused_conv_bn", True,
             "other conv and every other platform runs the identical "
             "unfused op sequence")
 
-# monitor/opprof.py profile_program — per-op replay measurement
-# discipline: each op's jitted kernel is warmed `opprof_warmup` times,
-# then timed best-of-`opprof_repeats` behind block_until_ready. Raise
-# repeats for tighter numbers on a noisy host; the smoke/CI defaults
-# keep a full BERT-smoke replay under a second on the CPU runner.
-define_flag("opprof_warmup", 1,
-            "per-op replay profiler: warmup runs before timing each op")
-define_flag("opprof_repeats", 3,
-            "per-op replay profiler: timed runs per op (best-of-N)")
-
-# monitor/opprof.py top_ops / profilez_payload — how many ops the
-# /statz top-K table and the default /profilez view keep (the full
-# per-op table stays in the stored profile; /profilez?topk=N overrides
-# per request).
-define_flag("opprof_topk", 10,
-            "top-K ops by device time shown on /statz and /profilez")

@@ -13,8 +13,8 @@ prefill/decode split:
 - **Decode** is a single jitted step over ALL decode slots: read last
   tokens ``[S]``, attend the static cache window, sample, write back —
   its shapes never depend on sequence length or slot turnover, so its
-  steady-state compile count is exactly 1 (asserted in tests and the
-  gen-smoke the same way ``serving/unexpected_compiles`` is).
+  steady-state compile count is exactly 1 (asserted in tests the same
+  way ``serving/unexpected_compiles`` is).
 
 Compile accounting mirrors the serving pool: every new signature is AOT
 lowered/compiled through the cost model (so decode MFU lands in the

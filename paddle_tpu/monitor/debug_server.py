@@ -193,7 +193,7 @@ class _Handler(BaseHTTPRequestHandler):
 
 class DebugServer:
     """Threaded HTTP debug server; ``port=0`` binds an ephemeral port
-    (tests / debugz-smoke). Serving happens on a daemon thread, so the
+    (tests). Serving happens on a daemon thread, so the
     endpoint stays reachable while the main thread is hung — which is
     precisely when it matters."""
 

@@ -83,17 +83,15 @@ __all__ = [
     "chrome_events",
 ]
 
-# Program-level predicted-vs-measured envelope asserted by
-# tools/opprof_smoke.py: the calibrated roofline prediction must land
-# within this factor of the measured replay total, either direction
-# (time_accuracy in [1/ENVELOPE, ENVELOPE]). An order of magnitude is
-# deliberately wide: on the CPU CI runner the "device" is a shared host,
-# per-op kernels sit microseconds from the dispatch floor, and ambient
-# load inflates measured totals ~2x run-to-run (observed band on the
-# smoke programs: 0.15-0.9). The gate exists to catch the model or the
-# measurement going off the rails, not to certify the CPU runner; on a
-# real TPU, where kernels dwarf the dispatch floor, the same model
-# tracks far tighter.
+# Program-level predicted-vs-measured envelope that /profilez reports
+# beside `time_accuracy`: the calibrated roofline prediction against the
+# measured replay total, either direction (time_accuracy in
+# [1/ENVELOPE, ENVELOPE]). An order of magnitude is deliberately wide:
+# off the chip the "device" is a shared host, per-op kernels sit
+# microseconds from the dispatch floor, and ambient load moves measured
+# totals about 2x run to run. It is there to catch the model or the
+# measurement going off the rails; how the model tracks a TPU is not
+# measured on the chip.
 TIME_ACCURACY_ENVELOPE = 10.0
 
 
@@ -377,13 +375,11 @@ def _symmetric_ratio(predicted, measured):
 # ---------------------------------------------------------------------------
 
 
-def _flag_int(name, fallback):
-    from ..flags import flag
-
-    try:
-        return int(str(flag(name)).strip() or fallback)
-    except (KeyError, ValueError):
-        return fallback
+# profile_program's defaults: untimed runs an op, timed runs an op
+# (best-of-N), and the rows of the /statz and default /profilez tables
+WARMUP = 1
+REPEATS = 3
+TOPK = 10
 
 
 def profile_program(program, feed=None, fetch_list=None, *, scope=None,
@@ -415,9 +411,8 @@ def profile_program(program, feed=None, fetch_list=None, *, scope=None,
     from . import registry as _registry
 
     scope = scope or _exec.global_scope()
-    warmup = _flag_int("opprof_warmup", 1) if warmup is None else int(warmup)
-    repeats = _flag_int("opprof_repeats", 3) if repeats is None \
-        else int(repeats)
+    warmup = WARMUP if warmup is None else int(warmup)
+    repeats = REPEATS if repeats is None else int(repeats)
     block = program.global_block()
     name = name or f"program{getattr(program, '_identity_token', id(program))}"
 
@@ -598,7 +593,7 @@ def reset_profiles():
 def top_ops(k=None) -> list:
     """Top-K replayed ops by measured device time from the most recent
     profile — the /statz table."""
-    k = _flag_int("opprof_topk", 10) if k is None else int(k)
+    k = TOPK if k is None else int(k)
     prof = latest_profile()
     if prof is None:
         return []
@@ -638,16 +633,16 @@ def profilez_payload(query=None):
         return 200, {
             "status": "no-data", "programs": [],
             "hint": "run paddle_tpu.monitor.opprof.profile_program(...) "
-                    "(or tools/opprof_smoke.py) to populate"}
+                    "to populate"}
     want = query.get("program")
     if want is not None and latest_profile(want) is None:
         return 404, {"status": "unknown-program", "program": want,
                      "programs": names}
     prof = latest_profile(want)
     try:
-        topk = int(query.get("topk", _flag_int("opprof_topk", 10)))
+        topk = int(query.get("topk", TOPK))
     except (TypeError, ValueError):
-        topk = _flag_int("opprof_topk", 10)
+        topk = TOPK
     ops = sorted((r for r in prof["ops"] if r.get("replayed")),
                  key=lambda r: -(r["time_us"] or 0.0))[:max(topk, 0)]
     skipped = [{"scope": r["scope"], "reason": r.get("reason", "")}

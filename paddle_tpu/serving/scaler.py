@@ -14,19 +14,20 @@ that fleet should BE. An :class:`AutoScaler` periodically gathers
 and runs one decision per tick against a pluggable **launcher**:
 
 - *scale up* when mean queue depth per healthy backend sustains at or
-  above ``FLAGS_serving_scaler_up_queue_depth`` for
-  ``FLAGS_serving_scaler_window`` consecutive evaluations (hysteresis —
-  one spiky tick must not flap the fleet), bounded by
-  ``FLAGS_serving_scaler_max_backends``;
+  above ``up_queue_depth`` for ``window`` consecutive evaluations
+  (hysteresis — one spiky tick must not flap the fleet), bounded by
+  ``max_backends``;
 - *scale down* when the fleet sustains idle (queue depth at or below
-  ``FLAGS_serving_scaler_down_queue_depth`` with zero in-flight) for a
-  full window, bounded by ``FLAGS_serving_scaler_min_backends`` — the
-  victim is the least-loaded backend the scaler itself launched, which
-  is first removed from rotation (no new traffic) and then terminated
-  through the launcher (SIGTERM -> the backend's graceful drain);
-- after ANY action, ``FLAGS_serving_scaler_cooldown_s`` suppresses
-  further decisions so a booting backend's warmup cannot be misread as
-  sustained pressure.
+  ``down_queue_depth`` with zero in-flight) for a full window, bounded
+  by ``min_backends`` — the victim is the least-loaded backend the
+  scaler itself launched, which is first removed from rotation (no new
+  traffic) and then terminated through the launcher (SIGTERM -> the
+  backend's graceful drain);
+- after ANY action, ``cooldown_s`` suppresses further decisions so a
+  booting backend's warmup cannot be misread as sustained pressure.
+
+Each is a constructor argument of :class:`AutoScaler`; the defaults are
+the ``DEFAULT_*`` constants below (no deployment sets another).
 
 Decisions, hysteresis, and cooldowns are pure functions of the signal
 stream and an injectable clock (``AutoScaler(clock=...)``) — unit tests
@@ -55,6 +56,15 @@ from ..monitor import flight_recorder as _flight
 
 __all__ = ["AutoScaler", "FleetSignals", "SubprocessLauncher",
            "LaunchedBackend", "launch_process"]
+
+# AutoScaler's defaults
+DEFAULT_INTERVAL_S = 5.0        # seconds between evaluations
+DEFAULT_MIN_BACKENDS = 1        # never drain below
+DEFAULT_MAX_BACKENDS = 4        # never launch above
+DEFAULT_UP_QUEUE_DEPTH = 4.0    # mean depth per backend that is pressure
+DEFAULT_DOWN_QUEUE_DEPTH = 0.25  # mean depth per backend that is idleness
+DEFAULT_WINDOW = 3              # consecutive evaluations before acting
+DEFAULT_COOLDOWN_S = 30.0       # no decision for this long after an action
 
 
 @dataclass
@@ -276,9 +286,9 @@ class AutoScaler:
     ``router`` needs ``backend_states()`` / ``add_backend`` /
     ``remove_backend`` (duck-typed; tests pass a stub). ``launcher``
     needs ``launch() -> LaunchedBackend`` and ``terminate(handle,
-    drain=)``. All thresholds default to their ``serving_scaler_*``
-    flags; ``clock`` is injectable for deterministic hysteresis/cooldown
-    tests.
+    drain=)``. All thresholds default to the module's ``DEFAULT_*``
+    constants; ``clock`` is injectable for deterministic
+    hysteresis/cooldown tests.
     """
 
     def __init__(self, router, launcher, min_backends=None,
@@ -293,33 +303,28 @@ class AutoScaler:
         # launcher must boot backends of the matching --kind)
         self.kind = kind
         self.min_backends = int(
-            min_backends if min_backends is not None
-            else flag("serving_scaler_min_backends"))
+            min_backends if min_backends is not None else DEFAULT_MIN_BACKENDS)
         self.max_backends = int(
-            max_backends if max_backends is not None
-            else flag("serving_scaler_max_backends"))
+            max_backends if max_backends is not None else DEFAULT_MAX_BACKENDS)
         if not 0 < self.min_backends <= self.max_backends:
             raise InvalidArgumentError(
                 f"scaler bounds must satisfy 0 < min <= max, got "
                 f"min={self.min_backends} max={self.max_backends}")
         self.up_queue_depth = float(
             up_queue_depth if up_queue_depth is not None
-            else flag("serving_scaler_up_queue_depth"))
+            else DEFAULT_UP_QUEUE_DEPTH)
         self.down_queue_depth = float(
             down_queue_depth if down_queue_depth is not None
-            else flag("serving_scaler_down_queue_depth"))
-        self.window = int(window if window is not None
-                          else flag("serving_scaler_window"))
+            else DEFAULT_DOWN_QUEUE_DEPTH)
+        self.window = int(window if window is not None else DEFAULT_WINDOW)
         if self.window <= 0:
             raise InvalidArgumentError(
                 f"scaler hysteresis window must be positive, got "
                 f"{self.window}")
         self.cooldown_s = float(
-            cooldown_s if cooldown_s is not None
-            else flag("serving_scaler_cooldown_s"))
+            cooldown_s if cooldown_s is not None else DEFAULT_COOLDOWN_S)
         self.interval_s = float(
-            interval_s if interval_s is not None
-            else flag("serving_scaler_interval_s"))
+            interval_s if interval_s is not None else DEFAULT_INTERVAL_S)
         # burn at/above this (both SLO windows confirming) is up-pressure
         # on its own: latency SLOs can burn while queues stay shallow
         # (e.g. a wedged-but-answering backend)

@@ -28,6 +28,20 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def child_env():
+    """``child_env(**extra)``: this process's environment for a child
+    that must import THIS checkout's ``paddle_tpu``, plus ``extra``."""
+    def make(**extra):
+        return dict(os.environ, PYTHONPATH=os.pathsep.join(
+            [REPO, os.environ.get("PYTHONPATH", "")]), **extra)
+
+    return make
+
+
 @pytest.fixture(autouse=True)
 def _seed_rng():
     import paddle_tpu

@@ -2,7 +2,7 @@
 kill -9 and resumes RESHARDED at whatever world size it is relaunched at.
 
 Driven by test_dist_multiprocess.py (2-proc → 1-proc → 2-proc phases)
-and tools/chaos_smoke.py (single-proc world resizes + mid-save kills).
+and test_elastic_checkpoint.py (one-process world resize + mid-save kill).
 Each launch:
 
   1. joins the world (fleet.init — jax.distributed when nproc > 1),

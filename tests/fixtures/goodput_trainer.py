@@ -1,10 +1,9 @@
 """Goodput-ledger fixture: a checkpointing trainer with a controlled
-phase mix, driven by tools/goodput_smoke.py.
+phase mix, driven by tests/test_goodput.py.
 
 Unlike dist_elastic.py (whose per-step math is microseconds, so XLA
 compile dominates any CPU run), this trainer's step is real busy-work
-wall time — the phase mix is controllable, so the smoke can assert
-goodput >= 0.8 and 2% conservation against known ground truth. It still
+wall time, so the phase mix is controllable. It still
 exercises the REAL machinery end to end: TrainingMonitor step frames,
 ``record_input_wait_ms``, checkpoint save (sync, so
 ``chaos.inject("mid_save")`` kills THIS process deterministically),
@@ -18,7 +17,7 @@ GOODPUT_WAIT_MS (simulated input wait per step, default 1),
 GOODPUT_SAVE_EVERY (checkpoint cadence in steps, default 5).
 
 Prints one JSON line: resume identity + the ledger snapshot fields the
-smoke asserts on.
+test asserts on.
 """
 import json
 import os
@@ -65,7 +64,7 @@ def main():
 
     # the ledger must exist BEFORE the restore so note_resume lands in it
     led = gp.maybe_start_from_flags()
-    assert led is not None, "smoke must set FLAGS_goodput_dir"
+    assert led is not None, "the caller must set FLAGS_goodput_dir"
 
     lines = []
     mon = monitor.TrainingMonitor("train", interval=10,

@@ -4,11 +4,13 @@ cache, and the runtime coupling.
 The tuner itself is certified with a DETERMINISTIC fake timer — the
 selection pipeline (candidate enumeration, pre-compile pruning,
 best-of-N, cache write, resolve swap-in) runs with zero real compiles
-and scripted timings, so every assertion is exact. Real-measurement
-paths are covered by tools/autotune_smoke.py.
+and scripted timings, so every assertion is exact. One test tunes the
+two real kernels under the wall clock and reads the file back in a
+fresh process.
 """
 import json
 import os
+import subprocess
 import sys
 import warnings
 
@@ -238,6 +240,73 @@ def test_truncated_cache_degrades_to_defaults(tmp_path):
     # the reject is ONE-time, not per lookup
     assert c.lookup(space, {"n": 512}) is None
     assert _counter("autotune::cache_reject") == before + 1
+
+
+_RESOLVE_IN_A_FRESH_PROCESS = """
+import json, sys
+import paddle_tpu
+from paddle_tpu import profiler, tuning
+
+path, infos = sys.argv[1], json.loads(sys.argv[2])
+tuning.reset_tuning_cache(path)
+params = {k: tuning.resolve(k, **info) for k, info in infos.items()}
+c = profiler.counters()
+print(json.dumps({"params": params, "pending": tuning.pending_searches(),
+                  **{k: c.get("autotune::" + k, 0) for k in
+                     ("search", "enqueued", "cache_hit", "cache_reject")}}))
+"""
+
+
+def test_real_kernels_tune_then_resolve_in_a_fresh_process(tmp_path,
+                                                           child_env):
+    """The search over the two real kernels under the wall clock
+    (interpreted off the chip): the inadmissible candidate is pruned
+    before any compile, the winners land in the versioned file, a FRESH
+    process in search mode resolves them with no search at all, and a
+    torn file there degrades to the defaults with one reject."""
+    infos = {"layernorm_residual": dict(rows=128, h=256, dtype="float32"),
+             "conv_bn_relu": dict(m=256, k=64, c=128, dtype="float32")}
+    path = str(tmp_path / tuning.CACHE_FILE_NAME)
+    set_flags({"kernel_autotune": "search"})
+    tuning.reset_tuning_cache(path)
+    tuner = tuning.KernelTuner(measure_n=1)
+    res = tuner.tune("layernorm_residual",
+                     candidates=[{"block_r": 8}, {"block_r": 32},
+                                 {"block_r": 4096}],
+                     **infos["layernorm_residual"])
+    assert res.pruned == 1 and res.default_us is not None
+    winners = {"layernorm_residual": res.params,
+               "conv_bn_relu": tuner.tune(
+                   "conv_bn_relu",
+                   candidates=[{"tile_m": 64}, {"tile_m": 128}],
+                   **infos["conv_bn_relu"]).params}
+    with open(path) as f:
+        raw = json.load(f)
+    assert raw["schema"] == tuning.CACHE_SCHEMA_VERSION
+    assert len(raw["entries"]) == 2
+    assert _counter("autotune::search") == 2
+
+    def fresh_process():
+        out = subprocess.run(
+            [sys.executable, "-c", _RESOLVE_IN_A_FRESH_PROCESS, path,
+             json.dumps(infos)],
+            env=child_env(FLAGS_kernel_autotune="search"),
+            capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr[-3000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    got = fresh_process()
+    assert got["params"] == winners
+    assert got["search"] == got["enqueued"] == got["pending"] == 0
+    assert got["cache_hit"] >= 2
+
+    with open(path, "w") as f:
+        f.write('{"schema": 1, "entries": {"torn')
+    got = fresh_process()
+    assert got["params"] == {
+        k: tuning.schedule_space(k).default_params(info)
+        for k, info in infos.items()}
+    assert got["cache_reject"] == 1
 
 
 def test_wrong_schema_version_degrades_to_defaults(tmp_path):

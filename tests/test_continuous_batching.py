@@ -546,5 +546,7 @@ def test_generate_http_429_and_drain(model):
         srv.stop(drain=True)
         assert srv.scheduler.live_slots == 0
         assert srv.scheduler.alive == 0
+        with pytest.raises(OSError):  # the listener went with the loop
+            urlopen(srv.url + "/healthz", timeout=2)
     finally:
         srv.stop(drain=False)

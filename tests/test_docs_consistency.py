@@ -1,7 +1,8 @@
 """The documents name what the tree holds: every flag has its README row
 and every row its flag; every file README, Makefile and
-tools/build_and_test.sh point at exists. (Reads sources only: flags other
-tests define at run time do not count.)"""
+tools/build_and_test.sh point at exists; and the two documents a session
+must read before it writes a line stay of a size it can read. (Reads
+sources only: flags other tests define at run time do not count.)"""
 import os
 import re
 
@@ -52,3 +53,22 @@ def test_every_file_a_document_names_exists(doc):
     missing = sorted(p for p in named
                      if not os.path.exists(os.path.join(REPO, p)))
     assert not missing, f"{doc} names files that do not exist: {missing}"
+
+
+def test_perf_md_stays_readable_in_one_session():
+    """PERF.md is read whole by every session: at PR 47 it was 268,945
+    bytes in lines of up to 14.5 KB and the file tool refused it. When
+    Findings outgrows this, merge the oldest entries and leave the text
+    to git (`git show <commit>:PERF.md`)."""
+    raw = _read("PERF.md")
+    assert len(raw.encode("utf-8")) <= 200_000
+    longest = max(raw.split("\n"), key=len)
+    assert len(longest) <= 4_000, longest[:120]
+
+
+def test_verify_skill_stays_a_procedure():
+    """.claude/skills/verify/SKILL.md is a procedure, not a log that
+    every PR appends to (904 lines at PR 47)."""
+    lines = _read(os.path.join(".claude", "skills", "verify",
+                               "SKILL.md")).splitlines()
+    assert len(lines) <= 400

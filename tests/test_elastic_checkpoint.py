@@ -7,7 +7,10 @@ distributed/chaos.py (FLAGS_fault_injection), and the elastic layer
 elastic_run's world-change handling). The multi-process 2→1→2 e2e
 lives in test_dist_multiprocess.py.
 """
+import json
 import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -272,6 +275,66 @@ def test_mid_save_crash_leaves_previous_intact(tmp_path, flagged):
     assert path.endswith("step_0") and manifest["step"] == 0
     ckpt.sweep_tmp(str(tmp_path))
     assert not (tmp_path / "step_1.tmp").exists()
+
+
+def _start_elastic_trainer(env, ckpt_dir, devices):
+    """tests/fixtures/dist_elastic.py as a one-process world of
+    ``devices`` virtual devices, six steps, a checkpoint after each."""
+    from paddle_tpu.distributed.launch import _build_env, _free_port
+
+    base = dict(env, ELASTIC_CKPT_DIR=ckpt_dir, ELASTIC_TOTAL_STEPS="6",
+                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    return subprocess.Popen(
+        [sys.executable, os.path.join(os.path.dirname(__file__), "fixtures",
+                                      "dist_elastic.py")],
+        env=_build_env(0, 1, f"127.0.0.1:{_free_port()}", base),
+        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
+
+
+def _trainer_result(proc):
+    out, err = proc.communicate(timeout=120)
+    assert proc.returncode == 0, err[-3000:]
+    return json.loads([ln for ln in out.splitlines()
+                       if ln.startswith("{")][-1])
+
+
+def test_kill9_inside_a_save_resumes_resharded_on_the_same_curve(tmp_path,
+                                                                 child_env):
+    """A REAL kill -9 between a snapshot's data files and its manifest:
+    the torn ``.tmp`` never loads and is swept, the relaunch at twice
+    the devices resumes from the newest intact snapshot with the ZeRO-1
+    state re-sliced, and every recomputed step matches the uninterrupted
+    run's loss."""
+    ref_proc = _start_elastic_trainer(child_env(), str(tmp_path / "ref"), 4)
+    chaos_dir = str(tmp_path / "chaos")
+    victim = _start_elastic_trainer(
+        child_env(FLAGS_fault_injection="kill:point=mid_save,n=3"),
+        chaos_dir, 2)
+    try:
+        _, err = victim.communicate(timeout=120)
+        assert victim.returncode == -9, err[-2000:]
+        ref = _trainer_result(ref_proc)
+    finally:
+        for p in (ref_proc, victim):
+            if p.poll() is None:
+                p.kill()
+    assert sorted(map(int, ref["losses"])) == list(range(6))
+    assert ref["zero1_dp_sharded"]
+
+    assert [d for d in os.listdir(chaos_dir) if d.endswith(".tmp")]
+    path, manifest = ckpt.latest_checkpoint(chaos_dir)
+    assert path is not None and manifest["step"] < 5
+    assert manifest["mesh_shape"]["dp"] == 2
+
+    out = _trainer_result(_start_elastic_trainer(child_env(), chaos_dir, 4))
+    assert out["resumed_from"] == manifest["step"]
+    assert out["reshards"] >= 1 and out["zero1_dp_sharded"]
+    assert out["steps"] == list(range(manifest["step"] + 1, 6))
+    assert not [d for d in os.listdir(chaos_dir) if d.endswith(".tmp")]
+    for s, v in out["losses"].items():
+        np.testing.assert_allclose(
+            v, ref["losses"][s], rtol=5e-4, atol=1e-6,
+            err_msg=f"step {s} diverged after kill -9 + reshard")
 
 
 def test_async_save_error_surfaces_on_wait(tmp_path, flagged):

@@ -127,6 +127,35 @@ def test_dump_file_format(tmp_path):
     assert [p.name for p in tmp_path.iterdir()] == ["d.json"]
 
 
+def test_debug_dump_cli_reads_a_dump_back(tmp_path, capsys):
+    """``tools/debug_dump.py`` on a dump the recorder wrote: the event
+    listing, the ``--kind`` filter as JSON, and exit code 2 for a file
+    that is no dump."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "debug_dump", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "debug_dump.py"))
+    debug_dump = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(debug_dump)
+
+    rec = fr.FlightRecorder(capacity=8)
+    rec.record("executor_run_begin", program="p", jit_cache="miss")
+    rec.record("executor_run_end", program="p", ok=True)
+    path = rec.dump(path=str(tmp_path / "d.json"), reason="unit")
+    assert debug_dump.main([path]) == 0
+    out = capsys.readouterr().out
+    assert "executor_run_begin" in out and "unit" in out
+    assert debug_dump.main([path, "--kind", "executor_run_end",
+                            "--json"]) == 0
+    assert [e["kind"] for e in json.loads(capsys.readouterr().out)] \
+        == ["executor_run_end"]
+    assert debug_dump.main([path, "--threads"]) == 0
+    assert "MainThread" in capsys.readouterr().out
+    (tmp_path / "torn.json").write_text('{"events": [')
+    assert debug_dump.main([str(tmp_path / "torn.json")]) == 2
+
+
 def test_default_dump_path_uses_flag_dir(tmp_path):
     set_flags({"flight_recorder_dump_dir": str(tmp_path)})
     try:

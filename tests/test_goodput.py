@@ -15,6 +15,8 @@ the goodput SLO gating.
 import json
 import os
 import re
+import subprocess
+import sys
 import threading
 
 import pytest
@@ -167,6 +169,58 @@ def test_sidecar_roundtrip_and_lost_work(tmp_path, clock):
     ev = [e for e in fr.get_recorder().snapshot()["events"]
           if e.get("kind") == "goodput_resume"]
     assert ev and ev[-1]["steps_to_recompute"] == 3
+
+
+def _run_goodput_trainer(root, env):
+    """tests/fixtures/goodput_trainer.py as a process: twelve steps of
+    5 ms, a synchronous checkpoint every third, the sidecar published at
+    every commit."""
+    os.makedirs(os.path.join(root, "ckpt"), exist_ok=True)
+    return subprocess.run(
+        [sys.executable, os.path.join(os.path.dirname(__file__), "fixtures",
+                                      "goodput_trainer.py")],
+        env=dict(env, GOODPUT_CKPT_DIR=os.path.join(root, "ckpt"),
+                 FLAGS_goodput_dir=os.path.join(root, "goodput"),
+                 FLAGS_goodput_publish_interval_s="0",
+                 GOODPUT_TOTAL_STEPS="12", GOODPUT_STEP_MS="5",
+                 GOODPUT_SAVE_EVERY="3"),
+        capture_output=True, text=True, timeout=120)
+
+
+def test_kill9_inside_a_save_continues_the_lifetime_ledger(tmp_path,
+                                                           child_env):
+    """A REAL kill -9 inside the second checkpoint save, then a
+    relaunch: the sidecar of the first life is loaded, the lifetime wall
+    and steps go on from it, and the steps committed after the restored
+    manifest are charged to lost_work, not to compute."""
+    root = str(tmp_path)
+    killed = _run_goodput_trainer(root, child_env(
+        FLAGS_fault_injection="kill:point=mid_save,n=2"))
+    assert killed.returncode == -9, killed.stderr[-2000:]
+    with open(os.path.join(root, "goodput", gp.SIDECAR)) as f:
+        before = json.load(f)["body"]
+
+    resumed = _run_goodput_trainer(root, child_env())
+    assert resumed.returncode == 0, resumed.stderr[-3000:]
+    out = json.loads([ln for ln in resumed.stdout.splitlines()
+                      if ln.startswith("{")][-1])
+    assert out["resumed_from"] >= 0 and out["sidecar_loaded"]
+    assert out["resumes"] == 1
+    life = out["lifetime"]
+    assert life["wall_s"] > out["wall_s"]
+    assert life["wall_s"] >= before["wall_s"]
+    assert life["steps"] > before["steps"]
+    recomputed = out["max_committed_step"] - out["resumed_from"]
+    assert 1 <= out["lost_steps"] <= recomputed
+    assert out["phases"]["lost_work"] > 0
+    assert out["lost_work_priced_s"] > 0
+    # the real feeds reached the ledger, and the phases do not overrun
+    # the wall they partition
+    for phase in ("compute", "input_wait", "checkpoint"):
+        assert out["phases"][phase] > 0, phase
+    assert out["conservation_error"] <= 0.02
+    assert any(ln.startswith("[monitor:goodput] wall_s=")
+               for ln in out["monitor_lines"])
 
 
 def test_unknown_global_step_never_guesses_lost_work(tmp_path, clock):

@@ -320,6 +320,41 @@ def test_training_monitor_cache_hit_rates_from_executor():
         static.reset_default_programs()
 
 
+def test_monitored_steps_under_the_profiler_reach_both_exports(tmp_path):
+    """Three executor steps inside ``mon.step()`` with the profiler on:
+    the merged chrome trace carries the monitor's own step span beside
+    the executor's, and the Prometheus dump the step histogram."""
+    import paddle_tpu.static as static
+
+    static.reset_default_programs()
+    static.enable_static()
+    try:
+        x = static.data("x", [2, 2], "float32")
+        y = paddle.add(x, x)
+        exe = static.Executor()
+        profiler.reset_profiler()
+        profiler.start_profiler(state="CPU")
+        mon = monitor.TrainingMonitor("both", interval=3,
+                                      log_fn=lambda line: None)
+        for _ in range(3):
+            with mon.step(examples=2):
+                exe.run(feed={"x": np.ones((2, 2), np.float32)},
+                        fetch_list=[y])
+        profiler.stop_profiler()
+    finally:
+        static.disable_static()
+        static.reset_default_programs()
+    monitor.export_merged_chrome_trace(str(tmp_path / "merged.json"))
+    with open(tmp_path / "merged.json") as f:
+        names = [e.get("name") for e in json.load(f)["traceEvents"]]
+    assert names.count("monitor::both::step") == 3
+    assert "executor::dispatch" in names
+    prom = monitor.export_prometheus(str(tmp_path / "metrics.prom"))
+    assert 'monitor_both_step_ms_bucket{le="+Inf"} 3' in prom
+    assert "step=3" in mon.last_line
+    profiler.reset_profiler()
+
+
 def test_training_monitor_step_end_without_begin_raises():
     mon = monitor.TrainingMonitor("bad", interval=0)
     with pytest.raises(RuntimeError):

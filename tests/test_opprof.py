@@ -231,6 +231,47 @@ def test_profile_program_skips_grad_ops_cleanly():
     assert prof["replayed_ops"] > 0  # the forward half still profiles
 
 
+def test_measure_pass_deltas_profiles_both_sides_of_the_rewrite():
+    """``measure_pass_deltas`` replays the program before and after the
+    pass pipeline: the fused op has a time only after, the chain it
+    replaced only before, and the op with the most FLOPs on either side
+    is the convolution. Which side is faster is not asserted: a time
+    taken here says nothing of the chip."""
+    from paddle_tpu.analysis import measure_pass_deltas
+
+    static.enable_static()
+    static.global_scope().clear()
+    main, startup = static.Program(), static.Program()
+    with static.program_guard(main, startup):
+        img = static.data("img", [2, 3, 8, 8], "float32")
+        h = static.nn.conv2d(img, num_filters=4, filter_size=3, padding=1,
+                             bias_attr=False, name="c1")
+        out = static.nn.fc(ops.relu(static.nn.batch_norm(h, is_test=True)),
+                           5, name="head")
+    exe = static.Executor()
+    exe.run_startup(startup)
+    feeds = {"img": np.ones((2, 3, 8, 8), np.float32)}
+    exe.run(main, feed=feeds, fetch_list=[out])
+
+    got = measure_pass_deltas(main, feeds, [out.name], level=1,
+                              name="convnet", warmup=1, repeats=2)
+    assert got["changed"]
+    assert any(p["name"] == "fuse_conv_bn_relu" and p["ops_rewritten"] >= 1
+               for p in got["passes"])
+    fused = got["deltas"]["fused_conv_bn_relu"]
+    assert fused["before_ops"] == 0 and fused["after_ops"] == 1
+    assert fused["after_us"] > 0
+    for op_type in ("conv2d", "batch_norm", "relu"):
+        row = got["deltas"][op_type]
+        assert row["before_ops"] == 1 and row["after_ops"] == 0
+        assert row["before_us"] > 0 and row["after_us"] == 0
+    assert got["before_us"] > 0 and got["after_us"] > 0
+    for side, top in (("pre", "conv2d"), ("post", "fused_conv_bn_relu")):
+        rows = [r for r in opprof.latest_profile(f"convnet@{side}")["ops"]
+                if r["replayed"]]
+        assert max(rows, key=lambda r: r["flops"] or 0)["op_type"] == top
+
+
 def test_chrome_events_track():
     main, feeds, _, _ = _small_program()
     opprof.profile_program(main, feeds, name="tracked", with_trace=False)

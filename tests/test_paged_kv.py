@@ -28,9 +28,10 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu import monitor
+from paddle_tpu import monitor, profiler
 from paddle_tpu.errors import InvalidArgumentError
 from paddle_tpu.generation import (
+    COMPILE_COUNTER,
     GenerationEngine,
     HandoffError,
     PagePool,
@@ -163,14 +164,35 @@ def test_paged_parity_greedy_fp32(model):
     prompts = _prompts(6, rng_seed=2)
     want = _ring(model).warmup().generate(
         prompts, max_new_tokens=6, temperature=0.0)
+    before = profiler.counters().get(COMPILE_COUNTER, 0)
     eng = _paged(model).warmup()
     got = eng.generate(prompts, max_new_tokens=6, temperature=0.0)
     assert got == want
+    # one program a bucket and one decode, however much prefix is shared
+    assert profiler.counters().get(COMPILE_COUNTER, 0) - before \
+        == len(BUCKETS) + 1
     assert eng.extra_compiles() == 0
     # every slot vacated -> every non-index page reclaimed
     st = eng.paging_stats()
     assert st["pages_free"] + st["prefix_index"]["pages"] == \
         st["pages_total"]
+
+
+def test_mixed_burst_on_a_pool_smaller_than_the_rings(model):
+    """Four slots of rings reserve 16 pages; the same short/long burst
+    runs token-identically from 12 (1.33 x the slots a byte): a short
+    request holds only the pages it touches and idle prefix-index pages
+    are evicted under pressure."""
+    rng = np.random.RandomState(4)
+    prompts = [list(map(int, rng.randint(3, 200, size=8 if i % 2 else 2)))
+               for i in range(8)]
+    want = _ring(model, slots=4).warmup().generate(
+        prompts, max_new_tokens=6, temperature=0.0)
+    eng = _paged(model, slots=4, kv_pool_pages=12).warmup()
+    assert eng.generate(prompts, max_new_tokens=6, temperature=0.0) == want
+    st = eng.paging_stats()
+    assert st["pages_total"] == 12 and st["peak_pages_used"] <= 12
+    assert eng.extra_compiles() == 0
 
 
 def test_paged_parity_greedy_int8(model):

@@ -340,3 +340,67 @@ def test_use_int8_matmul_flag_never_changes_numerics():
     finally:
         paddle.set_flags({"use_int8_matmul": True})
     np.testing.assert_array_equal(a, b)
+
+
+def test_int8_model_served_at_bounded_compiles(tmp_path):
+    """PTQ -> ``save_int8_model`` -> an unchanged Predictor inside a real
+    ``InferenceServer``: the int8 program warms one compile a bucket
+    through the same store as any other, mixed-size traffic adds none,
+    and the HTTP answers stay within the documented 5 % envelope of the
+    fp32 program."""
+    import json
+    from urllib.request import Request, urlopen
+
+    import paddle_tpu.static as static
+    from paddle_tpu import slim
+    from paddle_tpu.inference import Config, create_predictor
+    from paddle_tpu.serving import InferenceServer
+
+    buckets = (1, 2, 4)
+    rng = np.random.RandomState(4)
+    static.enable_static()
+    static.reset_default_programs()
+    static.global_scope().clear()
+    try:
+        x = static.data("x", [None, 16], "float32")
+        y = static.nn.fc(static.nn.fc(x, 64, activation="relu", name="qs1"),
+                         8, name="qs2")
+        exe = static.Executor()
+        exe.run_startup()
+        tests = [rng.randn(r, 16).astype("float32") for r in (1, 2, 3, 1)]
+        refs = [np.asarray(exe.run(feed={"x": a}, fetch_list=[y])[0])
+                for a in tests]
+        ptq = slim.PostTrainingQuantization(
+            exe, static.default_main_program(),
+            [{"x": rng.randn(16, 16).astype("float32")} for _ in range(4)])
+        ptq.quantize()
+        ptq.save_int8_model(str(tmp_path), ["x"], [y])
+    finally:
+        static.disable_static()
+        static.reset_default_programs()
+        static.global_scope().clear()
+
+    def misses():
+        return profiler.counters().get("executor::jit_cache_miss", 0)
+
+    pred = create_predictor(Config(str(tmp_path)))
+    assert "mul_int8" in [op.type for op in pred._program.global_block().ops]
+    srv = InferenceServer(pred, port=0, replicas=2, buckets=buckets,
+                          batch_timeout_ms=1.0)
+    try:
+        before = misses()
+        srv.start()  # warms every bucket
+        assert misses() - before == len(buckets)
+        scale = max(np.abs(r).max() for r in refs)
+        for a, ref in zip(tests, refs):
+            body = json.dumps({"inputs": a.tolist()}).encode()
+            out = json.loads(urlopen(Request(
+                srv.url + "/predict", data=body,
+                headers={"Content-Type": "application/json"}),
+                timeout=30).read())
+            got = np.asarray(next(iter(out["outputs"].values())), "float32")
+            assert np.abs(got - ref).max() < 0.05 * scale + 0.05
+        assert misses() - before == len(buckets)
+        assert srv.pool.extra_compiles() == 0
+    finally:
+        srv.stop(drain=True)

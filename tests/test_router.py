@@ -687,6 +687,11 @@ def test_router_e2e_real_backends(model_dir):
                 break
             time.sleep(0.02)
         assert not states[s1.url].in_rotation
+        # a request is counted after its reply is relayed: wait for the
+        # last one's
+        while (router.statz()["fleet"]["requests"] < 12
+               and time.monotonic() < deadline):
+            time.sleep(0.02)
         sz = router.statz()
         assert sz["fleet"]["requests"] >= 12
         assert sz["backends_healthy"] == 1
@@ -694,6 +699,95 @@ def test_router_e2e_real_backends(model_dir):
         router.stop(drain=True)
         s1.stop(drain=False)
         s2.stop(drain=False)
+
+
+def test_kill9_of_a_backend_process_is_invisible_to_clients(model_dir):
+    """Two real backend PROCESSES behind the router, one SIGKILLed in the
+    middle of a burst: every client request still answers 200 (the
+    refused connection retries on the survivor and evicts the victim),
+    the retried request keeps ONE trace_id over two attempt spans, the
+    survivor drains to exit code 0 and nothing is left listening."""
+    import os
+    import signal
+    from urllib.error import URLError
+
+    from paddle_tpu.monitor import tracing
+    from paddle_tpu.serving import SubprocessLauncher
+
+    clients, per_client = 4, 15
+    launcher = SubprocessLauncher(model_dir, buckets=(1, 2, 4),
+                                  batch_timeout_ms=1.0, queue_capacity=256,
+                                  startup_timeout_s=120.0)
+    handles = [launcher.launch(), launcher.launch()]
+    # a long probe interval: the victim must leave rotation through the
+    # DISPATCH path (refused connection -> evict -> retry), not a probe
+    router = Router(backends=[h.url for h in handles],
+                    probe_interval_s=60.0).start()
+    try:
+        assert router.healthy_count == 2
+        for h in handles:
+            lz = json.loads(urlopen(h.url + "/loadz", timeout=10).read())
+            assert lz["ready"] and lz["kind"] == "predict"
+            assert lz["compiles"]["jit_misses"] == 3
+            assert lz["compiles"]["unexpected"] == 0
+
+        statuses, lock = [], threading.Lock()
+
+        def client():
+            for i in range(per_client):
+                rows = np.zeros(((i % 3) + 1, IN_DIM)).tolist()
+                try:
+                    s = _post(router.url, payload={"inputs": rows})[0]
+                except (URLError, ConnectionError, OSError) as e:
+                    s = f"conn: {type(e).__name__}"  # counts as a failure
+                with lock:
+                    statuses.append(s)
+
+        threads = [threading.Thread(target=client) for _ in range(clients)]
+        for t in threads:
+            t.start()
+        deadline = time.monotonic() + 60
+        while (len(statuses) < clients * per_client // 4
+               and time.monotonic() < deadline):
+            time.sleep(0.002)  # kill once the burst is in flight
+        victim, survivor = handles
+        os.kill(victim.proc.pid, signal.SIGKILL)
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        victim.proc.wait(10)
+
+        assert statuses == [200] * (clients * per_client), sorted(
+            set(map(str, statuses)))
+        sz = router.statz()
+        assert sz["fleet"]["evictions"] >= 1 and sz["fleet"]["retries"] >= 1
+        assert sz["backends_healthy"] == 1
+        merged = sz["latency"]["backends_merged"]
+        assert merged["serving/e2e_ms"]["count"] > 0
+        # the retried request: one trace, two attempts, the first errored
+        retried = [r for r in tracing.store().summaries()
+                   if "retry" in r["kept"]]
+        assert retried
+        atts = [s for s in tracing.store().get(retried[0]["trace_id"])["spans"]
+                if s["name"] == "serving::attempt"]
+        assert len(atts) >= 2
+        assert len({s["trace_id"] for s in atts}) == 1
+        assert len({s["span_id"] for s in atts}) == len(atts)
+        failed = [s for s in atts if s.get("error")]
+        ok = [s for s in atts if s["attrs"].get("status") == 200]
+        assert failed[0]["attrs"]["backend"] == victim.url
+        assert ok[0]["attrs"]["backend"] == survivor.url
+
+        launcher.terminate(survivor, drain=True)
+        assert survivor.proc.returncode == 0
+        router.stop(drain=True)
+        for url in (router.url, victim.url, survivor.url):
+            with pytest.raises((URLError, ConnectionError, OSError)):
+                urlopen(url + "/healthz", timeout=2)
+    finally:
+        router.stop(drain=False)
+        for h in handles:
+            launcher.terminate(h, drain=False, timeout_s=5)
 
 
 def test_loadz_schema_stable_and_statz_unchanged(model_dir):

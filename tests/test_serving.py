@@ -367,6 +367,8 @@ def test_http_429_and_deadline(predictor):
         assert results and results[0][0] == 504, results
         for req in parked[:1]:
             req.wait(timeout=30)
+        sz = json.loads(urlopen(srv.url + "/statz").read())
+        assert sz["requests"]["rejected_429"] >= 1
     finally:
         srv.stop(drain=False)
 
@@ -391,6 +393,8 @@ def test_model_serve_roundtrip():
     finally:
         srv.stop(drain=True)
         assert srv.pool.alive == 0
+    with pytest.raises(OSError):  # the listener went with the workers
+        urlopen(srv.url + "/healthz", timeout=2)
 
 
 # -- monitor integration -----------------------------------------------------

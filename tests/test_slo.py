@@ -407,3 +407,52 @@ def test_fleetz_round_trip_two_real_processes(model_dir):
             if b.proc is not None:
                 b.proc.kill()
                 b.proc.wait(10)
+
+
+def test_sloz_pages_on_the_wedged_backend_process_only(model_dir):
+    """Two backend PROCESSES given one latency objective through
+    ``FLAGS_slo_objectives`` in their environment; one holds every
+    request past the threshold (a long batch window: slow but
+    answering, so /healthz stays green). Its own sampler thread drives
+    both window burns past the alert on ``/sloz``; the healthy one
+    never pages."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from paddle_tpu.serving.scaler import launch_process
+
+    env = {"FLAGS_slo_objectives":
+           "predict-fast|serving/e2e_ms{kind=predict}"
+           "|threshold_ms=200|target=0.99|window_s=120",
+           "FLAGS_slo_sample_interval_s": "0.2"}
+    with ThreadPoolExecutor(2) as pool:
+        boots = [pool.submit(
+            launch_process, "paddle_tpu.serving.backend",
+            ["--model-dir", model_dir, "--port", "0", "--buckets", "1,2,4",
+             "--batch-timeout-ms", window_ms],
+            env=env, startup_timeout_s=120.0) for window_ms in ("1", "500")]
+    try:
+        healthy, wedged = (b.result() for b in boots)
+        body = json.dumps({"inputs": np.zeros((1, IN_DIM)).tolist()}).encode()
+        for b in (healthy, wedged):
+            for _ in range(4):
+                req = Request(b.url + "/predict", data=body,
+                              headers={"Content-Type": "application/json"})
+                with urlopen(req, timeout=30) as r:
+                    assert r.status == 200
+        deadline = time.monotonic() + 15
+        while time.monotonic() < deadline:
+            wz = json.loads(_get(wedged.url + "/sloz")[2])["slos"][0]
+            hz = json.loads(_get(healthy.url + "/sloz")[2])["slos"][0]
+            if wz["alerting"] and hz["samples"] >= 2:
+                break
+            time.sleep(0.1)
+        assert wz["name"] == "predict-fast" and wz["alerting"], wz
+        assert wz["burn"]["fast"] >= wz["alert_burn"]
+        assert wz["burn"]["slow"] >= wz["alert_burn"]
+        assert hz["samples"] >= 2 and not hz["alerting"], hz
+        assert (hz["burn"]["fast"] or 0.0) < hz["alert_burn"]
+    finally:
+        for b in boots:
+            if b.exception() is None:
+                b.result().proc.kill()
+                b.result().proc.wait(10)

@@ -14,7 +14,9 @@ Pins the two cost-per-token levers this PR adds:
   serving plumbing (kind-scoped routes, router kind-aware pick +
   re-pick, per-kind autoscaler signals) behaves.
 """
+import contextlib
 import json
+from concurrent.futures import ThreadPoolExecutor
 from urllib.error import HTTPError
 from urllib.request import Request, urlopen
 
@@ -43,7 +45,11 @@ from paddle_tpu.models import (
     truncated_draft,
 )
 from paddle_tpu.serving import GenerationServer, Router
-from paddle_tpu.serving.scaler import AutoScaler, FleetSignals
+from paddle_tpu.serving.scaler import (
+    AutoScaler,
+    FleetSignals,
+    launch_process,
+)
 
 CACHE = 24
 BUCKETS = (4, 8)
@@ -373,37 +379,76 @@ def test_decode_kind_server_generate_kv_parity(model):
         dec.stop(drain=False)
 
 
-def test_router_disagg_generate_end_to_end(model):
-    """Router-orchestrated prefill->decode /generate equals unified
-    output; /statz kinds and retry counters stay sane."""
-    ref = _engine(model, slots=1).warmup()
-    pre = GenerationServer(_engine(model, slots=1), kind="prefill",
-                           queue_capacity=4).start()
-    dec = GenerationServer(_engine(model, slots=2), kind="decode",
-                           queue_capacity=4).start()
-    router = Router(backends=[pre.url, dec.url]).start()
+@contextlib.contextmanager
+def _two_tiers(model, how, tmp_path):
+    """A prefill tier and a decode tier, as servers of this process or as
+    two ``paddle_tpu.serving.backend`` PROCESSES over a saved model."""
+    if how == "in_process":
+        pre = GenerationServer(_engine(model, slots=1), kind="prefill",
+                               queue_capacity=4).start()
+        dec = GenerationServer(_engine(model, slots=2), kind="decode",
+                               queue_capacity=4).start()
+        try:
+            yield pre.url, dec.url
+        finally:
+            pre.stop(drain=False)
+            dec.stop(drain=False)
+        return
+    gpt_dir = str(tmp_path / "gpt")
+    save_gpt_model(model, gpt_dir)
+    common = ["--gpt-dir", gpt_dir, "--cache-len", str(CACHE),
+              "--prefill-buckets", ",".join(map(str, BUCKETS))]
+    with ThreadPoolExecutor(2) as pool:  # the two warm-ups side by side
+        boots = [pool.submit(launch_process, "paddle_tpu.serving.backend",
+                             ["--kind", kind, *common, "--slots", slots],
+                             startup_timeout_s=120.0)
+                 for kind, slots in (("prefill", "1"), ("decode", "2"))]
     try:
-        prompt = [3, 7, 2]
-        want = ref.generate([prompt], max_new_tokens=6, temperature=0.0,
-                            stop_at_eos=False)[0]
-        body = json.dumps({"prompt": prompt, "max_new_tokens": 6,
-                           "temperature": 0.0}).encode()
-        out = json.loads(urlopen(
-            Request(router.url + "/generate", data=body),
-            timeout=60).read())
-        assert out["tokens"] == want
-        # streaming survives both hops
-        body = json.dumps({"prompt": prompt, "max_new_tokens": 6,
-                           "temperature": 0.0, "stream": True}).encode()
-        lines = [json.loads(line) for line in urlopen(
-            Request(router.url + "/generate", data=body),
-            timeout=60).read().decode().splitlines()]
-        toks = [ln["token"] for ln in lines if "token" in ln]
-        assert toks == want and lines[-1].get("done")
+        yield tuple(b.result().url for b in boots)
     finally:
-        router.stop(drain=False)
-        pre.stop(drain=False)
-        dec.stop(drain=False)
+        for b in boots:
+            if b.exception() is None:
+                b.result().proc.kill()
+                b.result().proc.wait(10)
+
+
+@pytest.mark.parametrize("how", ["in_process", "two_processes"])
+def test_router_disagg_generate_end_to_end(model, how, tmp_path):
+    """Router-orchestrated prefill->decode /generate equals unified
+    output, through servers of this process and through two backend
+    processes handing the KV slab over HTTP; neither tier compiles
+    anything its warm-up did not."""
+    ref = _engine(model, slots=1).warmup()
+    with _two_tiers(model, how, tmp_path) as (pre_url, dec_url):
+        router = Router(backends=[pre_url, dec_url]).start()
+        try:
+            for url, kind in ((pre_url, "prefill"), (dec_url, "decode")):
+                hz = json.loads(urlopen(url + "/healthz", timeout=10).read())
+                assert hz["kind"] == kind
+            prompt = [3, 7, 2]
+            want = ref.generate([prompt], max_new_tokens=6,
+                                temperature=0.0, stop_at_eos=False)[0]
+            body = json.dumps({"prompt": prompt, "max_new_tokens": 6,
+                               "temperature": 0.0}).encode()
+            out = json.loads(urlopen(
+                Request(router.url + "/generate", data=body),
+                timeout=60).read())
+            assert out["tokens"] == want
+            assert out["prompt_tokens"] == len(prompt)
+            # streaming survives both hops
+            body = json.dumps({"prompt": prompt, "max_new_tokens": 6,
+                               "temperature": 0.0,
+                               "stream": True}).encode()
+            lines = [json.loads(line) for line in urlopen(
+                Request(router.url + "/generate", data=body),
+                timeout=60).read().decode().splitlines()]
+            toks = [ln["token"] for ln in lines if "token" in ln]
+            assert toks == want and lines[-1].get("done")
+            for url in (pre_url, dec_url):
+                lz = json.loads(urlopen(url + "/loadz", timeout=10).read())
+                assert lz["compiles"]["unexpected"] == 0
+        finally:
+            router.stop(drain=False)
 
 
 def test_disagg_needs_both_tiers_else_unified(model):

@@ -737,6 +737,60 @@ def test_router_retry_preserves_trace_id_across_attempts():
     assert carried.span_id == ok[0]["span_id"]
 
 
+def test_one_trace_id_spans_router_and_a_backend_process(model_dir):
+    """A real backend PROCESS behind the in-process router: the backend's
+    ``/tracez`` holds the router's trace_id, its ``serving::predict``
+    root hangs under the router's ``serving::attempt`` span, and the
+    dispatch span carries the cache disposition, the cost-model FLOPs
+    and a link to the member trace."""
+    from paddle_tpu.serving.scaler import launch_process
+
+    backend = launch_process(
+        "paddle_tpu.serving.backend",
+        ["--model-dir", model_dir, "--port", "0", "--buckets", "1,2",
+         "--batch-timeout-ms", "1"], startup_timeout_s=120.0)
+    router = Router(backends=[backend.url], probe_interval_s=30.0).start()
+    try:
+        # the first finished trace of a sampling window is always kept,
+        # on both sides of the hop
+        status, _ = _http_json(router.url + "/predict",
+                               {"inputs": _rand(2).tolist()})
+        assert status == 200
+        # a root finishes after its reply is on the wire: wait for it
+        deadline = time.monotonic() + 5
+        mine = []
+        while not mine and time.monotonic() < deadline:
+            mine = [t for t in tracing.store().summaries()
+                    if t["root"] == "serving::router"]
+            time.sleep(0.01)
+        assert mine
+        tid = mine[0]["trace_id"]
+        spans = tracing.store().get(tid)["spans"]
+        root = [s for s in spans if s["name"] == "serving::router"][0]
+        attempts = [s for s in spans if s["name"] == "serving::attempt"]
+        assert attempts and attempts[0]["parent_id"] == root["span_id"]
+        assert attempts[0]["attrs"]["status"] == 200
+        status = None
+        while status != 200 and time.monotonic() < deadline:
+            status, theirs = _http_json(backend.url + f"/tracez?id={tid}")
+            time.sleep(0.01)
+        assert status == 200 and theirs["trace_id"] == tid
+        by_name = {s["name"]: s for s in theirs["spans"]}
+        assert {"serving::predict", "serving::queue_wait",
+                "serving::assemble", "serving::dispatch"} <= set(by_name)
+        assert by_name["serving::predict"]["parent_id"] \
+            == attempts[0]["span_id"]
+        disp = by_name["serving::dispatch"]
+        assert disp["attrs"]["plan_cache"] in ("hit", "miss")
+        assert disp["attrs"]["jit_cache"] in ("hit", "miss")
+        assert disp["attrs"]["flops"] > 0
+        assert any(l["trace_id"] == tid for l in disp["links"])
+    finally:
+        router.stop(drain=False)
+        backend.proc.kill()
+        backend.proc.wait(10)
+
+
 def test_router_timeout_records_orphaned_attempt_span():
     """The satellite fix: a read-timeout 504 must leave a per-attempt
     record naming the backend that swallowed the request."""
