@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..errors import UnavailableError
+from ..nn.sparse_attention import SparseCache, SparseConfig
 from ..nn.transformer import (ContinuedCache, LatentCache,
                               QuantizedStaticCache, RecurrentCache,
                               StaticCache, update_slice_in_range)
@@ -57,7 +58,8 @@ __all__ = [
     "fresh_layer_caches", "cache_nbytes",
     "kv_bytes_per_token", "decode_mask", "prefill_mask", "verify_mask",
     "pad_slot_arrays",
-    "kv", "state", "latent", "KVKind", "StateKind", "LatentKind",
+    "kv", "state", "latent", "sparse_kv", "KVKind", "StateKind",
+    "LatentKind", "SparseKVKind",
     "is_layer_kinds",
     "init_kinds_cache", "kinds_layer_caches", "unzip_kinds_caches",
     "kinds_slot_nbytes", "kinds_bytes_per_token", "kinds_ring_lengths",
@@ -180,7 +182,9 @@ def kv_bytes_per_token(num_layers, num_heads, head_dim,
 #
 # A model whose layers do not all keep the same thing per slot (softmax
 # attention beside a recurrence) answers ``cache_spec()`` with a list,
-# one kind a layer. The whole-model cache is then
+# one kind a layer, of the four below: a K/V ring, a constant state, a
+# latent ring, a K/V ring with a ring of pooled keys beside it. The
+# whole-model cache is then
 # ``(layer_0_arrays, ..., layer_{L-1}_arrays, pos)``: per layer the tuple
 # of that kind's arrays, every one with the slot axis first, and the one
 # shared ``pos [B]`` last, as in the all-alike tuples above. It is still
@@ -193,7 +197,11 @@ def kv_bytes_per_token(num_layers, num_heads, head_dim,
 # The rings of one cache may differ in length (a layer that attends a
 # window keeps the window's rows): every ring is written at ``pos mod
 # its own length`` and read under the decode mask of that length
-# (:func:`kinds_decode_mask`), all from the one ``pos``. A model may
+# (:func:`kinds_decode_mask`), all from the one ``pos``. What a decode
+# step reads of a ring is what the layer's step names
+# (``rows_read``: every live row, or the rows of the blocks a sparse
+# layer chose) and what it brings from HBM for that what its
+# implementation fetches (``rows_fetched``). A model may
 # list more kinds than it has layers (two attentions a layer: two
 # rings), in the order its forward consumes them. A kind also says
 # whether its layer can take a prompt up again from what the slot holds
@@ -226,9 +234,15 @@ class KVKind(NamedTuple):
         return int(store) if self.window is None \
             else min(self.window, int(store))
 
+    def rows_read(self, live):
+        """Ring rows a decode step's attention has to read a slot, for
+        ``live [S]`` live rows a slot: what the layer's step names,
+        which for plain attention is every live row."""
+        return live
+
     def rows_fetched(self, live, store, dtype):
-        """Ring rows a decode step's attention brings from HBM a slot,
-        for ``live [S]`` live rows a slot: the ring is read whole."""
+        """Ring rows that step brings from HBM a slot: XLA's attention
+        over a ring reads it whole and masks."""
         return np.full_like(live, self.ring(store))
 
     def arrays(self, batch, store, dtype):
@@ -249,12 +263,14 @@ class KVKind(NamedTuple):
 
 
 class StateKind(NamedTuple):
-    """A recurrent layer: per slot the arrays of ``shapes`` / ``dtypes``
-    (state, then convolution tail: :class:`nn.RecurrentCache`), none
-    with a cache-length axis."""
+    """A recurrent layer: per slot the arrays of ``shapes`` / ``dtypes``,
+    none with a cache-length axis, handed to the layer as ``cache``
+    (state, then convolution tail: :class:`nn.RecurrentCache`; a state
+    alone: :class:`nn.StateCache`)."""
 
     shapes: tuple
     dtypes: tuple
+    cache: type = RecurrentCache
 
     #: a chunk boundary would be a state hand-over and a convolution
     #: tail, and a decode step between two chunks would advance a
@@ -269,7 +285,7 @@ class StateKind(NamedTuple):
         return None
 
     def wrap(self, arrays, pos):
-        return RecurrentCache(*arrays, pos)
+        return self.cache(*arrays, pos)
 
     def bytes_per_token(self, dtype):
         return 0
@@ -296,6 +312,11 @@ class LatentKind(NamedTuple):
 
     def ring(self, store):
         return int(store)
+
+    def rows_read(self, live):
+        """Ring rows a decode step's attention has to read a slot: every
+        live row."""
+        return live
 
     def rows_fetched(self, live, store, dtype):
         """Ring rows a decode step's attention brings from HBM a slot,
@@ -328,6 +349,100 @@ class LatentKind(NamedTuple):
         return int(store) * self.row_nbytes(dtype)
 
 
+class SparseKVKind(NamedTuple):
+    """A block-sparse attention layer (:class:`nn.SparseGQAttention`): a
+    ``[B, heads, store, head_dim]`` K and V ring and beside them a ring
+    of pooled keys, one row for every ``sparse.stride`` positions, which
+    the layer's queries choose their blocks from
+    (:class:`nn.SparseCache`). A position is a block address: the ring
+    is ``store`` rows long, a whole number of blocks, and does not wrap;
+    past its end every new token takes the last row's place
+    (``nn/sparse_attention.py``), and a server keeps a request's
+    positions inside the ring."""
+
+    heads: int
+    head_dim: int
+    sparse: SparseConfig
+
+    #: a chunk would have to make its queries' choices against pooled
+    #: rows of the chunks before it and attend blocks of the ring:
+    #: neither is written, and the state layers beside it refuse anyway
+    continues = False
+    #: a full-length ring: the engine counts it with the K/V rings that
+    #: have no window (``GenerationEngine._kind_place``)
+    window = None
+
+    def ring(self, store):
+        return int(store)
+
+    def _newest(self, live):
+        return np.maximum(np.asarray(live, np.int64) - 1, 0)
+
+    def blocks_live(self, live):
+        """Blocks that hold a live row, for ``live [S]`` rows a slot."""
+        return self._newest(live) // self.sparse.block + 1
+
+    def blocks_read(self, live):
+        """Blocks a decode step attends a slot: every live one under
+        ``dense_len``, else ``topk`` of them (fewer if fewer live)."""
+        have = self.blocks_live(live)
+        return np.where(np.asarray(live) < self.sparse.dense_len, have,
+                        np.minimum(have, self.sparse.topk))
+
+    def pooled_read(self, live):
+        """Pooled rows a decode step scores a slot: none under
+        ``dense_len``, else every one that exists."""
+        c = self.sparse
+        rows = np.maximum(self._newest(live) - (c.kernel - 1), -1) \
+            // c.stride + 1
+        return np.where(np.asarray(live) < c.dense_len, 0, rows)
+
+    def rows_read(self, live):
+        """Ring rows a decode step has to read a slot, in K/V rows (a
+        pooled row is a key alone: half a row): the attended blocks'
+        live rows (the newest block is full as far as the step's own
+        position) and the pooled rows scored."""
+        t = self._newest(live)
+        c = self.sparse
+        rows = (self.blocks_read(live) - 1) * c.block + t % c.block + 1
+        return rows + (self.pooled_read(live) + 1) // 2
+
+    def rows_fetched(self, live, store, dtype):
+        """Ring rows the step brings from HBM a slot, in K/V rows: the
+        gather takes ``sparse.gather_blocks`` whole blocks whatever the
+        slot uses of them, and the selection scores the pooled ring
+        whole and masks."""
+        c = self.sparse
+        blocks = min(c.gather_blocks, int(store) // c.block)
+        return np.full_like(live, blocks * c.block
+                            + int(store) // c.stride // 2)
+
+    def arrays(self, batch, store, dtype):
+        if int(store) % self.sparse.block:
+            raise ValueError(f"a ring of {store} rows is no whole number "
+                             f"of blocks of {self.sparse.block}")
+        shape = (int(batch), self.heads, int(store), self.head_dim)
+        pooled = shape[:2] + (int(store) // self.sparse.stride, shape[3])
+        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
+                jnp.zeros(pooled, dtype))
+
+    def wrap(self, arrays, pos):
+        return SparseCache(*arrays, pos)
+
+    def row_nbytes(self, dtype):
+        return 2 * self.heads * self.head_dim * jnp.dtype(dtype).itemsize
+
+    def bytes_per_token(self, dtype):
+        """A K and a V row, and a sixteenth (1 / stride) of a pooled
+        row."""
+        return self.row_nbytes(dtype) + -(-self.row_nbytes(dtype) // (
+            2 * self.sparse.stride))
+
+    def slot_nbytes(self, store, dtype):
+        return int(store) * self.row_nbytes(dtype) + (
+            int(store) // self.sparse.stride) * self.row_nbytes(dtype) // 2
+
+
 def kv(heads, head_dim, window=None):
     """The kind of a layer that keeps K/V rows for ``heads`` K/V heads:
     as many as the cache is long, or the last ``window`` of them."""
@@ -335,10 +450,11 @@ def kv(heads, head_dim, window=None):
                   None if window is None else int(window))
 
 
-def state(shapes, dtypes):
-    """The kind of a layer that keeps a constant per-slot state."""
+def state(shapes, dtypes, cache=RecurrentCache):
+    """The kind of a layer that keeps a constant per-slot state, in the
+    named tuple ``cache`` (its arrays, then ``pos``)."""
     return StateKind(tuple(tuple(int(n) for n in s) for s in shapes),
-                     tuple(str(d) for d in dtypes))
+                     tuple(str(d) for d in dtypes), cache)
 
 
 def latent(rank, rope):
@@ -347,10 +463,18 @@ def latent(rank, rope):
     return LatentKind(int(rank), int(rope))
 
 
+def sparse_kv(heads, head_dim, sparse):
+    """The kind of a block-sparse attention layer: K/V rows for
+    ``heads`` K/V heads and the pooled keys its ``sparse`` sizes
+    (:class:`nn.SparseConfig`) ask."""
+    return SparseKVKind(int(heads), int(head_dim),
+                        SparseConfig(*(int(n) for n in sparse)).check())
+
+
 def is_layer_kinds(spec):
     """Is this ``cache_spec()`` a per-layer list of kinds (and not the
     ``(layers, heads, head_dim)`` of a model whose layers are alike)?"""
-    return all(isinstance(k, (KVKind, StateKind, LatentKind))
+    return all(isinstance(k, (KVKind, StateKind, LatentKind, SparseKVKind))
                for k in spec) and len(spec) > 0
 
 

@@ -164,16 +164,21 @@ class _KeptState:
 class DecodeStep:
     """One enqueued decode step (:meth:`GenerationEngine.enqueue_step`):
     its ``[S]`` tokens and routing statistics, still on the device, the
-    ring rows it had to read and those it brought from HBM, counted on
-    the host as it was enqueued (only while the profiler is on), and the
-    program that computes it (its :class:`flight_recorder.PhaseRing`:
+    ring rows it had to read and those it brought from HBM (and, where
+    layers choose their blocks, :meth:`GenerationEngine.sparse_blocks`),
+    counted on the host as it was enqueued (only while the profiler is
+    on), and the program that computes it (its
+    :class:`flight_recorder.PhaseRing`:
     the fetch is noted there)."""
 
-    __slots__ = ("tokens", "stats", "rows_read", "rows_fetched", "program")
+    __slots__ = ("tokens", "stats", "rows_read", "rows_fetched", "program",
+                 "sparse")
 
-    def __init__(self, tokens, stats, rows_read, rows_fetched, program):
+    def __init__(self, tokens, stats, rows_read, rows_fetched, program,
+                 sparse=None):
         self.tokens, self.stats, self.rows_read = tokens, stats, rows_read
         self.rows_fetched, self.program = rows_fetched, program
+        self.sparse = sparse
 
 
 class Admission:
@@ -1515,7 +1520,7 @@ class GenerationEngine:
             self._admission = None
 
     def _sample_stats(self, stats, rows_read=None, rows_fetched=None,
-                      program=None):
+                      program=None, sparse=None):
         """While the profiler is on, fetch the routing statistics a
         program returned and put them on its timeline as counter
         samples: ``moe::expert_load`` (per held expert, prompt and
@@ -1527,7 +1532,10 @@ class GenerationEngine:
         hold, of :meth:`cache_nbytes`), ``generation::kv_rows_read`` and
         ``generation::kv_rows_fetched`` (``rows_read``, ``rows_fetched``:
         :meth:`kv_rows_read` and :meth:`kv_rows_fetched` as the step was
-        enqueued; ``None`` for a prompt). The whole of it is the span
+        enqueued; ``None`` for a prompt), and where layers choose their
+        blocks ``sparse::blocks_read``, ``sparse::blocks_live`` and
+        ``sparse::slots_dense`` (``sparse``: :meth:`sparse_blocks`). The
+        whole of it is the span
         ``generation::stats_fetch``. Off, the arrays are dropped where
         they lie: no transfer, one boolean."""
         if not _profiler_enabled():
@@ -1551,6 +1559,10 @@ class GenerationEngine:
             _record_counter("generation::kv_rows_read", list(rows_read))
             _record_counter("generation::kv_rows_fetched",
                             list(rows_fetched))
+            if sparse is not None:
+                for name, value in zip(("blocks_read", "blocks_live",
+                                        "slots_dense"), sparse):
+                    _record_counter("sparse::" + name, value)
         # a sibling of the fetch spans: the transfer is the loop
         # thread's time (3-4 ms an iteration on the chip), and only
         # spent while the profiler is on
@@ -1589,20 +1601,41 @@ class GenerationEngine:
     def kv_rows_read(self):
         """``(full-length K/V layers, window layers, latent layers)``:
         the ring rows a decode step at the host's copy of ``pos`` has to
-        read, summed over slots and layers: ``min(pos + 1, ring)`` a
-        slot and layer, the row the step writes included. A vacant slot
-        counts with the position it was left at: the step computes it
-        too."""
-        return self._ring_rows(lambda kind, live: live)
+        read, summed over slots and layers: what each layer's step names
+        (``kind.rows_read``), which is ``min(pos + 1, ring)`` a slot and
+        layer, the row the step writes included, and for a layer that
+        chooses its blocks the chosen blocks' rows and the pooled rows
+        it scores (first place). A vacant slot counts with the position
+        it was left at: the step computes it too."""
+        return self._ring_rows(lambda kind, live: kind.rows_read(live))
 
     def kv_rows_fetched(self):
         """:meth:`kv_rows_read`'s three places, holding the ring rows
         that step's attention brings from HBM
         (``kind.rows_fetched``): the whole ring a slot and layer where
         XLA reads it, the live rows rounded up to whole key blocks where
-        the latent decode kernel runs."""
+        the latent decode kernel runs, the blocks a sparse layer's
+        gather takes and its pooled ring."""
         return self._ring_rows(lambda kind, live: kind.rows_fetched(
             live, self.store_len, self.kv_cache_dtype))
+
+    def sparse_blocks(self):
+        """``(blocks read, blocks live, slots under dense_len)`` of a
+        decode step at the host's copy of ``pos``: the blocks the layers
+        that choose theirs attend and the blocks that hold a live row,
+        each summed over slots and such layers (the count read is a
+        function of the length alone), and the slots that still attend
+        everything. ``None`` where no layer chooses."""
+        kinds = [k for k in self._kinds or ()
+                 if isinstance(k, _cache.SparseKVKind)]
+        if not kinds:
+            return None
+        with self._key_lock:
+            pos = self._pos_host.copy()
+        live = [np.minimum(pos + 1, k.ring(self.store_len)) for k in kinds]
+        return (int(sum(k.blocks_read(n).sum() for k, n in zip(kinds, live))),
+                int(sum(k.blocks_live(n).sum() for k, n in zip(kinds, live))),
+                int((live[0] < kinds[0].sparse.dense_len).sum()))
 
     def _ring_rows(self, rows):
         """``rows(kind, live [S])`` summed over slots and ring layers by
@@ -2228,12 +2261,13 @@ class GenerationEngine:
         t0 = time.perf_counter_ns()
         with RecordEvent("generation::decode"):
             out = self._dispatch(*self._decode_call(tokens, temps, ctr))
-        stats = rows_read = rows_fetched = None
+        stats = rows_read = rows_fetched = sparse = None
         if self._kinds is not None:
             self._kv, nxt, stats = out
             if _profiler_enabled():
                 rows_read = self.kv_rows_read()  # before pos moves on
                 rows_fetched = self.kv_rows_fetched()
+                sparse = self.sparse_blocks()
             with self._key_lock:
                 self._pos_host += 1
         else:
@@ -2245,7 +2279,7 @@ class GenerationEngine:
         self._note_phase("generation::decode", t0,
                          time.perf_counter_ns() - t0)
         return DecodeStep(nxt, stats, rows_read, rows_fetched,
-                          self._program)
+                          self._program, sparse)
 
     def fetch_step(self, step) -> np.ndarray:
         """The second half of :meth:`step`: the tokens of an enqueued
@@ -2256,7 +2290,7 @@ class GenerationEngine:
                             np.asarray, step.program)
         if self._kinds is not None:
             self._sample_stats(step.stats, step.rows_read,
-                               step.rows_fetched, step.program)
+                               step.rows_fetched, step.program, step.sparse)
         return nxt
 
     def step(self, tokens, temps) -> np.ndarray:

@@ -59,6 +59,10 @@ from .nemotron_h import (  # noqa: F401
     NemotronHConfig,
     NemotronHForCausalLM,
 )
+from .minicpm_sala import (  # noqa: F401
+    MiniCPMSALAConfig,
+    MiniCPMSALAForCausalLM,
+)
 from .se_resnext import (  # noqa: F401
     SEResNeXt,
     se_resnext50_32x4d,

@@ -138,6 +138,15 @@ class RecurrentCache(NamedTuple):
     pos: Any
 
 
+class StateCache(NamedTuple):
+    """Per-slot state of ONE recurrent layer that keeps nothing else
+    (:class:`nn.LightningAttention`: no convolution, so no tail):
+    ``state [B, H, Dk, Dv]`` float32 and the shared ``pos [B]``."""
+
+    state: Any
+    pos: Any
+
+
 class LatentCache(NamedTuple):
     """Ring cache of ONE latent-attention layer: ``c`` is ``[B, C, rank
     + rope]``, one row a token and no head axis: the normalised latent
